@@ -1,6 +1,6 @@
-"""Compose SWEEP_r05.md from the JSONL emitted by benchmarks/sweep_sf.py.
+"""Compose a sweep report from the JSONL emitted by benchmarks/sweep_sf.py.
 
-Usage: python benchmarks/compose_sweep_md.py [--in .sweep_r05.jsonl] [--out SWEEP_r05.md]
+Usage: python benchmarks/compose_sweep_md.py [--inp sweep.jsonl] [--out SWEEP.md]
 """
 
 from __future__ import annotations
@@ -12,8 +12,8 @@ from collections import defaultdict
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--inp", default="/root/repo/.sweep_r05.jsonl")
-    ap.add_argument("--out", default="/root/repo/SWEEP_r05.md")
+    ap.add_argument("--inp", default="sweep.jsonl")
+    ap.add_argument("--out", default="SWEEP.md")
     args = ap.parse_args()
 
     rows = [json.loads(l) for l in open(args.inp) if l.strip()]

@@ -12,7 +12,8 @@ Usage:
                                   [--queries q1,q3,...] [--out sweep.jsonl]
 
 Each completed (tier, query) appends one JSON line so an interrupted sweep
-still reports; compose SWEEP_r05.md from the JSONL afterwards.
+still reports; compose the report from the JSONL afterwards
+(benchmarks/compose_sweep_md.py).
 """
 
 from __future__ import annotations
@@ -31,9 +32,9 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
     ).strip()
-# spec-load the shared host-env helper: a package import HERE would run
-# __init__ before DFTPU_COMPILE_CACHE below exists, and __init__ reads
-# that env var exactly once
+# spec-load the shared host-env helper: a package import HERE would import
+# jax, which reads JAX_COMPILATION_CACHE_DIR (exported below) exactly once,
+# as it is imported
 import importlib.util as _ilu
 
 _spec = _ilu.spec_from_file_location(
@@ -51,25 +52,14 @@ _hostenv.ensure_collective_timeout_flags()
 # the same 66+ stage/mesh programs (mesh q1 reload: 21 s -> 4.4 s).
 # Fingerprinted per CPU like tests/conftest.py: XLA:CPU AOT entries embed
 # host machine features, and loading them on a different host risks SIGILL.
-if "DFTPU_COMPILE_CACHE" not in os.environ:
-    os.environ["DFTPU_COMPILE_CACHE"] = os.path.join(
+# jax reads the variable itself, so it must be exported before its first
+# import.
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
         os.path.expanduser("~"), ".cache",
         f"dftpu_sweep_xla_{_hostenv.cpu_fingerprint()}",
     )
-    os.makedirs(os.environ["DFTPU_COMPILE_CACHE"], exist_ok=True)
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
-# Aged-process guard: the cache-WRITE budget now lives in the package
-# (__init__.py, behind DFTPU_COMPILE_CACHE_WRITES) so every long-lived
-# process is protected; the sweep just opts in before the package import
-# below. DFTPU_SWEEP_CACHE_WRITES kept as the sweep-specific alias.
-os.environ.setdefault(
-    "DFTPU_COMPILE_CACHE_WRITES",
-    os.environ.get("DFTPU_SWEEP_CACHE_WRITES", "150"),
-)
+    os.makedirs(os.environ["JAX_COMPILATION_CACHE_DIR"], exist_ok=True)
 
 QUERIES_DIR = "/root/reference/testdata/tpch/queries"
 
@@ -110,7 +100,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=13)
     ap.add_argument("--tiers", default="static,adaptive,mesh8")
     ap.add_argument("--queries", default=",".join(f"q{i}" for i in range(1, 23)))
-    ap.add_argument("--out", default="/root/repo/.sweep_r05.jsonl")
+    ap.add_argument("--out", default="sweep.jsonl")
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--tasks", type=int, default=8)
     ap.add_argument("--rlimit-gb", type=float, default=96.0,

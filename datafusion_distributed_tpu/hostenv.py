@@ -98,6 +98,24 @@ def ensure_collective_timeout_flags(warn_stuck_s: int = 120,
     os.environ["XLA_FLAGS"] = flags.strip()
 
 
+def compile_cache_dir() -> str:
+    """The directory jax's persistent compile cache uses, placing it when
+    the environment has not. With JAX_COMPILATION_CACHE_DIR set, jax reads
+    the variable itself and this configures nothing. Otherwise the cache
+    goes to ``<checkout>/.xla_cache``: a fixed path, because the path is
+    part of the cache key and a directory that moves never hits. Call
+    before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(checkout, ".xla_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def cpu_fingerprint() -> str:
     """Short stable id of the host CPU's feature set (x86: the
     /proc/cpuinfo flags line; elsewhere the platform processor string)."""

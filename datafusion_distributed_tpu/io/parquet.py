@@ -61,6 +61,30 @@ def schema_from_arrow(arrow_schema) -> Schema:
     )
 
 
+def _encode_sorted_dictionary(col, null_mask) -> tuple[np.ndarray, Dictionary]:
+    """Arrow string array -> (int32 codes, fresh SORTED Dictionary of its
+    non-null values); null rows get code 0 (their validity masks them).
+
+    Arrow hash-encodes the rows and only the DISTINCT values are sorted —
+    bytewise on UTF-8, which is code-point order, the order numpy and
+    Python compare strings in. Sorting all N rows as fixed-width unicode
+    (np.unique + searchsorted) cost 72 s for TPC-H SF1's l_comment alone."""
+    import pyarrow.compute as pc
+
+    enc = pc.dictionary_encode(col)
+    distinct = enc.dictionary
+    if len(distinct) == 0:
+        return (np.zeros(len(col), dtype=np.int32),
+                Dictionary(np.empty(0, dtype=object)))
+    order = pc.sort_indices(distinct).to_numpy()
+    rank = np.empty(len(order), dtype=np.int32)
+    rank[order] = np.arange(len(order), dtype=np.int32)
+    idx = pc.fill_null(enc.indices, 0).to_numpy(zero_copy_only=False)
+    codes = np.where(null_mask, rank[idx], 0).astype(np.int32, copy=False)
+    values = distinct.take(order).to_numpy(zero_copy_only=False)
+    return codes, Dictionary(values)
+
+
 def arrow_to_host_columns(
     arrow_table,
     dictionaries: Optional[dict[str, Dictionary]] = None,
@@ -98,8 +122,8 @@ def arrow_to_host_columns(
             col = col.combine_chunks()
         null_mask = np.asarray(col.is_valid())
         if f.dtype == DataType.STRING:
-            provided0 = dictionaries.get(f.name) if dictionaries else None
-            if pa.types.is_dictionary(col.type) and provided0 is None:
+            provided = dictionaries.get(f.name) if dictionaries else None
+            if pa.types.is_dictionary(col.type) and provided is None:
                 # wire fast path: a dictionary array arriving from
                 # encode_table carries a GC'd, SORTED dictionary — adopt it
                 # and its codes directly instead of decoding + re-uniquing
@@ -131,13 +155,15 @@ def arrow_to_host_columns(
                     continue
             if pa.types.is_dictionary(col.type):
                 col = col.cast(pa.string())
+            if provided is None:
+                data[f.name], dicts[f.name] = _encode_sorted_dictionary(
+                    col, null_mask
+                )
+                validity[f.name] = null_mask
+                continue
             values = np.asarray(col.to_numpy(zero_copy_only=False), dtype=object)
             strs = np.where(null_mask, values, "").astype(str)
-            provided = dictionaries.get(f.name) if dictionaries else None
-            if provided is not None:
-                d = provided
-            else:
-                d = Dictionary(np.unique(strs[null_mask]).astype(object))
+            d = provided
             # Vectorized encode: a sorted dictionary admits searchsorted with
             # an equality check for absent values; unsorted (caller-provided)
             # dictionaries fall back to the exact hash-map path.

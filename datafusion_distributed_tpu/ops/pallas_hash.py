@@ -35,10 +35,16 @@ Trade-off being measured (benchmarks/micro_bench.py hashbuild_* rows):
 
 The engine uses the XLA path by default; DFTPU_PALLAS=1 switches
 build_group_table's group-id assignment to this kernel where legal
-(single-device, table <= _MAX_TABLE_SLOTS). On CPU the kernel runs in
-interpret mode (correctness tests); perf claims are only meaningful on a
-real chip — the micro-bench prints both paths so BENCH notes can record
-the verdict either way.
+(single-device, table <= _MAX_TABLE_SLOTS).
+
+STATUS: interpret-only. All three kernels of this module
+(pallas_build_group_ids, pallas_global_hash_aggregate,
+pallas_multiway_probe) have only ever run in interpret mode on the CPU,
+where the correctness tests run them, and the TPU v5e compiler REFUSES
+each of them today with ``ValueError: Cannot store scalars to VMEM``
+(tests/test_tpu_compile.py holds the three strict xfails). DFTPU_PALLAS=1
+on a TPU therefore raises; there is no on-chip timing of them, and the
+trade-off above is a design argument, not a measurement.
 """
 
 from __future__ import annotations

@@ -94,7 +94,7 @@ class EventLog:
             try:
                 # the file object's write/flush are thread-safe enough
                 # for whole-line appends; a torn tail only costs the
-                # reader one line (bench.py's event reader tolerates it)
+                # reader one line
                 line.write(json.dumps(event) + "\n")
                 line.flush()
             except (OSError, ValueError):

@@ -66,6 +66,13 @@ _MESH_COMPILE_CACHE_CAP = max(int(os.environ.get("DFTPU_MESH_CACHE", "8")), 1)
 def make_mesh(num_tasks: Optional[int] = None, devices=None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
     n = num_tasks or len(devices)
+    if n > len(devices):
+        # devices[:n] would silently build a narrower mesh: a 4-task query
+        # on a one-chip host must say so, not run on one device
+        raise ValueError(
+            f"mesh of {n} tasks requested but only {len(devices)} "
+            "device(s) are available"
+        )
     return Mesh(np.asarray(devices[:n]), (AXIS,))
 
 

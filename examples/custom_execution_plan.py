@@ -25,8 +25,6 @@ if "host_platform_device_count" not in flags:
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa

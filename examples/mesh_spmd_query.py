@@ -11,20 +11,16 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-# DFTPU_EXAMPLE_DEVICE=tpu uses the real chips; default is the virtual mesh
-_DEVICE = os.environ.get("DFTPU_EXAMPLE_DEVICE", "cpu")
-if _DEVICE == "cpu":
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
+# JAX_PLATFORMS decides: unset, this runs on the CPU's virtual mesh;
+# JAX_PLATFORMS=tpu uses the real chips (the device-count flag is CPU-only)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+flags = os.environ.get("XLA_FLAGS", "")
+if "host_platform_device_count" not in flags:
+    os.environ["XLA_FLAGS"] = (
+        flags + " --xla_force_host_platform_device_count=8"
+    ).strip()
 
 import jax
-
-if _DEVICE == "cpu":
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import pyarrow as pa

@@ -24,10 +24,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 import pyarrow as pa
 

@@ -22,10 +22,6 @@ if "host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 import pyarrow as pa
 

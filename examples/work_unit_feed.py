@@ -14,10 +14,6 @@ import tempfile
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq

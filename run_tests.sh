@@ -4,10 +4,10 @@
 # Rationale: the full suite compiles several hundred XLA programs; on this
 # image the XLA:CPU backend segfaults once a single process has aged
 # through roughly ~600 compiles. Root-caused in round 5 by two
-# instrumented single-process runs (PYTHONFAULTHANDLER, .oneproc_*.log):
-# both died at the same ~59% point of tests/ (test_tpcds), once inside
-# persistent-cache serialization (put_executable_and_time) and once —
-# with cache writes disabled via DFTPU_TEST_CACHE_WRITES=0 — inside
+# instrumented single-process runs (PYTHONFAULTHANDLER; their logs are
+# in git history, deleted in PR 23): both died at the same ~59% point
+# of tests/ (test_tpcds), once inside persistent-cache serialization
+# (put_executable_and_time) and once — with cache writes disabled via DFTPU_TEST_CACHE_WRITES=0 — inside
 # backend_compile_and_load itself. Crash site moves, trigger point does
 # not: process-age heap corruption in this image's XLA:CPU, independent
 # of the compile cache, not reachable from library code. Every file

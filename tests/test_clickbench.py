@@ -22,7 +22,7 @@ _LOCAL_QUERIES_DIR = os.path.join(
     "benchmarks", "queries", "clickbench",
 )
 # the reference checkout when present, else the in-repo adapted set
-# (benchmarks/queries/clickbench/ — same fallback bench.py._qdir uses)
+# (benchmarks/queries/clickbench/)
 QUERIES_DIR = (_REF_QUERIES_DIR if os.path.isdir(_REF_QUERIES_DIR)
                else _LOCAL_QUERIES_DIR)
 ROWS = 20_000

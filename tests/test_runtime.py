@@ -251,6 +251,19 @@ def test_mesh_metrics_per_task():
     assert total == 800
 
 
+def test_make_mesh_refuses_more_tasks_than_devices():
+    import jax
+
+    from datafusion_distributed_tpu.runtime.mesh_executor import make_mesh
+
+    assert len(jax.devices()) == 8  # tests/conftest.py
+    assert make_mesh(8).shape["tasks"] == 8
+    # a silent devices[:n] slice would hide the device count (a 4-task
+    # query on a one-chip host running on one device without a word)
+    with pytest.raises(ValueError, match="16 tasks.*8 device"):
+        make_mesh(16)
+
+
 def test_observability_service():
     from datafusion_distributed_tpu.runtime.observability import (
         ObservabilityService,

@@ -130,6 +130,38 @@ def test_parquet_roundtrip(tmp_path):
     assert back.column("val").to_pylist()[1] is None
 
 
+@pytest.mark.parametrize("case", ["mixed", "all_null", "empty", "chunked"])
+def test_string_ingest_builds_sorted_dictionary(case):
+    """Arrow strings -> (codes, sorted dictionary): held to the plain
+    reference (sorted set of the non-null values, code = position)."""
+    import pyarrow as pa
+
+    from datafusion_distributed_tpu.io.parquet import arrow_to_host_columns
+
+    rng = np.random.default_rng(3)
+    pool = ["", "a", "B", "b", "ab", "é", "zé", "中", "~", " a",
+            "Z", "aa", "\U0001f600"]
+    if case == "all_null":
+        vals = [None] * 5
+    elif case == "empty":
+        vals = []
+    else:
+        vals = [pool[i] for i in rng.integers(0, len(pool), 400)]
+        for i in rng.integers(0, 400, 40):
+            vals[i] = None
+    col = pa.array(vals, type=pa.string())
+    if case == "chunked":
+        col = pa.chunked_array([col[:150], col[150:]])
+    data, validity, dicts, _ = arrow_to_host_columns(pa.table({"s": col}))
+    expected = sorted({v for v in vals if v is not None})
+    assert list(dicts["s"].values) == expected
+    assert dicts["s"].is_sorted()
+    assert data["s"].dtype == np.int32
+    assert list(validity["s"]) == [v is not None for v in vals]
+    assert [expected[c] if ok else None
+            for c, ok in zip(data["s"], validity["s"])] == vals
+
+
 def test_gather_with_nonzero_pattern():
     t = make_simple_table(n=6, capacity=8)
 
