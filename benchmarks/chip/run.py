@@ -1,0 +1,261 @@
+"""One run of one benchmark cell on the machine this is started on.
+
+    python benchmarks/chip/run.py --workload <cell> --seed <n> \
+        --seconds <s> --trace <0|1>
+
+One process, no children. ``main`` refuses to start without a TPU, or with
+fewer chips than the cell's configuration names, before any data is made.
+Everything that belongs to one cell, configuration, tier, suite, traffic
+loop or metric is a file of its own beside this one, found by name:
+
+    workloads/<cell>.json      config, traffic mix, why, who
+    configs/<config>.json      suite, scale, tier, its arguments, chips
+    traffic/<mix>.json         the loop's name and its parameters
+    traffic/<loop>.py          run(call, mix, seed, seconds, limit) -> records
+    tiers/<tier>.py            Tier(ctx, args, suite): run, run_traced, close
+    suites/<suite>/suite.py    load, sql, expected, compare, frame, least_bytes
+    metrics/<name>.py          read(record) -> number, or None
+    peaks.json                 device_kind -> published peaks
+    trace_reduce.py            .xplane.pb -> busy time, ops, idle gaps
+
+Which metrics a cell reports is read from BENCHMARK.json: its ``end_to_end``
+metrics with ``--trace 0``, its ``per_layer`` metrics with ``--trace 1``.
+The last line of stdout is the result; the lines before it are for audit.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import importlib.util
+import json
+import os
+import shutil
+import sys
+import time
+
+# set-up is counted from here: what lies before it is the interpreter's own
+# start and the few standard-library imports above; jax and the program are
+# imported inside the functions below
+_PROCESS_START = time.perf_counter()
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+CACHE = os.path.join(HERE, ".cache")
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def read_json(*parts: str) -> dict:
+    with open(os.path.join(HERE, *parts)) as f:
+        return json.load(f)
+
+
+def load_module(*parts: str):
+    path = os.path.join(HERE, *parts)
+    name = "chipbench_" + "_".join(parts).replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def emit(**line) -> None:
+    print(json.dumps(line), flush=True)
+
+
+def cell_metrics(cell: str, trace: bool) -> list:
+    """The cell's entries of BENCHMARK.json: every metric of the run's kind
+    that has no ``workloads`` key or lists the cell."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entries = json.load(f)["per_layer" if trace else "end_to_end"]
+    return [m for m in entries if cell in m.get("workloads", [cell])]
+
+
+def run_cell(cell: str, seed: int, seconds: float, trace: bool,
+             scale=None) -> dict:
+    """Set up the cell, measure one window, compare, reduce. -> the result
+    line as a dict. ``scale`` overrides the configuration's (the CPU
+    rehearsals under tests/ pass a tiny one); nothing here looks at the
+    device's platform."""
+    import jax
+
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from datafusion_distributed_tpu import hostenv
+    from datafusion_distributed_tpu.sql.context import SessionContext
+
+    clock = time.perf_counter
+    workload = read_json("workloads", f"{cell}.json")
+    config = read_json("configs", f"{workload['config']}.json")
+    mix = read_json("traffic", f"{workload['traffic']}.json")
+    suite = load_module("suites", config["suite"], "suite.py")
+    tier_module = load_module("tiers", f"{config['tier']}.py")
+    loop = load_module("traffic", f"{mix['loop']}.py")
+    scale = config["scale"] if scale is None else scale
+
+    # every program goes to the persistent cache, the small ones too, so
+    # that only a checkout's first run of a cell compiles
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    compile_cache_dir = hostenv.compile_cache_dir()
+    devices = jax.devices()[:config["chips"]]
+    emit(cell=cell, seed=seed, seconds=seconds, trace=int(trace), scale=scale,
+         platform=devices[0].platform, kind=devices[0].device_kind,
+         count=len(jax.devices()), chips=config["chips"],
+         compile_cache_dir=compile_cache_dir)
+
+    # -- set-up -----------------------------------------------------------
+    setup = {}
+    t = clock()
+    tables = suite.load(scale, seed, os.path.join(CACHE, "data"))
+    setup["generate_s"] = clock() - t
+    t = clock()
+    ctx = SessionContext()
+    for name, arrow in tables.items():
+        ctx.register_arrow(name, arrow)
+    setup["register_s"] = clock() - t
+    t = clock()
+    texts = {q: suite.sql(q) for q in mix["queries"]}
+    expected = {q: suite.expected(q, tables) for q in texts}
+    least_bytes = {q: suite.least_bytes(q, tables) for q in texts}
+    setup["oracle_s"] = clock() - t
+
+    def call(query: str) -> dict:
+        if not trace:
+            frame, retries = tier.run(texts[query])
+            return {"frame": frame, "retries": retries}
+        spans: dict = {}
+
+        @contextlib.contextmanager
+        def span(name: str):
+            start = clock()
+            with jax.profiler.TraceAnnotation(name):
+                yield
+            spans[name] = spans.get(name, 0.0) + clock() - start
+
+        with jax.profiler.TraceAnnotation("bench.query"):
+            frame, retries = tier.run_traced(texts[query], span)
+        return {"frame": frame, "retries": retries, "spans": spans}
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **_: compiles.append(event)
+        if event == COMPILE_EVENT else None)
+    trace_dir = os.path.join(CACHE, "trace", f"{cell}-seed{seed}")
+    tier = tier_module.Tier(ctx, config["tier_args"], suite)
+    try:
+        t = clock()
+        for query in texts:
+            tier.run(texts[query])  # compiles, or loads from the cache
+            call(query)             # warm, by the path the window takes
+        setup["warmup_s"] = clock() - t
+        compiled_in_setup = len(compiles)
+        if trace:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0  # our spans only: less host load
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
+        opened = clock()
+        setup["setup_s"] = opened - _PROCESS_START
+        records = loop.run(call, mix, seed, seconds,
+                           limit=mix.get("traced_queries", 3) if trace
+                           else None)
+        closed = clock()
+        compiles_in_window = len(compiles) - compiled_in_setup
+        if trace:
+            jax.profiler.stop_trace()
+    finally:
+        tier.close()
+
+    # -- after the window: compare, count, reduce ---------------------------
+    for r in records:
+        result = r.pop("result")
+        r["ok"] = False
+        if result is not None:
+            r["retries"], r["spans"] = result["retries"], result.get("spans")
+            try:
+                suite.compare(result["frame"], expected[r["query"]])
+                r["ok"] = True
+            except AssertionError as e:
+                emit(mismatch=r["query"], error=str(e)[:400])
+    stats = [d.memory_stats() or {} for d in devices]
+    kind = devices[0].device_kind
+    reduced = None
+    if trace:
+        reducer = load_module("trace_reduce.py")
+        reduced = reducer.reduce(reducer.load(reducer.find(trace_dir)))
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    record = {
+        "setup": setup,
+        "window_s": (records[-1]["end"] if records else closed) - opened,
+        "queries": records,
+        "compiles_in_window": compiles_in_window,
+        "peak_bytes": [s.get("peak_bytes_in_use") for s in stats],
+        "least_bytes": least_bytes,
+        "peaks": read_json("peaks.json").get(kind),
+        "trace": reduced,
+    }
+    metrics = {}
+    for entry in cell_metrics(cell, trace):
+        value = load_module("metrics", f"{entry['name']}.py").read(record)
+        if value is not None:
+            metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
+
+    done = [r for r in records if r["ok"]]
+    emit(setup=setup, window_s=record["window_s"], requested_s=seconds,
+         samples=len(records), agreed=len(done),
+         compiled_in_setup=compiled_in_setup,
+         compiles_in_window=compiles_in_window,
+         walls_s=[round(r["end"] - r["start"], 4) for r in records[:64]],
+         rows={name: t.num_rows for name, t in tables.items()})
+    device = {"platform": devices[0].platform, "kind": kind,
+              "count": len(jax.devices()),
+              "memory_peak_bytes": max(
+                  (p for p in record["peak_bytes"] if p), default=0)}
+    result = {"correct": bool(records) and len(done) == len(records),
+              "attempted": len(records),
+              "failed": len(records) - len(done),
+              "metrics": metrics, "device": device}
+    if reduced is not None:
+        emit(traced_queries=reduced["queries"], window_s=reduced["window_s"],
+             device_busy_s=reduced.get("device_busy_s", {}))
+        device["busy_s"] = reduced["busy_s"]
+        device["window_s"] = reduced["window_s"]
+        result["breakdown"] = {"device_ops": reduced["ops"][:10],
+                               "idle_gaps": reduced["gaps"][:10]}
+    return result
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    workload = read_json("workloads", f"{args.workload}.json")
+    chips = read_json("configs", f"{workload['config']}.json")["chips"]
+
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < chips:
+        print(f"run.py: {args.workload} needs {chips} TPU chip(s), found "
+              f"{len(devices)} {devices[0].platform} device(s)",
+              file=sys.stderr)
+        return 1
+    if devices[0].device_kind not in read_json("peaks.json"):
+        print(f"run.py: no peaks for device kind "
+              f"{devices[0].device_kind!r} in peaks.json", file=sys.stderr)
+        return 1
+    result = run_cell(args.workload, args.seed, args.seconds,
+                      bool(args.trace))
+    if args.trace and not result["device"]["busy_s"]:
+        print("run.py: no operation ran on the device in the traced window",
+              file=sys.stderr)
+        return 1
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
