@@ -15,10 +15,12 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from datafusion_distributed_tpu import spans
 from datafusion_distributed_tpu.ops.table import (
     Column,
     Dictionary,
     Table,
+    fetch_counters,
     round_up_pow2,
 )
 from datafusion_distributed_tpu.schema import DataType, Field, Schema
@@ -269,6 +271,17 @@ def table_to_arrow(table: Table, dictionary_gc: bool = False,
     narrow in tpu precision mode (FLOAT64 logical -> f32 device data), and
     a consumer inferring dtypes from the wire would otherwise disagree
     with a same-worker bypass pull of the identical table."""
+    if dictionary_gc:  # the wire shape is the codec's work, not a fetch
+        return _table_to_arrow(table, True, logical_metadata)
+    with spans.fetch_call(table) as call:
+        out = _table_to_arrow(table, False, logical_metadata)
+        if call.tracer.active:
+            call.span.set(**fetch_counters(table, out.num_rows))
+        return out
+
+
+def _table_to_arrow(table: Table, dictionary_gc: bool,
+                    logical_metadata: bool):
     import pyarrow as pa
 
     n = int(table.num_rows)

@@ -33,7 +33,7 @@ import numpy as np
 
 from datafusion_distributed_tpu import precision
 from datafusion_distributed_tpu.ops.hash import fold_payload, hash_columns
-from datafusion_distributed_tpu.ops.table import Column, Table
+from datafusion_distributed_tpu.ops.table import Column, Table, scoped
 from datafusion_distributed_tpu.schema import DataType
 
 _LANE = precision.LANE_INT
@@ -200,9 +200,10 @@ def build_group_table(
         resolved0, slot0, gid0, slot_keys0, slot_used0,
         jnp.asarray(0, dtype=jnp.int32),
     )
-    resolved, slot, gid, slot_keys, slot_used, _ = jax.lax.while_loop(
-        cond, body, state
-    )
+    with jax.named_scope("agg.claim"):
+        resolved, slot, gid, slot_keys, slot_used, _ = jax.lax.while_loop(
+            cond, body, state
+        )
     overflow = ~jnp.all(resolved)
     return _group_table_from_raw(
         gid, slot_keys, slot_used, overflow, key_cols, key_valids,
@@ -450,6 +451,7 @@ def _try_global_hash_aggregate(
     return packed, overflow
 
 
+@scoped("agg.global")
 def global_aggregate(table: Table, aggs: Sequence[AggSpec], mode: str = "single",
                      prec_flags: Optional[list] = None) -> Table:
     """Aggregation with no GROUP BY: one output row (capacity 8 keeps the
@@ -500,7 +502,15 @@ def _mean_shifted_seg_sum(vals, valid, seg_sum, group_counts):
 
 def _eval_agg(spec, table, gid, live, num_slots, mode, seg_sum,
               prec_flags=None):
-    """Produce the output column(s) for one AggSpec in the given mode."""
+    """Produce the output column(s) for one AggSpec in the given mode,
+    under the scope ``agg.reduce.<func>``."""
+    with jax.named_scope(f"agg.reduce.{spec.func}"):
+        return _eval_agg_columns(spec, table, gid, live, num_slots, mode,
+                                 seg_sum, prec_flags)
+
+
+def _eval_agg_columns(spec, table, gid, live, num_slots, mode, seg_sum,
+                      prec_flags):
     name = spec.output_name
     if spec.func == "count_star":
         if mode in ("final", "partial_reduce"):

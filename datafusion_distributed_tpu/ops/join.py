@@ -34,7 +34,7 @@ import numpy as np
 from datafusion_distributed_tpu import precision
 from datafusion_distributed_tpu.ops.aggregate import GroupTable, build_group_table
 from datafusion_distributed_tpu.ops.hash import fold_payload, hash_columns
-from datafusion_distributed_tpu.ops.table import Column, Table
+from datafusion_distributed_tpu.ops.table import Column, Table, scoped
 from datafusion_distributed_tpu.schema import DataType
 
 
@@ -60,6 +60,7 @@ def _fold_keys(cols, valids, lane_plan):
     return jnp.stack(lanes, axis=1)  # [N, lanes]
 
 
+@scoped("join.probe")
 def probe_group_table(
     gt_slot_keys_raw: jnp.ndarray,  # [H, lanes] LANE_INT (raw matrix)
     slot_used: jnp.ndarray,  # [H] bool
@@ -129,6 +130,7 @@ class BuildSide:
     has_null_key: jnp.ndarray  # scalar bool: any live build row had a null key
 
 
+@scoped("join.build")
 def build_join_table(
     build: Table,
     key_names: Sequence[str],
@@ -248,7 +250,18 @@ def hash_join(
         out_rows = match_count
     else:
         raise NotImplementedError(f"join type {join_type}")
+    with jax.named_scope("join.expand"):
+        return _expand_matches(
+            probe, build_side, join_type, out_capacity, probe_prefix,
+            build_prefix, out_rows, match_count, g_safe, table_overflow,
+        )
 
+
+def _expand_matches(probe, build_side, join_type, out_capacity,
+                    probe_prefix, build_prefix, out_rows, match_count,
+                    g_safe, table_overflow):
+    """`hash_join`'s CSR expansion: one output row a (probe row, matching
+    build row) pair, gathered from both sides."""
     cum = jnp.cumsum(out_rows)
     total = cum[-1] if out_rows.shape[0] > 0 else jnp.asarray(0, jnp.int32)
     starts = cum - out_rows

@@ -17,7 +17,7 @@ from dataclasses import dataclass
 
 import jax.numpy as jnp
 
-from datafusion_distributed_tpu.ops.table import Table
+from datafusion_distributed_tpu.ops.table import Table, scoped
 
 
 @dataclass(frozen=True)
@@ -27,6 +27,7 @@ class SortKey:
     nulls_first: bool = False
 
 
+@scoped("sort.permutation")
 def sort_permutation(table: Table, keys: list[SortKey]) -> jnp.ndarray:
     """[capacity] permutation: live rows in key order first, dead rows last.
 

@@ -21,6 +21,7 @@ is a **padded** columnar batch:
 
 from __future__ import annotations
 
+import functools
 import itertools
 import os
 from dataclasses import dataclass
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from datafusion_distributed_tpu import spans
 from datafusion_distributed_tpu.schema import DataType, Field, Schema
 
 # ---------------------------------------------------------------------------
@@ -280,6 +282,21 @@ jax.tree_util.register_pytree_node(
 )
 
 
+def scoped(name: str):
+    """Run the kernel under `jax.named_scope(name)`: every XLA op it
+    lowers to carries the name in its ``op_name`` metadata, which is where
+    a profile of the compiled program finds it again. Trace time only;
+    nothing at run time, and the optimized HLO differs in metadata alone.
+    The kernels' names (lower-case, dotted) are listed in PERF.md."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def kernel(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return kernel
+    return decorate
+
+
 # ---------------------------------------------------------------------------
 # Table
 # ---------------------------------------------------------------------------
@@ -399,10 +416,12 @@ class Table:
         d[name] = col
         return Table(tuple(d.keys()), tuple(d.values()), self.num_rows)
 
+    @scoped("table.gather")
     def gather(self, idx: jnp.ndarray, num_rows) -> "Table":
         cols = tuple(c.gather(idx) for c in self.columns)
         return Table(self.names, cols, jnp.asarray(num_rows, dtype=jnp.int32))
 
+    @scoped("table.compact")
     def compact(self, keep: jnp.ndarray) -> "Table":
         """Select rows where ``keep`` is True, packed to the front (jit-safe).
 
@@ -470,19 +489,22 @@ class Table:
     def to_pandas(self):
         import pandas as pd
 
-        n = int(self.num_rows)
-        cols = {}
-        for name, col in zip(self.names, self.columns):
-            vals = np.asarray(col.data[:n])
-            if col.dtype == DataType.STRING:
-                assert col.dictionary is not None
-                vals = col.dictionary.decode(vals)
-            s = pd.Series(vals)
-            if col.validity is not None:
-                mask = np.asarray(col.validity[:n])
-                s = s.where(pd.Series(mask), other=None)
-            cols[name] = s
-        return pd.DataFrame(cols)
+        with spans.fetch_call(self) as call:
+            n = int(self.num_rows)
+            cols = {}
+            for name, col in zip(self.names, self.columns):
+                vals = np.asarray(col.data[:n])
+                if col.dtype == DataType.STRING:
+                    assert col.dictionary is not None
+                    vals = col.dictionary.decode(vals)
+                s = pd.Series(vals)
+                if col.validity is not None:
+                    mask = np.asarray(col.validity[:n])
+                    s = s.where(pd.Series(mask), other=None)
+                cols[name] = s
+            if call.tracer.active:
+                call.span.set(**fetch_counters(self, n))
+            return pd.DataFrame(cols)
 
     def __repr__(self) -> str:
         cols = ", ".join(
@@ -569,6 +591,27 @@ def to_device(arr) -> jnp.ndarray:
     return jnp.asarray(arr)
 
 
+def fetch_counters(table: "Table", rows: int) -> dict:
+    """What a result fetch (`to_pandas`, `table_to_arrow`) moved: one
+    device-to-host pull for every buffer of ``table`` that lives on a
+    device (the row count, then each column's data and validity, cut to
+    ``rows``), and their bytes."""
+    pulled = [table.num_rows]
+    for col in table.columns:
+        pulled.append(col.data)
+        if col.validity is not None:
+            pulled.append(col.validity)
+    on_device = [b for b in pulled if isinstance(b, jax.Array)]
+    return {
+        "transfers": len(on_device),
+        "bytes": sum(
+            b.dtype.itemsize * (min(rows, b.shape[0]) if b.ndim else 1)
+            for b in on_device
+        ),
+        "rows": rows,
+    }
+
+
 def is_host_backed(table: Table) -> bool:
     """True when every buffer is a host numpy array and num_rows is
     concrete — the staging representation the view-based data plane can
@@ -592,16 +635,22 @@ def host_view(table: Table) -> Table:
         raise ValueError("host_view of a traced table")
     if is_host_backed(table):
         return table
-    cols = tuple(
-        Column(
-            np.asarray(c.data),
-            np.asarray(c.validity) if c.validity is not None else None,
-            c.dtype,
-            c.dictionary,
+    tr = spans.current()
+    with tr.span("d2h", "d2h") as sp:
+        cols = tuple(
+            Column(
+                np.asarray(c.data),
+                np.asarray(c.validity) if c.validity is not None else None,
+                c.dtype,
+                c.dictionary,
+            )
+            for c in table.columns
         )
-        for c in table.columns
-    )
-    return Table(table.names, cols, np.int32(int(table.num_rows)))
+        rows = int(table.num_rows)
+        if tr.active:
+            sp.set(bytes=spans.table_nbytes(table), rows=rows,
+                   capacity=table.capacity)
+        return Table(table.names, cols, np.int32(rows))
 
 
 def slice_view(table: Table, lo: int, count: int) -> Table:

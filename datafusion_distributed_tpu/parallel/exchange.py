@@ -32,9 +32,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from datafusion_distributed_tpu.ops.hash import hash_columns
-from datafusion_distributed_tpu.ops.table import Column, Table
+from datafusion_distributed_tpu.ops.table import Column, Table, scoped
 
 
+@scoped("exchange.shuffle")
 def shuffle_exchange(
     table: Table,
     key_names: Sequence[str],
@@ -206,6 +207,7 @@ def _order_encode(col: Column, ascending: bool, nulls_first: bool):
     return u
 
 
+@scoped("exchange.range")
 def range_shuffle_exchange(
     table: Table,
     keys,  # list[ops.sort.SortKey]
@@ -272,6 +274,7 @@ def range_shuffle_exchange(
     return _route_by_dest(table, dest, axis, num_tasks, per_dest_capacity)
 
 
+@scoped("exchange.broadcast")
 def broadcast_exchange(table: Table, axis: str, num_tasks: int) -> Table:
     """Replicate every task's rows to all tasks (build sides of broadcast
     joins — the reference's BroadcastExec + NetworkBroadcastExec pair)."""
@@ -292,6 +295,7 @@ def broadcast_exchange(table: Table, axis: str, num_tasks: int) -> Table:
     return _compact_with_mask(out, live_mask)
 
 
+@scoped("exchange.coalesce")
 def coalesce_exchange(table: Table, axis: str, num_tasks: int) -> Table:
     """N tasks -> one logical table (replicated on every task; the consumer
     stage usually runs at task count 1, others see identical data — SPMD).
@@ -299,6 +303,7 @@ def coalesce_exchange(table: Table, axis: str, num_tasks: int) -> Table:
     return broadcast_exchange(table, axis, num_tasks)
 
 
+@scoped("exchange.coalesce")
 def group_coalesce_exchange(
     table: Table, axis: str, num_tasks: int, num_consumers: int
 ) -> Table:

@@ -23,6 +23,7 @@ makes XLA shapes static (SURVEY.md §7 hard part (c)).
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 from dataclasses import dataclass, field as dc_field
@@ -32,12 +33,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from datafusion_distributed_tpu import spans
 from datafusion_distributed_tpu.ops.aggregate import AggSpec, hash_aggregate
 from datafusion_distributed_tpu.ops.sort import SortKey, limit_table, sort_table
 from datafusion_distributed_tpu.ops.table import (
     Column,
     Table,
     concat_tables,
+    is_host_backed,
     round_up_pow2,
 )
 from datafusion_distributed_tpu.plan.expressions import (
@@ -94,6 +97,36 @@ class ExecContext:
 
 
 _PRECISION_TAG = "precision!"
+
+# trace-time only: the pre-order position of every node of the plan being
+# traced on this thread (`traced_positions`), for `node_scope`
+_POSITIONS = threading.local()
+
+
+@contextlib.contextmanager
+def traced_positions(plan: "ExecutionPlan"):
+    """-> node_id -> pre-order position in ``plan``: the address that is
+    the same for fingerprint-equal plan copies, where node ids are minted
+    per object. Inside the ``with`` (the trace of the plan on this thread)
+    it is also what `node_scope` names a node by."""
+    pos_of = {
+        n.node_id: i for i, n in enumerate(plan.collect(lambda _n: True))
+    }
+    _POSITIONS.of = pos_of
+    try:
+        yield pos_of
+    finally:
+        _POSITIONS.of = {}
+
+
+def node_scope(node: "ExecutionPlan") -> str:
+    """The `jax.named_scope` of a plan node inside the compiled program:
+    ``<node class>.<pre-order position>`` (`HashAggregateExec.2`), never
+    a node id or anything else that differs between fingerprint-equal
+    plans; the bare class where no positions are set."""
+    pos = getattr(_POSITIONS, "of", {}).get(node.node_id)
+    name = type(node).__name__
+    return name if pos is None else f"{name}.{pos}"
 
 _NODE_COUNTER = itertools.count()
 
@@ -184,9 +217,11 @@ class ExecutionPlan:
 
     # -- execution ----------------------------------------------------------
     def execute(self, ctx: ExecContext) -> Table:
-        """Trace this operator; records the per-node output_rows metric
-        (the DataFusion baseline metric set analogue)."""
-        out = self._execute(ctx)
+        """Trace this operator under its scope (`node_scope`); records
+        the per-node output_rows metric (the DataFusion baseline metric
+        set analogue)."""
+        with jax.named_scope(node_scope(self)):
+            out = self._execute(ctx)
         ctx.record_metric(self, "output_rows", out.num_rows)
         return out
 
@@ -719,11 +754,6 @@ def execute_plan(
     shapes/dtypes are appended to the key here, so same-stage tasks with
     divergent trees or leaf shapes simply miss (they can no longer silently
     bind another stage's inputs)."""
-    from datafusion_distributed_tpu.plan.fingerprint import (
-        bound_params,
-        prepare_plan,
-    )
-
     # lock-while-compiling witness (runtime/lockcheck.py, opt-in via
     # DFTPU_LOCK_CHECK=1): entering the XLA trace/compile/execute entry
     # point with an engine lock held stalls every contender for seconds —
@@ -734,6 +764,73 @@ def execute_plan(
         _lockcheck.note_blocking("xla_compile")
 
     task = task or DistributedTaskContext()
+    tr = spans.current()
+    traces_before = _TRACE_STATS["traces"]
+    with tr.span("prepare", "prepare") as psp:
+        (fn, overflow_box, metric_names, first_call_gate, input_list,
+         params, cache) = _prepare_program(
+            plan, task, config, use_cache, shared_cache, shared_key, tr
+        )
+        psp.set(cache=cache)
+    # ends on the fetch of the flag vector: the sync the program already
+    # makes, so the span holds the device's work and adds no wait
+    with tr.span("execute", "execute") as xsp:
+        result = None
+        if first_call_gate is not None and not first_call_gate["warmed"]:
+            with first_call_gate["lock"]:
+                # double-check: threads that queued behind the creator
+                # must NOT execute under the gate (that would serialize
+                # the whole task wave) — only the creator's
+                # trace+compile+first-run is serialized; everyone else
+                # re-checks and runs concurrently
+                if not first_call_gate["warmed"]:
+                    result = fn(input_list, params)
+                    first_call_gate["warmed"] = True
+        if result is None:
+            result = fn(input_list, params)
+        out, flags, metric_vals = result
+        flags = np.asarray(flags)  # one fetch for both sentinel checks
+        if tr.active:
+            xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before)
+    any_overflow, any_precision = bool(flags[0]), bool(flags[1])
+    if check_overflow and any_overflow:
+        raise RuntimeError(
+            f"hash table overflow in plan (nodes: "
+            f"{[name for name, _ in overflow_box if not name.startswith(_PRECISION_TAG)]}); "
+            "re-plan with more slots"
+        )
+    if any_precision:
+        # deliberately does NOT contain the word "overflow": the session's
+        # capacity-retry loop must not retry this (a bigger hash table can't
+        # restore int32 exactness).
+        raise RuntimeError(
+            "int32 accumulator range exceeded in plan (nodes: "
+            f"{[name for name, _ in overflow_box if name.startswith(_PRECISION_TAG)]}); "
+            "run with DFTPU_PRECISION=x64 for 64-bit accumulation"
+        )
+    if metrics_store is not None:
+        # positions -> THIS submission's node ids (hoisting preserves the
+        # original ids, so callers can look metrics up on their own plan)
+        nodes = plan.collect(lambda _n: True)
+        node_metrics: dict = {}
+        for (pos, name), v in zip(metric_names, metric_vals):
+            if 0 <= pos < len(nodes):
+                node_metrics.setdefault(nodes[pos].node_id, {})[name] = int(v)
+        metrics_store.insert(task_label or f"task{task.task_index}", node_metrics)
+    return out
+
+
+def _prepare_program(plan, task, config, use_cache, shared_cache,
+                     shared_key, tr):
+    """`execute_plan`'s host work before the device starts (its
+    ``prepare`` span): hoist and fingerprint the plan, load the leaves,
+    find or make the jitted program. -> (fn, overflow_box, metric_names,
+    first_call_gate, input_list, params, "hit" | "miss")."""
+    from datafusion_distributed_tpu.plan.fingerprint import (
+        bound_params,
+        prepare_plan,
+    )
+
     # content-address the program: literal-hoisted plan + structural
     # fingerprint (None -> legacy object-identity keying). The hoisted
     # plan reuses the original's leaf objects, so leaf traversal order —
@@ -748,9 +845,18 @@ def execute_plan(
     # ids in its input pytree — leaf traversal order is the cross-copy
     # stable identity (fingerprint-equal trees traverse identically)
     leaf_ids = [leaf.node_id for leaf in leaves if hasattr(leaf, "load")]
-    input_list = [
-        leaf.load(task) for leaf in leaves if hasattr(leaf, "load")
-    ]
+    with tr.span("h2d", "h2d") as hsp:
+        input_list = [
+            leaf.load(task) for leaf in leaves if hasattr(leaf, "load")
+        ]
+        if tr.active:
+            # what an exchange left on the host crosses to the device
+            # with the jitted call (inside ``execute``); its size is
+            # known here, where the consumer's scans hand it over
+            staged = [t for t in input_list if is_host_backed(t)]
+            hsp.set(bytes=sum(spans.table_nbytes(t) for t in staged),
+                    rows=sum(int(t.num_rows) for t in staged),
+                    capacity=sum(t.capacity for t in staged))
 
     overflow_box: list = []
     metric_names: list = []
@@ -759,19 +865,17 @@ def execute_plan(
         _TRACE_STATS["traces"] += 1
         inp = dict(zip(leaf_ids, inp_list))
         ctx = ExecContext(task=task, inputs=inp, config=config or {})
-        with bound_params(param_vecs):
-            out = exec_target.execute(ctx)
-        overflow_box.clear()
-        overflow_box.extend(ctx.overflow_flags)
         # metric names are POSITION-addressed (pre-order traversal index),
         # not node-id-addressed: a fingerprint-shared program executes for
         # plan copies whose node ids differ from the creator's, and
         # fingerprint-equal trees traverse identically — the caller remaps
-        # positions to ITS plan's node ids at insert time
-        pos_of = {
-            n.node_id: i
-            for i, n in enumerate(exec_target.collect(lambda _n: True))
-        }
+        # positions to ITS plan's node ids at insert time. The operator
+        # scopes inside the program use the same address.
+        with traced_positions(exec_target) as pos_of, \
+                bound_params(param_vecs):
+            out = exec_target.execute(ctx)
+        overflow_box.clear()
+        overflow_box.extend(ctx.overflow_flags)
         metric_names.clear()
         metric_names.extend(
             (pos_of.get(nid, -1), name) for nid, name, _ in ctx.metrics
@@ -819,6 +923,7 @@ def execute_plan(
     # TTL'd stage-share cache instead) keeps one-shot programs out of the
     # global cache so their closures don't pin shipped task tables.
     cached = None
+    cache = "hit"
     if use_cache:
         with _CACHE_LOCK:
             cached = _COMPILE_CACHE.get(cache_key)
@@ -848,6 +953,7 @@ def execute_plan(
         with _SHARED_LOCK:
             cached = shared_cache.get(skey)
             if cached is None:
+                cache = "miss"
                 _SHARED_STATS["miss"] += 1
                 # entry cap: each entry's closure pins its creator task's
                 # decoded plan (incl. device tables) until the query slot's
@@ -867,6 +973,7 @@ def execute_plan(
         first_call_gate = cached[3]
         cached = cached[:3]
     if cached is None:
+        cache = "miss"
         cached = (jax.jit(run), overflow_box, metric_names)
         if use_cache:
             with _CACHE_LOCK:
@@ -876,46 +983,8 @@ def execute_plan(
                     _COMPILE_CACHE.pop(next(iter(_COMPILE_CACHE)))
                 _COMPILE_CACHE[cache_key] = cached
     fn, overflow_box, metric_names = cached
-    result = None
-    if first_call_gate is not None and not first_call_gate["warmed"]:
-        with first_call_gate["lock"]:
-            # double-check: threads that queued behind the creator must
-            # NOT execute under the gate (that would serialize the whole
-            # task wave) — only the creator's trace+compile+first-run is
-            # serialized; everyone else re-checks and runs concurrently
-            if not first_call_gate["warmed"]:
-                result = fn(input_list, params)
-                first_call_gate["warmed"] = True
-    if result is None:
-        result = fn(input_list, params)
-    out, flags, metric_vals = result
-    flags = np.asarray(flags)  # one fetch for both sentinel checks
-    any_overflow, any_precision = bool(flags[0]), bool(flags[1])
-    if check_overflow and any_overflow:
-        raise RuntimeError(
-            f"hash table overflow in plan (nodes: "
-            f"{[name for name, _ in overflow_box if not name.startswith(_PRECISION_TAG)]}); "
-            "re-plan with more slots"
-        )
-    if any_precision:
-        # deliberately does NOT contain the word "overflow": the session's
-        # capacity-retry loop must not retry this (a bigger hash table can't
-        # restore int32 exactness).
-        raise RuntimeError(
-            "int32 accumulator range exceeded in plan (nodes: "
-            f"{[name for name, _ in overflow_box if name.startswith(_PRECISION_TAG)]}); "
-            "run with DFTPU_PRECISION=x64 for 64-bit accumulation"
-        )
-    if metrics_store is not None:
-        # positions -> THIS submission's node ids (hoisting preserves the
-        # original ids, so callers can look metrics up on their own plan)
-        nodes = plan.collect(lambda _n: True)
-        node_metrics: dict = {}
-        for (pos, name), v in zip(metric_names, metric_vals):
-            if 0 <= pos < len(nodes):
-                node_metrics.setdefault(nodes[pos].node_id, {})[name] = int(v)
-        metrics_store.insert(task_label or f"task{task.task_index}", node_metrics)
-    return out
+    return (fn, overflow_box, metric_names, first_call_gate, input_list,
+            params, cache)
 
 
 _COMPILE_CACHE: dict = {}  # insertion order == LRU order (move-to-end on hit)

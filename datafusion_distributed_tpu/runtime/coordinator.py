@@ -60,6 +60,7 @@ from datafusion_distributed_tpu.runtime.tracing import (
     DEFAULT_TRACE_STORE,
     NULL_TRACER,
     TRACE_CTX_KEY,
+    current as current_tracer,
     resolve_tracing_mode,
     table_nbytes,
 )
@@ -631,9 +632,7 @@ class Coordinator:
         self.stage_metrics.begin_query(query_id)
         q_t0 = _time.monotonic()
         tracer = self._tracer
-        qspan = tracer.start_span("query", "query", query_id=query_id)
-        if tracer.active:
-            tracer.trace.root_id = qspan.span_id
+        qspan = tracer.open_root("query", "query", query_id=query_id)
         try:
             resolved = self._materialize_exchanges(plan, query_id)
             # the root stage: a single consumer task — routed through the
@@ -663,7 +662,8 @@ class Coordinator:
                 query_id, r_t1 - q_t0
             )
             return out
-        except BaseException:
+        except BaseException as e:
+            qspan.set(error=type(e).__name__)
             self._signal_cancel()
             raise
         finally:
@@ -4634,6 +4634,22 @@ def _shuffle_regroup(
 
     The copying fallback prefers the native (C++) data plane for the hash +
     CSR bucket build (native/), falling back to device ops."""
+    tr = current_tracer()
+    with tr.span("regroup", "regroup", producers=len(outputs),
+                 partitions=num_tasks) as rsp:
+        slices = _shuffle_regroup_body(
+            outputs, key_names, num_tasks, per_dest_capacity, zero_copy,
+            exact,
+        )
+        if tr.active:
+            rsp.set(bytes=sum(table_nbytes(t) for t in slices))
+        return slices
+
+
+def _shuffle_regroup_body(
+    outputs: Sequence[Table], key_names, num_tasks: int,
+    per_dest_capacity: int, zero_copy: bool, exact: bool,
+) -> list[Table]:
     if zero_copy:
         host = _shuffle_regroup_host(
             outputs, key_names, num_tasks, per_dest_capacity, exact

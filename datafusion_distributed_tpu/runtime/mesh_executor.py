@@ -23,6 +23,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from datafusion_distributed_tpu import precision
 from datafusion_distributed_tpu.ops.table import Table
 from datafusion_distributed_tpu.plan.physical import _PRECISION_TAG
+from datafusion_distributed_tpu.runtime import tracing
 
 # per-task metric counters (row/byte counts); 32-bit in tpu precision mode
 _METRIC_DTYPE = precision.ACC_INT
@@ -91,7 +92,10 @@ def execute_on_mesh(
         bound_params,
         prepare_plan,
     )
-    from datafusion_distributed_tpu.plan.physical import _TRACE_STATS
+    from datafusion_distributed_tpu.plan.physical import (
+        _TRACE_STATS,
+        traced_positions,
+    )
 
     num_tasks = mesh.shape[AXIS]
     # content-address the SPMD program: fingerprint-equal plans (fresh
@@ -107,16 +111,25 @@ def execute_on_mesh(
     # input pytree structure between fingerprint-equal plan copies.
     leaf_ids = [leaf.node_id for leaf in leaves if hasattr(leaf, "load")]
     stacked_inputs: list[Table] = []
-    for leaf in leaves:
-        if not hasattr(leaf, "load"):
-            continue
-        per_task = [
-            leaf.load(DistributedTaskContext(i, num_tasks))
-            for i in range(num_tasks)
-        ]
-        stacked_inputs.append(
-            jax.tree.map(lambda *xs: jnp.stack(xs), *per_task)
-        )
+    tr = tracing.current()
+    traces_before = _TRACE_STATS["traces"]
+    with tr.span("mesh.stack_inputs", "mesh.stack_inputs") as ssp:
+        for leaf in leaves:
+            if not hasattr(leaf, "load"):
+                continue
+            per_task = [
+                leaf.load(DistributedTaskContext(i, num_tasks))
+                for i in range(num_tasks)
+            ]
+            stacked_inputs.append(
+                jax.tree.map(lambda *xs: jnp.stack(xs), *per_task)
+            )
+        if tr.active:
+            # every task's slice of every leaf, stacked on the first
+            # device before `shard_map` re-places it
+            ssp.set(bytes=sum(tracing.table_nbytes(t)
+                              for t in stacked_inputs),
+                    tasks=num_tasks)
 
     overflow_names: list = []
     metric_names: list = []
@@ -133,16 +146,14 @@ def execute_on_mesh(
             inputs=local_inputs,
             config={"mesh_axis": AXIS, "num_tasks": num_tasks},
         )
-        with bound_params(param_vecs):
+        # position-addressed metric names and operator scopes (see
+        # plan/physical.py run(): fingerprint-shared programs must not
+        # leak creator node ids)
+        with traced_positions(exec_target) as pos_of, \
+                bound_params(param_vecs):
             out = exec_target.execute(ctx)
         overflow_names.clear()
         overflow_names.extend(name for name, _ in ctx.overflow_flags)
-        # position-addressed metric names (see plan/physical.py run():
-        # fingerprint-shared programs must not leak creator node ids)
-        pos_of = {
-            n.node_id: i
-            for i, n in enumerate(exec_target.collect(lambda _n: True))
-        }
         metric_names.clear()
         metric_names.extend(
             (pos_of.get(nid, -1), name) for nid, name, _ in ctx.metrics
@@ -188,6 +199,7 @@ def execute_on_mesh(
     cache_key = (prep.fingerprint or ("id", plan.node_id),
                  tuple(d.id for d in mesh.devices.flat))
     cached = _MESH_COMPILE_CACHE.get(cache_key)
+    cached_hit = cached is not None
     if cached is not None:
         # move-to-end: LRU eviction must not take the entry being reused
         _MESH_COMPILE_CACHE.pop(cache_key)
@@ -207,14 +219,21 @@ def execute_on_mesh(
         cached = (fn, overflow_names, metric_names)
         _MESH_COMPILE_CACHE[cache_key] = cached
     fn, overflow_names, metric_names = cached
-    out, any_overflow, any_precision, mvec = fn(stacked_inputs, params)
-    if check_overflow and bool(any_overflow):
+    # ends on the fetch of the two flags, the sync this path already makes
+    with tr.span("mesh.execute", "mesh.execute",
+                 cache="hit" if cached_hit else "miss") as xsp:
+        out, any_overflow, any_precision, mvec = fn(stacked_inputs, params)
+        any_overflow = check_overflow and bool(any_overflow)
+        any_precision = bool(any_precision)
+        if tr.active:
+            xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before)
+    if any_overflow:
         raise RuntimeError(
             f"exchange/hash capacity overflow on mesh (nodes: "
             f"{[n for n in overflow_names if not n.startswith(_PRECISION_TAG)]}); "
             "re-plan with larger capacities"
         )
-    if bool(any_precision):
+    if any_precision:
         raise RuntimeError(
             "int32 accumulator range exceeded on mesh (nodes: "
             f"{[n for n in overflow_names if n.startswith(_PRECISION_TAG)]}); "

@@ -2,26 +2,23 @@
 
 The reference runs a separate gRPC ObservabilityService with `Ping`,
 `GetTaskProgress` (per-task partition completion + output rows) and
-`GetClusterWorkers`, plus optional 100 ms RSS/CPU sampling
+`GetClusterWorkers`, plus RSS/CPU sampling
 (`/root/reference/src/observability/service.rs`). Host-runtime equivalent
-over the in-process (or gRPC-wrapped) worker objects; system metrics read
-/proc directly (no sysinfo dependency).
+over the in-process (or gRPC-wrapped) worker objects; system metrics are
+read from /proc on demand (`sample_system_metrics`, no sysinfo dependency;
+the console samples once a frame).
 """
 
 from __future__ import annotations
 
 import os
-import threading
 import time
-from dataclasses import dataclass, field
-from typing import Optional
+from dataclasses import dataclass
 
 
 @dataclass(frozen=True)
 class SystemMetrics:
-    """Frozen: the sampler thread publishes a new snapshot per tick via a
-    single reference assignment (GIL-atomic), so readers can never observe
-    a half-updated sample — mutation is a bug by construction."""
+    """One frozen sample of the process's RSS and CPU seconds."""
 
     rss_bytes: int = 0
     cpu_seconds: float = 0.0
@@ -44,46 +41,6 @@ def sample_system_metrics() -> SystemMetrics:
     return SystemMetrics(rss_bytes=rss, cpu_seconds=cpu, sampled_at=time.time())
 
 
-class SystemMetricsSampler:
-    """Background sampler (the reference samples every 100 ms under the
-    `system-metrics` feature).
-
-    Thread-safety contract: ``latest`` always holds a FROZEN
-    SystemMetrics snapshot, replaced wholesale by the sampler thread —
-    a single reference assignment is atomic under the GIL, so readers on
-    any thread see either the previous complete sample or the next one,
-    never a torn mix. Assertion-backed: the snapshot type is frozen, so
-    an accidental in-place mutation raises instead of racing."""
-
-    def __init__(self, interval_s: float = 0.1):
-        self.interval = interval_s
-        self.latest = sample_system_metrics()
-        assert type(self.latest).__dataclass_params__.frozen, (
-            "SystemMetrics must stay frozen: the cross-thread handoff "
-            "relies on immutable snapshots + atomic reference swap"
-        )
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-
-    def start(self) -> "SystemMetricsSampler":
-        def loop():
-            while not self._stop.wait(self.interval):
-                # publish: one atomic reference swap of a frozen snapshot
-                self.latest = sample_system_metrics()
-
-        self._thread = threading.Thread(target=loop, daemon=True)
-        self._thread.start()
-        return self
-
-    def stop(self) -> None:
-        """Idempotent: stop() on a never-started or already-stopped
-        sampler is a no-op; concurrent/repeated calls join at most once."""
-        self._stop.set()
-        t, self._thread = self._thread, None
-        if t is not None:
-            t.join(timeout=1.0)
-
-
 class ObservabilityService:
     """Ping / GetTaskProgress / GetClusterWorkers over a worker cluster.
 
@@ -97,10 +54,9 @@ class ObservabilityService:
     latency summary through `get_serving_stats` (and the console's
     serving line)."""
 
-    def __init__(self, resolver, channels, sample_system: bool = False,
-                 health=None, fault_counters=None, serving=None,
-                 trace_store=None, checkpoints=None, telemetry=None,
-                 result_cache=None):
+    def __init__(self, resolver, channels, health=None,
+                 fault_counters=None, serving=None, trace_store=None,
+                 checkpoints=None, telemetry=None, result_cache=None):
         self.resolver = resolver
         self.channels = channels
         self.health = health
@@ -120,7 +76,6 @@ class ObservabilityService:
         # (runtime/telemetry.py) merged unlabeled into get_metrics();
         # falls back to the wired serving session's registry
         self.telemetry = telemetry
-        self.sampler = SystemMetricsSampler().start() if sample_system else None
 
     def ping(self) -> dict:
         return {"ok": True, "ts": time.time()}
@@ -402,6 +357,3 @@ class ObservabilityService:
             return store.summary()
         except Exception as e:
             return {"error": str(e)}
-
-    def system_metrics(self) -> Optional[SystemMetrics]:
-        return self.sampler.latest if self.sampler else None

@@ -64,6 +64,7 @@ import uuid
 import zlib
 from typing import Optional
 
+from datafusion_distributed_tpu.runtime import tracing
 from datafusion_distributed_tpu.runtime.errors import TaskCancelledError
 from datafusion_distributed_tpu.runtime.metrics import (
     FaultCounters,
@@ -126,6 +127,14 @@ class QueryHandle:
                  priority: int, est_bytes: int):
         self.query_id = uuid.uuid4().hex  # collision-free under any
         # concurrency: uuid4 per handle, never a shared counter
+        # the request's identifier in the trace store: the roots of its
+        # submit, queued, execute (one an attempt) and fetch traces carry
+        # it; None until one of them is traced
+        self.request_id = getattr(df, "request_id", None)
+        # overflow retries the resolved query took (its DataFrame's
+        # `last_retry_count`); None while unresolved, failed, or served
+        # from the result cache
+        self.retry_count: Optional[int] = None
         self.sql = sql
         self.priority = int(priority)
         self.est_bytes = int(est_bytes)
@@ -840,12 +849,24 @@ class ServingSession:
         within a class. ``_resume``: internal (recover()) — an existing
         checkpoint-store record id this submission resumes instead of
         registering a fresh one."""
+        if self._closed:
+            raise RuntimeError("serving session is closed")
+        with tracing.trace_call(
+            "submit", self.ctx.config.distributed_options
+        ):
+            return self._submit(sql, priority, _resume)
+
+    def _submit(self, sql: str, priority: int,
+                _resume: Optional[str]) -> QueryHandle:
+        """`submit` inside its ``submit`` span: planning and costing on
+        the client's thread, then the queue."""
         from datafusion_distributed_tpu.planner.statistics import (
             plan_device_bytes,
         )
 
-        if self._closed:
-            raise RuntimeError("serving session is closed")
+        # traced, `ctx.sql` is a child span of ``submit`` and its
+        # DataFrame takes the trace's request: one identifier for the
+        # handle's traces (submit, queued, every execute, the fetch)
         df = self.ctx.sql(sql)
         if df is None or not hasattr(df, "collect_coordinated_table"):
             raise ValueError(
@@ -1058,14 +1079,28 @@ class ServingSession:
                   priority=h.priority, est_bytes=h.est_bytes,
                   queue_wait_s=round(wait, 6) if wait is not None
                   else None)
+        df = h._df
+        if wait is not None:
+            # submit-to-admit began on the client's thread and ended on
+            # the admitting one: a trace of one span, after the fact
+            # (the request's first, where the submit was not traced)
+            h.request_id = df.request_id = tracing.record_span(
+                "queued", h.submitted_s, h.admitted_s,
+                self.ctx.config.distributed_options, df.request_id,
+                priority=h.priority,
+            ) or df.request_id
         coord = None
         try:
             if h._cancel_event.is_set():
                 raise TaskCancelledError("cancelled before execution")
             coord = h._coordinator = self._make_coordinator(h)
-            out = h._df.collect_coordinated_table(
-                coordinator=coord, num_tasks=self.num_tasks
-            )
+            try:
+                out = df.collect_coordinated_table(
+                    coordinator=coord, num_tasks=self.num_tasks
+                )
+            finally:  # before any `_finish` wakes the client
+                h.request_id = df.request_id
+            h.retry_count = getattr(df, "last_retry_count", None)
             if getattr(coord, "last_query_id", None) is None:
                 # the coordinator never executed: the result cache
                 # served this query while it sat in the queue (or a
@@ -1153,22 +1188,15 @@ class ServingSession:
     def _stamp_trace(self, h: QueryHandle, coord) -> None:
         """Bind the handle to its MAIN execute's trace (the last query id
         the coordinator ran — subquery executes resolved earlier) and
-        annotate the trace root with the serving tier's admission
-        queue-wait, so the profile shows the full submit->result story."""
+        name the handle on its root. The admission wait is the request's
+        ``queued`` span (`_drive`)."""
         qid = getattr(coord, "last_query_id", None)
         if qid is None:
             return
         h.trace_query_id = qid
-        wait = h.queue_wait_s()
-        if wait is not None:
-            from datafusion_distributed_tpu.runtime.tracing import (
-                DEFAULT_TRACE_STORE,
-            )
-
-            DEFAULT_TRACE_STORE.annotate(
-                qid, admission_wait_s=round(wait, 6),
-                serving_query_id=h.query_id, priority=h.priority,
-            )
+        tracing.DEFAULT_TRACE_STORE.annotate(
+            qid, serving_query_id=h.query_id, priority=h.priority,
+        )
 
     # -- query recovery (runtime/checkpoint.py) ------------------------------
     def recover(self, store=None, cluster=None) -> list:

@@ -10,11 +10,25 @@ worker execute, exchange transfer, TableStore staging) and structured
 trace *events* for every fault-path transition the engine already has
 (retry, reroute, quarantine, heal, cancel, membership epoch change).
 
+The recording half (`Span`, `Tracer`, `TraceStore`, `trace_call`, ...)
+lives in the leaf module `datafusion_distributed_tpu/spans.py`, which the
+layers below the runtime import; this module re-exports it and holds the
+worker's side of the wire and everything that reads a trace.
+
 Design constraints (mirrors the MetricsStore contracts):
 
 - ALWAYS CHEAP WHEN OFF: call sites hold a `NULL_TRACER` whose methods
   are no-ops; no span objects, no clock reads, no per-task dict copies.
-  `SET distributed.tracing = off|on|sampled` selects the mode per query.
+  `SET distributed.tracing = off|on|sampled` selects the mode per query,
+  and a recording `jax.profiler` session turns every query on while it
+  lasts (`resolve_tracing_mode`): capture a profile of a live server and
+  the program's spans are in it, with no restart and no setting.
+- ONE SPAN, TWO SINKS: every span opened live (`span()`, `start_span`)
+  also opens a `jax.profiler.TraceAnnotation("dftpu.<name>")` on the same
+  thread, so it lands in the host plane of the profiler's `.xplane.pb`
+  on the device's clock. Spans recorded after the fact with explicit
+  times (`finish_reserved`, `splice` of remote workers, `record_span`)
+  stay in the store only. The store keeps `time.monotonic`.
 - HOST-SIDE ONLY: spans wrap coordinator/worker *host* phases; nothing
   here may run inside a jax-traced function (tools/check_tracer_safety.py
   rule DFTPU109 enforces it), and the wire context must never enter a
@@ -44,382 +58,30 @@ folded into `explain_analyze`), and live aggregate counters
 
 from __future__ import annotations
 
-import json
-import threading
 import time
-import zlib
-from collections import deque
-from typing import Any, Optional
-
-#: `SET distributed.tracing` modes (validated at SET time, sql/context.py)
-TRACING_MODES = ("off", "on", "sampled")
-
-#: config key the trace context rides under in the task envelope. MUST
-#: stay out of every compile-cache key (plan/physical.py filters it from
-#: cfg_items; runtime/worker.py strips it before execute_plan) — span ids
-#: differ per task and would otherwise fragment the program caches into
-#: one XLA trace per task.
-TRACE_CTX_KEY = "trace_ctx"
-
-_SPAN_CAP = 4096     # ring-buffer bound per query
-_EVENT_CAP = 2048    # trace-level event bound per query
-_QUERY_CAP = 32      # LRU bound across queries (running ones pinned)
-
-
-def table_nbytes(table) -> int:
-    """Host-side device-buffer byte count of an ops Table: data + validity
-    of every column (no device sync — `.nbytes` reads the aval). The
-    data-plane attribution unit: in-process shipments move exactly these
-    buffers (by reference), the wire transport serializes them (plus codec
-    framing), so spans attributed with this match `nbytes` by
-    construction."""
-    total = 0
-    for c in getattr(table, "columns", ()):
-        data = getattr(c, "data", None)
-        if data is not None:
-            total += int(data.nbytes)
-        validity = getattr(c, "validity", None)
-        if validity is not None:
-            total += int(validity.nbytes)
-    return total
-
-
-def resolve_tracing_mode(options: Optional[dict]) -> str:
-    """The effective `SET distributed.tracing` mode from a config-options
-    dict (unknown/missing -> off: tracing is strictly opt-in)."""
-    mode = str((options or {}).get("tracing", "off") or "off").strip().lower()
-    return mode if mode in TRACING_MODES else "off"
-
-
-def _sampled(query_id: str, rate: float) -> bool:
-    """Deterministic per-query sampling decision: a hash of the query id
-    against ``rate`` — the same query id always decides the same way, so a
-    replayed run re-traces the same queries."""
-    if rate >= 1.0:
-        return True
-    if rate <= 0.0:
-        return False
-    return (zlib.crc32(query_id.encode()) / 0xFFFFFFFF) < rate
-
-
-class Span:
-    """One closed span. ``t0``/``t1`` are raw `time.monotonic` seconds;
-    exports normalize against the trace origin."""
-
-    __slots__ = ("span_id", "parent_id", "name", "kind", "t0", "t1",
-                 "attrs")
-
-    def __init__(self, span_id: int, parent_id: Optional[int], name: str,
-                 kind: str, t0: float, t1: float = 0.0,
-                 attrs: Optional[dict] = None):
-        self.span_id = span_id
-        self.parent_id = parent_id
-        self.name = name
-        self.kind = kind
-        self.t0 = t0
-        self.t1 = t1
-        self.attrs = attrs if attrs is not None else {}
-
-    @property
-    def duration(self) -> float:
-        return max(self.t1 - self.t0, 0.0)
-
-    def set(self, **attrs) -> "Span":
-        self.attrs.update(attrs)
-        return self
-
-    def to_dict(self) -> dict:
-        return {
-            "id": self.span_id, "parent": self.parent_id,
-            "name": self.name, "kind": self.kind,
-            "t0": self.t0, "t1": self.t1, "attrs": dict(self.attrs),
-        }
-
-
-class _NullSpan:
-    """The span NULL_TRACER hands out: swallows every mutation."""
-
-    __slots__ = ()
-    span_id = None
-    parent_id = None
-    attrs: dict = {}
-    t0 = t1 = 0.0
-    duration = 0.0
-
-    def set(self, **attrs) -> "_NullSpan":
-        return self
-
-
-_A_NULL_SPAN = _NullSpan()
-
-
-class _NullCtx:
-    """Reusable no-op context manager yielding the null span."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> _NullSpan:
-        return _A_NULL_SPAN
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-_A_NULL_CTX = _NullCtx()
-
-
-class _NullTracer:
-    """The off-mode tracer: every method is a constant-time no-op — call
-    sites keep one unconditional code path and pay ~nothing when tracing
-    is off (the "always cheap when off" contract)."""
-
-    __slots__ = ()
-    active = False
-
-    def span(self, name, kind, parent=None, **attrs):
-        return _A_NULL_CTX
-
-    def start_span(self, name, kind, parent=None, **attrs):
-        return _A_NULL_SPAN
-
-    def end_span(self, span) -> None:
-        pass
-
-    def event(self, name, **attrs) -> None:
-        pass
-
-    def reserved_id(self, key):
-        return None
-
-    def finish_reserved(self, key, name, kind, t0, t1, parent=None,
-                        **attrs) -> None:
-        pass
-
-    def current_id(self):
-        return None
-
-    def wire_ctx(self):
-        return None
-
-    def splice(self, span_dicts, default_parent=None) -> None:
-        pass
-
-
-NULL_TRACER = _NullTracer()
-
-
-class QueryTrace:
-    """One query's bounded span/event store. Thread-safe: spans land from
-    the coordinator's stage/task fan-out threads and (spliced) worker
-    payloads concurrently."""
-
-    def __init__(self, query_id: str, span_cap: int = _SPAN_CAP,
-                 event_cap: int = _EVENT_CAP):
-        self.query_id = query_id
-        self.t0 = time.monotonic()
-        self.t1: Optional[float] = None
-        self.finished = False
-        # ring buffers: deque(maxlen=...) drops the OLDEST on overflow;
-        # `dropped` counts evictions so exports can say "N spans dropped"
-        self.spans: deque = deque(maxlen=span_cap)  # guarded-by: _lock
-        self.events: deque = deque(maxlen=event_cap)  # guarded-by: _lock
-        self.dropped = 0  # guarded-by: _lock
-        self.events_dropped = 0  # guarded-by: _lock
-        self._lock = threading.Lock()
-        self._next_id = 0  # guarded-by: _lock
-        self._reserved: dict = {}  # guarded-by: _lock
-        self.root_id: Optional[int] = None
-        # summary tally memo, filled by TraceStore._tally once finished
-        self._tally_cache: Optional[tuple] = None
-
-    # -- id allocation ------------------------------------------------------
-    def new_id(self) -> int:
-        with self._lock:
-            self._next_id += 1
-            return self._next_id
-
-    def reserve(self, key) -> int:
-        """Pre-allocate a span id for ``key`` (e.g. ``("stage", 3)``) so
-        children created BEFORE the span closes (task spans inside a still
-        -running stage) can parent under it; `finish_reserved` later
-        appends the span with this id."""
-        with self._lock:
-            sid = self._reserved.get(key)
-            if sid is None:
-                self._next_id += 1
-                sid = self._reserved[key] = self._next_id
-            return sid
-
-    # -- recording ----------------------------------------------------------
-    def add_span(self, span: Span) -> None:
-        with self._lock:
-            if len(self.spans) == self.spans.maxlen:
-                self.dropped += 1
-            self.spans.append(span)
-
-    def add_event(self, t: float, name: str, attrs: dict,
-                  parent: Optional[int]) -> None:
-        with self._lock:
-            if len(self.events) == self.events.maxlen:
-                self.events_dropped += 1
-            self.events.append((t, name, attrs, parent))
-
-    # -- inspection ---------------------------------------------------------
-    def span_list(self) -> list:
-        with self._lock:
-            return list(self.spans)
-
-    def event_list(self) -> list:
-        with self._lock:
-            return list(self.events)
-
-    def root_span(self) -> Optional[Span]:
-        rid = self.root_id
-        if rid is None:
-            return None
-        for s in self.span_list():
-            if s.span_id == rid:
-                return s
-        return None
-
-    def finish(self) -> None:
-        self.finished = True
-        if self.t1 is None:
-            self.t1 = time.monotonic()
-
-
-class Tracer:
-    """Per-query recording facade over a QueryTrace. Implicit parenting
-    rides a PER-THREAD span stack (`span()` pushes/pops), so nested host
-    phases need no explicit plumbing; work fanned out to pool threads
-    passes an explicit ``parent`` (usually a reserved stage span id) to
-    seed its own stack."""
-
-    __slots__ = ("trace", "_local")
-    active = True
-
-    def __init__(self, trace: QueryTrace):
-        self.trace = trace
-        self._local = threading.local()
-
-    # -- parent stack -------------------------------------------------------
-    def _stack(self) -> list:
-        st = getattr(self._local, "stack", None)
-        if st is None:
-            st = self._local.stack = []
-        return st
-
-    def current_id(self) -> Optional[int]:
-        st = self._stack()
-        return st[-1] if st else self.trace.root_id
-
-    # -- spans --------------------------------------------------------------
-    def span(self, name: str, kind: str, parent: Optional[int] = None,
-             **attrs):
-        """Context manager: opens a span now, closes+records it on exit.
-        An exception closing the span is recorded as ``error=<TypeName>``
-        and re-raised."""
-        return _SpanCtx(self, name, kind, parent, attrs)
-
-    def start_span(self, name: str, kind: str,
-                   parent: Optional[int] = None, **attrs) -> Span:
-        """Explicit begin (no stack participation) — for spans whose end
-        lives in a different scope (the query root)."""
-        pid = parent if parent is not None else self.current_id()
-        return Span(self.trace.new_id(), pid, name, kind,
-                    time.monotonic(), attrs=attrs)
-
-    def end_span(self, span: Span) -> None:
-        span.t1 = time.monotonic()
-        self.trace.add_span(span)
-
-    def reserved_id(self, key) -> int:
-        return self.trace.reserve(key)
-
-    def finish_reserved(self, key, name: str, kind: str, t0: float,
-                        t1: float, parent: Optional[int] = None,
-                        **attrs) -> None:
-        """Record the span pre-allocated by `reserved_id(key)` with
-        explicit timestamps (the stage spans: the scheduler knows
-        submit/start/end after the fact). Default parent: the recording
-        thread's current span (the scheduler span), else the root."""
-        sid = self.trace.reserve(key)
-        pid = parent if parent is not None else self.current_id()
-        self.trace.add_span(Span(sid, pid, name, kind, t0, t1, attrs))
-
-    # -- events -------------------------------------------------------------
-    def event(self, name: str, **attrs) -> None:
-        self.trace.add_event(time.monotonic(), name, attrs,
-                             self.current_id())
-
-    # -- cross-wire ---------------------------------------------------------
-    def wire_ctx(self) -> dict:
-        """The context that rides the task envelope: worker-side spans
-        recorded under it join the trace at `splice` time via the
-        propagated parent span id."""
-        return {"q": self.trace.query_id, "parent": self.current_id()}
-
-    def splice(self, span_dicts, default_parent: Optional[int] = None
-               ) -> None:
-        """Adopt worker-side span dicts (see worker_span) into this trace:
-        each gets a fresh local id and parents under its propagated
-        ``wire_parent`` (falling back to ``default_parent`` / the root).
-        Worker timestamps are CLOCK_MONOTONIC — system-wide on Linux, so
-        same-host workers (in-process and gRPC-localhost tiers) splice
-        without rebasing."""
-        if default_parent is None:
-            default_parent = self.current_id()
-        for d in span_dicts:
-            try:
-                pid = d.get("wire_parent")
-                if pid is None:
-                    pid = default_parent
-                attrs = dict(d.get("attrs") or {})
-                attrs.setdefault("remote", True)
-                self.trace.add_span(Span(
-                    self.trace.new_id(), pid,
-                    str(d.get("name", "worker")),
-                    str(d.get("kind", "execute")),
-                    float(d.get("t0", 0.0)), float(d.get("t1", 0.0)),
-                    attrs,
-                ))
-            except (TypeError, ValueError, KeyError):
-                continue  # a malformed wire span must never fail the task
-
-
-class _SpanCtx:
-    __slots__ = ("_tracer", "_span", "_name", "_kind", "_parent", "_attrs")
-
-    def __init__(self, tracer: Tracer, name, kind, parent, attrs):
-        self._tracer = tracer
-        self._name = name
-        self._kind = kind
-        self._parent = parent
-        self._attrs = attrs
-        self._span: Optional[Span] = None
-
-    def __enter__(self) -> Span:
-        tr = self._tracer
-        pid = self._parent if self._parent is not None else tr.current_id()
-        sp = Span(tr.trace.new_id(), pid, self._name, self._kind,
-                  time.monotonic(), attrs=self._attrs)
-        tr._stack().append(sp.span_id)
-        self._span = sp
-        return sp
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        sp = self._span
-        tr = self._tracer
-        st = tr._stack()
-        if st and st[-1] == sp.span_id:
-            st.pop()
-        elif sp.span_id in st:  # defensive: unwound out of order
-            st.remove(sp.span_id)
-        if exc_type is not None:
-            sp.attrs.setdefault("error", exc_type.__name__)
-        sp.t1 = time.monotonic()
-        tr.trace.add_span(sp)
-        return False
+from typing import Optional
+
+# the recording half is a leaf module (`spans.py`), so that `ops/`, `io/`
+# and `plan/` can open spans without importing the runtime layer; this
+# module is its face for the runtime layer and everything above it
+from datafusion_distributed_tpu.spans import (  # noqa: F401
+    DEFAULT_TRACE_STORE,
+    NULL_TRACER,
+    PROFILE_PREFIX,
+    TRACE_CTX_KEY,
+    TRACING_MODES,
+    QueryTrace,
+    Span,
+    TraceStore,
+    current,
+    record_span,
+    request_of,
+    request_scope,
+    resolve_tracing_mode,
+    table_nbytes,
+    tag_request,
+    trace_call,
+)
 
 
 def worker_span(name: str, kind: str, t0: float, t1: float,
@@ -431,143 +93,54 @@ def worker_span(name: str, kind: str, t0: float, t1: float,
             "wire_parent": wire_parent, "attrs": attrs}
 
 
-class TraceStore:
-    """query_id -> QueryTrace, LRU-bounded with running queries pinned
-    (the MetricsStore retention contract). One process-wide default store
-    (`DEFAULT_TRACE_STORE`) backs `ctx.last_trace()`,
-    `QueryHandle.trace()`, explain_analyze's profile fold and the
-    observability summary."""
+class worker_phase:
+    """One worker-side phase (plan decode, task execute). Where the
+    coordinator's trace is open on this very thread (the in-process
+    transport) it is a live child span, profiler annotation included;
+    else (a gRPC worker, a deadline thread) a `worker_span` dict
+    appended to ``sink``, which rides the task-progress payload back to
+    `Tracer.splice`. ``tctx`` None: nothing at all."""
 
-    def __init__(self, query_cap: int = _QUERY_CAP,
-                 span_cap: int = _SPAN_CAP):
-        self.query_cap = query_cap
-        self.span_cap = span_cap
-        # insertion order == LRU order
-        self._traces: dict = {}  # guarded-by: _lock; per-query: swept-by finish
-        self._running: set = set()  # guarded-by: _lock
-        self._lock = threading.Lock()
-        self._started_total = 0  # guarded-by: _lock
+    __slots__ = ("_tctx", "_name", "_kind", "_sink", "_attrs", "_ctx",
+                 "_t0", "live")
 
-    # -- lifecycle ----------------------------------------------------------
-    def begin(self, query_id: str, mode: str,
-              sample_rate: float = 0.125):
-        """-> a live Tracer for this query, or NULL_TRACER when the mode
-        (or the sampling decision) says no. The trace is pinned against
-        LRU eviction until `finish(query_id)`."""
-        if mode == "off":
-            return NULL_TRACER
-        if mode == "sampled" and not _sampled(query_id, sample_rate):
-            return NULL_TRACER
-        trace = QueryTrace(query_id, span_cap=self.span_cap)
-        with self._lock:
-            self._running.add(query_id)
-            self._traces[query_id] = trace
-            self._started_total += 1
-            self._evict_locked()
-        return Tracer(trace)
+    def __init__(self, tctx, name: str, kind: str, sink: list, **attrs):
+        self._tctx = tctx
+        self._name = name
+        self._kind = kind
+        self._sink = sink
+        self._attrs = attrs
+        self._ctx = None
+        self.live = False
 
-    def finish(self, query_id: str) -> None:
-        with self._lock:
-            self._running.discard(query_id)
-            trace = self._traces.get(query_id)
-            self._evict_locked()
-        if trace is not None:
-            trace.finish()
+    def __enter__(self) -> "worker_phase":
+        tctx = self._tctx
+        if not tctx:
+            return self
+        tracer = current()
+        if tracer.active and tracer.trace.query_id == tctx.get("q"):
+            self.live = True
+            self._ctx = tracer.span(self._name, self._kind, **self._attrs)
+            self._ctx.__enter__()
+        else:
+            self._t0 = time.monotonic()
+        return self
 
-    def _evict_locked(self) -> None:
-        if len(self._traces) <= self.query_cap:
-            return
-        for qid in list(self._traces):
-            if len(self._traces) <= self.query_cap:
-                break
-            if qid in self._running:
-                continue  # never evict a live query's trace
-            self._traces.pop(qid)
+    def set(self, **attrs) -> None:
+        if self._ctx is not None:
+            self._ctx._span.set(**attrs)
+        else:
+            self._attrs.update(attrs)
 
-    # -- lookup -------------------------------------------------------------
-    def get(self, query_id: str) -> Optional[QueryTrace]:
-        with self._lock:
-            trace = self._traces.get(query_id)
-            if trace is not None:  # move-to-end: LRU touch
-                self._traces.pop(query_id)
-                self._traces[query_id] = trace
-            return trace
-
-    def last(self) -> Optional[QueryTrace]:
-        """Most recently FINISHED trace (running ones are still filling)."""
-        with self._lock:
-            finished = [t for t in self._traces.values() if t.finished]
-        if not finished:
-            return None
-        return max(finished, key=lambda t: t.t1 or 0.0)
-
-    def annotate(self, query_id: str, **attrs) -> None:
-        """Attach attrs to a trace's root span after the fact (the serving
-        tier adds admission queue-wait once the handle resolves)."""
-        trace = self.get(query_id)
-        if trace is None:
-            return
-        root = trace.root_span()
-        if root is not None:
-            root.attrs.update(attrs)
-
-    # -- aggregate counters (observability surface) -------------------------
-    @staticmethod
-    def _tally(trace: QueryTrace) -> tuple:
-        """(spans_by_kind, events_by_name, bytes, dropped) for one trace.
-        Cached once the trace is FINISHED — its spans/events are immutable
-        from then on (post-finish `annotate` only touches root attrs, not
-        counts), so the console polling the summary twice a second scans
-        only the handful of running traces, not every retained one."""
-        cached = getattr(trace, "_tally_cache", None)
-        if cached is not None:
-            return cached
-        by_kind: dict = {}
-        by_name: dict = {}
-        nbytes = 0
-        for s in trace.span_list():
-            by_kind[s.kind] = by_kind.get(s.kind, 0) + 1
-            b = s.attrs.get("bytes")
-            if b:
-                nbytes += int(b)
-        for _t, name, _a, _p in trace.event_list():
-            by_name[name] = by_name.get(name, 0) + 1
-        out = (by_kind, by_name, nbytes, trace.dropped)
-        if trace.finished:
-            trace._tally_cache = out
-        return out
-
-    def summary(self) -> dict:
-        with self._lock:
-            traces = list(self._traces.values())
-            running = len(self._running)
-            started = self._started_total
-        spans_by_kind: dict = {}
-        events_by_name: dict = {}
-        total_bytes = 0
-        dropped = 0
-        for t in traces:
-            by_kind, by_name, nbytes, t_dropped = self._tally(t)
-            dropped += t_dropped
-            for k, n in by_kind.items():
-                spans_by_kind[k] = spans_by_kind.get(k, 0) + n
-            for k, n in by_name.items():
-                events_by_name[k] = events_by_name.get(k, 0) + n
-            total_bytes += nbytes
-        return {
-            "traces": len(traces),
-            "traces_started": started,
-            "running": running,
-            "spans": sum(spans_by_kind.values()),
-            "spans_by_kind": spans_by_kind,
-            "spans_dropped": dropped,
-            "events": sum(events_by_name.values()),
-            "events_by_name": events_by_name,
-            "data_plane_bytes": total_bytes,
-        }
-
-
-DEFAULT_TRACE_STORE = TraceStore()
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._ctx is not None:
+            return self._ctx.__exit__(exc_type, exc, tb)
+        if self._tctx and exc_type is None:
+            self._sink.append(worker_span(
+                self._name, self._kind, self._t0, time.monotonic(),
+                self._tctx.get("parent"), **self._attrs,
+            ))
+        return False
 
 
 # ---------------------------------------------------------------------------
@@ -680,6 +253,68 @@ def self_times(trace: QueryTrace) -> list:
     return out
 
 
+def layer_report(store: Optional[TraceStore] = None) -> list:
+    """One row a request, its finished traces merged (a request's
+    `ctx.sql`, collect attempts, subqueries and result fetch are each a
+    trace of their own under one ``request``; a trace with no request is
+    its own row), oldest first:
+
+    - ``t0_s``: when its first trace began (`time.monotonic`);
+    - ``wall_s``: the summed wall of its traces' roots;
+    - ``self_s``: `self_times` summed by span KIND (`parse`, `plan`,
+      `attempt`, `prepare`, `execute`, `fetch`, `exchange`, `d2h`, ...):
+      what each layer itself costs, children taken out, so that the
+      kinds add up to ``wall_s`` where no two tasks overlap;
+    - ``total_s``: whole durations summed by span NAME (`worker_execute`
+      is the stage programs, each ended by its flag fetch);
+    - ``counters``: ``bytes`` by span kind, ``transfers`` (device-to-host
+      pulls of the fetch), ``retries`` (overflow retries, stamped on the
+      root that succeeded), ``new_traces`` (programs traced afresh).
+
+    The one report for operators (`render_profile` prints the last
+    trace's row) and for the benchmark's program metrics."""
+    return _layer_rows((store or DEFAULT_TRACE_STORE).finished_traces())
+
+
+def _layer_rows(traces) -> list:
+    rows: dict = {}
+    for trace in traces:
+        root = trace.root_span()
+        if root is None:
+            continue
+        key = trace.request or trace.query_id
+        row = rows.get(key)
+        if row is None:
+            row = rows[key] = {
+                "request": key, "traces": [], "t0_s": trace.t0,
+                "wall_s": 0.0,
+                "self_s": {}, "total_s": {},
+                "counters": {"bytes": {}, "transfers": 0, "retries": 0,
+                             "new_traces": 0},
+            }
+        row["traces"].append(trace.query_id)
+        row["wall_s"] += root.duration
+        counters = row["counters"]
+        counters["retries"] += int(root.attrs.get("retries", 0) or 0)
+        for span, self_s in self_times(trace):
+            row["self_s"][span.kind] = (
+                row["self_s"].get(span.kind, 0.0) + self_s
+            )
+            row["total_s"][span.name] = (
+                row["total_s"].get(span.name, 0.0) + span.duration
+            )
+            nbytes = span.attrs.get("bytes")
+            if nbytes:
+                counters["bytes"][span.kind] = (
+                    counters["bytes"].get(span.kind, 0) + int(nbytes)
+                )
+            counters["transfers"] += int(span.attrs.get("transfers", 0)
+                                         or 0)
+            counters["new_traces"] += int(span.attrs.get("new_traces", 0)
+                                          or 0)
+    return list(rows.values())
+
+
 def format_bytes(n: float) -> str:
     """Human-readable byte count (shared with console.py — one formatter,
     no drift between the panel and the profile report)."""
@@ -756,6 +391,19 @@ def render_profile(trace: QueryTrace, top_n: int = 10) -> str:
                 f"{k}={counts[k]}" for k in sorted(counts)
             )
         )
+    for row in _layer_rows([trace]):
+        layers = sorted(row["self_s"].items(), key=lambda kv: -kv[1])
+        lines.append(
+            "layers (self time by span kind): " + "  ".join(
+                f"{kind} {s:.4f}s" for kind, s in layers if s > 0.0
+            )
+        )
+        c = row["counters"]
+        lines.append(
+            f"counters: bytes {_fmt_bytes(sum(c['bytes'].values()))}"
+            f"  transfers {c['transfers']}  retries {c['retries']}"
+            f"  new_traces {c['new_traces']}"
+        )
     return "\n".join(lines)
 
 
@@ -828,7 +476,3 @@ def to_chrome_trace(trace: QueryTrace) -> dict:
             "spans_dropped": trace.dropped,
         },
     }
-
-
-def chrome_trace_json(trace: QueryTrace) -> str:
-    return json.dumps(to_chrome_trace(trace))

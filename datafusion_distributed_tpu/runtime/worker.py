@@ -27,6 +27,7 @@ from datafusion_distributed_tpu.plan.physical import (
     ExecutionPlan,
 )
 from datafusion_distributed_tpu.runtime.codec import TableStore, decode_plan
+from datafusion_distributed_tpu.runtime.tracing import worker_phase
 from datafusion_distributed_tpu.runtime.errors import (
     TaskTimeoutError,
     WorkerError,
@@ -563,25 +564,17 @@ class Worker:
         # nothing trace-related may enter a jax-traced function
         # (DFTPU109) or a compile-cache key (execute strips it).
         tctx = (config or {}).get("trace_ctx")
-        decode_t0 = time.monotonic() if tctx else 0.0
+        wire_spans: list = []
         try:
-            plan = decode_plan(plan_obj, self.table_store)
-            _check_decoded_plan(plan, plan_obj, self.url, key,
-                                config=config)
-            if self.on_plan is not None:
-                plan = self.on_plan(plan, key)
+            with worker_phase(tctx, "worker_decode", "codec", wire_spans,
+                              worker=self.url):
+                plan = decode_plan(plan_obj, self.table_store)
+                _check_decoded_plan(plan, plan_obj, self.url, key,
+                                    config=config)
+                if self.on_plan is not None:
+                    plan = self.on_plan(plan, key)
         except Exception as e:  # structured propagation to the coordinator
             raise wrap_worker_exception(e, self.url, key) from e
-        wire_spans = None
-        if tctx:
-            from datafusion_distributed_tpu.runtime.tracing import (
-                worker_span,
-            )
-
-            wire_spans = [worker_span(
-                "worker_decode", "codec", decode_t0, time.monotonic(),
-                tctx.get("parent"), worker=self.url,
-            )]
         from datafusion_distributed_tpu.runtime.codec import collect_table_ids
         from datafusion_distributed_tpu.runtime.peer import (
             attach_peer_channels,
@@ -626,69 +619,69 @@ class Worker:
             )
         data.executed_at = time.time()
         tctx = (data.config or {}).get("trace_ctx")
-        exec_t0 = time.monotonic() if tctx else 0.0
-        traces_before = 0
-        if tctx:
-            from datafusion_distributed_tpu.plan import physical as _phys
-
-            traces_before = _phys.trace_count()
+        phase = worker_phase(
+            tctx, "worker_execute", "execute",
+            data.metrics.setdefault("spans", []) if tctx else None,
+            worker=self.url,
+        )
         try:
-            from datafusion_distributed_tpu.plan.physical import execute_plan
-            from datafusion_distributed_tpu.runtime.metrics import MetricsStore
-
-            store = MetricsStore()
-            shared_cache, shared_key = self._stage_compile_cache(key, data)
-            # the wire trace context must NOT reach ExecContext.config or
-            # any compile-cache key: span ids differ per task, and keying
-            # a program on them would force one XLA trace per task
-            # (plan/physical.py filters it from cfg_items as a second
-            # line of defense)
-            exec_config = {
-                k: v for k, v in (data.config or {}).items()
-                if k != "trace_ctx"
-            }
-            out = execute_plan(
-                data.plan,
-                DistributedTaskContext(key.task_number, data.task_count),
-                config=exec_config or None,
-                metrics_store=store,
-                task_label=f"task{key.task_number}",
-                use_cache=False,  # freshly decoded plans never hit the cache
-                shared_cache=shared_cache,
-                shared_key=shared_key,
-            )
-            data.metrics["nodes"] = store.per_task.get(
-                f"task{key.task_number}", {}
-            )
+            with phase:
+                out = self._execute_task_plan(key, data, phase)
         except WorkerError:
             self._tm_tasks.inc(status="error")
             raise
         except Exception as e:
             self._tm_tasks.inc(status="error")
             raise wrap_worker_exception(e, self.url, key) from e
-        data.finished_at = time.time()
-        data.metrics["rows_out"] = int(out.num_rows)
-        data.metrics["elapsed_s"] = data.finished_at - data.executed_at
         # telemetry (host-side, after the compiled program returned —
         # never inside traced code, DFTPU110)
         self._tm_tasks.inc(status="ok")
         self._tm_rows.inc(data.metrics["rows_out"])
         self._tm_exec.observe(data.metrics["elapsed_s"])
-        if tctx:
-            from datafusion_distributed_tpu.plan import physical as _phys
-            from datafusion_distributed_tpu.runtime.tracing import (
-                worker_span,
-            )
+        return out
 
+    def _execute_task_plan(self, key: TaskKey, data, phase) -> Table:
+        """`_execute_task_body`'s work inside its ``worker_execute``
+        phase: run the stage program, note rows and wall."""
+        from datafusion_distributed_tpu.plan import physical as _phys
+        from datafusion_distributed_tpu.plan.physical import execute_plan
+        from datafusion_distributed_tpu.runtime.metrics import MetricsStore
+
+        traces_before = _phys.trace_count()
+        store = MetricsStore()
+        shared_cache, shared_key = self._stage_compile_cache(key, data)
+        # the wire trace context must NOT reach ExecContext.config or
+        # any compile-cache key: span ids differ per task, and keying
+        # a program on them would force one XLA trace per task
+        # (plan/physical.py filters it from cfg_items as a second
+        # line of defense)
+        exec_config = {
+            k: v for k, v in (data.config or {}).items()
+            if k != "trace_ctx"
+        }
+        out = execute_plan(
+            data.plan,
+            DistributedTaskContext(key.task_number, data.task_count),
+            config=exec_config or None,
+            metrics_store=store,
+            task_label=f"task{key.task_number}",
+            use_cache=False,  # freshly decoded plans never hit the cache
+            shared_cache=shared_cache,
+            shared_key=shared_key,
+        )
+        data.metrics["nodes"] = store.per_task.get(
+            f"task{key.task_number}", {}
+        )
+        data.finished_at = time.time()
+        data.metrics["rows_out"] = int(out.num_rows)
+        data.metrics["elapsed_s"] = data.finished_at - data.executed_at
+        phase.set(rows=data.metrics["rows_out"])
+        if not phase.live:
             # compile-cache attribution: new_traces > 0 means this
             # execute paid a fresh XLA trace (a stage-compile cache miss);
-            # 0 means it reused a shared program (hit)
-            data.metrics.setdefault("spans", []).append(worker_span(
-                "worker_execute", "execute", exec_t0, time.monotonic(),
-                tctx.get("parent"), worker=self.url,
-                rows=data.metrics["rows_out"],
-                new_traces=_phys.trace_count() - traces_before,
-            ))
+            # 0 means it reused a shared program (hit). A live phase
+            # holds `execute_plan`'s own ``execute`` span, which says so.
+            phase.set(new_traces=_phys.trace_count() - traces_before)
         return out
 
     def execute_task_stream(self, key: TaskKey, chunk_rows: int = 65536,

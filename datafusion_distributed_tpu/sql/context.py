@@ -30,6 +30,7 @@ from datafusion_distributed_tpu.plan.physical import (
     MemoryScanExec,
     execute_plan,
 )
+from datafusion_distributed_tpu.runtime import tracing
 from datafusion_distributed_tpu.schema import Schema
 from datafusion_distributed_tpu.sql import parser as ast
 from datafusion_distributed_tpu.sql.logical import Binder, LogicalPlan
@@ -501,9 +502,16 @@ def _overflow_retry_guard(plan, attempt: int, last_err) -> None:
 class DataFrame:
     """A planned (but unexecuted) query."""
 
-    def __init__(self, ctx: "SessionContext", logical: LogicalPlan):
+    def __init__(self, ctx: "SessionContext", logical: LogicalPlan,
+                 request_id: Optional[str] = None):
         self.ctx = ctx
         self.logical = logical
+        # the request's identifier: the root of every trace begun for
+        # this DataFrame carries it (runtime/tracing.py `layer_report`
+        # merges them). Minted by the first call that is traced (None
+        # until then). Traces are begun and finished inside one call, so
+        # a DataFrame that is never collected pins nothing in the store.
+        self.request_id = request_id
         # plan memoization: repeated collect() of the same DataFrame reuses
         # the plan object. Lookups go through the SESSION-level cache keyed
         # by the logical plan's structural fingerprint, so a fresh
@@ -579,27 +587,50 @@ class DataFrame:
         overflow."""
         cfg = self.ctx.config.planner
         last_err: Optional[Exception] = None
-        for _attempt in range(self.ctx.config.overflow_retries + 1):
-            try:
-                # planning is inside the try: scalar subqueries execute at
-                # plan time and their overflows must trigger the same retry
-                plan = self.physical_plan(cfg)
-                _overflow_retry_guard(plan, _attempt, last_err)
-                out = execute_plan(plan)
-                self.last_retry_count = _attempt  # observability (sweeps)
-                return out
-            except RuntimeError as e:
-                if isinstance(e, OverflowRetryAbandoned):
-                    raise
-                if "overflow" not in str(e):
-                    raise
-                last_err = e
-                cfg, _ = _widen_for_overflow(
-                    cfg, None, e,
-                    force_all=_attempt
-                    >= self.ctx.config.overflow_retries - 1,
-                )
-        raise last_err  # type: ignore[misc]
+        with self._trace_call("query") as call:
+            for _attempt in range(self.ctx.config.overflow_retries + 1):
+                try:
+                    with call.tracer.span("attempt", "attempt",
+                                          attempt=_attempt):
+                        # planning is inside the try: scalar subqueries
+                        # execute at plan time and their overflows must
+                        # trigger the same retry
+                        plan = self.physical_plan(cfg)
+                        _overflow_retry_guard(plan, _attempt, last_err)
+                        out = execute_plan(plan)
+                    call.span.set(retries=_attempt)
+                    self.last_retry_count = _attempt  # observability (sweeps)
+                    return self._tagged(out, call.request)
+                except RuntimeError as e:
+                    if isinstance(e, OverflowRetryAbandoned):
+                        raise
+                    if "overflow" not in str(e):
+                        raise
+                    last_err = e
+                    cfg, _ = _widen_for_overflow(
+                        cfg, None, e,
+                        force_all=_attempt
+                        >= self.ctx.config.overflow_retries - 1,
+                    )
+            raise last_err  # type: ignore[misc]
+
+    def _trace_call(self, name: str):
+        return tracing.trace_call(
+            name, self.ctx.config.distributed_options, self.request_id
+        )
+
+    def _tagged(self, out: Table, request: Optional[str]) -> Table:
+        """A collect's result. Traced (``request`` is its trace's), it
+        carries the request's identifier, so that the fetch of a bare
+        Table joins its request: on a Table object of the result's own,
+        never on ``out`` itself, which a result cache may hold too and
+        hand to a later, untraced hit."""
+        if request is None:
+            return out
+        self.request_id = request
+        return tracing.tag_request(
+            Table(out.names, out.columns, out.num_rows), request
+        )
 
     def collect(self):
         """-> pyarrow Table with user-facing column names."""
@@ -617,7 +648,10 @@ class DataFrame:
             # duplicate short names (SELECT c.x, o.x) keep their qualifier
             names.append(n if short in seen else short)
             seen.add(short)
-        return Table(tuple(names), t.columns, t.num_rows)
+        return tracing.tag_request(
+            Table(tuple(names), t.columns, t.num_rows),
+            tracing.request_of(t),
+        )
 
     # -- distributed execution -------------------------------------------------
     def distributed_plan(self, num_tasks: int = 8, config=None,
@@ -718,27 +752,33 @@ class DataFrame:
             self._seeded_distributed_config(t), uniform_stage_tasks=True
         )
         last_err: Optional[Exception] = None
-        for _attempt in range(self.ctx.config.overflow_retries + 1):
-            try:
-                plan = self.distributed_plan(t, dcfg, pcfg, mesh=mesh)
-                _overflow_retry_guard(plan, _attempt, last_err)
-                out = execute_on_mesh(plan, mesh)
-                self.last_retry_count = _attempt
-                return out
-            except RuntimeError as e:
-                if isinstance(e, OverflowRetryAbandoned):
-                    raise
-                if "overflow" not in str(e):
-                    raise
-                last_err = e
-                # widen in place so every other customized field survives
-                # the retry (session SET options, skew factor included)
-                pcfg, dcfg = _widen_for_overflow(
-                    pcfg, dcfg, e,
-                    force_all=_attempt
-                    >= self.ctx.config.overflow_retries - 1,
-                )
-        raise last_err  # type: ignore[misc]
+        with self._trace_call("query") as call:
+            for _attempt in range(self.ctx.config.overflow_retries + 1):
+                try:
+                    with call.tracer.span("attempt", "attempt",
+                                          attempt=_attempt):
+                        plan = self.distributed_plan(t, dcfg, pcfg,
+                                                     mesh=mesh)
+                        _overflow_retry_guard(plan, _attempt, last_err)
+                        out = execute_on_mesh(plan, mesh)
+                    call.span.set(retries=_attempt)
+                    self.last_retry_count = _attempt  # observability (sweeps)
+                    return self._tagged(out, call.request)
+                except RuntimeError as e:
+                    if isinstance(e, OverflowRetryAbandoned):
+                        raise
+                    if "overflow" not in str(e):
+                        raise
+                    last_err = e
+                    # widen in place so every other customized field
+                    # survives the retry (session SET options, skew
+                    # factor included)
+                    pcfg, dcfg = _widen_for_overflow(
+                        pcfg, dcfg, e,
+                        force_all=_attempt
+                        >= self.ctx.config.overflow_retries - 1,
+                    )
+            raise last_err  # type: ignore[misc]
 
     def _seeded_distributed_config(self, num_tasks: int):
         """DistributedConfig honoring the session's `SET distributed.*`
@@ -813,21 +853,21 @@ class DataFrame:
         rc = self.ctx.result_cache()
         key = self._result_cache_key(num_tasks) if rc is not None else None
         if key is None:
-            return self._collect_coordinated_uncached(
+            return self._tagged(*self._collect_coordinated_uncached(
                 coordinator, num_workers, num_tasks, adaptive
-            )
+            ))
         state, cached = rc.begin(key)
         if state == "hit":
             return cached
         try:
-            out = self._collect_coordinated_uncached(
+            out, request = self._collect_coordinated_uncached(
                 coordinator, num_workers, num_tasks, adaptive
             )
         except BaseException:
             rc.fail(key)
             raise
         rc.fill(key, out)
-        return out
+        return self._tagged(out, request)
 
     def _collect_coordinated_uncached(
         self,
@@ -835,7 +875,9 @@ class DataFrame:
         num_workers: int = 2,
         num_tasks: int = 4,
         adaptive: bool = False,
-    ) -> Table:
+    ) -> tuple:
+        """-> (the result, the request of its trace or None where it was
+        not traced)."""
         from datafusion_distributed_tpu.runtime.coordinator import (
             AdaptiveCoordinator,
             Coordinator,
@@ -867,20 +909,33 @@ class DataFrame:
                     # successes through the same coordinator must not reset
                     # the widened headroom mid-attempt)
                     coordinator.pin_overflow_headroom(_attempt)
+                # an attempt here is one `Coordinator.execute`, a trace
+                # of its own: its root carries the request and the
+                # attempt, and the one that succeeds the retries
+                scope = tracing.request_scope(self.request_id,
+                                              attempt=_attempt)
                 try:
-                    plan = self.distributed_plan(
-                        num_tasks, dcfg, pcfg, coordinator=coordinator
-                    )
-                    _overflow_retry_guard(plan, _attempt, last_err)
-                    out = coordinator.execute(plan)
-                    self.last_retry_count = _attempt
-                    return out
+                    with scope:
+                        plan = self.distributed_plan(
+                            num_tasks, dcfg, pcfg, coordinator=coordinator
+                        )
+                        _overflow_retry_guard(plan, _attempt, last_err)
+                        out = coordinator.execute(plan)
+                    traced = (
+                        getattr(coordinator, "trace_store", None)
+                        or tracing.DEFAULT_TRACE_STORE
+                    ).annotate(getattr(coordinator, "last_query_id", None),
+                               retries=_attempt)
+                    self.last_retry_count = _attempt  # observability (sweeps)
+                    return out, scope.request if traced else None
                 except RuntimeError as e:
                     if isinstance(e, OverflowRetryAbandoned):
                         raise
                     if "overflow" not in str(e):
                         raise
                     last_err = e
+                    # the retry's trace joins the request of this one
+                    self.request_id = scope.request
                     pcfg, dcfg = _widen_for_overflow(
                         pcfg, dcfg, e,
                         force_all=_attempt
@@ -1057,7 +1112,14 @@ class SessionContext:
 
     # -- SQL ------------------------------------------------------------------
     def sql(self, query: str) -> DataFrame:
-        stmts = parse_statements(query)
+        with tracing.trace_call("sql",
+                                self.config.distributed_options) as call:
+            with call.tracer.span("parse", "parse"):
+                stmts = parse_statements(query)
+            with call.tracer.span("plan", "plan"):
+                return self._bind_statements(stmts, call.request)
+
+    def _bind_statements(self, stmts, request_id: Optional[str]):
         result: Optional[DataFrame] = None
         views: dict[str, LogicalPlan] = dict(self.catalog.views)
         for stmt in stmts:
@@ -1091,7 +1153,7 @@ class SessionContext:
                 result = DataFrame(self, binder.bind(stmt.query)).explain_verify()
             else:
                 binder = Binder(_ViewCatalog(self.catalog, views), views)
-                result = DataFrame(self, binder.bind(stmt))
+                result = DataFrame(self, binder.bind(stmt), request_id)
         if result is None:
             if stmts:
                 return None  # DDL/SET-only script

@@ -95,12 +95,18 @@ def _table(columns: dict, capacity: int, sharding,
     return Table(tuple(columns), cols, _shape(sharding, jnp.int32, *lead))
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, scopes=()):
+    """``scopes``: operator scopes (`jax.named_scope`, listed in PERF.md)
+    that the chip's optimized program must still carry in its ops'
+    ``op_name`` metadata: that is where a profile of the chip finds them."""
     compiled = jax.jit(fn).lower(*args).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"program needs {total / 1e9:.1f} GB"
+    text = compiled.as_text()
+    for scope in scopes:
+        assert f"/{scope}/" in text, f"no op of the program names {scope}"
     return compiled
 
 
@@ -131,7 +137,8 @@ def test_claim_loop_group_by_compiles(one_chip, query):
         return hash_aggregate(t, keys, aggs, slots, "single",
                               out_capacity=out_capacity)
 
-    _compile(kernel, _table(columns, 8 * MI, one_chip))
+    _compile(kernel, _table(columns, 8 * MI, one_chip),
+             scopes=("agg.claim", "agg.reduce.sum", "table.gather"))
 
 
 def test_hash_join_build_and_probe_compiles(one_chip):
@@ -149,7 +156,8 @@ def test_hash_join_build_and_probe_compiles(one_chip):
         side = build_join_table(build, ["o_orderkey"], 2 * MI, [True])
         return hash_join(probe, side, ["l_orderkey"], "inner", 8 * MI)
 
-    _compile(kernel, lineitem, orders)
+    _compile(kernel, lineitem, orders,
+             scopes=("join.build", "join.probe", "join.expand"))
 
 
 def test_multi_key_sort_top_k_compiles(one_chip):
@@ -170,7 +178,7 @@ def test_multi_key_sort_top_k_compiles(one_chip):
         keys = [SortKey("revenue", ascending=False), SortKey("o_orderdate")]
         return sort_table(t, keys).head(10)
 
-    _compile(kernel, grouped)
+    _compile(kernel, grouped, scopes=("sort.permutation",))
 
 
 def test_hash_shuffle_compiles_on_four_chips(mesh4):
@@ -192,7 +200,7 @@ def test_hash_shuffle_compiles_on_four_chips(mesh4):
 
     program = shard_map(per_task, mesh=mesh4, in_specs=P(AXIS),
                         out_specs=P(AXIS), check_rep=False)
-    compiled = _compile(program, stacked)
+    compiled = _compile(program, stacked, scopes=("exchange.shuffle",))
     assert "all-to-all" in compiled.as_text()
 
 

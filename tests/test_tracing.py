@@ -50,10 +50,12 @@ from datafusion_distributed_tpu.runtime.coordinator import (
     InMemoryCluster,
 )
 from datafusion_distributed_tpu.runtime.errors import TaskCancelledError
+from datafusion_distributed_tpu.runtime import tracing
 from datafusion_distributed_tpu.runtime.tracing import (
     DEFAULT_TRACE_STORE,
     NULL_TRACER,
     TraceStore,
+    layer_report,
     table_nbytes,
     render_profile,
     stage_data_rates,
@@ -98,6 +100,24 @@ where c_custkey = o_custkey
 group by n_name
 order by revenue desc
 """
+
+
+TPCH_Q1 = """
+select l_returnflag, l_linestatus,
+       sum(l_quantity) as sum_qty,
+       sum(l_extendedprice) as sum_base_price,
+       sum(l_extendedprice * (1 - l_discount)) as sum_disc_price,
+       sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) as sum_charge,
+       avg(l_quantity) as avg_qty, avg(l_extendedprice) as avg_price,
+       avg(l_discount) as avg_disc, count(*) as count_order
+from lineitem
+where l_shipdate <= date '1998-09-02'
+group by l_returnflag, l_linestatus
+order by l_returnflag, l_linestatus
+"""
+
+# one group a live order: with too few slots the first attempt overflows
+HIGH_NDV = "select l_orderkey, count(*) as n from lineitem group by l_orderkey"
 
 
 @pytest.fixture(scope="module")
@@ -443,6 +463,36 @@ def test_tracing_off_zero_spans_and_zero_compiles():
     assert coord_on.last_query_trace() is not None
 
 
+def test_tracing_off_zero_spans_direct_and_mesh_tiers(tpch_ctx):
+    """No profiler session and no SET: the direct and mesh tiers hold the
+    null tracer too, leave nothing in the store, and turning tracing on
+    afterwards compiles nothing (no span or request id keys a program)."""
+    from datafusion_distributed_tpu.runtime.mesh_executor import make_mesh
+
+    mesh = make_mesh(2)
+    tpch_ctx.sql(TPCH_Q1).to_pandas()  # warm: compiles happen here
+    tpch_ctx.sql(TPCH_Q1).collect_distributed(mesh=mesh)
+    DEFAULT_TRACE_STORE.clear()
+    n0 = phys.trace_count()
+    tpch_ctx.sql(TPCH_Q1).to_pandas()
+    tpch_ctx.sql(TPCH_Q1).collect()
+    tpch_ctx.sql(TPCH_Q1).collect_distributed(mesh=mesh)
+    assert DEFAULT_TRACE_STORE.finished_traces() == []
+    assert layer_report() == []
+    assert tracing.current() is NULL_TRACER
+    df = tpch_ctx.sql(TPCH_Q1)
+    assert tracing.request_of(df.collect_table()) is None
+    assert df.request_id is None, "a request id was minted with tracing off"
+    tpch_ctx.config.distributed_options["tracing"] = "on"
+    try:
+        tpch_ctx.sql(TPCH_Q1).to_pandas()
+        tpch_ctx.sql(TPCH_Q1).collect_distributed(mesh=mesh)
+    finally:
+        tpch_ctx.config.distributed_options.pop("tracing", None)
+    assert phys.trace_count() == n0, "tracing on recompiled a program"
+    assert len(layer_report()) == 2
+
+
 # ---------------------------------------------------------------------------
 # serving path: traces isolated per query id
 # ---------------------------------------------------------------------------
@@ -471,9 +521,17 @@ def test_serving_traces_isolated_per_query(tpch_ctx):
     spans2 = {id(s) for s in t2.span_list()}
     assert not any(id(s) in spans2 for s in t1.span_list())
     assert h1.trace() is not None and h2.trace() is not None
-    # admission queue-wait annotated on the root span
+    # the handle is named on its execute's root; the admission wait is
+    # the request's `queued` span, a trace of its own under the request
     root = t1.root_span()
-    assert "admission_wait_s" in root.attrs
+    assert root.attrs["serving_query_id"] == h1.query_id
+    assert root.attrs["request"] == h1.request_id
+    assert "admission_wait_s" not in root.attrs
+    rows = {r["request"]: r for r in layer_report()}
+    for h in (h1, h2):
+        row = rows[h.request_id]
+        assert {"submit", "queued", "query"} <= set(row["self_s"])
+        assert row["total_s"]["queued"] == pytest.approx(h.queue_wait_s())
     assert h1.trace_profile()
 
 
@@ -510,26 +568,6 @@ def test_get_task_progress_degrades_per_worker():
     assert out[key]["worker"] == "mem://ok"
 
 
-def test_system_sampler_atomic_and_stop_idempotent():
-    import dataclasses
-
-    from datafusion_distributed_tpu.runtime.observability import (
-        SystemMetrics,
-        SystemMetricsSampler,
-    )
-
-    # the handoff contract: frozen snapshots swapped atomically
-    assert SystemMetrics.__dataclass_params__.frozen
-    with pytest.raises(dataclasses.FrozenInstanceError):
-        SystemMetrics().rss_bytes = 1
-    s = SystemMetricsSampler(interval_s=0.01).start()
-    assert s.latest.sampled_at > 0
-    s.stop()
-    s.stop()  # idempotent
-    # stop() on a never-started sampler is also a no-op
-    SystemMetricsSampler().stop()
-
-
 def test_trace_summary_and_console_panel():
     from datafusion_distributed_tpu.console import Console
     from datafusion_distributed_tpu.runtime.observability import (
@@ -546,6 +584,396 @@ def test_trace_summary_and_console_panel():
     assert summary["spans_by_kind"].get("stage")
     frame = Console(cluster, cluster).render_frame()
     assert "tracing" in frame
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 27: the profiler session is the switch; one span, two sinks; the
+# direct and mesh tiers trace too; one report a request
+# ---------------------------------------------------------------------------
+
+
+def _run_tier(ctx, tier: str, sql: str):
+    """One request on a tier, fetched to the host. -> (result frame,
+    the request's identifier, its overflow retries)."""
+    if tier == "direct":
+        df = ctx.sql(sql)
+        table = df.collect_table()
+        return table.to_pandas(), df.request_id, df.last_retry_count
+    if tier == "mesh":
+        from datafusion_distributed_tpu.runtime.mesh_executor import make_mesh
+
+        df = ctx.sql(sql)
+        out = df.collect_distributed(mesh=make_mesh(2))
+        return out.to_pandas(), df.request_id, df.last_retry_count
+    from datafusion_distributed_tpu.runtime.serving import ServingSession
+
+    with ServingSession(ctx, num_workers=2, num_tasks=2) as srv:
+        h = srv.submit(sql)
+        out = h.result(timeout=600)
+        return out.to_pandas(), h.request_id, h.retry_count
+
+
+def _request_spans(request_id: str) -> dict:
+    """kind -> [spans] over every finished trace of one request."""
+    out: dict = {}
+    for trace in DEFAULT_TRACE_STORE.finished_traces():
+        if trace.request != request_id:
+            continue
+        root = trace.root_span()
+        assert root.attrs["request"] == request_id
+        for s in trace.span_list():
+            out.setdefault(s.kind, []).append(s)
+    return out
+
+
+def _host_events(trace_dir) -> list:
+    """(name, start_ns, end_ns) of every `dftpu.*` event in the host planes
+    of the profile a `jax.profiler` session left under ``trace_dir``."""
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(
+        os.path.join(str(trace_dir), "plugins", "profile", "*",
+                     "*.xplane.pb")
+    )
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(tracing.PROFILE_PREFIX):
+                    events.append((ev.name, ev.start_ns,
+                                   ev.start_ns + ev.duration_ns))
+    return events
+
+
+@pytest.mark.parametrize("tier", ["direct", "coordinator", "mesh"])
+def test_profiler_session_switches_tracing_on_and_off(tier, tpch_ctx,
+                                                      tmp_path):
+    """No SET: a recording `jax.profiler` session traces the request on
+    every tier, its spans are `dftpu.*` events in the profile's host plane
+    inside the session's window, and the session's end turns it off."""
+    import jax
+
+    assert "tracing" not in tpch_ctx.config.distributed_options
+    _run_tier(tpch_ctx, tier, TPCH_Q1)  # warm, untraced
+    DEFAULT_TRACE_STORE.clear()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        assert jax.profiler.TraceAnnotation.is_enabled()
+        with jax.profiler.TraceAnnotation("test.window"):
+            _frame, request_id, _ = _run_tier(tpch_ctx, tier, TPCH_Q1)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _request_spans(request_id)
+    want = {
+        "direct": {"sql", "parse", "plan", "query", "attempt", "prepare",
+                   "execute", "fetch"},
+        "coordinator": {"submit", "sql", "parse", "plan", "queued", "query",
+                        "schedule", "stage", "task", "attempt", "dispatch",
+                        "prepare", "execute", "fetch"},
+        "mesh": {"sql", "parse", "plan", "query", "attempt",
+                 "mesh.stack_inputs", "mesh.execute", "fetch"},
+    }[tier]
+    assert want <= set(spans), sorted(set(spans))
+    (row,) = [r for r in layer_report() if r["request"] == request_id]
+    assert row["counters"]["transfers"] > 0
+    # the second sink: the same spans in the profiler's own trace
+    events = _host_events(tmp_path)
+    names = {name for name, _, _ in events}
+    live = {"direct": {"sql", "parse", "plan", "query", "attempt",
+                       "prepare", "execute", "fetch"},
+            "coordinator": {"submit", "query", "schedule", "task",
+                            "dispatch", "worker_execute", "execute",
+                            "fetch"},
+            "mesh": {"query", "mesh.stack_inputs", "mesh.execute",
+                     "fetch"}}[tier]
+    assert {tracing.PROFILE_PREFIX + n for n in live} <= names, sorted(names)
+    # after-the-fact spans (the stage spans, `queued`) stay in the store
+    assert tracing.PROFILE_PREFIX + "queued" not in names
+    # the session's end is the switch's: nothing is traced any more
+    DEFAULT_TRACE_STORE.clear()
+    _run_tier(tpch_ctx, tier, TPCH_Q1)
+    assert DEFAULT_TRACE_STORE.finished_traces() == []
+
+
+def test_direct_q1_span_tree_under_one_request(tpch_ctx):
+    tpch_ctx.sql(TPCH_Q1).to_pandas()  # warm
+    DEFAULT_TRACE_STORE.clear()
+    tpch_ctx.config.distributed_options["tracing"] = "on"
+    try:
+        df = tpch_ctx.sql(TPCH_Q1)
+        table = df.collect_table()
+        frame = table.to_pandas()
+    finally:
+        tpch_ctx.config.distributed_options.pop("tracing", None)
+    assert len(frame) == 4
+    traces = [t for t in DEFAULT_TRACE_STORE.finished_traces()
+              if t.request == df.request_id]
+
+    def tree(trace):
+        spans = trace.span_list()
+        kids: dict = {}
+        for s in spans:
+            kids.setdefault(s.parent_id, []).append(s)
+
+        def walk(s):
+            below = sorted(kids.get(s.span_id, []), key=lambda k: k.t0)
+            return (s.kind, [walk(k) for k in below])
+
+        return walk(trace.root_span())
+
+    assert [tree(t) for t in traces] == [
+        ("sql", [("parse", []), ("plan", [])]),
+        ("query", [("attempt", [
+            ("prepare", [("h2d", [])]), ("execute", []),
+        ])]),
+        ("fetch", []),
+    ]
+    for t in traces:
+        _assert_monotonic_tree(t)
+    (row,) = [r for r in layer_report() if r["request"] == df.request_id]
+    assert len(row["traces"]) == 3
+    assert row["counters"]["retries"] == 0
+    assert row["counters"]["new_traces"] == 0
+    # ten output columns, data and validity each where nullable, and the
+    # row count: every one a device-to-host pull
+    fetch = traces[2].root_span()
+    assert fetch.attrs["rows"] == 4
+    assert fetch.attrs["transfers"] == 1 + sum(
+        1 + (c.validity is not None) for c in table.columns
+    )
+    assert "layers (self time by span kind)" in render_profile(traces[1])
+    # a DataFrame that is never collected pins nothing in the store
+    tpch_ctx.config.distributed_options["tracing"] = "on"
+    try:
+        tpch_ctx.sql(TPCH_Q1)
+    finally:
+        tpch_ctx.config.distributed_options.pop("tracing", None)
+    assert DEFAULT_TRACE_STORE.summary()["running"] == 0
+
+
+def test_untraced_cache_hit_after_a_traced_collect_leaves_no_trace(
+        tpch_ctx):
+    """`SET distributed.result_cache = on`: the traced collect that fills
+    the cache tags a Table of its own with the request, never the object
+    the cache holds, so a later hit with tracing off hands back an
+    untagged Table whose fetch opens no trace and joins no old request;
+    and with tracing off no request identifier is minted at all."""
+    opts = tpch_ctx.config.distributed_options
+    sql = "select count(*) as n from nation"
+    opts["result_cache"] = True
+    try:
+        opts["tracing"] = "on"
+        try:
+            df = tpch_ctx.sql(sql)
+            out = df.collect_coordinated_table()
+            assert len(out.to_pandas()) == 1
+        finally:
+            opts.pop("tracing", None)
+        assert tracing.request_of(out) == df.request_id is not None
+        (row,) = [r for r in layer_report()
+                  if r["request"] == df.request_id]
+        transfers = row["counters"]["transfers"]
+        assert transfers > 0
+        hits0 = tpch_ctx.result_cache().stats()["hits"]
+        DEFAULT_TRACE_STORE.clear()
+        df2 = tpch_ctx.sql(sql)
+        hit = df2.collect_coordinated_table()
+        assert tpch_ctx.result_cache().stats()["hits"] == hits0 + 1
+        assert hit is not out and tracing.request_of(hit) is None
+        assert len(hit.to_pandas()) == 1
+        assert len(df2.collect_coordinated()) == 1
+        assert df2.request_id is None
+        assert DEFAULT_TRACE_STORE.finished_traces() == []
+        assert layer_report() == []
+    finally:
+        opts.pop("result_cache", None)
+        tpch_ctx._result_cache = None
+
+
+@pytest.mark.parametrize("tier,slot_factor", [
+    ("direct", 0.3), ("coordinator", 0.07), ("mesh", 0.05),
+])
+def test_forced_overflow_is_two_attempts_and_one_retry(tier, slot_factor,
+                                                       tpch_ctx):
+    planner = tpch_ctx.config.planner
+    saved = planner.agg_slot_factor
+    planner.agg_slot_factor = slot_factor
+    tpch_ctx.config.distributed_options["tracing"] = "on"
+    DEFAULT_TRACE_STORE.clear()
+    try:
+        frame, request_id, retries = _run_tier(tpch_ctx, tier, HIGH_NDV)
+    finally:
+        planner.agg_slot_factor = saved
+        tpch_ctx.config.distributed_options.pop("tracing", None)
+    assert retries == 1
+    assert len(frame) == len(set(frame["l_orderkey"]))
+    (row,) = [r for r in layer_report() if r["request"] == request_id]
+    assert row["counters"]["retries"] == 1
+    spans = _request_spans(request_id)
+    if tier == "coordinator":
+        # an attempt is one `Coordinator.execute`, a trace of its own
+        attempts = sorted(
+            (s.attrs["attempt"], s.attrs.get("error"),
+             s.attrs.get("retries")) for s in spans["query"]
+        )
+    else:
+        (root,) = spans["query"]
+        assert root.attrs["retries"] == 1
+        attempts = sorted(
+            (s.attrs["attempt"], s.attrs.get("error"), None)
+            for s in spans["attempt"]
+        )
+        attempts[1] = attempts[1][:2] + (1,)
+    assert [a[0] for a in attempts] == [0, 1]
+    assert attempts[0][1] is not None and attempts[1][1] is None
+    assert attempts[1][2] == 1
+
+
+def test_d2h_and_h2d_bytes_are_table_nbytes_of_what_crossed():
+    from datafusion_distributed_tpu.ops.table import host_view
+
+    rng = np.random.default_rng(5)
+    device = arrow_to_table(pa.table({
+        "k": rng.integers(0, 8, 512), "v": rng.normal(size=512),
+    }))
+    store = TraceStore()
+    with tracing.trace_call("query", {"tracing": "on"}, "r1",
+                            store=store) as call:
+        host = host_view(device)
+        assert host_view(host) is host  # already on the host: no span
+        scan = MemoryScanExec([host], host.schema())
+        agg = HashAggregateExec(
+            "single", ["k"], [AggSpec("sum", "v", "sv")], scan, 32
+        )
+        phys.execute_plan(agg)
+    spans = call.tracer.trace.span_list()
+    (d2h,) = [s for s in spans if s.kind == "d2h"]
+    assert d2h.attrs["bytes"] == table_nbytes(device) > 0
+    assert d2h.attrs["rows"] == 512
+    assert d2h.attrs["capacity"] == device.capacity
+    (h2d,) = [s for s in spans if s.kind == "h2d"]
+    assert h2d.attrs["bytes"] == table_nbytes(host) == table_nbytes(device)
+    (row,) = layer_report(store)
+    assert row["counters"]["bytes"] == {
+        "d2h": table_nbytes(device), "h2d": table_nbytes(host),
+    }
+
+
+def test_exchange_spans_split_d2h_and_regroup(tpch_ctx):
+    """Inside an exchange the host's work is named: the pull of the
+    producers' outputs (`d2h`) and the regroup, children of the
+    `exchange` span, with the bytes that crossed."""
+    cluster = InMemoryCluster(2)
+    _out, coord = _run_tpch(tpch_ctx, TPCH_Q1, cluster,
+                            pipelined_shuffle=False, data_plane="unary")
+    trace = coord.last_query_trace()
+    spans = trace.span_list()
+    by_id = {s.span_id: s for s in spans}
+
+    def ancestors(s):
+        while s.parent_id in by_id:
+            s = by_id[s.parent_id]
+            yield s.kind
+
+    d2h = [s for s in spans if s.kind == "d2h"]
+    regroup = [s for s in spans if s.kind == "regroup"]
+    assert d2h and regroup
+    for s in d2h + regroup:
+        assert "exchange" in set(ancestors(s)), s.name
+        assert s.attrs["bytes"] > 0
+    h2d = [s for s in spans if s.kind == "h2d" and s.attrs["bytes"]]
+    assert h2d, "no consumer scan handed host-staged bytes to a program"
+    for s in h2d:
+        assert "prepare" in set(ancestors(s))
+    _assert_monotonic_tree(trace)
+
+
+def test_layer_report_self_times_sum_to_the_wall():
+    """Where no two tasks overlap, the layers add up: the self times of a
+    request's spans, summed over kinds, are its roots' wall."""
+    cluster = InMemoryCluster(1)
+    store = TraceStore()
+    coord = Coordinator(
+        resolver=cluster, channels=cluster, trace_store=store,
+        config_options={**FAST, "stage_parallelism": 1},
+    )
+    coord.execute(_plan(num_tasks=1))
+    (row,) = layer_report(store)
+    assert row["wall_s"] > 0
+    assert sum(row["self_s"].values()) == pytest.approx(
+        row["wall_s"], rel=1e-3
+    )
+    assert row["total_s"]["worker_execute"] > 0
+    assert {"query", "schedule", "stage", "task", "execute"} <= set(
+        row["self_s"]
+    )
+
+
+def test_scopes_name_the_kernels_and_change_metadata_only(tpch_ctx,
+                                                          monkeypatch):
+    """The lowered q1 and q3 programs carry the operator scopes in
+    `op_name`; without them the optimized HLO differs in metadata only
+    and the results are byte-equal."""
+    import contextlib
+    import re
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # jax leaves metadata out of the persistent cache's key, so a cached
+    # executable would answer for both variants: compile afresh
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def programs(sql):
+        plan = tpch_ctx.sql(sql).physical_plan()
+        fn, _, _, _, inputs, params, _ = phys._prepare_program(
+            plan, phys.DistributedTaskContext(), None, False, None, None,
+            NULL_TRACER,
+        )
+        lowered = fn.lower(inputs, params)
+        out = jax.tree_util.tree_leaves(fn(inputs, params)[0])
+        return (lowered.as_text(debug_info=True),
+                lowered.compile().as_text(),
+                [np.asarray(x).tobytes() for x in out])
+
+    def strip(hlo):
+        """The module without its metadata: every op's `metadata={...}`
+        and the tables of source locations the module opens with."""
+        head, _, body = hlo.partition("\n\n")
+        body = body[re.search(r"^(%|ENTRY)", body, re.M).start():]
+        return head + re.sub(r", metadata=\{[^}]*\}", "", body)
+
+    scoped = {q: programs(sql) for q, sql in
+              (("q1", TPCH_Q1), ("q3", TPCH_Q3))}
+    for name in ("agg.claim", "agg.reduce.sum", "sort.permutation",
+                 "table.gather", "HashAggregateExec."):
+        assert name in scoped["q1"][0], name
+    for name in ("agg.claim", "agg.reduce.sum", "join.build", "join.probe",
+                 "join.expand", "sort.permutation", "HashJoinExec."):
+        assert name in scoped["q3"][0], name
+    # a node is named by its class and pre-order position, never its id
+    assert re.search(r"SortExec\.0/", scoped["q1"][0])
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    try:
+        for q, sql in (("q1", TPCH_Q1), ("q3", TPCH_Q3)):
+            lowered, optimized, result = programs(sql)
+            assert "agg.claim" not in lowered
+            assert "agg.claim" in scoped[q][1]
+            assert "agg.claim" not in optimized
+            assert strip(optimized) == strip(scoped[q][1])
+            assert result == scoped[q][2]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -576,6 +1004,28 @@ def test_dftpu109_flags_spans_in_traced_code(tmp_path):
     report = json.loads(proc.stdout)
     rules = {v["rule"] for v in report["violations"]}
     assert "DFTPU109" in rules, report
+    # the profiler annotation a live span doubles as is host-side too;
+    # `jax.named_scope` is the one instrumentation that belongs in a trace
+    bad.write_text(
+        "import jax\n"
+        "from jax import jit\n"
+        "def kernel(x):\n"
+        "    with jax.profiler.TraceAnnotation('dftpu.k'):\n"
+        "        y = x + 1\n"
+        "    with jax.named_scope('agg.claim'):\n"
+        "        y = y * 2\n"
+        "    return y\n"
+        "f = jit(kernel)\n"
+    )
+    proc = subprocess.run(
+        [sys.executable,
+         os.path.join(os.path.dirname(os.path.dirname(
+             os.path.abspath(__file__))), "tools", "check_tracer_safety.py"),
+         "--json", str(bad)],
+        capture_output=True, text=True,
+    )
+    found = json.loads(proc.stdout)["violations"]
+    assert [(v["rule"], v["line"]) for v in found] == [("DFTPU109", 4)], found
     # the package itself must stay clean under the new rule
     proc2 = subprocess.run(
         [sys.executable,

@@ -32,7 +32,8 @@ plan/verify.py):
                                  time.monotonic/perf_counter[_ns] report
                                  as DFTPU109, the tracing-span rule)
   DFTPU106  mutable-default      def f(x=[] / {} / set())
-  DFTPU109  span-in-trace        tracing-span API / time.monotonic /
+  DFTPU109  span-in-trace        tracing-span API / profiler
+                                 TraceAnnotation / time.monotonic /
                                  time.perf_counter call in a trace path
                                  (distributed-tracing instrumentation is
                                  host-side only: a span opened inside a
@@ -370,10 +371,13 @@ class _RuleVisitor(ast.NodeVisitor):
     @staticmethod
     def _is_tracing_api(name: str) -> bool:
         """Calls that belong to the distributed-tracing span surface
-        (runtime/tracing.py): any receiver/attribute chain naming a
+        (spans.py, runtime/tracing.py): any receiver/attribute chain naming a
         tracer (`self._tracer.span`, `tr.event`, `NULL_TRACER...`), the
-        module-level span constructors, and the monotonic clocks the
-        span layer is built on."""
+        module-level span constructors, the profiler annotation a live
+        span doubles as (`jax.profiler.TraceAnnotation`), and the
+        monotonic clocks the span layer is built on. `jax.named_scope` is
+        NOT one of them: it names the ops a traced function lowers to, at
+        trace time, and is the one instrumentation that belongs there."""
         if name in ("time.monotonic", "time.perf_counter",
                     "time.perf_counter_ns", "time.monotonic_ns"):
             return True
@@ -381,7 +385,10 @@ class _RuleVisitor(ast.NodeVisitor):
         if any("tracer" in p.lower() for p in parts):
             return True
         return parts[-1] in ("start_span", "end_span", "worker_span",
-                             "finish_reserved") or (
+                             "finish_reserved", "open_root", "trace_call",
+                             "fetch_call", "record_span", "worker_phase",
+                             "TraceAnnotation",
+                             "StepTraceAnnotation") or (
             len(parts) > 1 and parts[-1] in ("span", "event")
             and parts[-2] in ("tr", "tracing")
         )
