@@ -248,8 +248,13 @@ def hash_aggregate(
     mode: str = "single",  # "single" | "partial" | "final" | "partial_reduce"
     prec_flags: Optional[list] = None,
     out_capacity: Optional[int] = None,
+    live: Optional[jnp.ndarray] = None,
 ) -> tuple[Table, jnp.ndarray]:
     """GROUP BY aggregation. Returns (result table, overflow flag).
+
+    ``live``, when given, is the ``[capacity] bool`` mask of the rows to
+    aggregate, anywhere in the table (a filter's mask handed up unpacked:
+    `ExecutionPlan.execute_masked`); None is the ``num_rows`` prefix.
 
     ``prec_flags``, when given, collects traced bools flagging integer SUM
     results that left int32's exact range (tpu precision mode only; the
@@ -264,15 +269,18 @@ def hash_aggregate(
                         `partial_reduce_below_network_shuffles.rs` /
                         the progressive reduction-tree example): fewer
                         partial states cross each exchange hop
-    The result table has capacity == num_slots, groups packed to the front.
+    The result table has capacity == min(out_capacity or num_slots,
+    num_slots), groups packed to the front.
     """
+    if live is None:
+        live = table.row_mask()
     if mode == "single" and group_names and aggs:
         fused = _try_global_hash_aggregate(
-            table, group_names, aggs, num_slots, out_capacity, prec_flags
+            table, group_names, aggs, num_slots, out_capacity, prec_flags,
+            live,
         )
         if fused is not None:
             return fused
-    live = table.row_mask()
     key_cols = [table.column(g).data for g in group_names]
     key_valids = [table.column(g).validity for g in group_names]
     gt = build_group_table(key_cols, key_valids, live, num_slots)
@@ -318,6 +326,7 @@ def _try_global_hash_aggregate(
     num_slots: int,
     out_capacity: Optional[int],
     prec_flags: Optional[list],
+    live: jnp.ndarray,
 ) -> Optional[tuple[Table, jnp.ndarray]]:
     """Fused single-pass global-hash-table aggregation (DFTPU_PALLAS=1):
     one VMEM-resident kernel builds the group table AND folds the
@@ -349,7 +358,6 @@ def _try_global_hash_aggregate(
         if np.dtype(col.data.dtype).itemsize != 4:
             return None
 
-    live = table.row_mask()
     n = table.capacity
     i32 = jnp.int32
     int32_max = np.iinfo(np.int32).max
@@ -453,11 +461,14 @@ def _try_global_hash_aggregate(
 
 @scoped("agg.global")
 def global_aggregate(table: Table, aggs: Sequence[AggSpec], mode: str = "single",
-                     prec_flags: Optional[list] = None) -> Table:
+                     prec_flags: Optional[list] = None,
+                     live: Optional[jnp.ndarray] = None) -> Table:
     """Aggregation with no GROUP BY: one output row (capacity 8 keeps the
     result TPU-lane-friendly). Shares the per-aggregate evaluation with
-    hash_aggregate, with every live row mapped to group 0."""
-    live = table.row_mask()
+    hash_aggregate, with every live row (``live`` as there) mapped to
+    group 0."""
+    if live is None:
+        live = table.row_mask()
     cap = 8
     gid = jnp.zeros(table.capacity, dtype=jnp.int32)
 

@@ -433,10 +433,9 @@ class Table:
         keep = keep & self.row_mask()
         (idx,) = jnp.nonzero(keep, size=self.capacity, fill_value=0)
         n = jnp.sum(keep, dtype=jnp.int32)
-        t = self.gather(idx, n)
-        # Rows past n were filled from index 0; mark them invalid via validity
-        # where present (data beyond num_rows is garbage by contract anyway).
-        return t
+        # rows past n are copies of row 0, validity included: garbage by
+        # the contract (data beyond num_rows may hold anything)
+        return self.gather(idx, n)
 
     def head(self, limit: int | jnp.ndarray) -> "Table":
         n = jnp.minimum(self.num_rows, jnp.asarray(limit, dtype=jnp.int32))

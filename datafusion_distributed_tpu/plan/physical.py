@@ -81,6 +81,10 @@ class ExecContext:
     # participate unconditionally); IsolatedArmExec relies on this to
     # pre-execute an arm's exchanges before conditioning its local compute
     exchange_cache: dict = dc_field(default_factory=dict)
+    # trace-time count of the FilterExec nodes answered on the masked path
+    # (`ExecutionPlan.execute_masked`): the `execute` span's
+    # ``masked_filters``
+    masked_filters: int = 0
 
     def record_overflow(self, node: "ExecutionPlan", flag) -> None:
         self.overflow_flags.append((node.label(), flag))
@@ -227,6 +231,27 @@ class ExecutionPlan:
 
     def _execute(self, ctx: ExecContext) -> Table:
         raise NotImplementedError
+
+    def execute_masked(self, ctx: ExecContext):
+        """What a consumer that works on a mask pulls (an aggregate: every
+        reduction of it masks dead rows out anyway). -> (table, live,
+        rows): this node's output, the ``[capacity] bool`` mask of its
+        live rows, which need not sit at the front (None: the
+        ``num_rows`` prefix), and their count. The default is the packed
+        table; `FilterExec` and `ProjectionExec` answer without packing.
+        Who pulls decides, by its type: every other consumer calls
+        `execute` and gets packed rows."""
+        t = self.execute(ctx)
+        return t, None, t.num_rows
+
+    def _answer_masked(self, ctx: ExecContext, work):
+        """`execute`'s scope and ``output_rows`` metric for a unary node
+        answered on the masked path: ``work(table, live, rows) -> (table,
+        live, rows)`` over what the child answers."""
+        with jax.named_scope(node_scope(self)):
+            out, live, rows = work(*self.child.execute_masked(ctx))
+        ctx.record_metric(self, "output_rows", rows)
+        return out, live, rows
 
     # -- display ------------------------------------------------------------
     def label(self) -> str:
@@ -406,11 +431,24 @@ class FilterExec(ExecutionPlan):
     def output_capacity(self):
         return self.child.output_capacity()
 
+    def _keep(self, t: Table) -> jnp.ndarray:
+        v = self.predicate.evaluate(t)
+        return v.data.astype(jnp.bool_) & v.valid_mask()
+
     def _execute(self, ctx: ExecContext) -> Table:
         t = self.child.execute(ctx)
-        v = self.predicate.evaluate(t)
-        keep = v.data.astype(jnp.bool_) & v.valid_mask()
-        return t.compact(keep)
+        return t.compact(self._keep(t))
+
+    def execute_masked(self, ctx: ExecContext):
+        """No compaction: the child's rows stay where they are and the
+        predicate narrows the mask (stacked filters AND theirs)."""
+        ctx.masked_filters += 1
+
+        def narrow(t, live, _rows):
+            keep = self._keep(t) & (t.row_mask() if live is None else live)
+            return t, keep, jnp.sum(keep, dtype=jnp.int32)
+
+        return self._answer_masked(ctx, narrow)
 
     def display(self):
         return f"Filter: {self.predicate.display()}"
@@ -439,12 +477,20 @@ class ProjectionExec(ExecutionPlan):
     def output_capacity(self):
         return self.child.output_capacity()
 
-    def _execute(self, ctx: ExecContext) -> Table:
-        t = self.child.execute(ctx)
+    def _project(self, t: Table) -> Table:
         cols = {}
         for expr, name in self.exprs:
             cols[name] = expr_to_column(expr.evaluate(t))
         return Table(tuple(cols.keys()), tuple(cols.values()), t.num_rows)
+
+    def _execute(self, ctx: ExecContext) -> Table:
+        return self._project(self.child.execute(ctx))
+
+    def execute_masked(self, ctx: ExecContext):
+        """Elementwise, so the child's mask passes through."""
+        return self._answer_masked(
+            ctx, lambda t, live, rows: (self._project(t), live, rows)
+        )
 
     def display(self):
         inner = ", ".join(f"{e.display()} AS {n}" for e, n in self.exprs)
@@ -511,17 +557,20 @@ class HashAggregateExec(ExecutionPlan):
         return self.out_capacity if self.group_names else self.num_slots
 
     def _execute(self, ctx: ExecContext) -> Table:
-        t = self.child.execute(ctx)
+        # every reduction below masks dead rows out, so the input need not
+        # be packed: filters and projections underneath hand their mask up
+        t, live, _ = self.child.execute_masked(ctx)
         prec_flags: list = []
         if not self.group_names:
             from datafusion_distributed_tpu.ops.aggregate import global_aggregate
 
             out = global_aggregate(t, self.aggs, self.mode,
-                                   prec_flags=prec_flags)
+                                   prec_flags=prec_flags, live=live)
         else:
             out, overflow = hash_aggregate(
                 t, self.group_names, self.aggs, self.num_slots, self.mode,
                 prec_flags=prec_flags, out_capacity=self.out_capacity,
+                live=live,
             )
             ctx.record_overflow(self, overflow)
         for f in prec_flags:
@@ -767,8 +816,8 @@ def execute_plan(
     tr = spans.current()
     traces_before = _TRACE_STATS["traces"]
     with tr.span("prepare", "prepare") as psp:
-        (fn, overflow_box, metric_names, first_call_gate, input_list,
-         params, cache) = _prepare_program(
+        (fn, overflow_box, metric_names, trace_counters, first_call_gate,
+         input_list, params, cache) = _prepare_program(
             plan, task, config, use_cache, shared_cache, shared_key, tr
         )
         psp.set(cache=cache)
@@ -791,7 +840,8 @@ def execute_plan(
         out, flags, metric_vals = result
         flags = np.asarray(flags)  # one fetch for both sentinel checks
         if tr.active:
-            xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before)
+            xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before,
+                    **trace_counters)
     any_overflow, any_precision = bool(flags[0]), bool(flags[1])
     if check_overflow and any_overflow:
         raise RuntimeError(
@@ -825,7 +875,7 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
     """`execute_plan`'s host work before the device starts (its
     ``prepare`` span): hoist and fingerprint the plan, load the leaves,
     find or make the jitted program. -> (fn, overflow_box, metric_names,
-    first_call_gate, input_list, params, "hit" | "miss")."""
+    trace_counters, first_call_gate, input_list, params, "hit" | "miss")."""
     from datafusion_distributed_tpu.plan.fingerprint import (
         bound_params,
         prepare_plan,
@@ -860,6 +910,8 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
 
     overflow_box: list = []
     metric_names: list = []
+    # what the trace counted, for the `execute` span: ``masked_filters``
+    trace_counters: dict = {}
 
     def run(inp_list, param_vecs):
         _TRACE_STATS["traces"] += 1
@@ -881,6 +933,7 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
             (pos_of.get(nid, -1), name) for nid, name, _ in ctx.metrics
         )
         metric_vals = [v for _, _, v in ctx.metrics]
+        trace_counters["masked_filters"] = ctx.masked_filters
         cap_flags = [
             f for name, f in ctx.overflow_flags
             if not name.startswith(_PRECISION_TAG)
@@ -917,11 +970,12 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
     else:
         cache_key = ("id", plan.node_id, task.task_index,
                      task.task_count, cfg_items)
-    # the trace-time boxes (overflow names, metric names) must come from the
-    # SAME closure as the cached executable, or cache hits would see them
-    # empty. use_cache=False (worker path: per-task programs go through the
-    # TTL'd stage-share cache instead) keeps one-shot programs out of the
-    # global cache so their closures don't pin shipped task tables.
+    # the trace-time boxes (overflow names, metric names, counters) must
+    # come from the SAME closure as the cached executable, or cache hits
+    # would see them empty. use_cache=False (worker path: per-task programs
+    # go through the TTL'd stage-share cache instead) keeps one-shot
+    # programs out of the global cache so their closures don't pin shipped
+    # task tables.
     cached = None
     cache = "hit"
     if use_cache:
@@ -964,17 +1018,17 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
                 while len(shared_cache) >= _SHARED_ENTRY_CAP:
                     shared_cache.pop(next(iter(shared_cache)))
                 cached = (
-                    jax.jit(run), overflow_box, metric_names,
+                    jax.jit(run), overflow_box, metric_names, trace_counters,
                     {"lock": threading.Lock(), "warmed": False},
                 )
                 shared_cache[skey] = cached
             else:
                 _SHARED_STATS["hit"] += 1
-        first_call_gate = cached[3]
-        cached = cached[:3]
+        first_call_gate = cached[4]
+        cached = cached[:4]
     if cached is None:
         cache = "miss"
-        cached = (jax.jit(run), overflow_box, metric_names)
+        cached = (jax.jit(run), overflow_box, metric_names, trace_counters)
         if use_cache:
             with _CACHE_LOCK:
                 # bounded LRU eviction (was: a full clear() at the cap — a
@@ -982,9 +1036,9 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
                 while len(_COMPILE_CACHE) >= _COMPILE_CACHE_MAX:
                     _COMPILE_CACHE.pop(next(iter(_COMPILE_CACHE)))
                 _COMPILE_CACHE[cache_key] = cached
-    fn, overflow_box, metric_names = cached
-    return (fn, overflow_box, metric_names, first_call_gate, input_list,
-            params, cache)
+    fn, overflow_box, metric_names, trace_counters = cached
+    return (fn, overflow_box, metric_names, trace_counters, first_call_gate,
+            input_list, params, cache)
 
 
 _COMPILE_CACHE: dict = {}  # insertion order == LRU order (move-to-end on hit)

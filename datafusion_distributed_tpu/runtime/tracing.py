@@ -269,7 +269,9 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
       is the stage programs, each ended by its flag fetch);
     - ``counters``: ``bytes`` by span kind, ``transfers`` (device-to-host
       pulls of the fetch), ``retries`` (overflow retries, stamped on the
-      root that succeeded), ``new_traces`` (programs traced afresh).
+      root that succeeded), ``new_traces`` (programs traced afresh),
+      ``masked_filters`` (filters of its programs that handed an
+      aggregate their mask and did not compact).
 
     The one report for operators (`render_profile` prints the last
     trace's row) and for the benchmark's program metrics."""
@@ -290,7 +292,7 @@ def _layer_rows(traces) -> list:
                 "wall_s": 0.0,
                 "self_s": {}, "total_s": {},
                 "counters": {"bytes": {}, "transfers": 0, "retries": 0,
-                             "new_traces": 0},
+                             "new_traces": 0, "masked_filters": 0},
             }
         row["traces"].append(trace.query_id)
         row["wall_s"] += root.duration
@@ -308,10 +310,8 @@ def _layer_rows(traces) -> list:
                 counters["bytes"][span.kind] = (
                     counters["bytes"].get(span.kind, 0) + int(nbytes)
                 )
-            counters["transfers"] += int(span.attrs.get("transfers", 0)
-                                         or 0)
-            counters["new_traces"] += int(span.attrs.get("new_traces", 0)
-                                          or 0)
+            for name in ("transfers", "new_traces", "masked_filters"):
+                counters[name] += int(span.attrs.get(name, 0) or 0)
     return list(rows.values())
 
 

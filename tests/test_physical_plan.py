@@ -1,5 +1,7 @@
 """Physical plan IR: single-task execution, operator composition."""
 
+import os
+
 import numpy as np
 
 from datafusion_distributed_tpu import precision as _precision
@@ -20,13 +22,16 @@ from datafusion_distributed_tpu.plan.expressions import (
     Col,
     Literal,
 )
+from datafusion_distributed_tpu.plan import physical as phys
 from datafusion_distributed_tpu.plan.physical import (
     DistributedTaskContext,
+    ExecutionPlan,
     FilterExec,
     HashAggregateExec,
     LimitExec,
     MemoryScanExec,
     ParquetScanExec,
+    PartialPassthroughExec,
     ProjectionExec,
     SortExec,
     execute_plan,
@@ -198,3 +203,283 @@ def test_final_mode_schema_after_partial():
     np.testing.assert_allclose(out["sv"], df["sv"], rtol=FLOAT_RTOL)
     np.testing.assert_allclose(out["av"], df["av"], rtol=FLOAT_RTOL)
     np.testing.assert_array_equal(out["mn"], df["mn"])
+
+
+# ---------------------------------------------------------------------------
+# the masked path: an aggregate pulls (table, live) and filters and
+# projections underneath hand their mask up without compacting
+# ---------------------------------------------------------------------------
+
+class _PackedExec(ExecutionPlan):
+    """Pass-through with the base class's `execute_masked`: whatever pulls
+    through it gets its child's PACKED rows, as every consumer did before
+    the masked path."""
+
+    def __init__(self, child):
+        super().__init__()
+        self.child = child
+
+    def children(self):
+        return [self.child]
+
+    def with_new_children(self, children):
+        return _PackedExec(children[0])
+
+    def schema(self):
+        return self.child.schema()
+
+    def output_capacity(self):
+        return self.child.output_capacity()
+
+    def _execute(self, ctx):
+        return self.child.execute(ctx)
+
+
+def _masked_case_scan(n=200, seed=5):
+    """k: group key; w: nullable predicate column; v: nullable aggregate
+    input. Floats are quarters, so every sum is exact and no order of
+    adding them can show: equal answers are then equal bit for bit.
+    200 rows in a capacity of 256: padding rows past ``num_rows``."""
+    rng = np.random.default_rng(seed)
+    w = rng.integers(-50, 50, n).astype(object)
+    w[rng.random(n) < 0.2] = None
+    v = (rng.integers(-400, 400, n) / 4.0).astype(object)
+    v[rng.random(n) < 0.2] = None
+    t = arrow_to_table(pa.table({
+        "k": rng.integers(0, 8, n),
+        "w": pa.array(list(w), type=pa.int64()),
+        "v": pa.array(list(v), type=pa.float64()),
+    }))
+    assert t.capacity > n
+    assert t.column("w").validity is not None
+    assert t.column("v").validity is not None
+    return MemoryScanExec([t], t.schema())
+
+
+_MASKED_PREDICATES = {
+    # nullable column: a null predicate keeps no row
+    "some": BinaryOp(">", Col("w"), Literal(0, DataType.INT64)),
+    "none": BinaryOp("<", Col("k"), Literal(0, DataType.INT64)),
+    "all": BinaryOp(">=", Col("k"), Literal(0, DataType.INT64)),
+}
+_MASKED_AGGS = [
+    AggSpec("sum", "v2", "s"), AggSpec("avg", "v", "a"),
+    AggSpec("min", "w", "mn"), AggSpec("max", "v", "mx"),
+    AggSpec("count", "v", "c"), AggSpec("count_star", None, "n"),
+]
+
+
+def _masked_case_input(shape, predicate):
+    """The aggregate's child, and the group names of the aggregate."""
+    scan = _masked_case_scan()
+    proj = [(Col("k"), "k"), (Col("w"), "w"), (Col("v"), "v"),
+            (BinaryOp("*", Col("v"), Literal(2.0, DataType.FLOAT64)), "v2")]
+    if shape == "agg_filter_filter":
+        # the projection sits under the filters, which stack
+        inner = FilterExec(
+            BinaryOp(">", Col("w"), Literal(-40, DataType.INT64)),
+            ProjectionExec(proj, scan),
+        )
+        return FilterExec(predicate, inner), ["k"]
+    child = ProjectionExec(proj, FilterExec(predicate, scan))
+    return child, ([] if shape == "global" else ["k"])
+
+
+def _aggregate_over(child, groups, mode):
+    if mode == "single":
+        return HashAggregateExec("single", groups, _MASKED_AGGS, child, 32)
+    partial = HashAggregateExec("partial", groups, _MASKED_AGGS, child, 32)
+    return HashAggregateExec("final", groups, _MASKED_AGGS, partial, 32)
+
+
+def _assert_bit_equal(got, want):
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+    for name in want.columns:
+        assert (got[name].to_numpy().tobytes()
+                == want[name].to_numpy().tobytes()), name
+
+
+def _compacting_filters(plan) -> dict:
+    """`FilterExec.<position>` -> the number of op locations of the plan's
+    lowered program under that filter's ``table.compact`` scope (the
+    ``nonzero`` and the gathers): the filters that pack their rows."""
+    import collections
+    import re
+
+    from datafusion_distributed_tpu.spans import NULL_TRACER
+
+    prepared = phys._prepare_program(
+        plan, DistributedTaskContext(), None, False, None, None, NULL_TRACER
+    )
+    fn, inputs, params = prepared[0], prepared[-3], prepared[-2]
+    text = fn.lower(inputs, params).as_text(debug_info=True)
+    assert "HashAggregateExec" in text or "PartialPassthroughExec" in text
+    return dict(collections.Counter(re.findall(
+        r'loc\("[^"]*/(FilterExec\.\d+)/table\.compact[/"]', text
+    )))
+
+
+@pytest.mark.parametrize("predicate", ["some", "none", "all"])
+@pytest.mark.parametrize("mode", ["single", "partial_final"])
+@pytest.mark.parametrize(
+    "shape", ["agg_proj_filter", "agg_filter_filter", "global"]
+)
+def test_masked_aggregate_equals_packed(shape, mode, predicate):
+    """An aggregate over filters and projections reduces under their mask;
+    the same aggregate over the same child's packed rows gives the same
+    frame bit for bit."""
+    child, groups = _masked_case_input(shape, _MASKED_PREDICATES[predicate])
+    masked = _aggregate_over(child, groups, mode)
+    packed = _aggregate_over(_PackedExec(child), groups, mode)
+    filters = len(masked.collect(lambda n: isinstance(n, FilterExec)))
+    assert filters == (2 if shape == "agg_filter_filter" else 1)
+    assert _compacting_filters(masked) == {}
+    assert len(_compacting_filters(packed)) == filters
+    got = execute_plan(masked).to_pandas()
+    want = execute_plan(packed).to_pandas()
+    _assert_bit_equal(got, want)
+    if groups:
+        assert len(want) == {"some": 8, "none": 0, "all": 8}[predicate]
+    else:
+        assert len(want) == 1
+        kept = {"some": None, "none": 0, "all": 200}[predicate]
+        if kept is not None:
+            assert int(want["n"][0]) == kept
+
+
+def _filter_under(consumer):
+    scan = _masked_case_scan()
+    filt = FilterExec(_MASKED_PREDICATES["some"], scan)
+    aggs = [AggSpec("sum", "v", "s"), AggSpec("count_star", None, "n")]
+    return {
+        "aggregate": lambda: HashAggregateExec("single", ["k"], aggs, filt,
+                                               32),
+        "partial_aggregate": lambda: HashAggregateExec("partial", ["k"],
+                                                       aggs, filt, 32),
+        "partial_passthrough": lambda: PartialPassthroughExec(["k"], aggs,
+                                                              filt),
+        "sort": lambda: HashAggregateExec(
+            "single", ["k"], aggs, SortExec([SortKey("w")], filt), 32),
+        "limit": lambda: HashAggregateExec(
+            "single", ["k"], aggs, LimitExec(filt, fetch=50), 32),
+    }[consumer]()
+
+
+@pytest.mark.parametrize("consumer,compact_ops", [
+    ("aggregate", {}),
+    ("partial_aggregate", {}),
+    # the counts of the program at the parent commit, from before the
+    # masked path (there the two aggregates read 24 as well)
+    ("partial_passthrough", {"FilterExec.1": 24}),
+    ("sort", {"FilterExec.2": 24}),
+    ("limit", {"FilterExec.2": 24}),
+])
+def test_filter_compacts_for_every_consumer_but_an_aggregate(consumer,
+                                                             compact_ops):
+    """Who pulls decides: an aggregate takes the mask; the bail-out form of
+    a partial aggregate emits a state a row and a sort or limit needs a
+    prefix, so under them the filter packs exactly as it did."""
+    assert _compacting_filters(_filter_under(consumer)) == compact_ops
+
+
+@pytest.fixture(scope="module")
+def tpch_ctx():
+    from datafusion_distributed_tpu.data.tpchgen import gen_tpch
+    from datafusion_distributed_tpu.sql.context import SessionContext
+
+    ctx = SessionContext()
+    for name, arrow in gen_tpch(sf=0.002, seed=7).items():
+        ctx.register_arrow(name, arrow)
+    return ctx
+
+
+@pytest.mark.parametrize("query,filters,compact_ops", [
+    # Sort/Projection/Aggregate/Projection/Filter/Projection/scan
+    ("q1", 1, {}),
+    # Projection/Aggregate/Projection/Filter x4/Projection/scan
+    ("q6", 4, {}),
+    # every filter feeds a join: the program at the parent commit
+    ("q3", 3, {"FilterExec.7": 24, "FilterExec.10": 24,
+               "FilterExec.13": 24}),
+])
+def test_tpch_programs_compact_only_under_joins(tpch_ctx, query, filters,
+                                                compact_ops):
+    """No op under ``table.compact`` below q1's and q6's aggregates; q3's
+    three filters pack their rows for the joins exactly as before."""
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "queries", "tpch")
+    with open(os.path.join(root, f"{query}.sql")) as f:
+        plan = tpch_ctx.sql(f.read()).physical_plan()
+    assert len(plan.collect(lambda n: isinstance(n, FilterExec))) == filters
+    assert _compacting_filters(plan) == compact_ops
+
+
+def test_masked_filter_reports_the_kept_rows():
+    """`output_rows` of a filter and a projection on the masked path is
+    the mask's popcount: what the packed nodes report."""
+    from datafusion_distributed_tpu.runtime.metrics import (
+        MetricsStore,
+        explain_analyze,
+    )
+
+    child, groups = _masked_case_input("agg_filter_filter",
+                                       _MASKED_PREDICATES["some"])
+    rows = {}
+    for name, over in (("masked", child), ("packed", _PackedExec(child))):
+        plan = _aggregate_over(over, groups, "single")
+        store = MetricsStore()
+        execute_plan(plan, metrics_store=store, task_label="task0")
+        by_node = store.aggregated()
+        rows[name] = [
+            (type(n).__name__, by_node[n.node_id]["output_rows"])
+            for n in plan.collect(lambda n: not isinstance(n, _PackedExec))
+        ]
+        text = explain_analyze(plan, store)
+        for _, count in rows[name]:
+            assert f"output_rows={count}" in text
+    assert rows["masked"] == rows["packed"]
+    df = child.child.child.child.tasks[0].to_pandas()
+    outer = int(((df.w > -40) & (df.w > 0)).sum())
+    inner = int((df.w > -40).sum())
+    assert [c for _, c in rows["masked"]] == [8, outer, inner, 200, 200]
+
+
+# the fingerprints of `_aggregate_over(*_masked_case_input("agg_proj_filter",
+# some), "single")` at the parent commit (ff446a1): as they are, and as the
+# program cache keys them (literals hoisted)
+_MASKED_CASE_FINGERPRINT = "d97adc7c137ba9b5a77aede26c5f7c7d"
+_MASKED_CASE_HOISTED_FINGERPRINT = "c181cd94e922ee1b19c9e7c723166ae1"
+
+
+def test_masked_path_adds_nothing_to_fingerprint_or_codec():
+    """The plan tree is the one it was: the fingerprint of
+    Aggregate/Projection/Filter/scan is the stored one (taken before the
+    masked path existed), and the codec carries the same keys a node and
+    round-trips to the same fingerprint."""
+    from datafusion_distributed_tpu.plan.fingerprint import (
+        plan_fingerprint,
+        prepare_plan,
+    )
+    from datafusion_distributed_tpu.runtime.codec import (
+        TableStore,
+        decode_plan,
+        encode_plan,
+    )
+
+    child, groups = _masked_case_input("agg_proj_filter",
+                                       _MASKED_PREDICATES["some"])
+    plan = _aggregate_over(child, groups, "single")
+    assert plan_fingerprint(plan) == _MASKED_CASE_FINGERPRINT
+    # the program cache's key: the literal-hoisted plan's
+    fp = prepare_plan(plan).fingerprint
+    assert fp == _MASKED_CASE_HOISTED_FINGERPRINT
+    store = TableStore()
+    wire = encode_plan(plan, store)
+    assert set(wire) == {"t", "mode", "groups", "aggs", "slots", "c", "_fp"}
+    assert set(wire["c"]) == {"t", "exprs", "c"}
+    assert set(wire["c"]["c"]) == {"t", "pred", "c"}
+    assert wire["_fp"] == fp
+    decoded = decode_plan(wire, store)
+    assert prepare_plan(decoded).fingerprint == fp
+    assert plan_fingerprint(decoded) == _MASKED_CASE_FINGERPRINT
+    assert "masked" not in plan.display_tree().lower()

@@ -116,6 +116,15 @@ group by l_returnflag, l_linestatus
 order by l_returnflag, l_linestatus
 """
 
+TPCH_Q6 = """
+select sum(l_extendedprice * l_discount) as revenue
+from lineitem
+where l_shipdate >= date '1994-01-01'
+  and l_shipdate < date '1995-01-01'
+  and l_discount between 0.05 and 0.07
+  and l_quantity < 24
+"""
+
 # one group a live order: with too few slots the first attempt overflows
 HIGH_NDV = "select l_orderkey, count(*) as n from lineitem group by l_orderkey"
 
@@ -758,6 +767,36 @@ def test_direct_q1_span_tree_under_one_request(tpch_ctx):
     assert DEFAULT_TRACE_STORE.summary()["running"] == 0
 
 
+@pytest.mark.parametrize("query,masked", [
+    (TPCH_Q1, 1),  # one filter, under the aggregate's projection
+    (TPCH_Q6, 4),  # four stacked filters under the global aggregate
+    (TPCH_Q3, 0),  # three filters, each under a join: they compact
+], ids=["q1", "q6", "q3"])
+def test_execute_span_counts_the_masked_filters(tpch_ctx, query, masked):
+    """`masked_filters` on the `execute` span: the filters that handed an
+    aggregate their mask. Counted when the program is traced and kept
+    with the cached executable, so a program-cache hit reports it too."""
+    tpch_ctx.config.distributed_options["tracing"] = "on"
+    try:
+        requests = []
+        for _ in range(2):
+            df = tpch_ctx.sql(query)
+            df.collect_table()
+            requests.append(df.request_id)
+    finally:
+        tpch_ctx.config.distributed_options.pop("tracing", None)
+    for request_id in requests:
+        spans = _request_spans(request_id)
+        (execute,) = spans["execute"]
+        assert execute.attrs["masked_filters"] == masked
+        (row,) = [r for r in layer_report() if r["request"] == request_id]
+        assert row["counters"]["masked_filters"] == masked
+    (prepare,) = _request_spans(requests[1])["prepare"]
+    assert prepare.attrs["cache"] == "hit"
+    (execute,) = _request_spans(requests[1])["execute"]
+    assert execute.attrs["new_traces"] == 0
+
+
 def test_untraced_cache_hit_after_a_traced_collect_leaves_no_trace(
         tpch_ctx):
     """`SET distributed.result_cache = on`: the traced collect that fills
@@ -934,7 +973,7 @@ def test_scopes_name_the_kernels_and_change_metadata_only(tpch_ctx,
 
     def programs(sql):
         plan = tpch_ctx.sql(sql).physical_plan()
-        fn, _, _, _, inputs, params, _ = phys._prepare_program(
+        fn, _, _, _, _, inputs, params, _ = phys._prepare_program(
             plan, phys.DistributedTaskContext(), None, False, None, None,
             NULL_TRACER,
         )
