@@ -35,18 +35,12 @@ from datafusion_distributed_tpu.plan.physical import (
 
 AXIS = "tasks"
 
-# History: an earlier round wrapped the invocation below in
-# `enable_compilation_cache(False)` against an observed XLA CHECK abort
-# serializing multi-device executables. Re-verified on this image (jax
-# 0.9, 8-device virtual mesh, real TPC-H mesh programs): serialization,
-# cache write, AND fresh-process reload all work (q1 mesh 21 s -> 4.4 s
-# on reload), and the toggle never actually suppressed writes on this
-# jax version anyway (is_cache_used is memoized per process). The abort
-# matches the process-age XLA:CPU heap corruption root-caused in
-# run_tests.sh — aged processes crash in the cache-write serializer among
-# other places — so tests/conftest.py still skips multi-device cache
-# WRITES in suite processes; normal (young) processes cache freely,
-# which is what lets a persistent-cache sweep skip mesh recompiles.
+# The four-device executable goes through jax's persistent compile cache
+# like any single-device one. On the v5e (PR 29, TPC-H q1 at SF1) a fresh
+# process compiled and wrote it in a 28.2 s warm-up, the next process
+# loaded it in 9.0 s, and every result of both agreed with the oracle.
+# Only tests/conftest.py skips multi-device cache WRITES, for the aged
+# suite processes of the CPU backend.
 
 # Re-executing the SAME plan object on the same mesh reuses the compiled
 # SPMD program (the reference's cached TaskData plan re-execution analogue).
@@ -133,6 +127,9 @@ def execute_on_mesh(
 
     overflow_names: list = []
     metric_names: list = []
+    # what the trace counted, for the `mesh.execute` span (as
+    # plan/physical.py execute_plan keeps it): ``masked_filters``
+    trace_counters: dict = {}
 
     def run(inputs_stacked, param_vecs):
         _TRACE_STATS["traces"] += 1
@@ -158,6 +155,7 @@ def execute_on_mesh(
         metric_names.extend(
             (pos_of.get(nid, -1), name) for nid, name, _ in ctx.metrics
         )
+        trace_counters["masked_filters"] = ctx.masked_filters
         if ctx.metrics:
             mvec = jnp.stack(
                 [v.astype(_METRIC_DTYPE) for _, _, v in ctx.metrics]
@@ -216,9 +214,9 @@ def execute_on_mesh(
                 check_rep=False,
             )
         )
-        cached = (fn, overflow_names, metric_names)
+        cached = (fn, overflow_names, metric_names, trace_counters)
         _MESH_COMPILE_CACHE[cache_key] = cached
-    fn, overflow_names, metric_names = cached
+    fn, overflow_names, metric_names, trace_counters = cached
     # ends on the fetch of the two flags, the sync this path already makes
     with tr.span("mesh.execute", "mesh.execute",
                  cache="hit" if cached_hit else "miss") as xsp:
@@ -226,7 +224,8 @@ def execute_on_mesh(
         any_overflow = check_overflow and bool(any_overflow)
         any_precision = bool(any_precision)
         if tr.active:
-            xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before)
+            xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before,
+                    **trace_counters)
     if any_overflow:
         raise RuntimeError(
             f"exchange/hash capacity overflow on mesh (nodes: "

@@ -1,0 +1,225 @@
+"""The four-chip cell's path, rehearsed on four of the eight virtual CPU
+devices at SF0.01: `benchmarks/chip/tiers/mesh.py` (the call `mesh4-q1`
+times) against the benchmark's plain reference (`suites/tpch/oracle.py`,
+pandas) on seeded data, against the direct tier, and the mesh tier's spans
+and counters as `tracing.layer_report()` and the cell's metric files read
+them. Answers and counts only: none of the numbers is a measurement."""
+
+import contextlib
+import importlib.util
+import json
+import os
+import statistics
+import time
+
+import pytest
+
+from datafusion_distributed_tpu.runtime import tracing
+from datafusion_distributed_tpu.sql.context import SessionContext
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEED = 2147483649  # more than 32 signed bits hold, as the driver's are
+REQUESTS = 3
+# metric file -> what its BENCHMARK.json entry says beside `mesh4-q1`
+MESH_METRICS = {
+    "stack_inputs_ms": ("ms", "program_span", "mesh input placement"),
+    "stack_input_mb": ("MB", "program_counter", "mesh input placement"),
+    "mesh_masked_filters": ("count", "program_counter", "operators"),
+}
+
+
+def _load(name: str, *parts: str):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, "benchmarks", "chip", *parts))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+run = _load("chipbench_run", "run.py")
+
+
+def read(name: str, record: dict):
+    return run.load_module("metrics", f"{name}.py").read(record)
+
+
+@pytest.fixture(scope="module")
+def cell(tmp_path_factory):
+    """The cell's set-up at SF0.01, as `run.run_cell` makes it: the suite's
+    seeded tables registered, the reference's answer, both tiers."""
+    workload = run.read_json("workloads", "mesh4-q1.json")
+    config = run.read_json("configs", f"{workload['config']}.json")
+    assert (config["tier"], config["chips"]) == ("mesh", 4)
+    suite = run.load_module("suites", config["suite"], "suite.py")
+    tables = suite.load(0.01, SEED, str(tmp_path_factory.mktemp("data")))
+    ctx = SessionContext()
+    for name, arrow in tables.items():
+        ctx.register_arrow(name, arrow)
+    mesh = run.load_module("tiers", "mesh.py").Tier(
+        ctx, config["tier_args"], suite)
+    direct = run.load_module("tiers", "direct.py").Tier(ctx, {}, suite)
+    assert mesh.mesh.devices.size == 4
+    return {"suite": suite, "sql": suite.sql("q1"), "mesh": mesh,
+            "direct": direct, "expected": suite.expected("q1", tables)}
+
+
+@contextlib.contextmanager
+def profiler_session(trace_dir):
+    """A recording `jax.profiler` session: the program's only switch."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def run_traced(tier, sql: str):
+    """`run.run_cell`'s traced call, the benchmark's own spans left out."""
+    return tier.run_traced(sql, lambda name: contextlib.nullcontext())
+
+
+@pytest.fixture(scope="module")
+def window(cell, tmp_path_factory):
+    """One warm query, then REQUESTS under a profiler session. -> the part
+    of run.py's record the readers look at, the report's rows, the result
+    frames, every span of the window."""
+    cell["mesh"].run(cell["sql"])
+    tracing.DEFAULT_TRACE_STORE.clear()
+    queries, frames = [], []
+    with profiler_session(tmp_path_factory.mktemp("trace")):
+        for _ in range(REQUESTS):
+            start = time.perf_counter()
+            frame, retries = run_traced(cell["mesh"], cell["sql"])
+            queries.append({"start": start, "end": time.perf_counter(),
+                            "retries": retries})
+            frames.append(frame)
+    spans = [span
+             for trace in tracing.DEFAULT_TRACE_STORE.finished_traces()
+             for span in trace.span_list()]
+    return {"record": {"queries": queries}, "rows": tracing.layer_report(),
+            "frames": frames, "spans": spans}
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["plain", "traced"])
+def test_mesh_tier_q1_agrees_with_the_plain_reference(cell, window, traced):
+    if traced:
+        frames = window["frames"]
+    else:
+        frame, retries = cell["mesh"].run(cell["sql"])
+        assert retries == 0
+        frames = [frame]
+    for frame in frames:
+        assert len(frame) == 4
+        cell["suite"].compare(frame, cell["expected"])
+
+
+def test_mesh_q1_equals_the_direct_tiers(cell):
+    """The configuration's tolerance (`guarantees.floats`) between the two
+    tiers as well: the shards' partial sums add up in another order."""
+    mesh_frame, _ = cell["mesh"].run(cell["sql"])
+    direct_frame, _ = cell["direct"].run(cell["sql"])
+    assert list(mesh_frame.columns) == list(direct_frame.columns)
+    cell["suite"].compare(mesh_frame, direct_frame)
+    cell["suite"].compare(direct_frame, cell["expected"])
+
+
+def test_traced_mesh_q1_rows_carry_the_tiers_spans_and_counters(window):
+    record, rows, spans = window["record"], window["rows"], window["spans"]
+    assert len(rows) == REQUESTS
+    assert [q["retries"] for q in record["queries"]] == [0] * REQUESTS
+    for row in rows:
+        assert row["total_s"]["mesh.stack_inputs"] > 0
+        assert row["total_s"]["mesh.execute"] > 0
+        assert row["counters"]["bytes"]["mesh.stack_inputs"] > 0
+        assert row["counters"]["masked_filters"] == 1
+        assert row["counters"]["new_traces"] == 0
+        assert row["counters"]["transfers"] > 0  # the fetch joined its row
+    # the counter sits on the `mesh.execute` span, on a program-cache hit
+    kinds = [span.kind for span in spans]
+    assert kinds.count("mesh.execute") == REQUESTS
+    assert kinds.count("mesh.stack_inputs") == REQUESTS
+    for span in spans:
+        if span.kind == "mesh.execute":
+            assert span.attrs["cache"] == "hit"
+            assert span.attrs["masked_filters"] == 1
+        else:
+            assert "masked_filters" not in span.attrs
+        if span.kind == "mesh.stack_inputs":
+            assert span.attrs["tasks"] == 4
+
+
+@pytest.mark.parametrize("name", MESH_METRICS)
+def test_the_mesh_tiers_metric_files_read_the_report(name, window):
+    record, rows = window["record"], window["rows"]
+    want = {
+        "stack_inputs_ms": lambda r: r["total_s"]["mesh.stack_inputs"] * 1e3,
+        "stack_input_mb":
+            lambda r: r["counters"]["bytes"]["mesh.stack_inputs"] / 1e6,
+        "mesh_masked_filters": lambda r: r["counters"]["masked_filters"],
+    }[name]
+    value = read(name, record)
+    assert value == pytest.approx(statistics.median(map(want, rows)))
+    assert value > 0
+    # requests from before the window, or no request at all: nothing
+    assert read(name, {"queries": [{"start": time.perf_counter()}]}) is None
+    assert read(name, {"queries": []}) is None
+
+
+@pytest.mark.parametrize("name", MESH_METRICS)
+def test_the_mesh_readers_find_nothing_on_another_tier(name, cell, window,
+                                                       monkeypatch):
+    """A request with no `mesh.*` span (the direct tier's), and a program
+    with no report at all, read as None: the line leaves the metric out."""
+    tracing.DEFAULT_TRACE_STORE.clear()
+    start = time.perf_counter()
+    cell["direct"].ctx.config.distributed_options["tracing"] = "on"
+    try:
+        run_traced(cell["direct"], cell["sql"])
+    finally:
+        cell["direct"].ctx.config.distributed_options.pop("tracing", None)
+    record = {"queries": [{"start": start}]}
+    assert len(tracing.layer_report()) == 1
+    assert read(name, record) is None
+    assert read("masked_filters", record) == 1  # the direct tier's reader
+    monkeypatch.delattr(tracing, "layer_report")
+    assert read(name, window["record"]) is None
+
+
+def test_benchmark_json_lists_the_cell_and_its_metrics():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (entry,) = [w for w in bench["workloads"] if w["name"] == "mesh4-q1"]
+    workload = run.read_json("workloads", "mesh4-q1.json")
+    config = run.read_json("configs", "tpch-sf1-mesh4.json")
+    assert entry == {"name": "mesh4-q1", "config": "tpch-sf1-mesh4",
+                     "traffic": "q1-closed1", "chips": 4,
+                     "why": workload["why"]}
+    (listed,) = [c for c in bench["configs"] if c["name"] == entry["config"]]
+    assert (listed["source"], listed["reduced"], listed["file"]) == (
+        config["source"], [], "benchmarks/chip/configs/tpch-sf1-mesh4.json")
+    # two deployments from one public benchmark: sources that differ
+    assert len({c["source"] for c in bench["configs"]}) == len(
+        bench["configs"])
+    metrics = {m["name"]: m for m in bench["per_layer"]}
+    for name, (unit, source, layer) in MESH_METRICS.items():
+        module = run.load_module("metrics", f"{name}.py")
+        assert (module.UNIT, module.SOURCE, module.LAYER, module.MOVES) == (
+            unit, source, layer, "query_p50_s")
+        assert metrics[name] == {
+            "name": name, "unit": unit, "better": metrics[name]["better"],
+            "source": source, "layer": layer, "moves": "query_p50_s",
+            "workloads": ["mesh4-q1"]}
+    # the reducer's pattern misses the TPU's `%all_to_all.N` (PERF.md,
+    # PR 29): the reader's file stands, its entry waits for a repair
+    assert "collective_ms" not in metrics
+    # the accepted entries that list `direct-q1` stay as they were
+    for name in ("prepare_ms", "fetch_transfers", "masked_filters"):
+        assert metrics[name]["workloads"] == ["direct-q1"]
+    # every metric without a list is reported in the new cell too
+    assert {m["name"] for m in run.cell_metrics("mesh4-q1", True)} >= {
+        "execute_ms", "fetch_ms", "overflow_retries", "hbm_roofline_share",
+        *MESH_METRICS}

@@ -767,33 +767,44 @@ def test_direct_q1_span_tree_under_one_request(tpch_ctx):
     assert DEFAULT_TRACE_STORE.summary()["running"] == 0
 
 
-@pytest.mark.parametrize("query,masked", [
-    (TPCH_Q1, 1),  # one filter, under the aggregate's projection
-    (TPCH_Q6, 4),  # four stacked filters under the global aggregate
-    (TPCH_Q3, 0),  # three filters, each under a join: they compact
-], ids=["q1", "q6", "q3"])
-def test_execute_span_counts_the_masked_filters(tpch_ctx, query, masked):
-    """`masked_filters` on the `execute` span: the filters that handed an
-    aggregate their mask. Counted when the program is traced and kept
-    with the cached executable, so a program-cache hit reports it too."""
+@pytest.mark.parametrize("tier,query,masked", [
+    ("direct", TPCH_Q1, 1),  # one filter, under the aggregate's projection
+    ("direct", TPCH_Q6, 4),  # four stacked filters under the global aggregate
+    ("direct", TPCH_Q3, 0),  # three filters, each under a join: they compact
+    ("mesh", TPCH_Q1, 1),  # the SPMD program's partial aggregate takes it
+    ("mesh", TPCH_Q6, 4),
+], ids=["q1", "q6", "q3", "mesh-q1", "mesh-q6"])
+def test_execute_span_counts_the_masked_filters(tpch_ctx, tier, query,
+                                                masked):
+    """`masked_filters` on the `execute` span (`mesh.execute` on the mesh
+    tier): the filters that handed an aggregate their mask. Counted when
+    the program is traced and kept with the cached executable, so a
+    program-cache hit reports it too."""
+    from datafusion_distributed_tpu.runtime.mesh_executor import make_mesh
+
+    kind = {"direct": "execute", "mesh": "mesh.execute"}[tier]
     tpch_ctx.config.distributed_options["tracing"] = "on"
     try:
         requests = []
         for _ in range(2):
             df = tpch_ctx.sql(query)
-            df.collect_table()
+            if tier == "mesh":
+                df.collect_distributed_table(mesh=make_mesh(2))
+            else:
+                df.collect_table()
             requests.append(df.request_id)
     finally:
         tpch_ctx.config.distributed_options.pop("tracing", None)
     for request_id in requests:
         spans = _request_spans(request_id)
-        (execute,) = spans["execute"]
+        (execute,) = spans[kind]
         assert execute.attrs["masked_filters"] == masked
         (row,) = [r for r in layer_report() if r["request"] == request_id]
         assert row["counters"]["masked_filters"] == masked
-    (prepare,) = _request_spans(requests[1])["prepare"]
-    assert prepare.attrs["cache"] == "hit"
-    (execute,) = _request_spans(requests[1])["execute"]
+    spans = _request_spans(requests[1])
+    (cached,) = spans["prepare"] if tier == "direct" else spans[kind]
+    assert cached.attrs["cache"] == "hit"
+    (execute,) = spans[kind]
     assert execute.attrs["new_traces"] == 0
 
 
