@@ -1,0 +1,26 @@
+"""Median over the traced window's requests of the aggregates that
+addressed their groups by dictionary codes and built no group table: the
+``direct_groupings`` counter of the requests that hold an ``execute`` or a
+``mesh.execute`` span (`ops/aggregate.py _dictionary_bases`, counted when
+the program is traced and kept with the cached executable), from
+`tracing.layer_report`. A program from before the counter reports none."""
+
+import os
+import runpy
+
+LAYERS = runpy.run_path(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, "layer_rows.py"))
+
+UNIT = "count"
+LAYER = "operators"
+SOURCE = "program_counter"
+MOVES = "query_p50_s"
+
+
+def read(record: dict):
+    def direct(row):
+        if not {"execute", "mesh.execute"} & set(row["self_s"]):
+            return None
+        return row["counters"].get("direct_groupings")
+
+    return LAYERS["median"](record, direct)

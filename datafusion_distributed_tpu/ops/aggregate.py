@@ -20,10 +20,17 @@ results (the bit-parity requirement of SURVEY.md §7 hard part (d)).
 
 Group keys may be any fixed-width device dtype (dict codes included); nulls
 group together (SQL semantics), tracked via a folded-in validity lane.
+
+Where every group key is dictionary-coded and the keys' whole domain fits
+the planned table (`_dictionary_bases`: q1's 3 x 2 codes, 4 x 3 with their
+NULLs, in 2048 slots), no table is built at all: a row's group id is the mixed-radix number of its
+codes (`direct_group_table`), one fused elementwise pass in place of the
+claim loop, and the slots, the reductions and the pack stay as they are.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -240,6 +247,85 @@ def _group_table_from_raw(gid, slot_keys, slot_used, overflow, key_cols,
     )
 
 
+def _dictionary_bases(key_columns: Sequence[Column],
+                      num_slots: int) -> Optional[list[int]]:
+    """The one rule for addressing groups directly: every key column is
+    dictionary-coded and the keys' domain, the product of each column's
+    ``len(dictionary)`` (one more where it has a validity array: NULL is
+    a key of its own), is not empty and fits the ``num_slots`` the planner
+    gave the aggregate. -> each column's base, or None for the claim loop.
+    All of it is static at trace time (``Column.dictionary`` is pytree
+    aux). It rests on a live, valid row's code lying in
+    ``[0, len(dictionary))``, which every producer of a dictionary column
+    keeps (tests/test_aggregate.py pins it)."""
+    if any(c.dictionary is None for c in key_columns):
+        return None
+    bases = [len(c.dictionary) + (c.validity is not None)
+             for c in key_columns]
+    return bases if 0 < math.prod(bases) <= num_slots else None
+
+
+#: `direct_group_table` finds the used slots by comparing every row's id
+#: with each slot of the domain (N x D compares, fused into one pass) up to
+#: this domain, and by one more scatter (N serialized updates) beyond it. On
+#: the v5e over 8Mi rows the compares cost 0.64 ms at a domain of 12, 1.5 at
+#: 128, 12.2 at 2048 (6 us a slot) and the scatter 53.7 ms at any width: they
+#: cross near 9,000, wherever the rows stand (my chip run, PERF.md, PR 30).
+_PRESENCE_BY_COMPARE_MAX_DOMAIN = 8192
+
+
+@scoped("agg.direct")
+def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
+                       live: jnp.ndarray, num_slots: int) -> GroupTable:
+    """`build_group_table`'s result with no table built: the group id of a
+    row is ``sum(digit_i * radix_i)`` over its key columns, ``digit_i`` the
+    dictionary code of a valid key and ``len(dictionary_i)`` of a NULL one
+    (``bases`` from `_dictionary_bases`). Slot ``s`` of the domain holds the
+    keys ``(s // radix_i) % base_i``; a slot is used if some live row has
+    its id. The arrays keep the planned ``[num_slots]`` width, so what
+    reduces and packs by slot sees the claim loop's shapes; groups come out
+    in radix order."""
+    domain = math.prod(bases)
+    radices = [math.prod(bases[i + 1:]) for i in range(len(bases))]
+    gid = jnp.zeros(live.shape, dtype=jnp.int32)
+    for col, radix in zip(key_columns, radices):
+        digit = col.data.astype(jnp.int32)
+        if col.validity is not None:
+            digit = jnp.where(col.validity, digit, len(col.dictionary))
+        gid = gid + digit * np.int32(radix)
+    gid = jnp.where(live, gid, num_slots)  # dead rows use no slot
+    if domain <= _PRESENCE_BY_COMPARE_MAX_DOMAIN:
+        present = jnp.any(
+            jnp.arange(domain, dtype=jnp.int32)[:, None] == gid[None, :],
+            axis=1,
+        )
+        slot_used = jnp.pad(present, (0, num_slots - domain))
+    else:
+        slot_used = jnp.zeros(num_slots, dtype=jnp.bool_).at[gid].set(
+            True, mode="drop"
+        )
+    slot = jnp.arange(num_slots, dtype=jnp.int32)
+    slot_keys = []
+    slot_key_valid = []
+    for col, base, radix in zip(key_columns, bases, radices):
+        digit = (slot // np.int32(radix)) % np.int32(base)
+        if col.validity is not None:
+            key_valid = digit < len(col.dictionary)
+            digit = jnp.where(key_valid, digit, 0)
+            slot_key_valid.append(key_valid)
+        else:
+            slot_key_valid.append(None)
+        slot_keys.append(digit.astype(col.data.dtype))
+    return GroupTable(
+        group_ids=gid,
+        slot_used=slot_used,
+        slot_keys=slot_keys,
+        slot_key_valid=slot_key_valid,
+        num_groups=jnp.sum(slot_used, dtype=jnp.int32),
+        overflow=jnp.asarray(False),
+    )
+
+
 def hash_aggregate(
     table: Table,
     group_names: Sequence[str],
@@ -249,6 +335,7 @@ def hash_aggregate(
     prec_flags: Optional[list] = None,
     out_capacity: Optional[int] = None,
     live: Optional[jnp.ndarray] = None,
+    direct: Optional[list] = None,
 ) -> tuple[Table, jnp.ndarray]:
     """GROUP BY aggregation. Returns (result table, overflow flag).
 
@@ -259,6 +346,10 @@ def hash_aggregate(
     ``prec_flags``, when given, collects traced bools flagging integer SUM
     results that left int32's exact range (tpu precision mode only; the
     executor raises a non-retryable error for these).
+
+    ``direct``, when given, collects the domain's size if the groups were
+    addressed directly by their dictionary codes (`_dictionary_bases`) and
+    no group table was built: the executor's ``direct_groupings``.
 
     Modes mirror DataFusion's AggregateMode as used by the reference planner:
       partial        -> emits sum/count/min/max accumulator columns per agg
@@ -281,9 +372,17 @@ def hash_aggregate(
         )
         if fused is not None:
             return fused
-    key_cols = [table.column(g).data for g in group_names]
-    key_valids = [table.column(g).validity for g in group_names]
-    gt = build_group_table(key_cols, key_valids, live, num_slots)
+    key_columns = [table.column(g) for g in group_names]
+    bases = _dictionary_bases(key_columns, num_slots)
+    if bases is not None:
+        gt = direct_group_table(key_columns, bases, live, num_slots)
+        if direct is not None:
+            direct.append(math.prod(bases))
+    else:
+        gt = build_group_table(
+            [c.data for c in key_columns],
+            [c.validity for c in key_columns], live, num_slots,
+        )
     gid = jnp.where(live, gt.group_ids, num_slots)  # dead rows drop out
 
     out_cols: dict[str, Column] = {}

@@ -85,6 +85,11 @@ class ExecContext:
     # (`ExecutionPlan.execute_masked`): the `execute` span's
     # ``masked_filters``
     masked_filters: int = 0
+    # trace-time count of the HashAggregateExec nodes whose groups were
+    # addressed by their dictionary codes, no group table built
+    # (ops/aggregate.py `_dictionary_bases`): the `execute` span's
+    # ``direct_groupings``
+    direct_groupings: int = 0
 
     def record_overflow(self, node: "ExecutionPlan", flag) -> None:
         self.overflow_flags.append((node.label(), flag))
@@ -561,6 +566,7 @@ class HashAggregateExec(ExecutionPlan):
         # be packed: filters and projections underneath hand their mask up
         t, live, _ = self.child.execute_masked(ctx)
         prec_flags: list = []
+        direct: list = []
         if not self.group_names:
             from datafusion_distributed_tpu.ops.aggregate import global_aggregate
 
@@ -570,9 +576,10 @@ class HashAggregateExec(ExecutionPlan):
             out, overflow = hash_aggregate(
                 t, self.group_names, self.aggs, self.num_slots, self.mode,
                 prec_flags=prec_flags, out_capacity=self.out_capacity,
-                live=live,
+                live=live, direct=direct,
             )
             ctx.record_overflow(self, overflow)
+            ctx.direct_groupings += len(direct)
         for f in prec_flags:
             ctx.record_precision_error(self, f)
         return out
@@ -910,7 +917,8 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
 
     overflow_box: list = []
     metric_names: list = []
-    # what the trace counted, for the `execute` span: ``masked_filters``
+    # what the trace counted, for the `execute` span: ``masked_filters``,
+    # ``direct_groupings``
     trace_counters: dict = {}
 
     def run(inp_list, param_vecs):
@@ -934,6 +942,7 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
         )
         metric_vals = [v for _, _, v in ctx.metrics]
         trace_counters["masked_filters"] = ctx.masked_filters
+        trace_counters["direct_groupings"] = ctx.direct_groupings
         cap_flags = [
             f for name, f in ctx.overflow_flags
             if not name.startswith(_PRECISION_TAG)

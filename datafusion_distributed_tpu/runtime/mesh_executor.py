@@ -128,7 +128,8 @@ def execute_on_mesh(
     overflow_names: list = []
     metric_names: list = []
     # what the trace counted, for the `mesh.execute` span (as
-    # plan/physical.py execute_plan keeps it): ``masked_filters``
+    # plan/physical.py execute_plan keeps it): ``masked_filters``,
+    # ``direct_groupings``
     trace_counters: dict = {}
 
     def run(inputs_stacked, param_vecs):
@@ -156,6 +157,7 @@ def execute_on_mesh(
             (pos_of.get(nid, -1), name) for nid, name, _ in ctx.metrics
         )
         trace_counters["masked_filters"] = ctx.masked_filters
+        trace_counters["direct_groupings"] = ctx.direct_groupings
         if ctx.metrics:
             mvec = jnp.stack(
                 [v.astype(_METRIC_DTYPE) for _, _, v in ctx.metrics]

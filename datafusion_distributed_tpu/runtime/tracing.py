@@ -271,7 +271,9 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
       pulls of the fetch), ``retries`` (overflow retries, stamped on the
       root that succeeded), ``new_traces`` (programs traced afresh),
       ``masked_filters`` (filters of its programs that handed an
-      aggregate their mask and did not compact).
+      aggregate their mask and did not compact), ``direct_groupings``
+      (aggregates of its programs that addressed their groups by
+      dictionary codes and built no group table).
 
     The one report for operators (`render_profile` prints the last
     trace's row) and for the benchmark's program metrics."""
@@ -292,7 +294,8 @@ def _layer_rows(traces) -> list:
                 "wall_s": 0.0,
                 "self_s": {}, "total_s": {},
                 "counters": {"bytes": {}, "transfers": 0, "retries": 0,
-                             "new_traces": 0, "masked_filters": 0},
+                             "new_traces": 0, "masked_filters": 0,
+                             "direct_groupings": 0},
             }
         row["traces"].append(trace.query_id)
         row["wall_s"] += root.duration
@@ -310,7 +313,8 @@ def _layer_rows(traces) -> list:
                 counters["bytes"][span.kind] = (
                     counters["bytes"].get(span.kind, 0) + int(nbytes)
                 )
-            for name in ("transfers", "new_traces", "masked_filters"):
+            for name in ("transfers", "new_traces", "masked_filters",
+                         "direct_groupings"):
                 counters[name] += int(span.attrs.get(name, 0) or 0)
     return list(rows.values())
 

@@ -207,3 +207,250 @@ def test_partial_reduce_tree_equals_single():
                                atol=2e-6)
     np.testing.assert_allclose(fin["vr"], single["vr"], rtol=FLOAT_RTOL * 10)
     np.testing.assert_array_equal(fin["n"], single["n"])
+
+
+# ---------------------------------------------------------------------------
+# direct addressing: dictionary-coded keys group by arithmetic on their codes
+# ---------------------------------------------------------------------------
+
+_DIRECT_AGGS = [
+    AggSpec("sum", "v", "sv"),
+    AggSpec("sum", "i", "si"),
+    AggSpec("count", "v", "cv"),
+    AggSpec("count_star", None, "n"),
+    AggSpec("min", "i", "mn"),
+    AggSpec("max", "i", "mx"),
+    AggSpec("avg", "v", "av"),
+]
+
+
+def _direct_case(case):
+    """-> (table, group names, num_slots, live(table) -> mask or None, the
+    domain if the direct path must engage else None)."""
+    from datafusion_distributed_tpu.ops import aggregate
+    from datafusion_distributed_tpu.ops.table import Dictionary, Table
+
+    rng = np.random.default_rng(11)
+    n = 600
+    a = rng.choice(["x", "y", "z"], n).astype(object)
+    b = rng.choice(["p", "q"], n).astype(object)
+    v = pa.array(rng.integers(-40, 40, n) / 4.0,  # quarters: sums are exact
+                 mask=rng.random(n) < 0.1)
+    columns = {"a": a, "b": b, "i": rng.integers(-1000, 1000, n), "v": v}
+    groups, slots, live, domain = ["a", "b"], 64, (lambda t: None), 3 * 2
+    nullable, dictionaries = (), None
+    if case == "nullable_key":  # NULL is a group of its own
+        a[rng.random(n) < 0.2] = None
+        nullable, domain = ("a",), (3 + 1) * 2
+    elif case == "code_never_occurs":  # no output row for "w"
+        dictionaries = {"a": Dictionary.from_strings(["w", "x", "y", "z"])}
+        domain = 4 * 2
+    elif case == "no_live_row":
+        live = lambda t: jnp.zeros(t.capacity, dtype=jnp.bool_)  # noqa: E731
+    elif case == "live_with_holes":
+        live = lambda t: t.row_mask() & jnp.asarray(  # noqa: E731
+            np.random.default_rng(5).random(t.capacity) < 0.5)
+    elif case == "domain_equals_slots":
+        columns["a"] = rng.choice(["w", "x", "y", "z"], n).astype(object)
+        slots, domain = 8, 4 * 2
+    elif case == "domain_over_slots":  # 8 > 4: the claim loop, 4 groups
+        columns["a"] = rng.choice(["w", "x", "y", "z"], n).astype(object)
+        columns["b"] = np.where(np.isin(columns["a"], ["w", "y"]), "p", "q")
+        slots, domain = 4, None
+    elif case == "wide_domain":  # past the compares: presence by scatter
+        columns["a"] = rng.integers(0, 100, n).astype(str).astype(object)
+        columns["b"] = rng.integers(0, 100, n).astype(str).astype(object)
+        vocabulary = Dictionary.from_strings(sorted(map(str, range(100))))
+        dictionaries = {"a": vocabulary, "b": vocabulary}
+        slots, domain = 16384, 100 * 100
+        assert domain > aggregate._PRESENCE_BY_COMPARE_MAX_DOMAIN
+    elif case == "key_without_dictionary":
+        groups, domain = ["a", "k"], None
+        columns["k"] = rng.integers(0, 3, n)
+    else:
+        assert case == "two_keys_3x2"
+    t = arrow_to_table(pa.table(columns), dictionaries=dictionaries)
+    # arrow ingestion gives every column a validity array; a key that is
+    # not nullable carries none after a projection or an aggregate
+    cols = tuple(c if name in nullable or name not in groups
+                 else c.with_validity(None)
+                 for name, c in zip(t.names, t.columns))
+    return Table(t.names, cols, t.num_rows), groups, slots, live, domain
+
+
+def _without_dictionaries(table, groups):
+    """The same table, its key columns plain integers: `hash_aggregate`'s
+    reference path, the claim loop."""
+    from datafusion_distributed_tpu.ops.table import Column, Table
+
+    cols = tuple(Column(c.data, c.validity, c.dtype, None) if name in groups
+                 else c for name, c in zip(table.names, table.columns))
+    return Table(table.names, cols, table.num_rows)
+
+
+def _group_rows(out, groups) -> dict:
+    """key tuple (None for a NULL key) -> the row's other values."""
+    n = int(out.num_rows)
+    cols = [(np.asarray(c.data)[:n],
+             None if c.validity is None else np.asarray(c.validity)[:n])
+            for c in out.columns]
+    rows = {}
+    for r in range(n):
+        vals = tuple(None if valid is not None and not valid[r]
+                     else data[r].item() for data, valid in cols)
+        rows[vals[:len(groups)]] = vals[len(groups):]
+    assert len(rows) == n  # no group twice
+    return rows
+
+
+@pytest.mark.parametrize("mode", ["single", "partial", "final",
+                                  "partial_reduce"])
+@pytest.mark.parametrize("case", [
+    "two_keys_3x2", "nullable_key", "code_never_occurs", "no_live_row",
+    "live_with_holes", "domain_equals_slots", "domain_over_slots",
+    "wide_domain", "key_without_dictionary",
+])
+def test_direct_grouping_equals_the_claim_loop(case, mode, monkeypatch):
+    """Dictionary-coded keys whose domain fits the planned table group by
+    the mixed-radix number of their codes and build no table; the result
+    is the claim loop's (`build_group_table`, reached by the same call
+    over the same keys without their dictionaries), group for group:
+    integers and counts exactly, floats at the oracle's tolerance. A
+    domain over the planned width, or a key with no dictionary, falls
+    back to the claim loop."""
+    from datafusion_distributed_tpu.ops import aggregate
+    from datafusion_distributed_tpu.ops.table import concat_tables
+
+    claims = []
+    build = aggregate.build_group_table
+    monkeypatch.setattr(
+        aggregate, "build_group_table",
+        lambda *a, **k: claims.append(1) or build(*a, **k))
+    table, groups, slots, live, domain = _direct_case(case)
+    if mode in ("final", "partial_reduce"):
+        # two partial states a group, from the halves of the rows
+        halves = [table.row_mask() & (jnp.arange(table.capacity) % 2 == h)
+                  for h in (0, 1)]
+        states = [hash_aggregate(table, groups, _DIRECT_AGGS, slots,
+                                 "partial", live=half)[0] for half in halves]
+        table = concat_tables(states, capacity=2 * states[0].capacity)
+        if case == "nullable_key":
+            assert table.column("a").validity is not None
+        claims.clear()
+
+    def run(t, direct):
+        out, overflow = jax.jit(
+            lambda t: hash_aggregate(t, groups, _DIRECT_AGGS, slots, mode,
+                                     live=live(t), direct=direct))(t)
+        assert not bool(overflow)
+        return out
+
+    direct: list = []
+    got = run(table, direct)
+    assert direct == ([] if domain is None else [domain])
+    assert len(claims) == (1 if domain is None else 0)
+    unused: list = []
+    want = run(_without_dictionaries(table, groups), unused)
+    assert unused == [] and len(claims) == (2 if domain is None else 1)
+
+    assert got.names == want.names and got.capacity == want.capacity
+    got_rows, want_rows = _group_rows(got, groups), _group_rows(want, groups)
+    assert set(got_rows) == set(want_rows)
+    if case == "no_live_row":
+        assert got_rows == {}
+    elif case == "nullable_key":
+        assert any(key[0] is None for key in got_rows)
+    elif case == "code_never_occurs":
+        assert all(key[0] != 0 for key in got_rows) and len(got_rows) == 6
+    for key, want_vals in want_rows.items():
+        for name, g, w in zip(got.names[len(groups):], got_rows[key],
+                              want_vals):
+            if isinstance(w, float):
+                # the benchmark's oracle: 5e-4 relative, 1e-4 absolute
+                np.testing.assert_allclose(g, w, rtol=5e-4, atol=1e-4,
+                                           err_msg=f"{key} {name}")
+            else:
+                assert g == w and type(g) is type(w), (key, name)
+
+
+def _string_columns():
+    """(name, Column) of every way a dictionary-coded column is made."""
+    from datafusion_distributed_tpu.ops.table import (
+        Dictionary,
+        unify_dictionaries,
+    )
+    from datafusion_distributed_tpu.plan import expressions as ex
+    from datafusion_distributed_tpu.schema import DataType
+
+    strings = ["pear", None, "apple", "fig", None, "apple", "kiwi"]
+    ingested = {
+        "plain": pa.array(strings),
+        "large": pa.array(strings, type=pa.large_string()),
+        "all_null": pa.array([None, None, None], type=pa.string()),
+        "empty": pa.array([], type=pa.string()),
+        # the wire's shape: sorted dictionary adopted with its codes
+        "wire": pa.DictionaryArray.from_arrays(
+            pa.array([2, None, 0, 1, None], type=pa.int32()),
+            pa.array(["a", "b", "c"])),
+        # unsorted, with a duplicate: decoded and encoded again
+        "unsorted": pa.DictionaryArray.from_arrays(
+            pa.array([0, 1, None, 2, 1], type=pa.int32()),
+            pa.array(["b", "a", "b"])),
+    }
+    for name, array in ingested.items():
+        yield name, arrow_to_table(pa.table({"s": array})).column("s")
+    for name, vocabulary in (("provided", ["apple", "fig"]),  # others NULL
+                             ("provided_unsorted", ["pear", "apple"]),
+                             ("provided_empty", [])):
+        t = arrow_to_table(
+            pa.table({"s": pa.array(strings)}),
+            dictionaries={"s": Dictionary.from_strings(vocabulary)})
+        yield name, t.column("s")
+
+    t = arrow_to_table(pa.table({
+        "s": pa.array(strings),
+        "u": pa.array(["zeta", "apple", None, None, "beta", "fig", None]),
+    }))
+    # codes re-encoded into the sorted union of two vocabularies
+    union, luts = unify_dictionaries(
+        [t.column("s").dictionary, None, t.column("u").dictionary,
+         Dictionary.from_strings([])])
+    for name, lut in (("s", luts[0]), ("u", luts[2])):
+        src = t.column(name)
+        yield f"unified_{name}", type(src)(
+            ex._remap_codes(src.data, lut), src.validity, src.dtype, union)
+    s, u = ex.Col("s"), ex.Col("u")
+    derived = {
+        "substring": ex.Substring(s, 1, 1),
+        "upper": ex.StringCase(s, True),
+        "regexp_replace": ex.RegexpReplace(s, "[aeiou]", "_"),
+        "concat": ex.ConcatStrings((s, ex.Literal("-", DataType.STRING), u)),
+        "coalesce": ex.Coalesce((s, u)),
+        "coalesce_literal": ex.Coalesce((s, ex.Literal("none",
+                                                       DataType.STRING))),
+        "literal": ex.Literal("only", DataType.STRING),
+    }
+    for name, expr in derived.items():
+        yield name, ex.expr_to_column(expr.evaluate(t))
+
+
+@pytest.mark.parametrize("producer", [
+    "plain", "large", "all_null", "empty", "wire", "unsorted", "provided",
+    "provided_unsorted", "provided_empty", "unified_s", "unified_u",
+    "substring", "upper", "regexp_replace", "concat", "coalesce",
+    "coalesce_literal", "literal",
+])
+def test_valid_dictionary_codes_lie_inside_the_dictionary(producer):
+    """What direct addressing rests on (`_dictionary_bases`): wherever a
+    column carries a dictionary, a valid row's code is an index into it,
+    NULL rows included in the column (their code is anything, their
+    validity False)."""
+    column = dict(_string_columns())[producer]
+    assert column.dictionary is not None
+    codes = np.asarray(column.data)
+    valid = (np.ones(len(codes), dtype=bool) if column.validity is None
+             else np.asarray(column.validity))
+    assert codes.dtype == np.int32
+    assert ((codes[valid] >= 0) & (codes[valid] < len(column.dictionary))).all()
+    if producer in ("all_null", "provided_empty"):
+        assert len(column.dictionary) == 0 and not valid.any()

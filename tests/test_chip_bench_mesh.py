@@ -169,6 +169,26 @@ def test_the_mesh_tiers_metric_files_read_the_report(name, window):
     assert read(name, {"queries": []}) is None
 
 
+def test_direct_groupings_reads_the_mesh_and_the_direct_tier(cell, window):
+    """`metrics/direct_groupings.py` (PR 30) reads `execute` and
+    `mesh.execute` alike: q1's partial and final aggregates on the mesh,
+    its one aggregate on the direct tier, all grouped by dictionary codes
+    and counted on a program-cache hit. (Reads the window's store: before
+    the tests below, which clear it.)"""
+    assert [r["counters"]["direct_groupings"] for r in window["rows"]] == (
+        [2] * REQUESTS)
+    assert read("direct_groupings", window["record"]) == 2
+    tracing.DEFAULT_TRACE_STORE.clear()
+    start = time.perf_counter()
+    cell["direct"].ctx.config.distributed_options["tracing"] = "on"
+    try:
+        run_traced(cell["direct"], cell["sql"])
+    finally:
+        cell["direct"].ctx.config.distributed_options.pop("tracing", None)
+    assert read("direct_groupings", {"queries": [{"start": start}]}) == 1
+    assert read("direct_groupings", {"queries": []}) is None
+
+
 @pytest.mark.parametrize("name", MESH_METRICS)
 def test_the_mesh_readers_find_nothing_on_another_tier(name, cell, window,
                                                        monkeypatch):

@@ -299,13 +299,8 @@ def _assert_bit_equal(got, want):
                 == want[name].to_numpy().tobytes()), name
 
 
-def _compacting_filters(plan) -> dict:
-    """`FilterExec.<position>` -> the number of op locations of the plan's
-    lowered program under that filter's ``table.compact`` scope (the
-    ``nonzero`` and the gathers): the filters that pack their rows."""
-    import collections
-    import re
-
+def _lowered(plan) -> str:
+    """The plan's lowered program, its ops' scopes in the locations."""
     from datafusion_distributed_tpu.spans import NULL_TRACER
 
     prepared = phys._prepare_program(
@@ -314,6 +309,17 @@ def _compacting_filters(plan) -> dict:
     fn, inputs, params = prepared[0], prepared[-3], prepared[-2]
     text = fn.lower(inputs, params).as_text(debug_info=True)
     assert "HashAggregateExec" in text or "PartialPassthroughExec" in text
+    return text
+
+
+def _compacting_filters(plan) -> dict:
+    """`FilterExec.<position>` -> the number of op locations of the plan's
+    lowered program under that filter's ``table.compact`` scope (the
+    ``nonzero`` and the gathers): the filters that pack their rows."""
+    import collections
+    import re
+
+    text = _lowered(plan)
     return dict(collections.Counter(re.findall(
         r'loc\("[^"]*/(FilterExec\.\d+)/table\.compact[/"]', text
     )))
@@ -393,6 +399,13 @@ def tpch_ctx():
     return ctx
 
 
+def _tpch_plan(ctx, query):
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "queries", "tpch")
+    with open(os.path.join(root, f"{query}.sql")) as f:
+        return ctx.sql(f.read()).physical_plan()
+
+
 @pytest.mark.parametrize("query,filters,compact_ops", [
     # Sort/Projection/Aggregate/Projection/Filter/Projection/scan
     ("q1", 1, {}),
@@ -406,12 +419,30 @@ def test_tpch_programs_compact_only_under_joins(tpch_ctx, query, filters,
                                                 compact_ops):
     """No op under ``table.compact`` below q1's and q6's aggregates; q3's
     three filters pack their rows for the joins exactly as before."""
-    root = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks", "queries", "tpch")
-    with open(os.path.join(root, f"{query}.sql")) as f:
-        plan = tpch_ctx.sql(f.read()).physical_plan()
+    plan = _tpch_plan(tpch_ctx, query)
     assert len(plan.collect(lambda n: isinstance(n, FilterExec))) == filters
     assert _compacting_filters(plan) == compact_ops
+
+
+@pytest.mark.parametrize("query,grouping", [
+    # l_returnflag x l_linestatus: dictionary codes, (3+1) x (2+1) <= 2048
+    ("q1", "agg.direct"),
+    # integer and date keys: the claim loop, as at the parent commit
+    ("q3", "agg.claim"),
+    ("q18", "agg.claim"),
+    # no GROUP BY: neither
+    ("q6", None),
+])
+def test_tpch_programs_claim_only_without_dictionary_keys(tpch_ctx, query,
+                                                          grouping):
+    """q1's group ids are arithmetic on its keys' dictionary codes: no op
+    under ``agg.claim`` and no `while` in its lowered program. Keys
+    without a dictionary still build the group table by claim rounds."""
+    text = _lowered(_tpch_plan(tpch_ctx, query))
+    for scope in ("agg.direct", "agg.claim"):
+        assert (f"/{scope}" in text) == (scope == grouping), scope
+    if grouping != "agg.claim":
+        assert "stablehlo.while" not in text
 
 
 def test_masked_filter_reports_the_kept_rows():

@@ -141,6 +141,33 @@ def test_claim_loop_group_by_compiles(one_chip, query):
              scopes=("agg.claim", "agg.reduce.sum", "table.gather"))
 
 
+def test_direct_group_by_compiles(one_chip):
+    """q1 as it runs since PR 30: its two keys carry dictionaries of 3 and
+    2 values (and a validity array each), so the group ids are arithmetic
+    on the codes, (3+1) x (2+1) = 12 slots of the 2048 can be used, and
+    the chip's program holds no `while` at all."""
+    from datafusion_distributed_tpu.ops.table import Dictionary
+
+    columns, keys, aggs, slots, out_capacity = GROUP_BY_CASES["q1"]
+    dictionaries = {"g0": Dictionary.from_strings(["A", "N", "R"]),
+                    "g1": Dictionary.from_strings(["F", "O"])}
+    t = _table(columns, 8 * MI, one_chip)
+    t = Table(t.names, tuple(
+        Column(c.data, c.validity, c.dtype, dictionaries.get(name))
+        for name, c in zip(t.names, t.columns)), t.num_rows)
+    direct: list = []
+
+    def kernel(t):
+        return hash_aggregate(t, keys, aggs, slots, "single",
+                              out_capacity=out_capacity, direct=direct)
+
+    compiled = _compile(kernel, t, scopes=("agg.direct", "agg.reduce.sum",
+                                           "table.gather"))
+    assert direct == [12]
+    assert " while(" not in compiled.as_text()
+    assert "/agg.claim/" not in compiled.as_text()
+
+
 def test_hash_join_build_and_probe_compiles(one_chip):
     """q3's lineitem x orders: 2Mi slots built from orders (2Mi rows),
     lineitem (8Mi rows) probing and expanding into 8Mi rows."""

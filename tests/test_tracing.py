@@ -767,19 +767,25 @@ def test_direct_q1_span_tree_under_one_request(tpch_ctx):
     assert DEFAULT_TRACE_STORE.summary()["running"] == 0
 
 
-@pytest.mark.parametrize("tier,query,masked", [
-    ("direct", TPCH_Q1, 1),  # one filter, under the aggregate's projection
-    ("direct", TPCH_Q6, 4),  # four stacked filters under the global aggregate
-    ("direct", TPCH_Q3, 0),  # three filters, each under a join: they compact
-    ("mesh", TPCH_Q1, 1),  # the SPMD program's partial aggregate takes it
-    ("mesh", TPCH_Q6, 4),
+@pytest.mark.parametrize("tier,query,masked,direct", [
+    # one filter, under the aggregate's projection; two dictionary keys
+    ("direct", TPCH_Q1, 1, 1),
+    # four stacked filters under the global aggregate: no group-by
+    ("direct", TPCH_Q6, 4, 0),
+    # three filters, each under a join: they compact; integer group keys
+    ("direct", TPCH_Q3, 0, 0),
+    # the SPMD program's partial aggregate takes the mask; the partial and
+    # the final aggregate both address their groups directly
+    ("mesh", TPCH_Q1, 1, 2),
+    ("mesh", TPCH_Q6, 4, 0),
 ], ids=["q1", "q6", "q3", "mesh-q1", "mesh-q6"])
-def test_execute_span_counts_the_masked_filters(tpch_ctx, tier, query,
-                                                masked):
-    """`masked_filters` on the `execute` span (`mesh.execute` on the mesh
-    tier): the filters that handed an aggregate their mask. Counted when
-    the program is traced and kept with the cached executable, so a
-    program-cache hit reports it too."""
+def test_execute_span_carries_the_trace_counters(tpch_ctx, tier, query,
+                                                 masked, direct):
+    """`masked_filters` (the filters that handed an aggregate their mask)
+    and `direct_groupings` (the aggregates that addressed their groups by
+    dictionary codes) on the `execute` span (`mesh.execute` on the mesh
+    tier). Counted when the program is traced and kept with the cached
+    executable, so a program-cache hit reports them too."""
     from datafusion_distributed_tpu.runtime.mesh_executor import make_mesh
 
     kind = {"direct": "execute", "mesh": "mesh.execute"}[tier]
@@ -799,8 +805,10 @@ def test_execute_span_counts_the_masked_filters(tpch_ctx, tier, query,
         spans = _request_spans(request_id)
         (execute,) = spans[kind]
         assert execute.attrs["masked_filters"] == masked
+        assert execute.attrs["direct_groupings"] == direct
         (row,) = [r for r in layer_report() if r["request"] == request_id]
         assert row["counters"]["masked_filters"] == masked
+        assert row["counters"]["direct_groupings"] == direct
     spans = _request_spans(requests[1])
     (cached,) = spans["prepare"] if tier == "direct" else spans[kind]
     assert cached.attrs["cache"] == "hit"
@@ -1003,9 +1011,11 @@ def test_scopes_name_the_kernels_and_change_metadata_only(tpch_ctx,
 
     scoped = {q: programs(sql) for q, sql in
               (("q1", TPCH_Q1), ("q3", TPCH_Q3))}
-    for name in ("agg.claim", "agg.reduce.sum", "sort.permutation",
+    # q1 groups by two dictionary-coded columns: ids by arithmetic, no claim
+    for name in ("agg.direct", "agg.reduce.sum", "sort.permutation",
                  "table.gather", "HashAggregateExec."):
         assert name in scoped["q1"][0], name
+    assert "agg.claim" not in scoped["q1"][0]
     for name in ("agg.claim", "agg.reduce.sum", "join.build", "join.probe",
                  "join.expand", "sort.permutation", "HashJoinExec."):
         assert name in scoped["q3"][0], name
@@ -1014,11 +1024,12 @@ def test_scopes_name_the_kernels_and_change_metadata_only(tpch_ctx,
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     try:
-        for q, sql in (("q1", TPCH_Q1), ("q3", TPCH_Q3)):
+        for q, sql, scope in (("q1", TPCH_Q1, "agg.direct"),
+                              ("q3", TPCH_Q3, "agg.claim")):
             lowered, optimized, result = programs(sql)
-            assert "agg.claim" not in lowered
-            assert "agg.claim" in scoped[q][1]
-            assert "agg.claim" not in optimized
+            assert scope not in lowered
+            assert scope in scoped[q][1]
+            assert scope not in optimized
             assert strip(optimized) == strip(scoped[q][1])
             assert result == scoped[q][2]
     finally:
