@@ -1,6 +1,6 @@
 """datafusion_distributed_tpu — a TPU-native distributed columnar query engine.
 
-A ground-up JAX/XLA/Pallas re-design of the capability set of
+A ground-up JAX/XLA re-design of the capability set of
 `datafusion-contrib/datafusion-distributed` (reference at /root/reference):
 stage-split distributed query execution, with per-stage columnar compute
 compiled by XLA onto TPU and shuffle/broadcast exchanges expressed as mesh

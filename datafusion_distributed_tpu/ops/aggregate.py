@@ -89,8 +89,7 @@ def _fold_key_lanes(key_cols, key_valids, lane_plan, n):
     mode, int64 in x64 mode). Nullability is an explicit extra lane in the
     compare matrix (not an in-band sentinel, which a real key value could
     collide with): column i with lane_plan[i] contributes lanes
-    [payload-with-nulls-zeroed, is_valid]. Shared by the claim loop and
-    the pallas kernel dispatches so every path compares identical lanes.
+    [payload-with-nulls-zeroed, is_valid].
     Returns (lanes list, per-key-column validity lane index or None)."""
     keys64 = []
     valid_lane_of: list[Optional[int]] = []  # per key col: its validity lane idx
@@ -140,30 +139,6 @@ def build_group_table(
     slot_keys0 = jnp.zeros((num_slots, n_lanes), dtype=_LANE)
     slot_used0 = jnp.zeros(num_slots, dtype=jnp.bool_)
     keys_mat = jnp.stack(keys64, axis=1)  # [N, k]
-
-    if np.dtype(_LANE).itemsize == 4:
-        from datafusion_distributed_tpu.ops import pallas_hash
-
-        if (
-            pallas_hash.use_pallas_hash()
-            and num_slots <= pallas_hash._MAX_TABLE_SLOTS
-        ):
-            # VMEM-resident build (DFTPU_PALLAS=1): row-blocked grid,
-            # partitioned multi-pass for tables beyond one VMEM block.
-            # Grouping is consistent with the claim loop below, but the
-            # slot LAYOUT may differ (sequential partition-confined vs
-            # min-row-id claim resolution) — see ops/pallas_hash.py for
-            # the trade-off being measured
-            interpret = jax.default_backend() != "tpu"
-            gid_p, tkeys_p, used_p, over_p = (
-                pallas_hash.pallas_build_group_ids(
-                    keys_mat, slot0, live, num_slots, interpret=interpret
-                )
-            )
-            return _group_table_from_raw(
-                gid_p, tkeys_p.astype(_LANE), used_p, over_p,
-                key_cols, key_valids, valid_lane_of,
-            )
 
     # Dead rows are born resolved and never claim a slot.
     resolved0 = ~live
@@ -365,13 +340,6 @@ def hash_aggregate(
     """
     if live is None:
         live = table.row_mask()
-    if mode == "single" and group_names and aggs:
-        fused = _try_global_hash_aggregate(
-            table, group_names, aggs, num_slots, out_capacity, prec_flags,
-            live,
-        )
-        if fused is not None:
-            return fused
     key_columns = [table.column(g) for g in group_names]
     bases = _dictionary_bases(key_columns, num_slots)
     if bases is not None:
@@ -415,146 +383,6 @@ def hash_aggregate(
     overflow = gt.overflow
     if out_cap < num_slots:
         overflow = overflow | (gt.num_groups > out_cap)
-    return packed, overflow
-
-
-def _try_global_hash_aggregate(
-    table: Table,
-    group_names: Sequence[str],
-    aggs: Sequence[AggSpec],
-    num_slots: int,
-    out_capacity: Optional[int],
-    prec_flags: Optional[list],
-    live: jnp.ndarray,
-) -> Optional[tuple[Table, jnp.ndarray]]:
-    """Fused single-pass global-hash-table aggregation (DFTPU_PALLAS=1):
-    one VMEM-resident kernel builds the group table AND folds the
-    accumulators, replacing build + per-agg XLA scatters ("Global Hash
-    Tables Strike Back!", PAPERS.md). Engages only where it is exact:
-    sum/min/max/count over 4-byte integer inputs (the kernel accumulates
-    int32, matching the XLA path's narrowed scatter-adds — integer adds
-    and min/max are order-independent, so slot-insertion order cannot
-    change any value). Under DFTPU_PALLAS=1 the slot layout equals
-    pallas_build_group_ids' sequential-insert layout, so output row order
-    is unchanged vs the unfused pallas path. Returns None when
-    ineligible (including kernel capacity refusal) -> reference path."""
-    from datafusion_distributed_tpu.ops import pallas_hash
-
-    if not pallas_hash.use_pallas_hash():
-        return None
-    if np.dtype(_LANE).itemsize != 4:
-        return None
-    if num_slots > pallas_hash._MAX_TABLE_SLOTS:
-        return None
-    for spec in aggs:
-        if spec.func == "count_star":
-            continue
-        if spec.func not in ("count", "sum", "min", "max"):
-            return None
-        col = table.column(spec.input_name)
-        if not col.dtype.is_integer:
-            return None
-        if np.dtype(col.data.dtype).itemsize != 4:
-            return None
-
-    n = table.capacity
-    i32 = jnp.int32
-    int32_max = np.iinfo(np.int32).max
-    int32_min = np.iinfo(np.int32).min
-
-    # accumulator plan: per agg, value columns pre-mapped so invalid rows
-    # carry the op identity (the kernel has no validity lanes)
-    ops: list[str] = []
-    vcols: list[jnp.ndarray] = []
-    plan: list[tuple] = []
-    for spec in aggs:
-        if spec.func == "count_star":
-            idx = len(ops)
-            ops.append("sum")
-            vcols.append(jnp.where(live, 1, 0).astype(i32))
-            plan.append(("count", spec.output_name, idx, None))
-            continue
-        col = table.column(spec.input_name)
-        valid = col.valid_mask() & live
-        cnt_idx = len(ops)
-        ops.append("sum")
-        vcols.append(jnp.where(valid, 1, 0).astype(i32))
-        if spec.func == "count":
-            plan.append(("count", spec.output_name, cnt_idx, None))
-            continue
-        vidx = len(ops)
-        if spec.func == "sum":
-            ops.append("sum")
-            vcols.append(jnp.where(valid, col.data, 0).astype(i32))
-        elif spec.func == "min":
-            ops.append("min")
-            vcols.append(jnp.where(valid, col.data, int32_max).astype(i32))
-        else:
-            ops.append("max")
-            vcols.append(jnp.where(valid, col.data, int32_min).astype(i32))
-        plan.append((spec.func, spec.output_name, vidx, (cnt_idx, col)))
-
-    key_cols = [table.column(g).data for g in group_names]
-    key_valids = [table.column(g).validity for g in group_names]
-    lane_plan = [v is not None for v in key_valids]
-    keys64, _ = _fold_key_lanes(key_cols, key_valids, lane_plan, n)
-    h0 = hash_columns(list(key_cols), list(key_valids))
-    slot0 = (h0 & np.uint32(num_slots - 1)).astype(i32)
-
-    interpret = jax.default_backend() != "tpu"
-    try:
-        gid, rep, used, acc, overflow = (
-            pallas_hash.pallas_global_hash_aggregate(
-                jnp.stack(keys64, axis=1).astype(i32),
-                slot0, live, jnp.stack(vcols, axis=1), num_slots,
-                tuple(ops), interpret=interpret,
-            )
-        )
-    except pallas_hash.PallasCapacityError:
-        return None
-
-    # group key columns: gather the claiming representative row — for
-    # every used slot that row holds exactly the slot's key values
-    safe_rep = jnp.where(used, rep, 0)
-    out_cols: dict[str, Column] = {}
-    for g in group_names:
-        src = table.column(g)
-        kv = None
-        if src.validity is not None:
-            kv = src.validity[safe_rep] & used
-        out_cols[g] = Column(src.data[safe_rep], kv, src.dtype,
-                             src.dictionary)
-
-    vgid = jnp.where(live, gid, num_slots)
-
-    def seg_sum(vals, dtype=None):
-        z = jnp.zeros(num_slots, dtype=dtype or vals.dtype)
-        return z.at[vgid].add(vals, mode="drop")
-
-    i64 = DataType.INT64.np_dtype
-    for kind, name, idx, extra in plan:
-        if kind == "count":
-            out_cols[name] = Column(acc[:, idx].astype(i64), None,
-                                    DataType.INT64)
-        elif kind == "sum":
-            cnt_idx, col = extra
-            nonempty = acc[:, cnt_idx]
-            out_cols[name] = Column(acc[:, idx].astype(i64), nonempty > 0,
-                                    DataType.INT64)
-            _check_int32_sum_range(vcols[idx], seg_sum, prec_flags)
-        else:  # min / max
-            cnt_idx, col = extra
-            nonempty = acc[:, cnt_idx]
-            out_cols[name] = Column(acc[:, idx].astype(col.data.dtype),
-                                    nonempty > 0, col.dtype, col.dictionary)
-
-    num_groups = jnp.sum(used, dtype=i32)
-    packed = Table.make(out_cols, num_groups)
-    out_cap = min(out_capacity or num_slots, num_slots)
-    (pack_idx,) = jnp.nonzero(used, size=out_cap, fill_value=0)
-    packed = packed.gather(pack_idx, num_groups)
-    if out_cap < num_slots:
-        overflow = overflow | (num_groups > out_cap)
     return packed, overflow
 
 

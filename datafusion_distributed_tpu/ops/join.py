@@ -203,7 +203,6 @@ def hash_join(
     out_capacity: int,
     probe_prefix: str = "",
     build_prefix: str = "",
-    precomputed: Optional[tuple] = None,
 ) -> tuple[Table, jnp.ndarray]:
     """Join probe against a built side. Returns (result, overflow flag).
 
@@ -211,25 +210,14 @@ def hash_join(
     (optionally name-prefixed). For semi/anti the result is probe rows
     filtered by match. For mark it is probe plus a BOOL `__mark` column.
     `left` marks unmatched probe rows' build columns invalid (SQL LEFT JOIN).
-
-    ``precomputed=(found, probe_overflow)`` short-circuits the probe loop
-    with slots resolved elsewhere (the multiway cascaded kernel probes all
-    tables of a fused join chain in one pass): ``found`` is [probe.capacity]
-    i32, the build-table slot per probe row or -1, with dead/padded rows
-    re-masked here so garbage lookups from expanded intermediates are
-    harmless.
     """
     live = probe.row_mask()
-    if precomputed is not None:
-        g, probe_overflow = precomputed
-        g = jnp.where(live, g, -1)
-    else:
-        cols = [probe.column(k).data for k in probe_keys]
-        valids = [probe.column(k).validity for k in probe_keys]
-        g, probe_overflow = probe_group_table(
-            build_side.raw_slot_keys, build_side.slot_used, cols, valids,
-            live, build_side.lane_plan,
-        )
+    cols = [probe.column(k).data for k in probe_keys]
+    valids = [probe.column(k).validity for k in probe_keys]
+    g, probe_overflow = probe_group_table(
+        build_side.raw_slot_keys, build_side.slot_used, cols, valids,
+        live, build_side.lane_plan,
+    )
     table_overflow = build_side.overflow | probe_overflow
     found = g >= 0
     g_safe = jnp.where(found, g, 0)

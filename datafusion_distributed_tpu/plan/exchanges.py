@@ -342,31 +342,18 @@ class IsolatedArmExec(ExecutionPlan):
         ):
             ex.execute(ctx)
 
-        # Probe the arm under a throwaway context (sharing the exchange
-        # cache): its outputs are used for SHAPES/DTYPES only, so XLA
-        # dead-code-eliminates the probe's compute; its overflow/metric
-        # lists tell us the side-channel structure the cond branches must
-        # return explicitly (tracers may not escape a branch via ctx lists).
-        probe_ctx = ExecContext(
-            task=ctx.task, inputs=ctx.inputs, config=ctx.config,
-            exchange_cache=ctx.exchange_cache,
-        )
+        # Probe the arm under a throwaway child context (sharing the
+        # exchange cache): its outputs are used for SHAPES/DTYPES only, so
+        # XLA dead-code-eliminates the probe's compute; what it recorded
+        # tells us the side-channel structure the cond branches must
+        # return explicitly (tracers may not escape a branch via the
+        # context). An arm's trace-time counters stay uncounted.
+        probe_ctx = ctx.child()
         probe = self.child.execute(probe_ctx)
-        flag_names = [name for name, _ in probe_ctx.overflow_flags]
-        metric_keys = [(nid, name) for nid, name, _ in probe_ctx.metrics]
-        metric_dtypes = [v.dtype for _, _, v in probe_ctx.metrics]
 
         def run_arm(_):
-            c2 = ExecContext(
-                task=ctx.task, inputs=ctx.inputs, config=ctx.config,
-                exchange_cache=ctx.exchange_cache,
-            )
-            t = self.child.execute(c2)
-            return (
-                t,
-                tuple(f for _, f in c2.overflow_flags),
-                tuple(v for _, _, v in c2.metrics),
-            )
+            arm_ctx = ctx.child()
+            return self.child.execute(arm_ctx), arm_ctx.traced_values()
 
         def empty_arm(_):
             cols = tuple(
@@ -380,26 +367,18 @@ class IsolatedArmExec(ExecutionPlan):
                 for c in probe.columns
             )
             t = Table(probe.names, cols, jnp.zeros((), dtype=jnp.int32))
-            return (
-                t,
-                tuple(jnp.zeros((), jnp.bool_) for _ in flag_names),
-                tuple(jnp.zeros((), d) for d in metric_dtypes),
+            return t, tuple(
+                jnp.zeros((), v.dtype) for v in probe_ctx.traced_values()
             )
 
-        out, flags, metrics = jax.lax.cond(
+        out, values = jax.lax.cond(
             me == self.assigned_task, run_arm, empty_arm, None
         )
-        for name, f in zip(flag_names, flags):
-            ctx.overflow_flags.append((name, f))
-        for (nid, name), v in zip(metric_keys, metrics):
-            ctx.metrics.append((nid, name, v))
+        ctx.adopt(probe_ctx, values)
         return out
 
     def _empty_like(self, ctx: ExecContext) -> Table:
-        probe_ctx = ExecContext(
-            task=ctx.task, inputs=ctx.inputs, config=ctx.config
-        )
-        t = self.child.execute(probe_ctx)
+        t = self.child.execute(ctx.child())
         return Table(t.names, t.columns, jnp.zeros((), dtype=jnp.int32))
 
     def display(self):

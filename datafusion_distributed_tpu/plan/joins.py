@@ -242,9 +242,6 @@ def _unify_key_dictionaries(probe: Table, build: Table, probe_keys, build_keys):
     return probe, build
 
 
-_MW_ORIG = "__mw_orig"
-
-
 @dataclass(frozen=True)
 class MultiwayJoinStep:
     """Parameters of one probe step of a fused multiway join — exactly the
@@ -284,15 +281,10 @@ class MultiwayHashJoinExec(ExecutionPlan):
     (planner/distributed._multiway_fusion_pass) only builds this node when
     every step's probe keys come from the BASE probe stream, which is what
     lets the intermediate shuffles be deleted (re-hashing the same keys to
-    the same task count is an identity re-partition) and lets the cascaded
-    pallas kernel resolve all K probes in one grid pass.
+    the same task count is an identity re-partition).
 
-    Execution is exact by construction: the reference path IS the original
-    binary chain (``to_binary_chain``), rebuilt with the captured per-step
-    capacities; the cascaded kernel path (DFTPU_PALLAS=1 + static
-    eligibility) replaces only the per-step probe loops, feeding their
-    resolved slots into the same expansion kernel via
-    ``hash_join(precomputed=...)``.
+    Execution is exact by construction: it IS the original binary chain
+    (``to_binary_chain``), rebuilt with the captured per-step capacities.
     """
 
     def __init__(self, probe: ExecutionPlan, builds: Sequence[ExecutionPlan],
@@ -343,124 +335,8 @@ class MultiwayHashJoinExec(ExecutionPlan):
     def output_capacity(self):
         return self._chain().output_capacity()
 
-    def cascade_eligible(self) -> bool:
-        """Static (schema-only) eligibility for the cascaded pallas probe:
-        inner-only steps, no residual/null-aware modes, every step's probe
-        keys on the BASE probe stream, no string (dictionary) keys, and
-        every table within one VMEM partition. Anything else takes the
-        reference chain path."""
-        import numpy as np
-
-        from datafusion_distributed_tpu import precision
-        from datafusion_distributed_tpu.ops import pallas_hash
-
-        if not pallas_hash.use_pallas_hash():
-            return False
-        if np.dtype(precision.LANE_INT).itemsize != 4:
-            return False
-        base = self.probe.schema()
-        base_names = set(base.names)
-        for s, b in zip(self.steps, self.builds):
-            if (s.join_type != "inner" or s.residual is not None
-                    or s.null_aware):
-                return False
-            if s.num_slots > pallas_hash._MAX_VMEM_SLOTS:
-                return False
-            if not set(s.probe_keys) <= base_names:
-                return False
-            bschema = b.schema()
-            for kn in s.probe_keys:
-                if base.field(kn).dtype == DataType.STRING:
-                    return False
-            for kn in s.build_keys:
-                if bschema.field(kn).dtype == DataType.STRING:
-                    return False
-        return True
-
     def _execute(self, ctx: ExecContext) -> Table:
-        if self.cascade_eligible():
-            return self._execute_cascade(ctx)
         return self._chain()._execute(ctx)
-
-    def _execute_cascade(self, ctx: ExecContext) -> Table:
-        import jax
-        import numpy as np
-
-        from datafusion_distributed_tpu.ops import pallas_hash
-        from datafusion_distributed_tpu.ops.hash import hash_columns
-        from datafusion_distributed_tpu.ops.join import _fold_keys
-
-        probe_t = self.probe.execute(ctx)
-        builds_t = [b.execute(ctx) for b in self.builds]
-
-        sides = []
-        for s, bt in zip(self.steps, builds_t):
-            lane_plan = [
-                probe_t.column(pk).validity is not None
-                or bt.column(bk).validity is not None
-                for pk, bk in zip(s.probe_keys, s.build_keys)
-            ]
-            sides.append(build_join_table(
-                bt, list(s.build_keys), s.num_slots, lane_plan
-            ))
-
-        live0 = probe_t.row_mask()
-        n = probe_t.capacity
-        lmax = max(bs.raw_slot_keys.shape[1] for bs in sides)
-        keys_list, slot0_list, active_list = [], [], []
-        tkeys_parts, used_parts, table_slots = [], [], []
-        for s, bs in zip(self.steps, sides):
-            cols = [probe_t.column(k).data for k in s.probe_keys]
-            valids = [probe_t.column(k).validity for k in s.probe_keys]
-            km = _fold_keys(cols, valids, bs.lane_plan).astype(jnp.int32)
-            if km.shape[1] < lmax:
-                km = jnp.pad(km, ((0, 0), (0, lmax - km.shape[1])))
-            hk = bs.slot_used.shape[0]
-            h0 = hash_columns(list(cols), list(valids))
-            slot0 = (h0 & np.uint32(hk - 1)).astype(jnp.int32)
-            has_null = jnp.zeros(n, dtype=jnp.bool_)
-            for v in valids:
-                if v is not None:
-                    has_null = has_null | ~v
-            keys_list.append(km)
-            slot0_list.append(slot0)
-            active_list.append(live0 & ~has_null)
-            tk = bs.raw_slot_keys.astype(jnp.int32)
-            if tk.shape[1] < lmax:
-                tk = jnp.pad(tk, ((0, 0), (0, lmax - tk.shape[1])))
-            tkeys_parts.append(tk)
-            used_parts.append(bs.slot_used.astype(jnp.int32))
-            table_slots.append(hk)
-
-        found, over = pallas_hash.pallas_multiway_probe(
-            jnp.stack(keys_list, axis=1),
-            jnp.stack(slot0_list, axis=1),
-            jnp.stack(active_list, axis=1),
-            jnp.concatenate(tkeys_parts, axis=0),
-            jnp.concatenate(used_parts, axis=0),
-            tuple(table_slots),
-            interpret=jax.default_backend() != "tpu",
-        )
-
-        # hidden original-row index threads the one-shot probe results
-        # through the per-step expansions (dead/padded rows carry garbage
-        # slots that hash_join re-masks against its own row_mask)
-        cur = probe_t.with_column(
-            _MW_ORIG,
-            Column(jnp.arange(n, dtype=jnp.int32), None, DataType.INT32),
-        )
-        for k, (s, bs) in enumerate(zip(self.steps, sides)):
-            orig = jnp.clip(
-                cur.column(_MW_ORIG).data.astype(jnp.int32), 0, n - 1
-            )
-            pre = found[:, k][orig]
-            cur, overflow = hash_join(
-                cur, bs, list(s.probe_keys), "inner", s.out_capacity,
-                precomputed=(pre, over[k]),
-            )
-            ctx.record_overflow(self, overflow)
-        names = [nm for nm in cur.names if nm != _MW_ORIG]
-        return cur.select(names)
 
     def display(self):
         parts = []

@@ -70,7 +70,11 @@ class ExecContext:
 
     task: DistributedTaskContext
     inputs: dict[int, Table]  # leaf node_id -> loaded device Table
-    overflow_flags: list = dc_field(default_factory=list)
+    # (node label, traced bool) of every node that can outgrow a planned
+    # capacity, and of every 32-bit accumulator that can leave its exact
+    # range: a re-plan with wider capacities cures the first kind only
+    capacity_flags: list = dc_field(default_factory=list)
+    precision_flags: list = dc_field(default_factory=list)
     config: dict = dc_field(default_factory=dict)
     # traced per-node metrics: (node_id, metric_name, traced scalar). The
     # executor returns these as program outputs and stitches them into a
@@ -81,31 +85,57 @@ class ExecContext:
     # participate unconditionally); IsolatedArmExec relies on this to
     # pre-execute an arm's exchanges before conditioning its local compute
     exchange_cache: dict = dc_field(default_factory=dict)
-    # trace-time count of the FilterExec nodes answered on the masked path
-    # (`ExecutionPlan.execute_masked`): the `execute` span's
-    # ``masked_filters``
-    masked_filters: int = 0
-    # trace-time count of the HashAggregateExec nodes whose groups were
-    # addressed by their dictionary codes, no group table built
-    # (ops/aggregate.py `_dictionary_bases`): the `execute` span's
-    # ``direct_groupings``
-    direct_groupings: int = 0
+    # what the trace counted, by the names of `spans.PROGRAM_COUNTERS`
+    counters: dict = dc_field(
+        default_factory=lambda: dict.fromkeys(spans.PROGRAM_COUNTERS, 0)
+    )
 
     def record_overflow(self, node: "ExecutionPlan", flag) -> None:
-        self.overflow_flags.append((node.label(), flag))
+        self.capacity_flags.append((node.label(), flag))
 
     def record_precision_error(self, node: "ExecutionPlan", flag) -> None:
-        """A 32-bit accumulator left its exact range (tpu precision mode).
-        Distinct from capacity overflow: growing the hash table cannot fix
-        it, so the executor raises a non-retryable error instead."""
-        self.overflow_flags.append((_PRECISION_TAG + node.label(), flag))
+        self.precision_flags.append((node.label(), flag))
 
     def record_metric(self, node: "ExecutionPlan", name: str, value) -> None:
         if self.config.get("collect_metrics", True):
             self.metrics.append((node.node_id, name, value))
 
+    def count(self, name: str, n: int = 1) -> None:
+        self.counters[name] += n
 
-_PRECISION_TAG = "precision!"
+    def child(self) -> "ExecContext":
+        """A context to trace a part of the plan apart from this one (a
+        `lax.cond` branch, a probe for shapes): this context's task,
+        inputs, config and exchange cache, and flags, metrics and
+        counters of its own."""
+        return ExecContext(
+            task=self.task, inputs=self.inputs, config=self.config,
+            exchange_cache=self.exchange_cache,
+        )
+
+    def traced_values(self) -> tuple:
+        """The traced scalars this context recorded, capacity flags,
+        precision flags, then metrics: what a `lax.cond` branch must
+        return for them to leave it."""
+        return (
+            tuple(f for _, f in self.capacity_flags)
+            + tuple(f for _, f in self.precision_flags)
+            + tuple(v for _, _, v in self.metrics)
+        )
+
+    def adopt(self, like: "ExecContext", values) -> None:
+        """Record here what the child context ``like`` recorded, with
+        ``values`` (in `traced_values` order) in place of its scalars."""
+        values = iter(values)
+        self.capacity_flags.extend(
+            (label, next(values)) for label, _ in like.capacity_flags
+        )
+        self.precision_flags.extend(
+            (label, next(values)) for label, _ in like.precision_flags
+        )
+        self.metrics.extend(
+            (nid, name, next(values)) for nid, name, _ in like.metrics
+        )
 
 # trace-time only: the pre-order position of every node of the plan being
 # traced on this thread (`traced_positions`), for `node_scope`
@@ -165,12 +195,8 @@ class ExecutionPlan:
     predicted_partial_rows: "int | None" = None
     #: multiway-join fusion annotations (planner/distributed
     #: _multiway_fusion_pass): a fused MultiwayHashJoinExec the coordinator
-    #: may bail back to its binary chain when measured build sizes diverge,
-    #: and the estimated-selectivity probe order the statistics module
-    #: picked (a hint only — steps execute in plan order, reordering would
-    #: change the output column order).
+    #: may bail back to its binary chain when measured build sizes diverge.
     multiway_bailout_candidate: "bool | None" = None
-    probe_order_hint: "tuple | None" = None
     #: shuffles the fusion pass deleted building this node (identity
     #: re-partitions); surfaced in EXPLAIN and asserted by tests
     multiway_deleted_exchanges: "int | None" = None
@@ -184,7 +210,7 @@ class ExecutionPlan:
     _PRESERVED_ANNOTATIONS = (
         "est_rows", "est_selectivity",
         "bailout_candidate", "predicted_partial_rows",
-        "multiway_bailout_candidate", "probe_order_hint",
+        "multiway_bailout_candidate",
         "multiway_deleted_exchanges", "global_agg_selected",
     )
 
@@ -447,7 +473,7 @@ class FilterExec(ExecutionPlan):
     def execute_masked(self, ctx: ExecContext):
         """No compaction: the child's rows stay where they are and the
         predicate narrows the mask (stacked filters AND theirs)."""
-        ctx.masked_filters += 1
+        ctx.count("masked_filters")
 
         def narrow(t, live, _rows):
             keep = self._keep(t) & (t.row_mask() if live is None else live)
@@ -532,16 +558,10 @@ class HashAggregateExec(ExecutionPlan):
         # never needs more than pow2(input capacity) — downstream operators
         # (the final sort especially) pay capacity-proportional work, and
         # slots = 2x input would hand them double-width padding for free.
-        # DFTPU_AGG_COMPACT=0 is the A/B lever.
-        import os as _os
-
-        if _os.environ.get("DFTPU_AGG_COMPACT", "1") == "1":
-            self.out_capacity = min(
-                self.num_slots,
-                round_up_pow2(max(child.output_capacity(), 16)),
-            )
-        else:
-            self.out_capacity = self.num_slots
+        self.out_capacity = min(
+            self.num_slots,
+            round_up_pow2(max(child.output_capacity(), 16)),
+        )
 
     def children(self):
         return [self.child]
@@ -579,7 +599,7 @@ class HashAggregateExec(ExecutionPlan):
                 live=live, direct=direct,
             )
             ctx.record_overflow(self, overflow)
-            ctx.direct_groupings += len(direct)
+            ctx.count("direct_groupings", len(direct))
         for f in prec_flags:
             ctx.record_precision_error(self, f)
         return out
@@ -823,70 +843,174 @@ def execute_plan(
     tr = spans.current()
     traces_before = _TRACE_STATS["traces"]
     with tr.span("prepare", "prepare") as psp:
-        (fn, overflow_box, metric_names, trace_counters, first_call_gate,
-         input_list, params, cache) = _prepare_program(
+        prog = _prepare_program(
             plan, task, config, use_cache, shared_cache, shared_key, tr
         )
-        psp.set(cache=cache)
+        psp.set(cache=prog.cache)
+    gate = prog.first_call_gate
     # ends on the fetch of the flag vector: the sync the program already
     # makes, so the span holds the device's work and adds no wait
     with tr.span("execute", "execute") as xsp:
         result = None
-        if first_call_gate is not None and not first_call_gate["warmed"]:
-            with first_call_gate["lock"]:
+        if gate is not None and not gate["warmed"]:
+            with gate["lock"]:
                 # double-check: threads that queued behind the creator
                 # must NOT execute under the gate (that would serialize
                 # the whole task wave) — only the creator's
                 # trace+compile+first-run is serialized; everyone else
                 # re-checks and runs concurrently
-                if not first_call_gate["warmed"]:
-                    result = fn(input_list, params)
-                    first_call_gate["warmed"] = True
+                if not gate["warmed"]:
+                    result = prog.fn(prog.inputs, prog.params)
+                    gate["warmed"] = True
         if result is None:
-            result = fn(input_list, params)
+            result = prog.fn(prog.inputs, prog.params)
         out, flags, metric_vals = result
         flags = np.asarray(flags)  # one fetch for both sentinel checks
         if tr.active:
             xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before,
-                    **trace_counters)
-    any_overflow, any_precision = bool(flags[0]), bool(flags[1])
-    if check_overflow and any_overflow:
-        raise RuntimeError(
-            f"hash table overflow in plan (nodes: "
-            f"{[name for name, _ in overflow_box if not name.startswith(_PRECISION_TAG)]}); "
-            "re-plan with more slots"
-        )
-    if any_precision:
-        # deliberately does NOT contain the word "overflow": the session's
-        # capacity-retry loop must not retry this (a bigger hash table can't
-        # restore int32 exactness).
-        raise RuntimeError(
-            "int32 accumulator range exceeded in plan (nodes: "
-            f"{[name for name, _ in overflow_box if name.startswith(_PRECISION_TAG)]}); "
-            "run with DFTPU_PRECISION=x64 for 64-bit accumulation"
-        )
+                    **prog.trace.counters)
+    raise_flagged(prog.trace, "plan", check_overflow and flags[0], flags[1])
     if metrics_store is not None:
         # positions -> THIS submission's node ids (hoisting preserves the
         # original ids, so callers can look metrics up on their own plan)
         nodes = plan.collect(lambda _n: True)
         node_metrics: dict = {}
-        for (pos, name), v in zip(metric_names, metric_vals):
+        for (pos, name), v in zip(prog.trace.metric_names, metric_vals):
             if 0 <= pos < len(nodes):
                 node_metrics.setdefault(nodes[pos].node_id, {})[name] = int(v)
         metrics_store.insert(task_label or f"task{task.task_index}", node_metrics)
     return out
 
 
+@dataclass
+class ProgramTrace:
+    """What the trace of a plan leaves on the host (`trace_plan` fills
+    it), kept beside the cached executable so that a program-cache hit,
+    which runs no Python of the plan, still reads it."""
+
+    #: labels of the nodes behind the capacity / precision flags, in the
+    #: order `trace_plan` hands the flags back
+    capacity_nodes: list = dc_field(default_factory=list)
+    precision_nodes: list = dc_field(default_factory=list)
+    #: (pre-order position, metric name) of every traced metric value
+    metric_names: list = dc_field(default_factory=list)
+    #: `ExecContext.counters` of the trace, for the executor's span
+    counters: dict = dc_field(default_factory=dict)
+
+
+def trace_plan(exec_target: ExecutionPlan, task: DistributedTaskContext,
+               inputs: dict, config: dict, param_vecs,
+               into: ProgramTrace):
+    """Trace ``exec_target`` for one task inside a program being traced:
+    the one place a program's root `ExecContext` is made. ``inputs``
+    maps leaf node ids to that task's tables, ``param_vecs`` are the
+    hoisted literals' vectors (None: the plan was not hoisted). Fills
+    ``into``; -> (output table, capacity flags, precision flags, metric
+    values), the lists traced scalars in ``into``'s order. How the flags
+    are reduced and fetched is the calling executor's."""
+    from datafusion_distributed_tpu.plan.fingerprint import bound_params
+
+    _TRACE_STATS["traces"] += 1
+    ctx = ExecContext(task=task, inputs=inputs, config=config)
+    # metric names and operator scopes are POSITION-addressed (pre-order
+    # traversal index), not node-id-addressed: a fingerprint-shared
+    # program executes for plan copies whose node ids differ from the
+    # creator's, and fingerprint-equal trees traverse identically — the
+    # caller remaps positions to ITS plan's node ids at insert time
+    with traced_positions(exec_target) as pos_of, (
+        contextlib.nullcontext() if param_vecs is None
+        else bound_params(param_vecs)
+    ):
+        out = exec_target.execute(ctx)
+    into.capacity_nodes[:] = [label for label, _ in ctx.capacity_flags]
+    into.precision_nodes[:] = [label for label, _ in ctx.precision_flags]
+    into.metric_names[:] = [
+        (pos_of.get(nid, -1), name) for nid, name, _ in ctx.metrics
+    ]
+    into.counters.clear()
+    into.counters.update(ctx.counters)
+    return (
+        out,
+        [f for _, f in ctx.capacity_flags],
+        [f for _, f in ctx.precision_flags],
+        [v for _, _, v in ctx.metrics],
+    )
+
+
+def any_flag(flags: list):
+    """Traced OR of a list of traced bools (False for none)."""
+    return jnp.any(jnp.stack(flags)) if flags else jnp.asarray(False)
+
+
+# executor -> (capacity overflow, precision range) message. The texts
+# are read by tests, by sql/context.py `_overflow_node_names` and on the
+# far side of the worker wire.
+_FLAG_TEXTS = {
+    "plan": (
+        "hash table overflow in plan (nodes: {}); re-plan with more slots",
+        "int32 accumulator range exceeded in plan (nodes: {}); "
+        "run with DFTPU_PRECISION=x64 for 64-bit accumulation",
+    ),
+    "mesh": (
+        "exchange/hash capacity overflow on mesh (nodes: {}); "
+        "re-plan with larger capacities",
+        "int32 accumulator range exceeded on mesh (nodes: {}); "
+        "run with DFTPU_PRECISION=x64 for 64-bit accumulation",
+    ),
+    "span": (
+        "hash table overflow in span program (nodes: {}); "
+        "re-plan with more slots",
+        "int32 accumulator range exceeded in span program "
+        "(nodes: {}); run with DFTPU_PRECISION=x64",
+    ),
+}
+
+
+def raise_flagged(trace: ProgramTrace, where: str, capacity,
+                  precision) -> None:
+    """Raise what a program's fetched flags say, capacity first.
+    ``capacity`` and ``precision`` are each one bool for all of the
+    trace's nodes of that kind (the OR the program reduced on the
+    device) or one bool a node; ``where`` is the executor, a key of
+    `_FLAG_TEXTS`."""
+    overflowed = _flagged(trace.capacity_nodes, capacity)
+    out_of_range = _flagged(trace.precision_nodes, precision)
+    if not (overflowed or out_of_range):
+        return
+    from datafusion_distributed_tpu.runtime.errors import (
+        CapacityOverflowError,
+        PrecisionRangeError,
+    )
+
+    cap_text, prec_text = _FLAG_TEXTS[where]
+    if overflowed:
+        raise CapacityOverflowError(cap_text.format(overflowed), overflowed)
+    raise PrecisionRangeError(prec_text.format(out_of_range))
+
+
+def _flagged(nodes: list, flags) -> list:
+    flags = np.broadcast_to(np.asarray(flags, dtype=bool), (len(nodes),))
+    return [n for n, f in zip(nodes, flags) if f]
+
+
+@dataclass
+class _Program:
+    """What `_prepare_program` hands `execute_plan`."""
+
+    fn: Callable  # the jitted (inputs, params) -> (out, flags, metrics)
+    trace: ProgramTrace
+    first_call_gate: Optional[dict]  # stage-shared programs only
+    inputs: list
+    params: tuple
+    cache: str  # "hit" | "miss"
+
+
 def _prepare_program(plan, task, config, use_cache, shared_cache,
-                     shared_key, tr):
+                     shared_key, tr) -> _Program:
     """`execute_plan`'s host work before the device starts (its
     ``prepare`` span): hoist and fingerprint the plan, load the leaves,
-    find or make the jitted program. -> (fn, overflow_box, metric_names,
-    trace_counters, first_call_gate, input_list, params, "hit" | "miss")."""
-    from datafusion_distributed_tpu.plan.fingerprint import (
-        bound_params,
-        prepare_plan,
-    )
+    find or make the jitted program."""
+    from datafusion_distributed_tpu.plan.fingerprint import prepare_plan
 
     # content-address the program: literal-hoisted plan + structural
     # fingerprint (None -> legacy object-identity keying). The hoisted
@@ -915,52 +1039,17 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
                     rows=sum(int(t.num_rows) for t in staged),
                     capacity=sum(t.capacity for t in staged))
 
-    overflow_box: list = []
-    metric_names: list = []
-    # what the trace counted, for the `execute` span: ``masked_filters``,
-    # ``direct_groupings``
-    trace_counters: dict = {}
+    trace = ProgramTrace()
 
     def run(inp_list, param_vecs):
-        _TRACE_STATS["traces"] += 1
-        inp = dict(zip(leaf_ids, inp_list))
-        ctx = ExecContext(task=task, inputs=inp, config=config or {})
-        # metric names are POSITION-addressed (pre-order traversal index),
-        # not node-id-addressed: a fingerprint-shared program executes for
-        # plan copies whose node ids differ from the creator's, and
-        # fingerprint-equal trees traverse identically — the caller remaps
-        # positions to ITS plan's node ids at insert time. The operator
-        # scopes inside the program use the same address.
-        with traced_positions(exec_target) as pos_of, \
-                bound_params(param_vecs):
-            out = exec_target.execute(ctx)
-        overflow_box.clear()
-        overflow_box.extend(ctx.overflow_flags)
-        metric_names.clear()
-        metric_names.extend(
-            (pos_of.get(nid, -1), name) for nid, name, _ in ctx.metrics
-        )
-        metric_vals = [v for _, _, v in ctx.metrics]
-        trace_counters["masked_filters"] = ctx.masked_filters
-        trace_counters["direct_groupings"] = ctx.direct_groupings
-        cap_flags = [
-            f for name, f in ctx.overflow_flags
-            if not name.startswith(_PRECISION_TAG)
-        ]
-        prec_flags = [
-            f for name, f in ctx.overflow_flags
-            if name.startswith(_PRECISION_TAG)
-        ]
-        any_overflow = (
-            jnp.any(jnp.stack(cap_flags)) if cap_flags else jnp.asarray(False)
-        )
-        any_precision = (
-            jnp.any(jnp.stack(prec_flags)) if prec_flags
-            else jnp.asarray(False)
+        out, cap_flags, prec_flags, metric_vals = trace_plan(
+            exec_target, task, dict(zip(leaf_ids, inp_list)), config or {},
+            param_vecs, trace,
         )
         # ONE packed flag vector: each separate scalar device->host fetch
         # is a synchronous round-trip, so both checks ride a single transfer
-        return out, jnp.stack([any_overflow, any_precision]), metric_vals
+        flags = jnp.stack([any_flag(cap_flags), any_flag(prec_flags)])
+        return out, flags, metric_vals
 
     # the distributed-tracing wire context (runtime/tracing.py
     # TRACE_CTX_KEY) must NEVER key a compiled program: its span ids
@@ -979,13 +1068,12 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
     else:
         cache_key = ("id", plan.node_id, task.task_index,
                      task.task_count, cfg_items)
-    # the trace-time boxes (overflow names, metric names, counters) must
-    # come from the SAME closure as the cached executable, or cache hits
-    # would see them empty. use_cache=False (worker path: per-task programs
-    # go through the TTL'd stage-share cache instead) keeps one-shot
-    # programs out of the global cache so their closures don't pin shipped
-    # task tables.
-    cached = None
+    # the `ProgramTrace` must come from the SAME closure as the cached
+    # executable, or cache hits would see it empty. use_cache=False
+    # (worker path: per-task programs go through the TTL'd stage-share
+    # cache instead) keeps one-shot programs out of the global cache so
+    # their closures don't pin shipped task tables.
+    cached = None  # (jitted run, its ProgramTrace)
     cache = "hit"
     if use_cache:
         with _CACHE_LOCK:
@@ -1014,8 +1102,8 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
         # concurrent siblings wait for its trace+compile instead of racing
         # jax's own dispatch into duplicate compiles.
         with _SHARED_LOCK:
-            cached = shared_cache.get(skey)
-            if cached is None:
+            entry = shared_cache.get(skey)
+            if entry is None:
                 cache = "miss"
                 _SHARED_STATS["miss"] += 1
                 # entry cap: each entry's closure pins its creator task's
@@ -1026,18 +1114,17 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
                 # evicted program just recompiles on next use.
                 while len(shared_cache) >= _SHARED_ENTRY_CAP:
                     shared_cache.pop(next(iter(shared_cache)))
-                cached = (
-                    jax.jit(run), overflow_box, metric_names, trace_counters,
+                entry = (
+                    jax.jit(run), trace,
                     {"lock": threading.Lock(), "warmed": False},
                 )
-                shared_cache[skey] = cached
+                shared_cache[skey] = entry
             else:
                 _SHARED_STATS["hit"] += 1
-        first_call_gate = cached[4]
-        cached = cached[:4]
+        cached, first_call_gate = entry[:2], entry[2]
     if cached is None:
         cache = "miss"
-        cached = (jax.jit(run), overflow_box, metric_names, trace_counters)
+        cached = (jax.jit(run), trace)
         if use_cache:
             with _CACHE_LOCK:
                 # bounded LRU eviction (was: a full clear() at the cap — a
@@ -1045,9 +1132,7 @@ def _prepare_program(plan, task, config, use_cache, shared_cache,
                 while len(_COMPILE_CACHE) >= _COMPILE_CACHE_MAX:
                     _COMPILE_CACHE.pop(next(iter(_COMPILE_CACHE)))
                 _COMPILE_CACHE[cache_key] = cached
-    fn, overflow_box, metric_names, trace_counters = cached
-    return (fn, overflow_box, metric_names, trace_counters, first_call_gate,
-            input_list, params, cache)
+    return _Program(*cached, first_call_gate, input_list, params, cache)
 
 
 _COMPILE_CACHE: dict = {}  # insertion order == LRU order (move-to-end on hit)

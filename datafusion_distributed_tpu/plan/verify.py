@@ -44,7 +44,6 @@ verification & lint"):
   DFTPU022  capacity exceeds int32 index range  (capacity, error)
   DFTPU023  join slots below build-side bound   (capacity, warning)
   DFTPU024  dictionary exceeds int32 code range (capacity, error)
-  DFTPU025  table exceeds pallas partition cap  (capacity, warning)
   DFTPU031  partition count mismatch at boundary(exchange, error)
   DFTPU032  stage id unstamped / duplicated     (exchange, error)
   DFTPU033  plan graph contains a cycle         (structure, error)
@@ -70,11 +69,6 @@ from typing import Any, Optional
 from datafusion_distributed_tpu.schema import DataType, Field, Schema
 
 _INT32_MAX = (1 << 31) - 1
-
-# largest hash table the pallas partition-pass kernels accept
-# (ops/pallas_hash._MAX_TABLE_SLOTS); mirrored here so the plan layer
-# never imports the ops layer at module load
-_PALLAS_MAX_TABLE_SLOTS = 1 << 20
 
 #: verification modes, in decreasing strictness
 MODES = ("strict", "warn", "off")
@@ -133,9 +127,9 @@ class VerifyResult:
 
 
 class PlanVerificationError(RuntimeError):
-    """A plan failed static verification under ``strict`` mode. Deliberately
-    NOT matched by the overflow-retry loops (`"overflow" not in message`):
-    re-planning cannot repair a structurally malformed plan."""
+    """A plan failed static verification under ``strict`` mode. Not a
+    capacity overflow, so nothing retries it: re-planning cannot repair a
+    structurally malformed plan."""
 
     def __init__(self, result: VerifyResult, context: str = ""):
         self.result = result
@@ -501,15 +495,6 @@ def _capacity_pass(nodes: list, p: _Pass) -> None:
                     f"estimated {int(est)} distinct groups: the claim "
                     "loop will overflow and force a re-plan retry",
                 )
-            if (getattr(node, "global_agg_selected", False)
-                    and node.num_slots > _PALLAS_MAX_TABLE_SLOTS):
-                p.emit(
-                    "DFTPU025", "warning", node,
-                    f"global-hash aggregate table of {node.num_slots} "
-                    f"slots exceeds the pallas partition budget "
-                    f"({_PALLAS_MAX_TABLE_SLOTS}): the kernel degrades "
-                    "to the XLA scatter path (correct but unaccelerated)",
-                )
         elif kind == "MultiwayHashJoinExec":
             for idx, (s, b) in enumerate(zip(node.steps, node.builds)):
                 try:
@@ -525,15 +510,6 @@ def _capacity_pass(nodes: list, p: _Pass) -> None:
                         f"{s.num_slots} slots for a build side bounded "
                         f"by {bound} rows (load factor > 1): guaranteed "
                         "overflow retry at full occupancy",
-                    )
-                if s.num_slots > _PALLAS_MAX_TABLE_SLOTS:
-                    p.emit(
-                        "DFTPU025", "warning", node,
-                        f"multiway step {idx} table of {s.num_slots} "
-                        f"slots exceeds the pallas partition budget "
-                        f"({_PALLAS_MAX_TABLE_SLOTS}): the cascaded "
-                        "probe kernel is ineligible and the stage takes "
-                        "the binary reference chain",
                     )
         elif kind == "HashJoinExec":
             try:

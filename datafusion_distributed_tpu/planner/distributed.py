@@ -176,10 +176,6 @@ class DistributedConfig:
     # statistics gate (planner/statistics.multiway_fusion_allowed) bounds
     # their padded sum
     multiway_build_bytes_max: int = 1 << 26
-    # stamp the statistics-chosen probe order (smallest estimated build
-    # first) as the `probe_order_hint` annotation. Hint only: steps always
-    # EXECUTE in plan order — reordering would permute output columns
-    multiway_probe_reorder: bool = False
     # global-hash-table aggregation (`SET distributed.global_hash_agg`):
     # when sampled NDV predicts partial states will NOT shrink the
     # exchange (the high-NDV regime of *Global Hash Tables Strike
@@ -691,10 +687,9 @@ def _inject_global_agg(plan: HashAggregateExec, child, ann,
     high-NDV regime where per-partition tables + merge is pure overhead),
     shuffle the RAW rows on the group keys and run ONE single-mode
     aggregate per task over its disjoint key range — one shared table, no
-    merge step. Under DFTPU_PALLAS=1 that single-mode aggregate lowers to
-    the fused build+accumulate kernel (ops/pallas_hash.
-    pallas_global_hash_aggregate). Returns the (plan, dist, annotation)
-    triple or None to keep the partial+final shape."""
+    merge step; the single-mode aggregate is the same `hash_aggregate` every
+    other aggregate runs. Off by default. Returns the (plan, dist,
+    annotation) triple or None to keep the partial+final shape."""
     from datafusion_distributed_tpu.planner.statistics import (
         estimate_rows,
         predict_partial_agg_reduction,
@@ -1015,7 +1010,6 @@ def _multiway_fusion_pass(
         return plan
 
     from datafusion_distributed_tpu.planner.statistics import (
-        choose_probe_order,
         multiway_fusion_allowed,
     )
 
@@ -1085,8 +1079,6 @@ def _multiway_fusion_pass(
         mw.multiway_bailout_candidate = True
         mw.est_rows = outer.est_rows
         mw.multiway_deleted_exchanges = deleted
-        if cfg.multiway_probe_reorder:
-            mw.probe_order_hint = choose_probe_order(builds)
         return mw
 
     def walk(node: ExecutionPlan) -> ExecutionPlan:

@@ -392,21 +392,10 @@ def multiway_fusion_allowed(builds, max_bytes: int) -> bool:
     """Statistics gate for the multiway fusion pass: every build side must
     carry a usable size AND their combined resident footprint must fit the
     configured budget. (Per-step NDV bounds ride on each step's captured
-    num_slots, checked by the verifier's DFTPU025 pass.)"""
+    num_slots, checked by the verifier's DFTPU023 pass.)"""
     if not builds:
         return False
     return multiway_build_bytes(builds) <= max_bytes
-
-
-def choose_probe_order(builds, stats: Optional[PlanStatistics] = None):
-    """Estimated probe order for a fused chain: most selective (smallest
-    estimated build) first, the classic multiway-join heuristic. Returned
-    as a tuple of step indices; the planner stamps it as the
-    ``probe_order_hint`` annotation ONLY — actually reordering steps would
-    permute the fused stage's output columns, which is illegal without a
-    restoring projection."""
-    est = [(estimate_rows(b, stats), i) for i, b in enumerate(builds)]
-    return tuple(i for _, i in sorted(est, key=lambda t: (t[0], t[1])))
 
 
 def plan_device_bytes(plan) -> int:

@@ -45,10 +45,12 @@ from datafusion_distributed_tpu.plan.physical import (
 )
 from datafusion_distributed_tpu.runtime.codec import TableStore, encode_plan
 from datafusion_distributed_tpu.runtime.errors import (
+    QueryError,
     TaskCancelledError,
     TaskTimeoutError,
     WorkerError,
     WorkerUnavailableError,
+    is_capacity_overflow,
     is_retryable,
 )
 from datafusion_distributed_tpu.runtime.metrics import (
@@ -4223,8 +4225,8 @@ class AdaptiveCoordinator(Coordinator):
                     self._group_of[s.stage_id] = gid
         try:
             out = super().execute(plan)
-        except RuntimeError as e:
-            if "overflow" in str(e):
+        except QueryError as e:
+            if is_capacity_overflow(e):
                 self.resize_headroom *= self.OVERFLOW_WIDEN_FACTOR
             raise
         # success: back to the constructed value so one query's widening does

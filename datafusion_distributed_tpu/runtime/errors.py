@@ -32,6 +32,24 @@ class PlanningError(QueryError):
     pass
 
 
+class CapacityOverflowError(QueryError):
+    """A program's statically planned capacity (a hash table's slots, a
+    join's or an exchange's output rows) was too small for the data:
+    the result is invalid and a re-plan with wider capacities can
+    succeed. ``nodes``: the labels of the program's nodes that can
+    overflow (the flags are OR-reduced on the device, so the culprit is
+    one of them), or of those that did where the executor knows."""
+
+    def __init__(self, message: str, nodes=()):
+        super().__init__(message)
+        self.nodes = list(nodes)
+
+
+class PrecisionRangeError(QueryError):
+    """A 32-bit accumulator left its exact range (tpu precision mode).
+    No wider capacity restores exactness, so nothing retries it."""
+
+
 class WorkerError(QueryError):
     """An error that happened on (or is attributed to) a worker.
 
@@ -161,6 +179,17 @@ _WIRE_CLASSES: dict[str, type] = {
     for c in (WorkerError, TransportError, WorkerUnavailableError,
               TaskTimeoutError, PlanIntegrityError)
 }
+
+
+def is_capacity_overflow(exc: BaseException) -> bool:
+    """Whether ``exc`` is a capacity overflow, raised here or on a worker
+    (``original_type`` crosses every wire with the `WorkerError`): what
+    the session's re-plan loop and the adaptive coordinator's headroom
+    act on."""
+    return isinstance(exc, CapacityOverflowError) or (
+        isinstance(exc, WorkerError)
+        and exc.original_type == CapacityOverflowError.__name__
+    )
 
 
 def is_retryable(exc: BaseException) -> bool:

@@ -22,16 +22,19 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from datafusion_distributed_tpu import precision
 from datafusion_distributed_tpu.ops.table import Table
-from datafusion_distributed_tpu.plan.physical import _PRECISION_TAG
+from datafusion_distributed_tpu.plan.physical import (
+    DistributedTaskContext,
+    ExecutionPlan,
+    ProgramTrace,
+    any_flag,
+    raise_flagged,
+    trace_count,
+    trace_plan,
+)
 from datafusion_distributed_tpu.runtime import tracing
 
 # per-task metric counters (row/byte counts); 32-bit in tpu precision mode
 _METRIC_DTYPE = precision.ACC_INT
-from datafusion_distributed_tpu.plan.physical import (
-    DistributedTaskContext,
-    ExecContext,
-    ExecutionPlan,
-)
 
 AXIS = "tasks"
 
@@ -82,14 +85,7 @@ def execute_on_mesh(
     With ``metrics_store`` (runtime/metrics.py protocol), traced per-node
     metrics come back per task via a P(axis)-stacked program output and are
     inserted under labels task0..taskN-1."""
-    from datafusion_distributed_tpu.plan.fingerprint import (
-        bound_params,
-        prepare_plan,
-    )
-    from datafusion_distributed_tpu.plan.physical import (
-        _TRACE_STATS,
-        traced_positions,
-    )
+    from datafusion_distributed_tpu.plan.fingerprint import prepare_plan
 
     num_tasks = mesh.shape[AXIS]
     # content-address the SPMD program: fingerprint-equal plans (fresh
@@ -106,7 +102,7 @@ def execute_on_mesh(
     leaf_ids = [leaf.node_id for leaf in leaves if hasattr(leaf, "load")]
     stacked_inputs: list[Table] = []
     tr = tracing.current()
-    traces_before = _TRACE_STATS["traces"]
+    traces_before = trace_count()
     with tr.span("mesh.stack_inputs", "mesh.stack_inputs") as ssp:
         for leaf in leaves:
             if not hasattr(leaf, "load"):
@@ -125,67 +121,28 @@ def execute_on_mesh(
                               for t in stacked_inputs),
                     tasks=num_tasks)
 
-    overflow_names: list = []
-    metric_names: list = []
-    # what the trace counted, for the `mesh.execute` span (as
-    # plan/physical.py execute_plan keeps it): ``masked_filters``,
-    # ``direct_groupings``
-    trace_counters: dict = {}
+    trace = ProgramTrace()
+
+    def axis_any(flags):
+        return jax.lax.pmax(any_flag(flags).astype(jnp.int32), AXIS) > 0
 
     def run(inputs_stacked, param_vecs):
-        _TRACE_STATS["traces"] += 1
         # local view: leading task axis of size 1 -> squeeze
         local_inputs = {
             nid: jax.tree.map(lambda x: x[0], t)
             for nid, t in zip(leaf_ids, inputs_stacked)
         }
-        ctx = ExecContext(
-            task=DistributedTaskContext(0, num_tasks),
-            inputs=local_inputs,
-            config={"mesh_axis": AXIS, "num_tasks": num_tasks},
+        out, cap_flags, prec_flags, metric_vals = trace_plan(
+            exec_target, DistributedTaskContext(0, num_tasks), local_inputs,
+            {"mesh_axis": AXIS, "num_tasks": num_tasks}, param_vecs, trace,
         )
-        # position-addressed metric names and operator scopes (see
-        # plan/physical.py run(): fingerprint-shared programs must not
-        # leak creator node ids)
-        with traced_positions(exec_target) as pos_of, \
-                bound_params(param_vecs):
-            out = exec_target.execute(ctx)
-        overflow_names.clear()
-        overflow_names.extend(name for name, _ in ctx.overflow_flags)
-        metric_names.clear()
-        metric_names.extend(
-            (pos_of.get(nid, -1), name) for nid, name, _ in ctx.metrics
-        )
-        trace_counters["masked_filters"] = ctx.masked_filters
-        trace_counters["direct_groupings"] = ctx.direct_groupings
-        if ctx.metrics:
+        if metric_vals:
             mvec = jnp.stack(
-                [v.astype(_METRIC_DTYPE) for _, _, v in ctx.metrics]
+                [v.astype(_METRIC_DTYPE) for v in metric_vals]
             )[None, :]
         else:
             mvec = jnp.zeros((1, 0), dtype=_METRIC_DTYPE)
-        cap_flags = [
-            f for name, f in ctx.overflow_flags
-            if not name.startswith(_PRECISION_TAG)
-        ]
-        prec_flags = [
-            f for name, f in ctx.overflow_flags
-            if name.startswith(_PRECISION_TAG)
-        ]
-        any_overflow = (
-            jnp.any(jnp.stack(cap_flags)) if cap_flags else jnp.asarray(False)
-        )
-        any_overflow = (
-            jax.lax.pmax(any_overflow.astype(jnp.int32), AXIS) > 0
-        )
-        any_precision = (
-            jnp.any(jnp.stack(prec_flags)) if prec_flags
-            else jnp.asarray(False)
-        )
-        any_precision = (
-            jax.lax.pmax(any_precision.astype(jnp.int32), AXIS) > 0
-        )
-        return out, any_overflow, any_precision, mvec
+        return out, axis_any(cap_flags), axis_any(prec_flags), mvec
 
     # pytree-PREFIX specs (one spec per leaf Table / param vector, applied
     # to the whole subtree): a full spec tree would bake the creator's
@@ -216,9 +173,9 @@ def execute_on_mesh(
                 check_rep=False,
             )
         )
-        cached = (fn, overflow_names, metric_names, trace_counters)
+        cached = (fn, trace)
         _MESH_COMPILE_CACHE[cache_key] = cached
-    fn, overflow_names, metric_names, trace_counters = cached
+    fn, trace = cached
     # ends on the fetch of the two flags, the sync this path already makes
     with tr.span("mesh.execute", "mesh.execute",
                  cache="hit" if cached_hit else "miss") as xsp:
@@ -226,28 +183,15 @@ def execute_on_mesh(
         any_overflow = check_overflow and bool(any_overflow)
         any_precision = bool(any_precision)
         if tr.active:
-            xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before,
-                    **trace_counters)
-    if any_overflow:
-        raise RuntimeError(
-            f"exchange/hash capacity overflow on mesh (nodes: "
-            f"{[n for n in overflow_names if not n.startswith(_PRECISION_TAG)]}); "
-            "re-plan with larger capacities"
-        )
-    if any_precision:
-        raise RuntimeError(
-            "int32 accumulator range exceeded on mesh (nodes: "
-            f"{[n for n in overflow_names if n.startswith(_PRECISION_TAG)]}); "
-            "run with DFTPU_PRECISION=x64 for 64-bit accumulation"
-        )
+            xsp.set(new_traces=trace_count() - traces_before,
+                    **trace.counters)
+    raise_flagged(trace, "mesh", any_overflow, any_precision)
     if metrics_store is not None:
-        import numpy as np_
-
         nodes = plan.collect(lambda _n: True)
-        m = np_.asarray(mvec)  # [T, M]
+        m = np.asarray(mvec)  # [T, M]
         for t in range(m.shape[0]):
             node_metrics: dict = {}
-            for (pos, name), v in zip(metric_names, m[t]):
+            for (pos, name), v in zip(trace.metric_names, m[t]):
                 if 0 <= pos < len(nodes):
                     node_metrics.setdefault(
                         nodes[pos].node_id, {}

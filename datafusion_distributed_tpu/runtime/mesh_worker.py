@@ -33,12 +33,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 from datafusion_distributed_tpu.ops.table import Table, concat_tables
 from datafusion_distributed_tpu.plan.exchanges import IsolatedArmExec
 from datafusion_distributed_tpu.plan.physical import (
-    _PRECISION_TAG,
     DistributedTaskContext,
-    ExecContext,
     ExecutionPlan,
     MemoryScanExec,
     ParquetScanExec,
+    ProgramTrace,
+    raise_flagged,
+    trace_plan,
 )
 from datafusion_distributed_tpu.runtime.worker import (
     TaskData,
@@ -183,25 +184,21 @@ def execute_stage_span_on_mesh(
         for nid, t in stacked.items()
     }
 
-    overflow_names: list = []
+    trace = ProgramTrace()
 
     def run(inputs_stacked):
         local = {
             nid: jax.tree.map(lambda x: x[0], t)
             for nid, t in inputs_stacked.items()
         }
-        ctx = ExecContext(
-            task=DistributedTaskContext(0, task_count),
-            inputs=local,
-            config=dict(config or {}),
+        # the plan was not hoisted: no parameter vectors
+        out, cap_flags, prec_flags, _ = trace_plan(
+            plan, DistributedTaskContext(0, task_count), local,
+            dict(config or {}), None, trace,
         )
-        out = plan.execute(ctx)
-        overflow_names.clear()
-        overflow_names.extend(name for name, _ in ctx.overflow_flags)
-        flags = (
-            jnp.stack([f for _, f in ctx.overflow_flags])
-            if ctx.overflow_flags else jnp.zeros((0,), jnp.bool_)
-        )
+        # one row of flags a task, capacity flags first
+        flags = cap_flags + prec_flags
+        flags = jnp.stack(flags) if flags else jnp.zeros((0,), jnp.bool_)
         return (
             jax.tree.map(lambda x: x[None], out),
             flags[None, :],
@@ -216,26 +213,10 @@ def execute_stage_span_on_mesh(
     # mesh_executor.py — the old disable-around-invocation workaround was
     # removed after re-verification)
     out_stacked, flags = jax.jit(fn)(stacked)
-    flags = np.asarray(flags)  # [W, F]
-    if flags.size:
-        cap = [
-            n for i, n in enumerate(overflow_names)
-            if not n.startswith(_PRECISION_TAG) and bool(flags[:, i].any())
-        ]
-        prec = [
-            n for i, n in enumerate(overflow_names)
-            if n.startswith(_PRECISION_TAG) and bool(flags[:, i].any())
-        ]
-        if cap:
-            raise RuntimeError(
-                f"hash table overflow in span program (nodes: {cap}); "
-                "re-plan with more slots"
-            )
-        if prec:
-            raise RuntimeError(
-                "int32 accumulator range exceeded in span program "
-                f"(nodes: {prec}); run with DFTPU_PRECISION=x64"
-            )
+    # [W, F] -> which node's flag any task of the span raised
+    flagged = np.asarray(flags).any(axis=0)
+    n_cap = len(trace.capacity_nodes)
+    raise_flagged(trace, "span", flagged[:n_cap], flagged[n_cap:])
     return [
         jax.tree.map(lambda x: x[i], out_stacked) for i in range(span_width)
     ]

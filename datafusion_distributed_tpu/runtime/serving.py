@@ -534,8 +534,7 @@ def run_closed_loop(session: "ServingSession", client_workloads,
                     classify=None, timeout: float = 600.0) -> dict:
     """Drive N closed-loop clients against ``session``: client ``i``
     submits each SQL in ``client_workloads[i]`` in order, waiting for
-    each result before the next (the serving bench harness of
-    `benchmarks/micro_bench.py`).
+    each result before the next.
 
     ``classify(client_index) -> label`` buckets the per-query walls
     (submit -> resolve, queue wait included — the client-visible

@@ -64,6 +64,7 @@ from typing import Optional
 # the recording half is a leaf module (`spans.py`), so that `ops/`, `io/`
 # and `plan/` can open spans without importing the runtime layer; this
 # module is its face for the runtime layer and everything above it
+from datafusion_distributed_tpu import spans as _spans
 from datafusion_distributed_tpu.spans import (  # noqa: F401
     DEFAULT_TRACE_STORE,
     NULL_TRACER,
@@ -270,10 +271,8 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
     - ``counters``: ``bytes`` by span kind, ``transfers`` (device-to-host
       pulls of the fetch), ``retries`` (overflow retries, stamped on the
       root that succeeded), ``new_traces`` (programs traced afresh),
-      ``masked_filters`` (filters of its programs that handed an
-      aggregate their mask and did not compact), ``direct_groupings``
-      (aggregates of its programs that addressed their groups by
-      dictionary codes and built no group table).
+      and every name of `spans.PROGRAM_COUNTERS` (what its programs
+      counted while they were traced), zero included.
 
     The one report for operators (`render_profile` prints the last
     trace's row) and for the benchmark's program metrics."""
@@ -281,6 +280,8 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
 
 
 def _layer_rows(traces) -> list:
+    # the span attributes summed into a request's ``counters``
+    summed = ("transfers", "new_traces") + _spans.PROGRAM_COUNTERS
     rows: dict = {}
     for trace in traces:
         root = trace.root_span()
@@ -293,9 +294,8 @@ def _layer_rows(traces) -> list:
                 "request": key, "traces": [], "t0_s": trace.t0,
                 "wall_s": 0.0,
                 "self_s": {}, "total_s": {},
-                "counters": {"bytes": {}, "transfers": 0, "retries": 0,
-                             "new_traces": 0, "masked_filters": 0,
-                             "direct_groupings": 0},
+                "counters": {"bytes": {}, "retries": 0,
+                             **dict.fromkeys(summed, 0)},
             }
         row["traces"].append(trace.query_id)
         row["wall_s"] += root.duration
@@ -313,8 +313,7 @@ def _layer_rows(traces) -> list:
                 counters["bytes"][span.kind] = (
                     counters["bytes"].get(span.kind, 0) + int(nbytes)
                 )
-            for name in ("transfers", "new_traces", "masked_filters",
-                         "direct_groupings"):
+            for name in summed:
                 counters[name] += int(span.attrs.get(name, 0) or 0)
     return list(rows.values())
 

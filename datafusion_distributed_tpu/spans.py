@@ -41,6 +41,16 @@ TRACE_CTX_KEY = "trace_ctx"
 #: trace reducer to keep. Fixed; PERF.md section 3 lists the names.
 PROFILE_PREFIX = "dftpu."
 
+#: what a program counts while it is traced (`ExecContext.count`), kept
+#: with its cached executable and set, every name, on the `execute` /
+#: `mesh.execute` span of each call; `layer_report()` sums every name into
+#: a request's ``counters``. A new counter is a name here and one
+#: ``ctx.count(name)``. ``masked_filters``: filters that handed an
+#: aggregate their mask and did not compact; ``direct_groupings``:
+#: aggregates that addressed their groups by dictionary codes and built
+#: no group table.
+PROGRAM_COUNTERS = ("masked_filters", "direct_groupings")
+
 _SPAN_CAP = 4096     # ring-buffer bound per query
 _EVENT_CAP = 2048    # trace-level event bound per query
 _QUERY_CAP = 32      # LRU bound across queries (running ones pinned)

@@ -413,11 +413,7 @@ class SessionConfig:
 
 class OverflowRetryAbandoned(RuntimeError):
     """Raised (instead of another widening) when an overflow retry's plan
-    would exceed the device-memory budget. A distinct type so the retry
-    loops' `"overflow" in str(e)` filter does not catch it and keep
-    widening — re-planning at 16x/64x factors executes plan-time scalar
-    subqueries at exactly the blown-up capacities the guard exists to
-    prevent."""
+    would exceed the device-memory budget."""
 
 
 def _overflow_node_names(err) -> str:
@@ -585,34 +581,55 @@ class DataFrame:
         the static-shape analogue of the reference's pending->ready two-phase
         planning: capacities are planned optimistically and revised on
         overflow."""
-        cfg = self.ctx.config.planner
-        last_err: Optional[Exception] = None
         with self._trace_call("query") as call:
-            for _attempt in range(self.ctx.config.overflow_retries + 1):
-                try:
-                    with call.tracer.span("attempt", "attempt",
-                                          attempt=_attempt):
-                        # planning is inside the try: scalar subqueries
-                        # execute at plan time and their overflows must
-                        # trigger the same retry
-                        plan = self.physical_plan(cfg)
-                        _overflow_retry_guard(plan, _attempt, last_err)
-                        out = execute_plan(plan)
-                    call.span.set(retries=_attempt)
-                    self.last_retry_count = _attempt  # observability (sweeps)
-                    return self._tagged(out, call.request)
-                except RuntimeError as e:
-                    if isinstance(e, OverflowRetryAbandoned):
-                        raise
-                    if "overflow" not in str(e):
-                        raise
-                    last_err = e
-                    cfg, _ = _widen_for_overflow(
-                        cfg, None, e,
-                        force_all=_attempt
-                        >= self.ctx.config.overflow_retries - 1,
-                    )
-            raise last_err  # type: ignore[misc]
+            def attempt(n, pcfg, _dcfg, guard):
+                with call.tracer.span("attempt", "attempt", attempt=n):
+                    plan = self.physical_plan(pcfg)
+                    guard(plan)
+                    return execute_plan(plan)
+
+            out = self._retry_on_overflow(
+                self.ctx.config.planner, None, attempt
+            )
+            call.span.set(retries=self.last_retry_count)
+            return self._tagged(out, call.request)
+
+    def _retry_on_overflow(self, pcfg: PlannerConfig, dcfg, attempt):
+        """The re-plan loop of every tier: ``attempt(n, pcfg, dcfg,
+        guard)`` plans, calls ``guard(plan)`` (`_overflow_retry_guard`)
+        and executes attempt ``n``, all of it inside that tier's own span
+        or scope. Planning belongs inside: scalar subqueries execute at
+        plan time and their overflows must trigger the same retry. A
+        capacity overflow, raised here or on a worker, widens the knobs
+        it implicates (all of them on the last widening) and tries
+        again, ``overflow_retries`` times; anything else passes through.
+        -> the attempt's result, with ``last_retry_count`` set."""
+        from datafusion_distributed_tpu.runtime.errors import (
+            QueryError,
+            is_capacity_overflow,
+        )
+
+        retries = self.ctx.config.overflow_retries
+        last_err: Optional[Exception] = None
+        for n in range(retries + 1):
+            try:
+                out = attempt(
+                    n, pcfg, dcfg,
+                    lambda plan: _overflow_retry_guard(plan, n, last_err),
+                )
+            except QueryError as e:
+                if not is_capacity_overflow(e):
+                    raise
+                last_err = e
+                # widen in place so every other customized field survives
+                # the retry (session SET options, skew factor included)
+                pcfg, dcfg = _widen_for_overflow(
+                    pcfg, dcfg, e, force_all=n >= retries - 1
+                )
+            else:
+                self.last_retry_count = n  # observability (sweeps)
+                return out
+        raise last_err  # type: ignore[misc]
 
     def _trace_call(self, name: str):
         return tracing.trace_call(
@@ -751,34 +768,16 @@ class DataFrame:
         dcfg = replace(
             self._seeded_distributed_config(t), uniform_stage_tasks=True
         )
-        last_err: Optional[Exception] = None
         with self._trace_call("query") as call:
-            for _attempt in range(self.ctx.config.overflow_retries + 1):
-                try:
-                    with call.tracer.span("attempt", "attempt",
-                                          attempt=_attempt):
-                        plan = self.distributed_plan(t, dcfg, pcfg,
-                                                     mesh=mesh)
-                        _overflow_retry_guard(plan, _attempt, last_err)
-                        out = execute_on_mesh(plan, mesh)
-                    call.span.set(retries=_attempt)
-                    self.last_retry_count = _attempt  # observability (sweeps)
-                    return self._tagged(out, call.request)
-                except RuntimeError as e:
-                    if isinstance(e, OverflowRetryAbandoned):
-                        raise
-                    if "overflow" not in str(e):
-                        raise
-                    last_err = e
-                    # widen in place so every other customized field
-                    # survives the retry (session SET options, skew
-                    # factor included)
-                    pcfg, dcfg = _widen_for_overflow(
-                        pcfg, dcfg, e,
-                        force_all=_attempt
-                        >= self.ctx.config.overflow_retries - 1,
-                    )
-            raise last_err  # type: ignore[misc]
+            def attempt(n, pcfg, dcfg, guard):
+                with call.tracer.span("attempt", "attempt", attempt=n):
+                    plan = self.distributed_plan(t, dcfg, pcfg, mesh=mesh)
+                    guard(plan)
+                    return execute_on_mesh(plan, mesh)
+
+            out = self._retry_on_overflow(pcfg, dcfg, attempt)
+            call.span.set(retries=self.last_retry_count)
+            return self._tagged(out, call.request)
 
     def _seeded_distributed_config(self, num_tasks: int):
         """DistributedConfig honoring the session's `SET distributed.*`
@@ -897,51 +896,41 @@ class DataFrame:
             # coordinator hook as checkpoint restore (None when the
             # result_cache knob is off)
             coordinator.result_cache = self.ctx.result_cache()
-        pcfg = self.ctx.config.planner
-        dcfg = self._seeded_host_config(num_tasks)
-        last_err: Optional[Exception] = None
         adaptive_coord = hasattr(coordinator, "pin_overflow_headroom")
-        try:
-            for _attempt in range(self.ctx.config.overflow_retries + 1):
-                if adaptive_coord and _attempt:
-                    # widen-and-pin for the retry (see
-                    # AdaptiveCoordinator.pin_overflow_headroom: subquery
-                    # successes through the same coordinator must not reset
-                    # the widened headroom mid-attempt)
-                    coordinator.pin_overflow_headroom(_attempt)
-                # an attempt here is one `Coordinator.execute`, a trace
-                # of its own: its root carries the request and the
-                # attempt, and the one that succeeds the retries
-                scope = tracing.request_scope(self.request_id,
-                                              attempt=_attempt)
-                try:
-                    with scope:
-                        plan = self.distributed_plan(
-                            num_tasks, dcfg, pcfg, coordinator=coordinator
-                        )
-                        _overflow_retry_guard(plan, _attempt, last_err)
-                        out = coordinator.execute(plan)
-                    traced = (
-                        getattr(coordinator, "trace_store", None)
-                        or tracing.DEFAULT_TRACE_STORE
-                    ).annotate(getattr(coordinator, "last_query_id", None),
-                               retries=_attempt)
-                    self.last_retry_count = _attempt  # observability (sweeps)
-                    return out, scope.request if traced else None
-                except RuntimeError as e:
-                    if isinstance(e, OverflowRetryAbandoned):
-                        raise
-                    if "overflow" not in str(e):
-                        raise
-                    last_err = e
-                    # the retry's trace joins the request of this one
-                    self.request_id = scope.request
-                    pcfg, dcfg = _widen_for_overflow(
-                        pcfg, dcfg, e,
-                        force_all=_attempt
-                        >= self.ctx.config.overflow_retries - 1,
+
+        def attempt(n, pcfg, dcfg, guard):
+            if adaptive_coord and n:
+                # widen-and-pin for the retry (see
+                # AdaptiveCoordinator.pin_overflow_headroom: subquery
+                # successes through the same coordinator must not reset
+                # the widened headroom mid-attempt)
+                coordinator.pin_overflow_headroom(n)
+            # an attempt here is one `Coordinator.execute`, a trace of
+            # its own: its root carries the request and the attempt, and
+            # the one that succeeds the retries
+            scope = tracing.request_scope(self.request_id, attempt=n)
+            try:
+                with scope:
+                    plan = self.distributed_plan(
+                        num_tasks, dcfg, pcfg, coordinator=coordinator
                     )
-            raise last_err  # type: ignore[misc]
+                    guard(plan)
+                    out = coordinator.execute(plan)
+            finally:
+                # a retry's trace joins the request of this one
+                self.request_id = scope.request
+            traced = (
+                getattr(coordinator, "trace_store", None)
+                or tracing.DEFAULT_TRACE_STORE
+            ).annotate(getattr(coordinator, "last_query_id", None),
+                       retries=n)
+            return out, scope.request if traced else None
+
+        try:
+            return self._retry_on_overflow(
+                self.ctx.config.planner, self._seeded_host_config(num_tasks),
+                attempt,
+            )
         finally:
             if adaptive_coord:
                 coordinator.release_overflow_headroom()

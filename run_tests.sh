@@ -155,18 +155,6 @@ if ! python -m pytest tests/test_telemetry.py -q --no-header \
         -p no:cacheprovider "${MARKER_ARGS[@]}" "$@"; then
     FAILED+=("tests/test_telemetry.py[gate]")
 fi
-# bench-compare smoke (tools/bench_compare.py): the bench trajectory
-# diff tool must at minimum hold a file equal to itself regression-free
-# (sub-second; BENCH_DETAIL.json ships with the repo). Real use diffs
-# two runs: python tools/bench_compare.py BENCH_old.json BENCH_new.json
-if [ -f BENCH_DETAIL.json ]; then
-    echo "=== tools/bench_compare.py (self-diff smoke)"
-    if ! python tools/bench_compare.py BENCH_DETAIL.json \
-            BENCH_DETAIL.json >/dev/null; then
-        echo "BENCH COMPARE FAILED: self-diff reported a regression"
-        FAILED+=("tools/bench_compare.py[smoke]")
-    fi
-fi
 # Tracing gate (tests/test_tracing.py): the distributed-tracing
 # subsystem — span-tree shape for distributed TPC-H (worker spans joined
 # via cross-wire context propagation, in-process AND gRPC), retry/heal/
@@ -195,8 +183,7 @@ fi
 # (partition drop + query-end sweep, incl. under chaos retries), TPC-H
 # q5/q9 byte-identical between the view and copying planes, a peak-
 # staged-bytes bound under the chaos retry schedule, and the >= 2x
-# view-vs-copy chunk-plane rate bound (the micro_bench data_plane case's
-# acceptance number).
+# view-vs-copy chunk-plane rate bound.
 # Runs under DFTPU_LOCK_CHECK=1: the 8-thread churn run exercises the
 # TableStore/TaskRegistry lock pairs the static graph predicts.
 echo "=== tests/test_data_plane.py (zero-copy data-plane gate, DFTPU_LOCK_CHECK=1 DFTPU_LEAK_CHECK=strict)"

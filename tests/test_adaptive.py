@@ -306,13 +306,16 @@ def test_targeted_overflow_widening():
     from datafusion_distributed_tpu.planner.distributed import (
         DistributedConfig,
     )
+    from datafusion_distributed_tpu.runtime.errors import (
+        CapacityOverflowError,
+    )
     from datafusion_distributed_tpu.sql.context import _widen_for_overflow
     from datafusion_distributed_tpu.sql.planner import PlannerConfig
 
     p = PlannerConfig()
     d = DistributedConfig(num_tasks=4)
 
-    agg = RuntimeError(
+    agg = CapacityOverflowError(
         "hash table overflow in plan (nodes: ['HashAggregate']); "
         "re-plan with more slots"
     )
@@ -321,7 +324,7 @@ def test_targeted_overflow_widening():
     assert p2.join_expansion_factor == p.join_expansion_factor
     assert d2.shuffle_skew_factor == d.shuffle_skew_factor
 
-    js = RuntimeError(
+    js = CapacityOverflowError(
         "exchange/hash capacity overflow on mesh (nodes: "
         "['HashJoin', 'ShuffleExchange']); re-plan with more slots"
     )
@@ -331,7 +334,7 @@ def test_targeted_overflow_widening():
     assert d3.shuffle_skew_factor == d.shuffle_skew_factor * 4
 
     # no parseable node list -> the pre-targeting widen-everything behavior
-    bare = RuntimeError("hash table overflow somewhere")
+    bare = CapacityOverflowError("hash table overflow somewhere")
     p4, d4 = _widen_for_overflow(p, d, bare)
     assert p4.agg_slot_factor == p.agg_slot_factor * 4
     assert p4.join_expansion_factor == p.join_expansion_factor * 4
@@ -339,7 +342,7 @@ def test_targeted_overflow_widening():
 
     # parsed list with NO recognized label (future node class): must widen
     # everything, not nothing — else every retry re-runs the same plan
-    odd = RuntimeError(
+    odd = CapacityOverflowError(
         "hash table overflow in plan (nodes: ['TopK']); re-plan"
     )
     p5, d5 = _widen_for_overflow(p, d, odd)
@@ -349,7 +352,7 @@ def test_targeted_overflow_widening():
 
     # single-process collect has no distributed config: a shuffle-only
     # list must still widen the planner factors, not no-op every retry
-    shuf_only = RuntimeError(
+    shuf_only = CapacityOverflowError(
         "hash table overflow in plan (nodes: ['ShuffleExchange']); re-plan"
     )
     p6, d6 = _widen_for_overflow(p, None, shuf_only)

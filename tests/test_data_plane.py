@@ -22,8 +22,7 @@ Contracts pinned here:
 - Peak staged bytes under the chaos retry schedule do not regress vs the
   copying plane.
 - Rate: the view chunk-plane (host slice + reassembly) beats the copying
-  chunk-plane by >= 2x on a 1M-row stream (the acceptance bound the
-  micro_bench `data_plane` case reports).
+  chunk-plane by >= 2x on a 1M-row stream.
 """
 
 import os

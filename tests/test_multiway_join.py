@@ -2,17 +2,12 @@
 
 Gate for `SET distributed.multiway_join` / `SET distributed.global_hash_agg`
 (planner/distributed._multiway_fusion_pass / _inject_global_agg,
-plan/joins.MultiwayHashJoinExec, ops/pallas_hash.pallas_multiway_probe /
-pallas_global_hash_aggregate):
+plan/joins.MultiwayHashJoinExec):
 
 - fusion-pass units: the broadcast same-stage link (case A), the
   identity-re-shuffle link (case B, deletes the interior exchanges),
   the no-fusion conditions, and the knob's default-off
-- kernel parity in interpret mode vs the XLA claim-loop oracle
-  (ops/join.probe_group_table) and the sequential-insert reference
-  (global_hash_aggregate_reference)
-- MultiwayHashJoinExec byte-identity vs the binary chain it fused, on
-  BOTH the reference chain path and the cascaded kernel path
+- MultiwayHashJoinExec byte-identity vs the binary chain it fused
 - TPC-H e2e byte identity fused-vs-unfused through the coordinator:
   q5/q9 under the default broadcast config (case A) and q21 co-shuffled
   (case B, `dftpu_exchanges_deleted` >= 2), under seeded chaos and
@@ -24,25 +19,17 @@ pallas_global_hash_aggregate):
   build rows over the captured table sizing swap the fused stage back to
   its rederived binary chain; padded (non-measured) capacities never bail
 - zero new XLA traces when a fused query is resubmitted
-- static-verifier arms: DFTPU011/012 (multiway step schema), DFTPU023/025
+- static-verifier arms: DFTPU011/012 (multiway step schema), DFTPU023
   (capacity), DFTPU034 (mixed co-shuffle widths)
 """
 
 import os
 
-import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pytest
 
 from datafusion_distributed_tpu.io.parquet import arrow_to_table
-from datafusion_distributed_tpu.ops import pallas_hash
-from datafusion_distributed_tpu.ops.hash import hash_columns
-from datafusion_distributed_tpu.ops.join import (
-    _fold_keys,
-    build_join_table,
-    probe_group_table,
-)
 from datafusion_distributed_tpu.plan.exchanges import ShuffleExchangeExec
 from datafusion_distributed_tpu.plan.joins import (
     HashJoinExec,
@@ -200,90 +187,6 @@ def test_fusion_stops_on_rekeying_shuffle():
 
 
 # ---------------------------------------------------------------------------
-# kernel parity (interpret mode) vs the XLA claim-loop oracle
-# ---------------------------------------------------------------------------
-
-
-def test_multiway_probe_kernel_matches_claim_loop_oracle():
-    """One cascaded grid pass == K independent probe_group_table walks,
-    including dup build keys, absent probe keys, and dead probe rows."""
-    rng = np.random.default_rng(5)
-    n = 500
-    probe_t = arrow_to_table(pa.table({
-        "k": rng.integers(0, 1024, n), "pv": np.arange(n),
-    }))
-    col = probe_t.column("k").data
-    live = probe_t.row_mask() & jnp.asarray(
-        rng.random(probe_t.capacity) > 0.1
-    )
-
-    sides = []
-    for nb, slots, key_range in ((100, 256, 64), (200, 512, 2048),
-                                 (60, 128, 16)):
-        bt = arrow_to_table(pa.table({
-            "k": rng.integers(0, key_range, nb), "bv": np.arange(nb),
-        }))
-        sides.append(build_join_table(bt, ["k"], slots))
-
-    keys_l, slot0_l, act_l, tk_l, used_l, expected = [], [], [], [], [], []
-    lmax = max(bs.raw_slot_keys.shape[1] for bs in sides)
-    for bs in sides:
-        g, over = probe_group_table(
-            bs.raw_slot_keys, bs.slot_used, [col], [None], live,
-            bs.lane_plan,
-        )
-        expected.append((np.asarray(g), bool(over)))
-        km = _fold_keys([col], [None], bs.lane_plan).astype(jnp.int32)
-        hk = bs.slot_used.shape[0]
-        h0 = hash_columns([col], [None])
-        keys_l.append(jnp.pad(km, ((0, 0), (0, lmax - km.shape[1]))))
-        slot0_l.append((h0 & np.uint32(hk - 1)).astype(jnp.int32))
-        act_l.append(live)
-        tk = bs.raw_slot_keys.astype(jnp.int32)
-        tk_l.append(jnp.pad(tk, ((0, 0), (0, lmax - tk.shape[1]))))
-        used_l.append(bs.slot_used.astype(jnp.int32))
-
-    found, over = pallas_hash.pallas_multiway_probe(
-        jnp.stack(keys_l, axis=1), jnp.stack(slot0_l, axis=1),
-        jnp.stack(act_l, axis=1), jnp.concatenate(tk_l, axis=0),
-        jnp.concatenate(used_l, axis=0),
-        tuple(bs.slot_used.shape[0] for bs in sides),
-        interpret=True,
-    )
-    for k, (eg, eo) in enumerate(expected):
-        np.testing.assert_array_equal(np.asarray(found[:, k]), eg,
-                                      err_msg=f"table {k} slots diverged")
-        assert bool(over[k]) == eo, f"table {k} overflow flag diverged"
-
-
-def test_global_hash_aggregate_kernel_matches_reference():
-    rng = np.random.default_rng(9)
-    n, slots = 1024, 512
-    keys = jnp.asarray(rng.integers(0, 200, n).astype(np.int32))
-    live = jnp.asarray(rng.random(n) > 0.1)
-    vals = jnp.stack([
-        jnp.asarray(rng.integers(0, 100, n).astype(np.int32)),
-        jnp.asarray(rng.integers(-50, 50, n).astype(np.int32)),
-        jnp.asarray(rng.integers(-50, 50, n).astype(np.int32)),
-    ], axis=1)
-    km = keys[:, None]
-    h0 = hash_columns([keys], [None])
-    slot0 = (h0 & np.uint32(slots - 1)).astype(jnp.int32)
-    ops = ("sum", "min", "max")
-
-    got = pallas_hash.pallas_global_hash_aggregate(
-        km, slot0, live, vals, slots, ops, interpret=True
-    )
-    ref = pallas_hash.global_hash_aggregate_reference(
-        km, slot0, live, vals, slots, ops
-    )
-    for name, g, r in zip(("gid", "rep", "used", "acc", "overflow"),
-                          got, ref):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r),
-                                      err_msg=f"{name} diverged")
-
-
-# ---------------------------------------------------------------------------
 # MultiwayHashJoinExec byte-identity vs its binary chain
 # ---------------------------------------------------------------------------
 
@@ -330,16 +233,6 @@ def _assert_tables_identical(got, base):
 def test_multiway_exec_reference_chain_byte_identical():
     rng = np.random.default_rng(3)
     chain, mw, leaves = _mk_mw_fixture(rng)
-    assert not mw.cascade_eligible()  # DFTPU_PALLAS unset here
-    _assert_tables_identical(_exec_node(mw, leaves),
-                             _exec_node(chain, leaves))
-
-
-def test_multiway_exec_cascade_byte_identical(monkeypatch):
-    monkeypatch.setenv("DFTPU_PALLAS", "1")
-    rng = np.random.default_rng(4)
-    chain, mw, leaves = _mk_mw_fixture(rng)
-    assert mw.cascade_eligible(), "fixture must take the kernel path"
     _assert_tables_identical(_exec_node(mw, leaves),
                              _exec_node(chain, leaves))
 
@@ -407,12 +300,6 @@ def test_tpch_fused_byte_identity_chaos_matrix(qname):
     _assert_no_leaks(cluster)
 
 
-@pytest.mark.slow
-def test_tpch_fused_byte_identity_pallas_kernels(monkeypatch):
-    monkeypatch.setenv("DFTPU_PALLAS", "1")
-    _fused_vs_unfused("q5", _E2E["q5"])
-
-
 def test_fused_resubmission_zero_new_traces():
     """Resubmitting an identical fused query through the same cluster
     performs ZERO new XLA compiles (the fused stage's fingerprint is
@@ -456,14 +343,6 @@ def test_global_hash_agg_exact_vs_merge():
         "high-NDV aggregate never took the global-hash shape"
     )
     _assert_frames_identical(_sorted(got), _sorted(base), "global-agg")
-
-
-def test_global_hash_agg_exact_vs_merge_pallas(monkeypatch):
-    monkeypatch.setenv("DFTPU_PALLAS", "1")
-    base, _ = _run(_mkctx(), _GA_SQL, InMemoryCluster(4))
-    got, _ = _run(_mkctx(global_hash_agg=True), _GA_SQL, InMemoryCluster(4))
-    _assert_frames_identical(_sorted(got), _sorted(base),
-                             "global-agg-pallas")
 
 
 def test_global_agg_not_selected_on_low_ndv():
@@ -596,16 +475,6 @@ def test_verifier_multiway_slots_below_build_bound_DFTPU023():
     r = verify_physical_plan(small)
     assert "DFTPU023" in r.codes()
     assert r.ok  # warning only: the claim loop retries, never corrupts
-
-
-def test_verifier_multiway_partition_cap_DFTPU025():
-    rng = np.random.default_rng(8)
-    _, mw, _ = _mk_mw_fixture(rng)
-    huge = MultiwayHashJoinExec(mw.probe, mw.builds,
-                                _shrunk_steps(mw.steps, num_slots=1 << 21))
-    r = verify_physical_plan(huge)
-    assert "DFTPU025" in r.codes()
-    assert r.ok  # warning: the stage degrades to the reference chain
 
 
 def test_verifier_multiway_mixed_shuffle_widths_DFTPU034():

@@ -303,11 +303,10 @@ def _lowered(plan) -> str:
     """The plan's lowered program, its ops' scopes in the locations."""
     from datafusion_distributed_tpu.spans import NULL_TRACER
 
-    prepared = phys._prepare_program(
+    prog = phys._prepare_program(
         plan, DistributedTaskContext(), None, False, None, None, NULL_TRACER
     )
-    fn, inputs, params = prepared[0], prepared[-3], prepared[-2]
-    text = fn.lower(inputs, params).as_text(debug_info=True)
+    text = prog.fn.lower(prog.inputs, prog.params).as_text(debug_info=True)
     assert "HashAggregateExec" in text or "PartialPassthroughExec" in text
     return text
 

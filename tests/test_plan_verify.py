@@ -53,6 +53,7 @@ from datafusion_distributed_tpu.plan.verify import (
     resolve_verify_mode,
     verify_physical_plan,
 )
+from datafusion_distributed_tpu.runtime.errors import is_capacity_overflow
 from datafusion_distributed_tpu.schema import DataType
 from datafusion_distributed_tpu.sql.context import SessionContext, VerifyReport
 
@@ -263,10 +264,38 @@ def test_enforce_modes():
     with pytest.raises(PlanVerificationError) as ei:
         enforce_verification(bad, mode="strict")
     assert "DFTPU011" in str(ei.value)
-    assert "overflow" not in str(ei.value)  # must not trip the retry loops
+    assert not is_capacity_overflow(ei.value)  # nothing retries it
     with pytest.warns(RuntimeWarning, match="DFTPU011"):
         enforce_verification(bad, mode="warn")
     assert enforce_verification(bad, mode="off") is None
+
+
+def test_strict_error_beside_a_capacity_warning_is_not_retried(monkeypatch):
+    """A report that holds an error beside DFTPU021 (whose text says the
+    table "will overflow") is raised on the first attempt: the re-plan
+    loop acts on the error's type, and this is no capacity overflow."""
+    from datafusion_distributed_tpu.sql.planner import PhysicalPlanner
+
+    agg = HashAggregateExec(
+        "single", ["a"], [AggSpec("count_star", None, "c")], _scan(),
+        num_slots=4,
+    )
+    agg.est_rows = 1000.0
+    bad = SortExec([SortKey("zzz", True, False)], agg)
+    ctx = SessionContext()
+    ctx.register_arrow("t", pa.table({"a": np.arange(8)}))
+    ctx.sql("SET distributed.verify_plans = strict")
+    df = ctx.sql("select a from t")
+    planned = []
+    monkeypatch.setattr(
+        PhysicalPlanner, "plan",
+        lambda self, logical: (planned.append(1), bad)[1],
+    )
+    with pytest.raises(PlanVerificationError) as ei:
+        df.collect_table()
+    assert {"DFTPU011", "DFTPU021"} <= set(ei.value.result.codes())
+    assert "overflow" in str(ei.value)
+    assert planned == [1]
 
 
 def test_coordinator_rejects_malformed_plan_before_dispatch():

@@ -81,9 +81,8 @@ def test_int_narrowing_overflow_is_loud():
 
 @pytest.mark.skipif(precision.MODE != "tpu", reason="tpu mode only")
 def test_int32_sum_range_exceeded_is_loud_and_not_retried():
-    """Integer SUM past 2^31 in tpu mode raises a non-retryable error (the
-    message must NOT contain 'overflow', which the session's capacity-retry
-    loop matches on)."""
+    """Integer SUM past 2^31 in tpu mode raises `PrecisionRangeError`,
+    which is no capacity overflow: nothing retries it."""
     from datafusion_distributed_tpu.plan.physical import (
         HashAggregateExec, MemoryScanExec, execute_plan,
     )
@@ -104,9 +103,14 @@ def test_int32_sum_range_exceeded_is_loud_and_not_retried():
         "single", ["k"], [AggSpec("sum", "v", "sv")],
         MemoryScanExec([t], schema), num_slots=8,
     )
-    with pytest.raises(RuntimeError) as e:
+    from datafusion_distributed_tpu.runtime.errors import (
+        PrecisionRangeError,
+        is_capacity_overflow,
+    )
+
+    with pytest.raises(PrecisionRangeError) as e:
         execute_plan(plan, use_cache=False)
-    assert "overflow" not in str(e.value)
+    assert not is_capacity_overflow(e.value)
     assert "DFTPU_PRECISION=x64" in str(e.value)
 
 

@@ -166,7 +166,7 @@ def _assert_frames_identical(got, base, label=""):
 def _delay_cluster(workers=4, delay_s=0.05, seed=CHAOS_SEED):
     """In-memory cluster with a uniform injected execute delay — the
     stand-in for device/DCN latency that makes scheduling effects
-    observable on a small box (micro_bench stage_overlap precedent)."""
+    observable on a small box."""
     return wrap_cluster(InMemoryCluster(workers), FaultPlan(seed, [
         FaultSpec(site="execute", kind="delay", delay_s=delay_s, rate=1.0),
     ], query_scoped=True))
@@ -610,9 +610,8 @@ def test_serving_overlap_beats_serialized(tpch_ctx):
     clients against the shared pool finish a fixed workload faster than
     the same workload serialized (max_concurrent_queries=1), because
     stages of DIFFERENT queries overlap across the cluster. A uniform
-    injected execute delay stands in for device/DCN latency (the
-    micro_bench stage_overlap precedent); both arms pay it identically
-    per task."""
+    injected execute delay stands in for device/DCN latency; both arms
+    pay it identically per task."""
     workload = [TPCH_Q6, TPCH_Q1, TPCH_Q6, TPCH_Q1]
 
     def run(max_conc):

@@ -34,11 +34,6 @@ from jax.sharding import SingleDeviceSharding
 
 from datafusion_distributed_tpu.ops.aggregate import AggSpec, hash_aggregate
 from datafusion_distributed_tpu.ops.join import build_join_table, hash_join
-from datafusion_distributed_tpu.ops.pallas_hash import (
-    pallas_build_group_ids,
-    pallas_global_hash_aggregate,
-    pallas_multiway_probe,
-)
 from datafusion_distributed_tpu.ops.sort import SortKey, sort_table
 from datafusion_distributed_tpu.ops.table import Column, Table
 from datafusion_distributed_tpu.parallel.exchange import shuffle_exchange
@@ -229,59 +224,3 @@ def test_hash_shuffle_compiles_on_four_chips(mesh4):
                         out_specs=P(AXIS), check_rep=False)
     compiled = _compile(program, stacked, scopes=("exchange.shuffle",))
     assert "all-to-all" in compiled.as_text()
-
-
-# -- the Pallas kernels: refused by the chip's compiler today ---------------
-# They have only ever run interpreted (the backend never had the name
-# "tpu"), and are off by default (DFTPU_PALLAS). Strict xfails: the PR that
-# repairs a kernel is forced to take its mark off.
-
-REFUSAL = "Cannot store scalars to VMEM"
-REFUSED = pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason=f"v5e compiler: 'ValueError: {REFUSAL}'",
-)
-N, SLOTS = MI, 1 << 16
-
-
-def _compile_pallas(kernel, *shapes, **static):
-    try:
-        kernel.lower(*shapes, interpret=False, **static).compile()
-    except ValueError as e:
-        # any other ValueError (a wrong shape, a new refusal) must FAIL,
-        # not hide behind the mark: AssertionError is not what it expects
-        assert REFUSAL in str(e), e
-        raise
-
-
-@REFUSED
-def test_pallas_build_group_ids_compiles(one_chip):
-    _compile_pallas(
-        pallas_build_group_ids,
-        _shape(one_chip, jnp.int32, N, 2), _shape(one_chip, jnp.int32, N),
-        _shape(one_chip, jnp.bool_, N), num_slots=SLOTS,
-    )
-
-
-@REFUSED
-def test_pallas_global_hash_aggregate_compiles(one_chip):
-    _compile_pallas(
-        pallas_global_hash_aggregate,
-        _shape(one_chip, jnp.int32, N, 2), _shape(one_chip, jnp.int32, N),
-        _shape(one_chip, jnp.bool_, N), _shape(one_chip, jnp.int32, N, 2),
-        num_slots=SLOTS, ops=("sum", "max"),
-    )
-
-
-@REFUSED
-def test_pallas_multiway_probe_compiles(one_chip):
-    tables = (SLOTS, SLOTS)
-    _compile_pallas(
-        pallas_multiway_probe,
-        _shape(one_chip, jnp.int32, N, 2, 2),
-        _shape(one_chip, jnp.int32, N, 2),
-        _shape(one_chip, jnp.int32, N, 2),
-        _shape(one_chip, jnp.int32, sum(tables), 2),
-        _shape(one_chip, jnp.int32, sum(tables)),
-        table_slots=tables,
-    )

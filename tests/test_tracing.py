@@ -992,12 +992,14 @@ def test_scopes_name_the_kernels_and_change_metadata_only(tpch_ctx,
 
     def programs(sql):
         plan = tpch_ctx.sql(sql).physical_plan()
-        fn, _, _, _, _, inputs, params, _ = phys._prepare_program(
+        prog = phys._prepare_program(
             plan, phys.DistributedTaskContext(), None, False, None, None,
             NULL_TRACER,
         )
-        lowered = fn.lower(inputs, params)
-        out = jax.tree_util.tree_leaves(fn(inputs, params)[0])
+        lowered = prog.fn.lower(prog.inputs, prog.params)
+        out = jax.tree_util.tree_leaves(
+            prog.fn(prog.inputs, prog.params)[0]
+        )
         return (lowered.as_text(debug_info=True),
                 lowered.compile().as_text(),
                 [np.asarray(x).tobytes() for x in out])

@@ -108,7 +108,7 @@ TRACE_SEED_NAMES = {
     "sort_table", "limit_table", "window_compute", "shuffle_exchange",
     "range_shuffle_exchange", "coalesce_exchange", "broadcast_exchange",
     "group_coalesce_exchange", "expr_to_column", "concat_tables",
-    "hash_columns", "pallas_multiway_probe", "pallas_global_hash_aggregate",
+    "hash_columns",
 }
 #: directories (package-relative) whose TRACE_METHOD_NAMES methods trace
 TRACE_DIRS = ("ops", "plan", "parallel")
