@@ -14,9 +14,15 @@ group table with *vectorized claim rounds* instead of per-row probing:
   converges in a handful of rounds (cf. "Global Hash Tables Strike Back!",
   PAPERS.md).
 
-Aggregates then reduce by slot id with `segment_sum` / scatter-min/max, which
-XLA lowers to deterministic TPU scatters — giving run-to-run identical float
-results (the bit-parity requirement of SURVEY.md §7 hard part (d)).
+Aggregates then reduce by slot id (`_reduce_by_slot`). Into a table of
+unknown keys that is a scatter-add / scatter-min / scatter-max: N updates
+the TPU serializes (67.6 ms for 8Mi rows, whatever the width), deterministic,
+so float results are identical run to run (the bit-parity requirement of
+SURVEY.md §7 hard part (d)). Over a domain that is small and known when the
+program is traced (below) it is a dense masked pass instead: for each slot
+``s``, the sum / min / max of ``where(id == s, vals, identity)``, which XLA
+fuses into one loop over the rows for all the aggregates of an operator and
+reduces in a fixed order, as deterministic as the scatter.
 
 Group keys may be any fixed-width device dtype (dict codes included); nulls
 group together (SQL semantics), tracked via a folded-in validity lane.
@@ -25,7 +31,12 @@ Where every group key is dictionary-coded and the keys' whole domain fits
 the planned table (`_dictionary_bases`: q1's 3 x 2 codes, 4 x 3 with their
 NULLs, in 2048 slots), no table is built at all: a row's group id is the mixed-radix number of its
 codes (`direct_group_table`), one fused elementwise pass in place of the
-claim loop, and the slots, the reductions and the pack stay as they are.
+claim loop. Up to `_DENSE_MAX_DOMAIN` slots every reduction is then
+a dense pass over that domain, padded to the planned ``[num_slots]`` width,
+so the pack and the partial / final state schema keep their shapes; beyond
+it, and for every table the claim loop builds, they stay scatters.
+`global_aggregate` is the domain of one: plain masked reductions into slot
+0, no scatter at all.
 """
 
 from __future__ import annotations
@@ -240,13 +251,27 @@ def _dictionary_bases(key_columns: Sequence[Column],
     return bases if 0 < math.prod(bases) <= num_slots else None
 
 
-#: `direct_group_table` finds the used slots by comparing every row's id
-#: with each slot of the domain (N x D compares, fused into one pass) up to
-#: this domain, and by one more scatter (N serialized updates) beyond it. On
-#: the v5e over 8Mi rows the compares cost 0.64 ms at a domain of 12, 1.5 at
-#: 128, 12.2 at 2048 (6 us a slot) and the scatter 53.7 ms at any width: they
-#: cross near 9,000, wherever the rows stand (my chip run, PERF.md, PR 30).
-_PRESENCE_BY_COMPARE_MAX_DOMAIN = 8192
+#: Up to this domain a direct grouping touches no scatter: `_reduce_by_slot`
+#: reduces by dense masked passes and `direct_group_table` finds the used
+#: slots by comparing every row's id with each slot (N x D compares, fused
+#: into one pass); beyond it each is a scatter, N serialized updates. On the
+#: v5e (my chip run, PERF.md, PR 32), ms for 8Mi rows (2Mi in brackets), one
+#: f32 sum and ten that share a pass, an int32 count and an f32 min reading
+#: as the one sum does to 0.1 ms:
+#:   domain      12     128    1024    2048    4096    8192
+#:   dense 1    0.68    1.40    6.49   13.21   36.57  132.85
+#:             (0.60    0.83    2.09    3.80    9.66   33.80)
+#:   dense 10   2.29    9.90   61.99  121.97  241.36  481.22
+#:             (1.50    3.52   16.65   31.61   61.50  121.36)
+#:   scatter 1  67.3 up to 1024, 56.7 from 2048 on (17.4, 14.7)
+#:   scatter 10 664.8 at 12, 559.9 at 2048 (167.5, 141.2)
+#: One sum crosses its scatter near 5,000 slots, ten near 9,500, at either
+#: height: 4096 is the largest power of two at which no width loses (at
+#: 8192 one sum would cost 2.3 scatters). The presence compares (PR 30: 0.64
+#: at 12, 1.5 at 128, 12.2 at 2048, 6 us a slot, against a scatter's 53.7)
+#: cross near 9,000; between the two crossings they would save some 5 ms
+#: beside reductions of 57 each, so the one cut serves both.
+_DENSE_MAX_DOMAIN = 4096
 
 
 @scoped("agg.direct")
@@ -258,8 +283,9 @@ def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
     (``bases`` from `_dictionary_bases`). Slot ``s`` of the domain holds the
     keys ``(s // radix_i) % base_i``; a slot is used if some live row has
     its id. The arrays keep the planned ``[num_slots]`` width, so what
-    reduces and packs by slot sees the claim loop's shapes; groups come out
-    in radix order."""
+    packs by slot sees the claim loop's shapes (the reductions read the
+    domain off the same rule: `hash_aggregate`, `_reduce_by_slot`); groups
+    come out in radix order."""
     domain = math.prod(bases)
     radices = [math.prod(bases[i + 1:]) for i in range(len(bases))]
     gid = jnp.zeros(live.shape, dtype=jnp.int32)
@@ -269,7 +295,7 @@ def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
             digit = jnp.where(col.validity, digit, len(col.dictionary))
         gid = gid + digit * np.int32(radix)
     gid = jnp.where(live, gid, num_slots)  # dead rows use no slot
-    if domain <= _PRESENCE_BY_COMPARE_MAX_DOMAIN:
+    if domain <= _DENSE_MAX_DOMAIN:
         present = jnp.any(
             jnp.arange(domain, dtype=jnp.int32)[:, None] == gid[None, :],
             axis=1,
@@ -301,6 +327,37 @@ def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
     )
 
 
+# op -> (the dense reduction, the scatter's method)
+_SLOT_REDUCTIONS = {"sum": (jnp.sum, "add"), "min": (jnp.min, "min"),
+                    "max": (jnp.max, "max")}
+
+
+def _reduce_by_slot(op: str, ids, vals, num_slots: int,
+                    dense_domain: Optional[int]):
+    """``op`` ("sum" | "min" | "max") of ``vals`` over the rows of each slot
+    id -> ``[num_slots]`` in ``vals``' dtype; a slot no row names holds the
+    op's identity, and a row whose id is ``num_slots`` (dead, NULL) names
+    none. ``dense_domain`` None: one scatter, N serialized updates on the
+    TPU. Else every id lies in ``[0, dense_domain)`` (or is ``num_slots``)
+    and each slot of the domain reduces ``where(id == slot, vals,
+    identity)``: the compiler fuses the compare and the select into the
+    reduction, and sibling reductions over the same ids into one pass, so
+    no ``[domain, N]`` operand exists. A NaN stays in its own slot (it is
+    masked, not multiplied), integers stay integers."""
+    reduce, scatter = _SLOT_REDUCTIONS[op]
+    identity = 0 if op == "sum" else (
+        _dtype_max if op == "min" else _dtype_min)(vals.dtype)
+    if dense_domain is None:
+        init = jnp.full(num_slots, identity, vals.dtype)
+        return getattr(init.at[ids], scatter)(vals, mode="drop")
+    slots = jnp.arange(dense_domain, dtype=jnp.int32)
+    kept = jnp.where(ids[None, :] == slots[:, None], vals[None, :],
+                     jnp.asarray(identity, vals.dtype))
+    out = reduce(kept, axis=1).astype(vals.dtype)
+    return jnp.pad(out, (0, num_slots - dense_domain),
+                   constant_values=identity)
+
+
 def hash_aggregate(
     table: Table,
     group_names: Sequence[str],
@@ -324,7 +381,9 @@ def hash_aggregate(
 
     ``direct``, when given, collects the domain's size if the groups were
     addressed directly by their dictionary codes (`_dictionary_bases`) and
-    no group table was built: the executor's ``direct_groupings``.
+    no group table was built: the executor's ``direct_groupings``. A domain
+    of at most `_DENSE_MAX_DOMAIN` also reduced by dense masked passes and
+    not by scatters (`_reduce_by_slot`): its ``dense_aggregates``.
 
     Modes mirror DataFusion's AggregateMode as used by the reference planner:
       partial        -> emits sum/count/min/max accumulator columns per agg
@@ -358,14 +417,13 @@ def hash_aggregate(
         src = table.column(g)
         out_cols[g] = Column(keys, kv, src.dtype, src.dictionary)
 
-    def seg_sum(vals, dtype=None):
-        z = jnp.zeros(num_slots, dtype=dtype or vals.dtype)
-        return z.at[gid].add(vals, mode="drop")
-
+    dense_domain = None
+    if bases is not None and math.prod(bases) <= _DENSE_MAX_DOMAIN:
+        dense_domain = math.prod(bases)
     for spec in aggs:
         out_cols.update(
-            _eval_agg(spec, table, gid, live, num_slots, mode, seg_sum,
-                      prec_flags)
+            _eval_agg(spec, table, gid, live, num_slots, mode, prec_flags,
+                      dense_domain)
         )
 
     # Pack used slots to the front — into a TIGHTER capacity when the
@@ -398,15 +456,10 @@ def global_aggregate(table: Table, aggs: Sequence[AggSpec], mode: str = "single"
         live = table.row_mask()
     cap = 8
     gid = jnp.zeros(table.capacity, dtype=jnp.int32)
-
-    def seg_sum(vals, dtype=None):
-        z = jnp.zeros(cap, dtype=dtype or vals.dtype)
-        return z.at[gid].add(vals, mode="drop")
-
     cols: dict[str, Column] = {}
     for spec in aggs:
-        cols.update(_eval_agg(spec, table, gid, live, cap, mode, seg_sum,
-                              prec_flags))
+        cols.update(_eval_agg(spec, table, gid, live, cap, mode, prec_flags,
+                              dense_domain=1))
     return Table(tuple(cols.keys()), tuple(cols.values()),
                  jnp.asarray(1, dtype=jnp.int32))
 
@@ -438,17 +491,22 @@ def _mean_shifted_seg_sum(vals, valid, seg_sum, group_counts):
     return z + m * group_counts.astype(vals.dtype)
 
 
-def _eval_agg(spec, table, gid, live, num_slots, mode, seg_sum,
-              prec_flags=None):
+def _eval_agg(spec, table, gid, live, num_slots, mode, prec_flags,
+              dense_domain):
     """Produce the output column(s) for one AggSpec in the given mode,
-    under the scope ``agg.reduce.<func>``."""
+    under the scope ``agg.reduce.<func>``; every reduction by slot goes
+    through `_reduce_by_slot` with ``dense_domain``."""
+    def seg_sum(vals, dtype=None):
+        return _reduce_by_slot("sum", gid, vals.astype(dtype or vals.dtype),
+                               num_slots, dense_domain)
+
     with jax.named_scope(f"agg.reduce.{spec.func}"):
         return _eval_agg_columns(spec, table, gid, live, num_slots, mode,
-                                 seg_sum, prec_flags)
+                                 seg_sum, prec_flags, dense_domain)
 
 
 def _eval_agg_columns(spec, table, gid, live, num_slots, mode, seg_sum,
-                      prec_flags):
+                      prec_flags, dense_domain):
     name = spec.output_name
     if spec.func == "count_star":
         if mode in ("final", "partial_reduce"):
@@ -472,15 +530,10 @@ def _eval_agg_columns(spec, table, gid, live, num_slots, mode, seg_sum,
             merged = seg_sum(vals)
             if spec.func == "sum":
                 _check_int32_sum_range(vals, seg_sum, prec_flags)
-        elif spec.func == "min":
-            init = jnp.full(num_slots, _dtype_max(acc.data.dtype), acc.data.dtype)
-            merged = init.at[jnp.where(valid, gid, num_slots)].min(
-                acc.data, mode="drop"
-            )
         else:
-            init = jnp.full(num_slots, _dtype_min(acc.data.dtype), acc.data.dtype)
-            merged = init.at[jnp.where(valid, gid, num_slots)].max(
-                acc.data, mode="drop"
+            merged = _reduce_by_slot(
+                spec.func, jnp.where(valid, gid, num_slots), acc.data,
+                num_slots, dense_domain,
             )
         nonempty = seg_sum(jnp.where(valid, 1, 0).astype(_ACC_INT))
         if spec.func == "count":
@@ -577,12 +630,8 @@ def _eval_agg_columns(spec, table, gid, live, num_slots, mode, seg_sum,
         return {name: Column(avg, cnt > 0, DataType.FLOAT64)}
 
     if spec.func in ("min", "max"):
-        if spec.func == "min":
-            init = jnp.full(num_slots, _dtype_max(col.data.dtype), col.data.dtype)
-            red = init.at[vgid].min(col.data, mode="drop")
-        else:
-            init = jnp.full(num_slots, _dtype_min(col.data.dtype), col.data.dtype)
-            red = init.at[vgid].max(col.data, mode="drop")
+        red = _reduce_by_slot(spec.func, vgid, col.data, num_slots,
+                              dense_domain)
         nonempty = seg_sum(jnp.where(valid, 1, 0).astype(_ACC_INT))
         return {
             name: Column(red, nonempty > 0, col.dtype, col.dictionary)

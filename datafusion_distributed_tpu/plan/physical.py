@@ -34,7 +34,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from datafusion_distributed_tpu import spans
-from datafusion_distributed_tpu.ops.aggregate import AggSpec, hash_aggregate
+from datafusion_distributed_tpu.ops.aggregate import (
+    _DENSE_MAX_DOMAIN,
+    AggSpec,
+    hash_aggregate,
+)
 from datafusion_distributed_tpu.ops.sort import SortKey, limit_table, sort_table
 from datafusion_distributed_tpu.ops.table import (
     Column,
@@ -592,6 +596,7 @@ class HashAggregateExec(ExecutionPlan):
 
             out = global_aggregate(t, self.aggs, self.mode,
                                    prec_flags=prec_flags, live=live)
+            ctx.count("dense_aggregates")  # one slot: no scatter to choose
         else:
             out, overflow = hash_aggregate(
                 t, self.group_names, self.aggs, self.num_slots, self.mode,
@@ -600,6 +605,8 @@ class HashAggregateExec(ExecutionPlan):
             )
             ctx.record_overflow(self, overflow)
             ctx.count("direct_groupings", len(direct))
+            ctx.count("dense_aggregates",
+                      sum(d <= _DENSE_MAX_DOMAIN for d in direct))
         for f in prec_flags:
             ctx.record_precision_error(self, f)
         return out

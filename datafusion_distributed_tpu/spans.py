@@ -48,8 +48,10 @@ PROFILE_PREFIX = "dftpu."
 #: ``ctx.count(name)``. ``masked_filters``: filters that handed an
 #: aggregate their mask and did not compact; ``direct_groupings``:
 #: aggregates that addressed their groups by dictionary codes and built
-#: no group table.
-PROGRAM_COUNTERS = ("masked_filters", "direct_groupings")
+#: no group table; ``dense_aggregates``: aggregates, grouped or global,
+#: whose reductions ran as dense masked passes over a small known domain
+#: and not as scatters.
+PROGRAM_COUNTERS = ("masked_filters", "direct_groupings", "dense_aggregates")
 
 _SPAN_CAP = 4096     # ring-buffer bound per query
 _EVENT_CAP = 2048    # trace-level event bound per query

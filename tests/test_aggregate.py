@@ -263,7 +263,7 @@ def _direct_case(case):
         vocabulary = Dictionary.from_strings(sorted(map(str, range(100))))
         dictionaries = {"a": vocabulary, "b": vocabulary}
         slots, domain = 16384, 100 * 100
-        assert domain > aggregate._PRESENCE_BY_COMPARE_MAX_DOMAIN
+        assert domain > aggregate._DENSE_MAX_DOMAIN
     elif case == "key_without_dictionary":
         groups, domain = ["a", "k"], None
         columns["k"] = rng.integers(0, 3, n)
@@ -371,6 +371,201 @@ def test_direct_grouping_equals_the_claim_loop(case, mode, monkeypatch):
                                            err_msg=f"{key} {name}")
             else:
                 assert g == w and type(g) is type(w), (key, name)
+
+
+# ---------------------------------------------------------------------------
+# dense reductions: a small known domain reduces by masked passes, not scatters
+# ---------------------------------------------------------------------------
+
+_DENSE_AGGS = _DIRECT_AGGS + [AggSpec("var_samp", "v", "vr")]
+
+
+def _reduction_spy(monkeypatch):
+    """-> the list that collects ``dense_domain`` of every
+    `_reduce_by_slot` call traced from here on."""
+    from datafusion_distributed_tpu.ops import aggregate
+
+    seen: list = []
+    reduce_by_slot = aggregate._reduce_by_slot
+
+    def spy(op, ids, vals, num_slots, dense_domain):
+        seen.append(dense_domain)
+        return reduce_by_slot(op, ids, vals, num_slots, dense_domain)
+
+    monkeypatch.setattr(aggregate, "_reduce_by_slot", spy)
+    return seen
+
+
+def _dense_case(case):
+    """-> (table, group names, num_slots, live(table), domain, the cut to
+    run under or None for the module's)."""
+    base = {"nulls_and_holes": "live_with_holes",
+            "no_live_row": "no_live_row",
+            "unused_slot": "code_never_occurs"}.get(case, "two_keys_3x2")
+    table, groups, slots, live, domain = _direct_case(base)
+    # the rows of one group: the first code of either key
+    first = (np.asarray(table.column("a").data) == 0) & (
+        np.asarray(table.column("b").data) == 0)
+    cut = None
+    if case == "nulls_and_holes":
+        # every value of one group NULL: its sum, avg, min, max are NULL
+        for name in ("v", "i"):
+            col = table.column(name)
+            table = table.with_column(name, col.with_validity(
+                col.valid_mask() & ~jnp.asarray(first)))
+    elif case == "nan_and_inf":
+        rows = np.flatnonzero(first)[:2]
+        col = table.column("v")
+        table = table.with_column("v", type(col)(
+            col.data.at[rows].set(jnp.asarray([np.nan, np.inf],
+                                              col.data.dtype)),
+            col.valid_mask().at[rows].set(True), col.dtype, col.dictionary))
+    elif case == "domain_at_cut":
+        cut = domain
+    elif case == "domain_over_cut":
+        cut = domain - 1
+    return table, groups, slots, live, domain, cut
+
+
+def _assert_same_values(names, got, want, where):
+    for name, g, w in zip(names, got, want):
+        if isinstance(w, float):
+            # the benchmark's oracle: 5e-4 relative, 1e-4 absolute
+            np.testing.assert_allclose(g, w, rtol=5e-4, atol=1e-4,
+                                       err_msg=f"{where} {name}")
+        else:
+            assert g == w and type(g) is type(w), (where, name)
+
+
+def _same_bits(a, b) -> bool:
+    leaves = zip(jax.tree.leaves(a), jax.tree.leaves(b))
+    return all(np.asarray(x).tobytes() == np.asarray(y).tobytes()
+               for x, y in leaves)
+
+
+@pytest.mark.parametrize("mode", ["single", "partial", "final",
+                                  "partial_reduce"])
+@pytest.mark.parametrize("case", [
+    "nulls_and_holes", "no_live_row", "unused_slot", "nan_and_inf",
+    "domain_at_cut", "domain_over_cut",
+])
+def test_dense_reduction_equals_the_scatter(case, mode, monkeypatch):
+    """A domain up to `_DENSE_MAX_DOMAIN` reduces every aggregate by
+    dense masked passes (`_reduce_by_slot`); the result is the scatters' (the
+    same call with the cut at 0), group for group: integers and counts
+    exactly, floats at the oracle's tolerance, a NaN and an Inf confined to
+    their own group, and two runs bit for bit the same. A domain one over
+    the cut keeps the scatters."""
+    from datafusion_distributed_tpu.ops import aggregate
+    from datafusion_distributed_tpu.ops.table import concat_tables
+
+    table, groups, slots, live, domain, cut = _dense_case(case)
+    if mode in ("final", "partial_reduce"):
+        halves = [table.row_mask() & (jnp.arange(table.capacity) % 2 == h)
+                  for h in (0, 1)]
+        states = [hash_aggregate(table, groups, _DENSE_AGGS, slots,
+                                 "partial", live=half)[0] for half in halves]
+        table = concat_tables(states, capacity=2 * states[0].capacity)
+    seen = _reduction_spy(monkeypatch)
+
+    def run(cut):
+        if cut is not None:
+            monkeypatch.setattr(aggregate, "_DENSE_MAX_DOMAIN", cut)
+        direct: list = []
+        out, overflow = jax.jit(
+            lambda t: hash_aggregate(t, groups, _DENSE_AGGS, slots, mode,
+                                     live=live(t), direct=direct))(table)
+        assert not bool(overflow) and direct == [domain]
+        return out
+
+    got = run(cut)
+    over = case == "domain_over_cut"
+    assert seen and set(seen) == {None if over else domain}
+    assert _same_bits(got, run(cut))
+    del seen[:]
+    want = run(0)
+    assert seen and set(seen) == {None}
+
+    assert got.names == want.names and got.capacity == want.capacity
+    got_rows, want_rows = _group_rows(got, groups), _group_rows(want, groups)
+    assert set(got_rows) == set(want_rows)
+    assert len(got_rows) == (0 if case == "no_live_row" else 6)
+    names = got.names[len(groups):]
+    for key, want_vals in want_rows.items():
+        _assert_same_values(names, got_rows[key], want_vals, key)
+    sums = {key: dict(zip(names, vals)) for key, vals in got_rows.items()}
+    if case == "nan_and_inf" and mode in ("single", "final"):
+        bad = [key for key, row in sums.items()
+               if row["sv"] is not None and not np.isfinite(row["sv"])]
+        assert len(bad) == 1 and np.isnan(sums[bad[0]]["av"])
+        assert all(np.isfinite(row["sv"]) and np.isfinite(row["av"])
+                   for key, row in sums.items() if key != bad[0])
+    if case == "nulls_and_holes" and mode in ("single", "final"):
+        (empty,) = [row for row in sums.values() if row["cv"] == 0]
+        assert (empty["sv"], empty["si"], empty["mn"], empty["mx"],
+                empty["av"]) == (None,) * 5 and empty["n"] > 0
+
+
+@pytest.mark.parametrize("mode", ["single", "partial", "final",
+                                  "partial_reduce"])
+@pytest.mark.parametrize("case", ["nulls_and_holes", "no_live_row",
+                                  "nan_and_inf"])
+def test_global_aggregate_reduces_densely(case, mode, monkeypatch):
+    """No GROUP BY is a domain of one: plain masked reductions into slot 0
+    of the capacity-8 result, no scatter in the program at all. The result
+    is a grouped aggregate's over one constant key, reduced by scatters."""
+    from datafusion_distributed_tpu.ops import aggregate
+    from datafusion_distributed_tpu.ops.aggregate import global_aggregate
+    from datafusion_distributed_tpu.ops.table import (
+        Column,
+        Dictionary,
+        concat_tables,
+    )
+    from datafusion_distributed_tpu.schema import DataType
+
+    table, _, _, live, _, _ = _dense_case(case)
+    if mode in ("final", "partial_reduce"):
+        halves = [table.row_mask() & (jnp.arange(table.capacity) % 2 == h)
+                  for h in (0, 1)]
+        states = [global_aggregate(table, _DENSE_AGGS, "partial",
+                                   live=half & (True if live(table) is None
+                                                else live(table)))
+                  for half in halves]
+        table = concat_tables(states, capacity=2 * states[0].capacity)
+        live = lambda t: None  # noqa: E731
+    seen = _reduction_spy(monkeypatch)
+    run = jax.jit(lambda t: global_aggregate(t, _DENSE_AGGS, mode,
+                                             live=live(t)))
+    got = run(table)
+    assert seen and set(seen) == {1}
+    assert "scatter" not in run.lower(table).as_text()
+    assert _same_bits(got, run(table))
+    assert got.capacity == 8 and int(got.num_rows) == 1
+
+    del seen[:]
+    constant = Dictionary.from_strings(["c"])
+    keyed = table.with_column("k", Column(
+        jnp.zeros(table.capacity, jnp.int32), None, DataType.STRING,
+        constant))
+    monkeypatch.setattr(aggregate, "_DENSE_MAX_DOMAIN", 0)
+    want, _ = jax.jit(lambda t: hash_aggregate(
+        t, ["k"], _DENSE_AGGS, 8, mode, live=live(t)))(keyed)
+    assert seen and set(seen) == {None}
+    (got_row,) = _group_rows(got, []).values()
+    want_rows = _group_rows(want, ["k"])
+    names = got.names
+    assert names == want.names[1:]
+    row = dict(zip(names, got_row))
+    if case == "no_live_row" and mode in ("single", "final"):
+        assert row == {"sv": None, "si": None, "cv": 0, "n": 0, "mn": None,
+                       "mx": None, "av": None, "vr": None}
+    if case == "no_live_row" and mode in ("single", "partial"):
+        assert want_rows == {}  # over a key no row makes no group
+        return
+    (want_row,) = want_rows.values()
+    _assert_same_values(names, got_row, want_row, case)
+    if case == "nan_and_inf" and mode in ("single", "final"):
+        assert np.isnan(row["sv"]) and row["si"] is not None
 
 
 def _string_columns():

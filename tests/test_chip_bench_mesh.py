@@ -169,24 +169,25 @@ def test_the_mesh_tiers_metric_files_read_the_report(name, window):
     assert read(name, {"queries": []}) is None
 
 
-def test_direct_groupings_reads_the_mesh_and_the_direct_tier(cell, window):
-    """`metrics/direct_groupings.py` (PR 30) reads `execute` and
-    `mesh.execute` alike: q1's partial and final aggregates on the mesh,
-    its one aggregate on the direct tier, all grouped by dictionary codes
+@pytest.mark.parametrize("name", ["direct_groupings", "dense_aggregates"])
+def test_an_operator_counter_reads_the_mesh_and_the_direct_tier(name, cell,
+                                                                window):
+    """`metrics/direct_groupings.py` (PR 30) and `metrics/dense_aggregates.py`
+    (PR 32) read `execute` and `mesh.execute` alike: q1's partial and final
+    aggregates on the mesh, its one aggregate on the direct tier, all
+    grouped by dictionary codes, all reduced densely over a domain of 12,
     and counted on a program-cache hit. (Reads the window's store: before
     the tests below, which clear it.)"""
-    assert [r["counters"]["direct_groupings"] for r in window["rows"]] == (
-        [2] * REQUESTS)
-    assert read("direct_groupings", window["record"]) == 2
-    tracing.DEFAULT_TRACE_STORE.clear()
+    assert [r["counters"][name] for r in window["rows"]] == [2] * REQUESTS
+    assert read(name, window["record"]) == 2
     start = time.perf_counter()
     cell["direct"].ctx.config.distributed_options["tracing"] = "on"
     try:
         run_traced(cell["direct"], cell["sql"])
     finally:
         cell["direct"].ctx.config.distributed_options.pop("tracing", None)
-    assert read("direct_groupings", {"queries": [{"start": start}]}) == 1
-    assert read("direct_groupings", {"queries": []}) is None
+    assert read(name, {"queries": [{"start": start}]}) == 1
+    assert read(name, {"queries": []}) is None
 
 
 @pytest.mark.parametrize("name", MESH_METRICS)

@@ -235,21 +235,25 @@ class _PackedExec(ExecutionPlan):
         return self.child.execute(ctx)
 
 
-def _masked_case_scan(n=200, seed=5):
-    """k: group key; w: nullable predicate column; v: nullable aggregate
-    input. Floats are quarters, so every sum is exact and no order of
-    adding them can show: equal answers are then equal bit for bit.
+def _masked_case_scan(n=200, seed=5, dictionary_key=False):
+    """k: group key (``dictionary_key``: and d, the same key as a
+    dictionary-coded string); w: nullable predicate column; v: nullable
+    aggregate input. Floats are quarters, so every sum is exact and no
+    order of adding them can show: equal answers are then equal bit for bit.
     200 rows in a capacity of 256: padding rows past ``num_rows``."""
     rng = np.random.default_rng(seed)
     w = rng.integers(-50, 50, n).astype(object)
     w[rng.random(n) < 0.2] = None
     v = (rng.integers(-400, 400, n) / 4.0).astype(object)
     v[rng.random(n) < 0.2] = None
-    t = arrow_to_table(pa.table({
+    columns = {
         "k": rng.integers(0, 8, n),
         "w": pa.array(list(w), type=pa.int64()),
         "v": pa.array(list(v), type=pa.float64()),
-    }))
+    }
+    if dictionary_key:
+        columns["d"] = np.array(list("abcdefgh"), dtype=object)[columns["k"]]
+    t = arrow_to_table(pa.table(columns))
     assert t.capacity > n
     assert t.column("w").validity is not None
     assert t.column("v").validity is not None
@@ -271,9 +275,12 @@ _MASKED_AGGS = [
 
 def _masked_case_input(shape, predicate):
     """The aggregate's child, and the group names of the aggregate."""
-    scan = _masked_case_scan()
+    by_dictionary = shape == "agg_dictionary_key"
+    scan = _masked_case_scan(dictionary_key=by_dictionary)
     proj = [(Col("k"), "k"), (Col("w"), "w"), (Col("v"), "v"),
             (BinaryOp("*", Col("v"), Literal(2.0, DataType.FLOAT64)), "v2")]
+    if by_dictionary:
+        proj.append((Col("d"), "d"))
     if shape == "agg_filter_filter":
         # the projection sits under the filters, which stack
         inner = FilterExec(
@@ -282,7 +289,8 @@ def _masked_case_input(shape, predicate):
         )
         return FilterExec(predicate, inner), ["k"]
     child = ProjectionExec(proj, FilterExec(predicate, scan))
-    return child, ([] if shape == "global" else ["k"])
+    return child, {"global": [], "agg_dictionary_key": ["d"]}.get(shape,
+                                                                 ["k"])
 
 
 def _aggregate_over(child, groups, mode):
@@ -292,11 +300,22 @@ def _aggregate_over(child, groups, mode):
     return HashAggregateExec("final", groups, _MASKED_AGGS, partial, 32)
 
 
-def _assert_bit_equal(got, want):
-    pd.testing.assert_frame_equal(got, want, check_exact=True)
-    for name in want.columns:
+def _assert_bit_equal(got, want, dense=False):
+    """The two frames bit for bit. ``dense``: but for the float columns,
+    which agree to a few units in the last place. A dense reduction
+    (`ops/aggregate.py _reduce_by_slot`, since PR 32) adds in an order the
+    rows' positions fix, and the centred residuals of
+    `_mean_shifted_seg_sum` are not quarters, so the same rows packed and
+    in place round differently; a scatter adds them in row order either
+    way."""
+    exact = [name for name in want.columns
+             if not (dense and want[name].dtype.kind == "f")]
+    pd.testing.assert_frame_equal(got[exact], want[exact], check_exact=True)
+    for name in exact:
         assert (got[name].to_numpy().tobytes()
                 == want[name].to_numpy().tobytes()), name
+    pd.testing.assert_frame_equal(got, want, check_exact=False, rtol=2e-6,
+                                  atol=0)
 
 
 def _lowered(plan) -> str:
@@ -327,12 +346,15 @@ def _compacting_filters(plan) -> dict:
 @pytest.mark.parametrize("predicate", ["some", "none", "all"])
 @pytest.mark.parametrize("mode", ["single", "partial_final"])
 @pytest.mark.parametrize(
-    "shape", ["agg_proj_filter", "agg_filter_filter", "global"]
+    "shape", ["agg_proj_filter", "agg_filter_filter", "global",
+              "agg_dictionary_key"]
 )
 def test_masked_aggregate_equals_packed(shape, mode, predicate):
     """An aggregate over filters and projections reduces under their mask;
     the same aggregate over the same child's packed rows gives the same
-    frame bit for bit."""
+    frame bit for bit where the reductions scatter (`k` is an integer with
+    no dictionary), and but for a float sum's last places where they are
+    dense (`global`, and `d`'s domain of 9: `_assert_bit_equal`)."""
     child, groups = _masked_case_input(shape, _MASKED_PREDICATES[predicate])
     masked = _aggregate_over(child, groups, mode)
     packed = _aggregate_over(_PackedExec(child), groups, mode)
@@ -342,7 +364,11 @@ def test_masked_aggregate_equals_packed(shape, mode, predicate):
     assert len(_compacting_filters(packed)) == filters
     got = execute_plan(masked).to_pandas()
     want = execute_plan(packed).to_pandas()
-    _assert_bit_equal(got, want)
+    dense = groups != ["k"]
+    reducing = [name for name in _scatter_scopes(_lowered(masked))
+                if "/agg.reduce." in name or "/agg.global" in name]
+    assert bool(reducing) != dense
+    _assert_bit_equal(got, want, dense)
     if groups:
         assert len(want) == {"some": 8, "none": 0, "all": 8}[predicate]
     else:
@@ -423,6 +449,29 @@ def test_tpch_programs_compact_only_under_joins(tpch_ctx, query, filters,
     assert _compacting_filters(plan) == compact_ops
 
 
+def _scatter_scopes(text: str) -> list:
+    """The scope path (`jit(run)/<node>/<scope>/...`) of every
+    `stablehlo.scatter` of a program lowered with debug info: the name
+    that the op's own location, after its region, is an alias of."""
+    import re
+
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    own = re.findall(
+        r'"stablehlo\.scatter".*?^\s*\}\) : [^\n]* loc\((#loc\d+)\)$', text,
+        re.M | re.S)
+    assert len(own) == text.count('"stablehlo.scatter"')
+    return [names[loc] for loc in own]
+
+
+# sha256 of q3's and q18's lowered text (no debug info) over `tpch_ctx`'s
+# tables at the parent commit (94fb45c): their aggregates keep the claim
+# loop and the scatters, so the program is the parent's, byte for byte
+_PARENT_LOWERED_SHA256 = {
+    "q3": "0346fb98c2af7a3b4fdebe6531f4a287a935d63f4e69b77e79c762a061a16b6c",
+    "q18": "7a234fcf9a7a97778e2f29bdf36695960c9b04bff52785996fd2be0f6d81b317",
+}
+
+
 @pytest.mark.parametrize("query,grouping", [
     # l_returnflag x l_linestatus: dictionary codes, (3+1) x (2+1) <= 2048
     ("q1", "agg.direct"),
@@ -436,12 +485,33 @@ def test_tpch_programs_claim_only_without_dictionary_keys(tpch_ctx, query,
                                                           grouping):
     """q1's group ids are arithmetic on its keys' dictionary codes: no op
     under ``agg.claim`` and no `while` in its lowered program. Keys
-    without a dictionary still build the group table by claim rounds."""
-    text = _lowered(_tpch_plan(tpch_ctx, query))
+    without a dictionary still build the group table by claim rounds.
+    The reductions follow: over q1's domain of 12 and q6's of one they are
+    dense passes, no `stablehlo.scatter` under ``agg.reduce.*`` or
+    ``agg.global``; q3 and q18 scatter into their 2Mi-slot tables as at the
+    parent commit, their whole lowered text unchanged."""
+    import hashlib
+
+    from datafusion_distributed_tpu.spans import NULL_TRACER
+
+    plan = _tpch_plan(tpch_ctx, query)
+    text = _lowered(plan)
     for scope in ("agg.direct", "agg.claim"):
         assert (f"/{scope}" in text) == (scope == grouping), scope
     if grouping != "agg.claim":
         assert "stablehlo.while" not in text
+    reducing = [name for name in _scatter_scopes(text)
+                if "/agg.reduce." in name or "/agg.global" in name]
+    if grouping == "agg.claim":
+        assert reducing
+        prog = phys._prepare_program(
+            plan, DistributedTaskContext(), None, False, None, None,
+            NULL_TRACER)
+        plain = prog.fn.lower(prog.inputs, prog.params).as_text()
+        assert hashlib.sha256(plain.encode()).hexdigest() == (
+            _PARENT_LOWERED_SHA256[query])
+    else:
+        assert reducing == []
 
 
 def test_masked_filter_reports_the_kept_rows():

@@ -136,31 +136,79 @@ def test_claim_loop_group_by_compiles(one_chip, query):
              scopes=("agg.claim", "agg.reduce.sum", "table.gather"))
 
 
+def _q1_with_dictionaries(one_chip, sizes):
+    """q1's table at 8Mi rows, its two keys dictionary-coded with
+    ``sizes`` values (and a validity array each, as arrow ingestion
+    leaves them: a domain of ``(sizes[0] + 1) * (sizes[1] + 1)``)."""
+    from datafusion_distributed_tpu.ops.table import Dictionary
+
+    columns = GROUP_BY_CASES["q1"][0]
+    dictionaries = {
+        f"g{i}": Dictionary.from_strings([f"{v:05d}" for v in range(size)])
+        for i, size in enumerate(sizes)}
+    t = _table(columns, 8 * MI, one_chip)
+    return Table(t.names, tuple(
+        Column(c.data, c.validity, c.dtype, dictionaries.get(name))
+        for name, c in zip(t.names, t.columns)), t.num_rows)
+
+
 def test_direct_group_by_compiles(one_chip):
     """q1 as it runs since PR 30: its two keys carry dictionaries of 3 and
     2 values (and a validity array each), so the group ids are arithmetic
     on the codes, (3+1) x (2+1) = 12 slots of the 2048 can be used, and
     the chip's program holds no `while` at all."""
-    from datafusion_distributed_tpu.ops.table import Dictionary
-
-    columns, keys, aggs, slots, out_capacity = GROUP_BY_CASES["q1"]
-    dictionaries = {"g0": Dictionary.from_strings(["A", "N", "R"]),
-                    "g1": Dictionary.from_strings(["F", "O"])}
-    t = _table(columns, 8 * MI, one_chip)
-    t = Table(t.names, tuple(
-        Column(c.data, c.validity, c.dtype, dictionaries.get(name))
-        for name, c in zip(t.names, t.columns)), t.num_rows)
+    _, keys, aggs, slots, out_capacity = GROUP_BY_CASES["q1"]
     direct: list = []
 
     def kernel(t):
         return hash_aggregate(t, keys, aggs, slots, "single",
                               out_capacity=out_capacity, direct=direct)
 
-    compiled = _compile(kernel, t, scopes=("agg.direct", "agg.reduce.sum",
-                                           "table.gather"))
+    compiled = _compile(kernel, _q1_with_dictionaries(one_chip, (3, 2)),
+                        scopes=("agg.direct", "agg.reduce.sum",
+                                "table.gather"))
     assert direct == [12]
     assert " while(" not in compiled.as_text()
     assert "/agg.claim/" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("domain", ["q1", "the_cut"])
+def test_dense_reduction_compiles(one_chip, domain):
+    """q1's reductions as they run since PR 32, at 8Mi rows over its domain
+    of 12 and over a domain at the cut (`_DENSE_MAX_DOMAIN`): dense
+    masked passes. The chip's compiler accepts them, no scatter is left under
+    ``agg.reduce.*`` (only the pack's, over the slots), and no
+    ``[domain, rows]`` operand is materialised: the temporaries stay within
+    a quarter of what this compiler read in PR 32, 136.4 MB at 12 slots and
+    172.4 MB at the cut, where one ``f32[12, 8Mi]`` operand alone is 403 MB.
+    (They are NOT under the scatter form's 71 MB, as ISSUE 32 asked: sibling
+    reductions share one pass, so their inputs are live together.)"""
+    import re
+
+    from datafusion_distributed_tpu.ops import aggregate
+
+    _, keys, aggs, slots, _ = GROUP_BY_CASES["q1"]
+    cut = aggregate._DENSE_MAX_DOMAIN
+    assert cut & (cut - 1) == 0 and cut >= 16
+    half = cut.bit_length() // 2  # cut = 2^(half) * 2^(rest)
+    sizes = (3, 2) if domain == "q1" else (
+        (1 << half) - 1, (cut >> half) - 1)
+    slots = max(slots, cut)
+    direct: list = []
+
+    def kernel(t):
+        return hash_aggregate(t, keys, aggs, slots, "single",
+                              out_capacity=slots, direct=direct)
+
+    compiled = _compile(kernel, _q1_with_dictionaries(one_chip, sizes),
+                        scopes=("agg.direct", "agg.reduce.sum"))
+    assert direct == [12 if domain == "q1" else cut]
+    text = compiled.as_text()
+    assert not re.search(r'op_name="[^"]*/agg\.reduce\.[^"]*scatter', text)
+    assert text.count(" scatter(") == 1  # `nonzero` of the pack
+    measured = {"q1": 136_443_392, "the_cut": 172_352_512}[domain]
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries <= 1.25 * measured, temporaries
 
 
 def test_hash_join_build_and_probe_compiles(one_chip):

@@ -767,25 +767,30 @@ def test_direct_q1_span_tree_under_one_request(tpch_ctx):
     assert DEFAULT_TRACE_STORE.summary()["running"] == 0
 
 
-@pytest.mark.parametrize("tier,query,masked,direct", [
-    # one filter, under the aggregate's projection; two dictionary keys
-    ("direct", TPCH_Q1, 1, 1),
-    # four stacked filters under the global aggregate: no group-by
-    ("direct", TPCH_Q6, 4, 0),
-    # three filters, each under a join: they compact; integer group keys
-    ("direct", TPCH_Q3, 0, 0),
+@pytest.mark.parametrize("tier,query,masked,direct,dense", [
+    # one filter, under the aggregate's projection; two dictionary keys,
+    # a domain of 12: dense reductions
+    ("direct", TPCH_Q1, 1, 1, 1),
+    # four stacked filters under the global aggregate: no group-by, one slot
+    ("direct", TPCH_Q6, 4, 0, 1),
+    # three filters, each under a join: they compact; integer group keys,
+    # the claim loop and the scatters
+    ("direct", TPCH_Q3, 0, 0, 0),
     # the SPMD program's partial aggregate takes the mask; the partial and
-    # the final aggregate both address their groups directly
-    ("mesh", TPCH_Q1, 1, 2),
-    ("mesh", TPCH_Q6, 4, 0),
+    # the final aggregate both address their groups directly and reduce
+    # densely
+    ("mesh", TPCH_Q1, 1, 2, 2),
+    ("mesh", TPCH_Q6, 4, 0, 2),
 ], ids=["q1", "q6", "q3", "mesh-q1", "mesh-q6"])
 def test_execute_span_carries_the_trace_counters(tpch_ctx, tier, query,
-                                                 masked, direct):
-    """`masked_filters` (the filters that handed an aggregate their mask)
-    and `direct_groupings` (the aggregates that addressed their groups by
-    dictionary codes) on the `execute` span (`mesh.execute` on the mesh
-    tier). Counted when the program is traced and kept with the cached
-    executable, so a program-cache hit reports them too."""
+                                                 masked, direct, dense):
+    """`masked_filters` (the filters that handed an aggregate their mask),
+    `direct_groupings` (the aggregates that addressed their groups by
+    dictionary codes) and `dense_aggregates` (the aggregates, grouped or
+    global, that reduced by dense passes and not by scatters) on the
+    `execute` span (`mesh.execute` on the mesh tier). Counted when the
+    program is traced and kept with the cached executable, so a
+    program-cache hit reports them too."""
     from datafusion_distributed_tpu.runtime.mesh_executor import make_mesh
 
     kind = {"direct": "execute", "mesh": "mesh.execute"}[tier]
@@ -806,9 +811,11 @@ def test_execute_span_carries_the_trace_counters(tpch_ctx, tier, query,
         (execute,) = spans[kind]
         assert execute.attrs["masked_filters"] == masked
         assert execute.attrs["direct_groupings"] == direct
+        assert execute.attrs["dense_aggregates"] == dense
         (row,) = [r for r in layer_report() if r["request"] == request_id]
         assert row["counters"]["masked_filters"] == masked
         assert row["counters"]["direct_groupings"] == direct
+        assert row["counters"]["dense_aggregates"] == dense
     spans = _request_spans(requests[1])
     (cached,) = spans["prepare"] if tier == "direct" else spans[kind]
     assert cached.attrs["cache"] == "hit"
