@@ -15,8 +15,9 @@ import statistics
 def rows(record: dict) -> list:
     """The rows of the requests begun inside the window. The store's clock
     is ``time.monotonic`` and the traffic loop's ``time.perf_counter``:
-    one clock on Linux, so a row is kept from the first query's start on
-    (the by-hand checks run several cells in one process)."""
+    one clock on Linux, so a row is kept from the first query's start to
+    the last one's end (the by-hand checks run several cells in one
+    process)."""
     try:
         from datafusion_distributed_tpu.runtime import tracing
     except ImportError:
@@ -25,7 +26,8 @@ def rows(record: dict) -> list:
     if report is None or not record["queries"]:
         return []
     opened = min(q["start"] for q in record["queries"])
-    return [row for row in report() if row["t0_s"] >= opened]
+    closed = max(q.get("end", float("inf")) for q in record["queries"])
+    return [row for row in report() if opened <= row["t0_s"] <= closed]
 
 
 def median(record: dict, value):
@@ -35,3 +37,16 @@ def median(record: dict, value):
     values = [v for v in map(value, rows(record)) if v is not None]
     return statistics.median(values) if values else None
 
+
+
+def coordinator_sum(record: dict, table, kinds: tuple):
+    """Median over the window's requests that went through the coordinator
+    (they hold a ``schedule`` span) of the sum of ``table(row)[kind]`` over
+    the ``kinds`` the request holds. None where no such request holds any."""
+    def held(row):
+        if "schedule" not in row["self_s"]:
+            return None
+        values = [table(row)[kind] for kind in kinds if kind in table(row)]
+        return sum(values) if values else None
+
+    return median(record, held)

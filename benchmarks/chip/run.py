@@ -13,7 +13,8 @@ loop or metric is a file of its own beside this one, found by name:
     traffic/<mix>.json         the loop's name and its parameters
     traffic/<loop>.py          run(call, mix, seed, seconds, limit) -> records
     tiers/<tier>.py            Tier(ctx, args, suite): run, run_traced, close
-    suites/<suite>/suite.py    load, sql, expected, compare, frame, least_bytes
+    suites/<suite>/suite.py    load, sql, expected, measure, LIMITS, frame,
+                               least_bytes
     metrics/<name>.py          read(record) -> number, or None
     peaks.json                 device_kind -> published peaks
     trace_reduce.py            .xplane.pb -> busy time, ops, idle gaps
@@ -27,10 +28,12 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import gc
 import importlib.util
 import json
 import os
 import shutil
+import statistics
 import sys
 import time
 
@@ -113,11 +116,8 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
     for name, arrow in tables.items():
         ctx.register_arrow(name, arrow)
     setup["register_s"] = clock() - t
-    t = clock()
     texts = {q: suite.sql(q) for q in mix["queries"]}
-    expected = {q: suite.expected(q, tables) for q in texts}
     least_bytes = {q: suite.least_bytes(q, tables) for q in texts}
-    setup["oracle_s"] = clock() - t
 
     def call(query: str) -> dict:
         if not trace:
@@ -135,6 +135,23 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
         with jax.profiler.TraceAnnotation("bench.query"):
             frame, retries = tier.run_traced(texts[query], span)
         return {"frame": frame, "retries": retries, "spans": spans}
+
+    # the collector while the window is open: the youngest generation's
+    # collections counted and summed, every older one [generation, start,
+    # seconds]
+    young = [0, 0.0]
+    collections: list = []
+    collecting = [0.0]
+
+    def on_collection(phase: str, info: dict) -> None:
+        if phase == "start":
+            collecting[0] = clock()
+        elif info["generation"] == 0:
+            young[0] += 1
+            young[1] += clock() - collecting[0]
+        else:
+            collections.append([info["generation"], collecting[0],
+                                clock() - collecting[0]])
 
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
@@ -154,6 +171,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0  # our spans only: less host load
             jax.profiler.start_trace(trace_dir, profiler_options=options)
+        gc.callbacks.append(on_collection)
         opened = clock()
         setup["setup_s"] = opened - _PROCESS_START
         records = loop.run(call, mix, seed, seconds,
@@ -164,20 +182,35 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
         if trace:
             jax.profiler.stop_trace()
     finally:
+        if on_collection in gc.callbacks:
+            gc.callbacks.remove(on_collection)
         tier.close()
 
     # -- after the window: compare, count, reduce ---------------------------
+    stats = [d.memory_stats() or {} for d in devices]
+    t = clock()
+    expected = {q: suite.expected(q, tables) for q in texts}
+    reference_s = clock() - t
+    # every result against the reference's; ``compared`` keeps the worst
+    # reading of the window under each of the suite's names, beside its limit
+    compared = {name: {"value": 0, "limit": limit}
+                for name, limit in suite.LIMITS.items()}
+    compared["results_missing"] = {"value": 0, "limit": 0}
     for r in records:
         result = r.pop("result")
         r["ok"] = False
-        if result is not None:
-            r["retries"], r["spans"] = result["retries"], result.get("spans")
-            try:
-                suite.compare(result["frame"], expected[r["query"]])
-                r["ok"] = True
-            except AssertionError as e:
-                emit(mismatch=r["query"], error=str(e)[:400])
-    stats = [d.memory_stats() or {} for d in devices]
+        if result is None:  # it raised: the loop printed the traceback
+            compared["results_missing"]["value"] += 1
+            continue
+        r["retries"], r["spans"] = result["retries"], result.get("spans")
+        numbers = suite.measure(result["frame"], expected[r["query"]])
+        over = {name: value for name, value in numbers.items()
+                if value > suite.LIMITS[name]}
+        for name, value in numbers.items():
+            compared[name]["value"] = max(compared[name]["value"], value)
+        r["ok"] = not over
+        if over:
+            emit(mismatch=r["query"], over=over)
     kind = devices[0].device_kind
     reduced = None
     if trace:
@@ -201,12 +234,19 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
             metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
 
     done = [r for r in records if r["ok"]]
-    emit(setup=setup, window_s=record["window_s"], requested_s=seconds,
+    emit(setup=setup, reference_s=reference_s, trace=int(trace),
+         window_s=record["window_s"], requested_s=seconds,
          samples=len(records), agreed=len(done),
          compiled_in_setup=compiled_in_setup,
          compiles_in_window=compiles_in_window,
-         walls_s=[round(r["end"] - r["start"], 4) for r in records[:64]],
-         rows={name: t.num_rows for name, t in tables.items()})
+         rows={name: t.num_rows for name, t in tables.items()},
+         # every query's start since the window opened and its wall, and
+         # every collection of the window, as measured: walls.py reads them
+         starts_s=[r["start"] - opened for r in records],
+         walls_s=[r["end"] - r["start"] for r in records],
+         young_collections=young[0], young_collections_s=young[1],
+         collections=[[g, start - opened, length]
+                      for g, start, length in collections])
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(jax.devices()),
               "memory_peak_bytes": max(
@@ -216,12 +256,21 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
               "failed": len(records) - len(done),
               "metrics": metrics, "device": device}
     if reduced is not None:
+        # the program's own report of the traced requests, whole: each span
+        # kind's median self time, whatever a metric file reads of it
+        rows = load_module("layer_rows.py").rows(record)
         emit(traced_queries=reduced["queries"], window_s=reduced["window_s"],
-             device_busy_s=reduced.get("device_busy_s", {}))
+             device_busy_s=reduced.get("device_busy_s", {}),
+             program_self_ms={
+                 kind: statistics.median(
+                     row["self_s"][kind] * 1e3 for row in rows
+                     if kind in row["self_s"])
+                 for kind in sorted({k for row in rows for k in row["self_s"]})})
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["ops"][:10],
                                "idle_gaps": reduced["gaps"][:10]}
+    result["compared"] = compared  # the line's last key
     return result
 
 
@@ -254,6 +303,9 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     print(json.dumps(result), flush=True)
+    for name, number in result["compared"].items():
+        print(f"compared {name} {number['value']} limit {number['limit']}",
+              file=sys.stderr)
     return 0
 
 

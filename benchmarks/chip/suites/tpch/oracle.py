@@ -418,16 +418,25 @@ def q22(T):
 ORACLES = {f"q{i}": globals()[f"q{i}"] for i in range(1, 23)}
 
 
-def compare_results(got: pd.DataFrame, exp: pd.DataFrame,
-                    rtol: float = RTOL, atol: float = ATOL):
-    """Order-insensitive multiset comparison with float tolerance.
-    Raises AssertionError on mismatch."""
-    assert len(got) == len(exp), f"row count {len(got)} != {len(exp)}"
-    assert len(got.columns) == len(exp.columns), (
-        f"column count {list(got.columns)} vs {list(exp.columns)}"
-    )
-    if len(exp) == 0:
-        return
+#: what one comparison measures, each with the most it may read: the
+#: guarantee of the configurations' files, as numbers
+LIMITS = {"rows_off": 0, "columns_off": 0, "cells_differing": 0,
+          "float_gap_in_tolerances": 1.0}
+
+
+def measure_results(got: pd.DataFrame, exp: pd.DataFrame,
+                    rtol: float = RTOL, atol: float = ATOL) -> dict:
+    """Order-insensitive multiset comparison -> the numbers of `LIMITS`:
+    how far the row and column counts are off, how many cells of the other
+    columns differ (a NaN against a number among them), and the widest
+    float gap as a multiple of its tolerance, ``|got - exp| / (atol + rtol
+    * |exp|)``: `np.testing.assert_allclose`'s own test, read and not
+    asserted."""
+    numbers = {"rows_off": abs(len(got) - len(exp)),
+               "columns_off": abs(len(got.columns) - len(exp.columns)),
+               "cells_differing": 0, "float_gap_in_tolerances": 0.0}
+    if numbers["rows_off"] or numbers["columns_off"] or len(exp) == 0:
+        return numbers
     g = got.copy()
     e = exp.copy()
     g.columns = list(range(len(g.columns)))
@@ -441,11 +450,26 @@ def compare_results(got: pd.DataFrame, exp: pd.DataFrame,
     for c in e.columns:
         ge, ee = g[c], e[c]
         if pd.api.types.is_float_dtype(ee) or pd.api.types.is_float_dtype(ge):
-            np.testing.assert_allclose(
-                ge.astype(float).to_numpy(), ee.astype(float).to_numpy(),
-                rtol=rtol, atol=atol, equal_nan=True, err_msg=f"column {c}",
-            )
+            ga = ge.astype(float).to_numpy()
+            ea = ee.astype(float).to_numpy()
+            same = (ga == ea) | (np.isnan(ga) & np.isnan(ea))
+            finite = np.isfinite(ga) & np.isfinite(ea)
+            numbers["cells_differing"] += int((~same & ~finite).sum())
+            gaps = np.abs(ga - ea)[finite] / (atol + rtol * np.abs(ea[finite]))
+            if gaps.size:
+                numbers["float_gap_in_tolerances"] = max(
+                    numbers["float_gap_in_tolerances"], float(gaps.max()))
         else:
-            assert list(ge) == list(ee), (
-                f"column {c} differs: {list(ge)[:5]} vs {list(ee)[:5]}"
-            )
+            numbers["cells_differing"] += sum(
+                1 for a, b in zip(ge, ee) if a != b)
+    return numbers
+
+
+def compare_results(got: pd.DataFrame, exp: pd.DataFrame,
+                    rtol: float = RTOL, atol: float = ATOL):
+    """`measure_results` held to `LIMITS`. Raises AssertionError on a
+    mismatch."""
+    numbers = measure_results(got, exp, rtol, atol)
+    over = {name: value for name, value in numbers.items()
+            if value > LIMITS[name]}
+    assert not over, f"over the limit: {over} (limits {LIMITS})"

@@ -11,8 +11,10 @@ directory with the same functions.
   validation parameters), from ``queries/<query>.sql``.
 - ``expected(query, tables)``        -> the pandas oracle's answer, every
   row in the query's order, cut to a trailing LIMIT.
-- ``compare(got, expected)``         -> raises AssertionError on a mismatch
-  (``oracle.compare_results``; tolerances fixed in ``oracle.py``).
+- ``measure(got, expected)``         -> the numbers one comparison rests on,
+  and ``LIMITS``, the most each may read (``oracle.measure_results``;
+  tolerances fixed in ``oracle.py``); ``compare`` raises AssertionError
+  where one is over.
 - ``frame(arrow)``                   -> a pyarrow result as a pandas frame in
   the oracle's conventions (dates as days since the epoch).
 - ``least_bytes(query, tables)``     -> the fewest bytes the query must read:
@@ -37,6 +39,8 @@ oracle = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(oracle)
 
 compare = oracle.compare_results
+measure = oracle.measure_results
+LIMITS = oracle.LIMITS
 
 
 def load(scale: float, seed: int, cache_dir: str) -> dict:

@@ -1,7 +1,6 @@
 """`metrics/direct_groupings.py` rehearsed on the CPU at SF0.01 the way a
-traced run reads it, in the two cells that list it and in `direct-q6`, the
-bypass cell whose files stand but which is not in `BENCHMARK.json` (its
-spread by seed missed the bar for a new cell: PERF.md, PR 30). A
+traced run reads it, in the three cells that list it; `direct-q6` is the
+bypass cell (no GROUP BY: the count is 0). A
 `jax.profiler` session is the program's only switch, the requests are the
 tiers' own traced calls, and the value is checked against
 `tracing.layer_report`'s rows. Counts only: none of the numbers is a
@@ -100,21 +99,20 @@ def test_the_reader_counts_the_direct_groupings_of_a_request(cell, ctx,
     assert read(record) is None
 
 
-def test_benchmark_json_lists_the_metric_and_leaves_direct_q6_out():
+def test_benchmark_json_lists_the_metric_in_its_three_cells():
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     module = run.load_module("metrics", "direct_groupings.py")
-    assert bench["per_layer"][-1] == {
+    (entry,) = [m for m in bench["per_layer"]
+                if m["name"] == "direct_groupings"]
+    # `direct-q6` came in with PR 33, appended to the list as it stood
+    cells = [w["name"] for w in bench["workloads"]]
+    assert entry == {
         "name": "direct_groupings", "unit": module.UNIT, "better": "higher",
         "source": module.SOURCE, "layer": module.LAYER,
-        "moves": module.MOVES, "workloads": ["direct-q1", "mesh4-q1"]}
-    # `direct-q6`: `query_p50_s` spread 5.2% and 6.3% over two sets of three
-    # at the parent's tree (a seed's data moves q6's two scatters by 6%)
-    # against the 0.5% a new cell is admitted under, so ISSUE 30 leaves it
-    # out; its files stand for the PR that admits it
-    assert "direct-q6" not in {w["name"] for w in bench["workloads"]}
-    assert all("direct-q6" not in entry.get("workloads", [])
-               for entry in bench["per_layer"])
+        "moves": module.MOVES,
+        "workloads": [c for c in cells if c in CELLS]}
+    assert set(CELLS) <= set(cells)
     workload = run.read_json("workloads", "direct-q6.json")
     assert (workload["config"], workload["traffic"]) == (
         "tpch-sf1-direct", "q6-closed1")

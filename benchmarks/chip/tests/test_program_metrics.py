@@ -4,6 +4,7 @@ a traced run reads them: a `jax.profiler` session is the program's only
 switch ("no SET"), the requests are the tiers' own calls, and every value is
 checked against the report's rows. None of the numbers is a measurement."""
 
+import contextlib
 import json
 import os
 import statistics
@@ -18,10 +19,16 @@ from datafusion_distributed_tpu.runtime import tracing
 from datafusion_distributed_tpu.sql.context import SessionContext
 
 # metric file -> the cells its BENCHMARK.json entry lists
+# (tier-1's tests/test_chip_bench_mesh.py pins both lists, and
+# `masked_filters`', to `direct-q1` alone, and a `benchmark` PR may touch no
+# test outside this directory: the cells PR 33 admitted read them by hand)
 PROGRAM_METRICS = {
     "prepare_ms": ["direct-q1"],
     "fetch_transfers": ["direct-q1"],
 }
+# the coordinator tier's readers (PR 33), all of `coord4-q1` alone
+COORDINATOR_METRICS = ("exchange_host_ms", "exchange_host_mb", "schedule_ms",
+                       "admission_wait_ms")
 REQUESTS = 3
 
 
@@ -29,13 +36,18 @@ def read(name: str, record: dict):
     return run.load_module("metrics", f"{name}.py").read(record)
 
 
-@pytest.fixture(scope="module")
-def ctx():
+def session(scale: float):
     suite = run.load_module("suites", "tpch", "suite.py")
-    tables = suite.load(0.01, 7, os.path.join(run.CACHE, "data"))
+    tables = suite.load(scale, 7, os.path.join(run.CACHE, "data"))
     ctx = SessionContext()
     for name, arrow in tables.items():
         ctx.register_arrow(name, arrow)
+    return ctx, suite
+
+
+@pytest.fixture(scope="module")
+def ctx():
+    ctx, suite = session(0.01)
     return ctx, suite.sql("q1")
 
 
@@ -86,7 +98,8 @@ def test_requests_from_before_the_window_are_left_out(direct_window):
     assert read("fetch_transfers", record) is not None
 
 
-@pytest.mark.parametrize("name", sorted(PROGRAM_METRICS))
+@pytest.mark.parametrize("name", sorted(PROGRAM_METRICS)
+                         + list(COORDINATOR_METRICS))
 def test_a_program_without_the_report_reads_as_nothing(name, direct_window,
                                                        monkeypatch):
     """The parent commit has no `layer_report`: the reader returns None
@@ -96,19 +109,75 @@ def test_a_program_without_the_report_reads_as_nothing(name, direct_window,
     assert read(name, record) is None
 
 
+@pytest.fixture(scope="module")
+def coordinator_window(tmp_path_factory):
+    """`coord4-q1`'s tier over SF0.05, the smallest scale at which the
+    planner splits q1 into stages with an exchange between them (at SF0.01
+    one task runs the whole plan)."""
+    ctx, suite = session(0.05)
+    config = run.read_json("configs", "tpch-sf1-coord4.json")
+    tier = run.load_module("tiers", "coord.py").Tier(
+        ctx, config["tier_args"], suite)
+    sql = suite.sql("q1")
+    try:
+        record = traced_window(
+            tmp_path_factory.mktemp("coord"), lambda: tier.run_traced(
+                sql, lambda name: contextlib.nullcontext()))
+    finally:
+        tier.close()
+    return record, tracing.layer_report()
+
+
+def test_the_coordinator_tiers_metrics_read_the_report(coordinator_window):
+    record, rows = coordinator_window
+    # the tier's `bench.parse` is a `ctx.sql` of the benchmark's own: a
+    # request of its own, with no coordinator in it, that every reader skips
+    served = [r for r in rows if "schedule" in r["self_s"]]
+    assert len(served) == REQUESTS and len(rows) == 2 * REQUESTS
+
+    def ms(kinds):
+        return statistics.median(
+            sum(r["self_s"].get(k, 0.0) for k in kinds) * 1e3 for r in served)
+
+    exchange = ("exchange", "transfer", "d2h", "regroup", "h2d")
+    assert {"exchange", "transfer", "h2d"} <= set(served[0]["self_s"])
+    assert 0 < read("exchange_host_ms", record) == pytest.approx(ms(exchange))
+    assert 0 < read("schedule_ms", record) == pytest.approx(ms(
+        ("query", "schedule", "stage", "task", "attempt", "dispatch",
+         "codec", "rpc")))
+    assert 0 < read("admission_wait_ms", record) == pytest.approx(
+        ms(("queued",)))
+    # the partial states q1's four tasks hand the final stage: 392 bytes
+    assert read("exchange_host_mb", record) == pytest.approx(
+        statistics.median(sum(r["counters"]["bytes"].get(k, 0)
+                              for k in ("d2h", "h2d")) for r in served) / 1e6)
+    assert 0 < read("exchange_host_mb", record) < 0.01
+    # none of them is the stage programs' time
+    assert read("schedule_ms", record) < statistics.median(
+        r["total_s"]["worker_execute"] for r in served) * 1e3
+
+
+@pytest.mark.parametrize("name", COORDINATOR_METRICS)
+def test_a_request_through_no_coordinator_reads_as_nothing(name,
+                                                           direct_window):
+    record, _rows = direct_window
+    assert read(name, record) is None
+
+
 def test_benchmark_json_lists_the_cells_that_can_report_them():
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"] for w in bench["workloads"]}
     entries = {m["name"]: m for m in bench["per_layer"]}
-    for name, workloads in PROGRAM_METRICS.items():
+    listed = dict(PROGRAM_METRICS,
+                  **dict.fromkeys(COORDINATOR_METRICS, ["coord4-q1"]))
+    for name, workloads in listed.items():
         module = run.load_module("metrics", f"{name}.py")
         assert module.MOVES == "query_p50_s"
         entry = entries[name]
         assert entry["workloads"] == workloads and set(workloads) <= cells
         assert (entry["unit"], entry["source"], entry["layer"]) == (
             module.UNIT, module.SOURCE, module.LAYER)
-    # the accepted metric keeps its entry as it was (no list): giving it
-    # one is an edit to an accepted entry, a `benchmark` PR's, and goes
-    # with the PR that admits `coord4-q1`, whose tier reports no count
+    # every tier reports its overflow retries (the coordinator tier's file
+    # reads `QueryHandle.retry_count` since PR 33), so the entry needs no list
     assert "workloads" not in entries["overflow_retries"]

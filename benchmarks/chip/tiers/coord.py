@@ -1,7 +1,7 @@
 """Coordinator tier as the reference deploys it: one ``ServingSession``
 over in-process workers that share the cell's chip, stages split at
 exchanges, exchanges through the host. Arguments: ``num_workers``,
-``num_tasks``. The session reports no overflow retries (None)."""
+``num_tasks``. Overflow retries are the handle's ``retry_count``."""
 
 from __future__ import annotations
 
@@ -19,8 +19,9 @@ class Tier:
                                       num_tasks=args["num_tasks"])
 
     def run(self, sql: str):
-        """The user's one call. -> (pandas frame, None)."""
-        return self.frame(self.session.submit(sql).result()), None
+        """The user's one call. -> (pandas frame, overflow retries)."""
+        handle = self.session.submit(sql)
+        return self.frame(handle.result()), handle.retry_count
 
     def run_traced(self, sql: str, span):
         """``submit`` parses and plans on the client's thread before it
@@ -34,7 +35,7 @@ class Tier:
             table = jax.block_until_ready(handle.result_table())
         with span("bench.fetch"):
             frame = self.frame(table_to_arrow(table))
-        return frame, None
+        return frame, handle.retry_count
 
     def close(self) -> None:
         self.session.close()
