@@ -1,4 +1,5 @@
-"""Deterministic TPC-H data generator (numpy, vectorized).
+"""Deterministic TPC-H data generator (numpy for the numbers, Arrow's string
+kernels for the text: no Python loop over rows).
 
 The reference generates benchmark data with external tools
 (`/root/reference/benchmarks/gen-tpch.sh` uses tpchgen-rs); data files are
@@ -15,6 +16,9 @@ part 200k, partsupp 800k, orders 1.5M, lineitem ~6M.
 """
 
 from __future__ import annotations
+
+import os
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -71,26 +75,110 @@ def _dates(rng, n, lo=_EPOCH_1992, hi=_EPOCH_1998_AUG2):
     return rng.integers(lo, hi + 1, n).astype(np.int32)
 
 
-def _comments(rng, n, max_words=8):
-    k = rng.integers(2, max_words + 1, n)
-    words = np.array(_COMMENT_WORDS, dtype=object)
-    # vectorized-ish: sample a matrix of word indices, join per row
-    idx = rng.integers(0, len(words), (n, max_words))
-    out = np.empty(n, dtype=object)
-    for i in range(n):
-        out[i] = " ".join(words[idx[i, : k[i]]])
-    return out
+# Text columns are built by Arrow's string kernels, a chunk of rows at a
+# time on a few threads (the kernels release the interpreter's lock): at SF10
+# `l_comment` alone is 60M strings, and a Python loop over rows cost 29 s a
+# SF unit. The chunks come back in order, so the result does not depend on
+# the threads.
+_TEXT_CHUNK = 1 << 18
+_TEXT_THREADS = min(8, os.cpu_count() or 1)
+
+
+def _text_column(n, make):
+    """-> pyarrow string column of ``n`` rows: ``make(lo, hi)`` -> the rows
+    ``[lo, hi)`` as a pyarrow string array, for each chunk."""
+    import pyarrow as pa
+
+    bounds = [(lo, min(lo + _TEXT_CHUNK, n)) for lo in range(0, n, _TEXT_CHUNK)]
+    if len(bounds) == 1:
+        return make(*bounds[0])
+    with ThreadPoolExecutor(_TEXT_THREADS) as pool:
+        chunks = list(pool.map(lambda b: make(*b), bounds))
+    return pa.chunked_array(chunks, pa.string())
+
+
+def _take_strings(vocab, codes):
+    """``vocab[codes]`` as a string column: one entry of a short word list
+    a row."""
+    import pyarrow as pa
+
+    words = pa.array(list(vocab), pa.string())
+    codes = np.asarray(codes)
+    return _text_column(
+        len(codes),
+        lambda lo, hi: words.take(pa.array(codes[lo:hi].astype(np.int32))))
+
+
+def _join_words(vocab, idx, counts=None, every=None, instead=None):
+    """Row i -> the words ``vocab[idx[i, :counts[i]]]`` joined by spaces
+    (the whole row of ``idx`` where ``counts`` is None; every count is at
+    least 2). Rows ``0, every, 2 * every, ...`` hold ``instead``: the
+    patterns q13 and q16 look for."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    words = pa.array(list(vocab), pa.string())
+    absent = pa.scalar(None, pa.string())
+
+    def make(lo, hi):
+        cols = []
+        for j in range(idx.shape[1]):
+            col = words.take(pa.array(np.ascontiguousarray(idx[lo:hi, j])))
+            if counts is not None and j >= 2:
+                col = pc.if_else(pa.array(counts[lo:hi] > j), col, absent)
+            cols.append(col)
+        out = pc.binary_join_element_wise(*cols, " ", null_handling="skip")
+        if every is not None:
+            out = pc.if_else(pa.array(np.arange(lo, hi) % every == 0),
+                             pa.scalar(instead, pa.string()), out)
+        return out
+
+    return _text_column(idx.shape[0], make)
+
+
+def _comments(rng, n, max_words=8, every=None, instead=None):
+    # the draws are the 64-bit ones the row loop made; they are kept as
+    # bytes, a quarter of a gigabyte for SF10's 60M line items and not two
+    k = rng.integers(2, max_words + 1, n).astype(np.uint8)
+    idx = rng.integers(0, len(_COMMENT_WORDS), (n, max_words)).astype(np.uint8)
+    return _join_words(_COMMENT_WORDS, idx, k, every, instead)
+
+
+def _numbered(prefix, numbers, width=9):
+    """``f"{prefix}{i:0{width}d}"`` for every i of ``numbers``."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    digits = pc.utf8_lpad(pc.cast(pa.array(numbers), pa.string()), width, "0")
+    return pc.binary_join_element_wise(pa.scalar(prefix), digits, "")
 
 
 def _phones(rng, n, nation_keys):
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
     a = nation_keys.astype(np.int64) + 10
     b = rng.integers(100, 1000, n)
     c = rng.integers(100, 1000, n)
     d = rng.integers(1000, 10000, n)
-    return np.array(
-        [f"{ai}-{bi}-{ci}-{di}" for ai, bi, ci, di in zip(a, b, c, d)],
-        dtype=object,
-    )
+    return pc.binary_join_element_wise(
+        *(pc.cast(pa.array(part), pa.string()) for part in (a, b, c, d)), "-")
+
+
+def tpch_cardinalities(sf: float) -> dict:
+    """-> {table: rows} at scale factor ``sf``, the spec's clause 4.2.5 (with
+    a floor, so that a tiny scale still joins); `lineitem` is drawn, one to
+    seven lines an order, and is not in it."""
+    n_part = max(int(200_000 * sf), 40)
+    return {
+        "region": 5,
+        "nation": 25,
+        "supplier": max(int(10_000 * sf), 10),
+        "customer": max(int(150_000 * sf), 30),
+        "part": n_part,
+        "partsupp": n_part * 4,
+        "orders": max(int(1_500_000 * sf), 150),
+    }
 
 
 def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
@@ -99,16 +187,15 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
 
     rng = np.random.default_rng(seed)
 
-    n_supp = max(int(10_000 * sf), 10)
-    n_cust = max(int(150_000 * sf), 30)
-    n_part = max(int(200_000 * sf), 40)
-    n_psupp = n_part * 4
-    n_ord = max(int(1_500_000 * sf), 150)
+    rows = tpch_cardinalities(sf)
+    n_supp, n_cust, n_part = rows["supplier"], rows["customer"], rows["part"]
+    n_psupp, n_ord = rows["partsupp"], rows["orders"]
+    n_clerks = max(n_supp // 10, 2)
 
     region = pa.table(
         {
             "r_regionkey": np.arange(5, dtype=np.int64),
-            "r_name": np.array(_REGIONS, dtype=object),
+            "r_name": pa.array(_REGIONS, pa.string()),
             "r_comment": _comments(rng, 5),
         }
     )
@@ -117,7 +204,7 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
     nation = pa.table(
         {
             "n_nationkey": n_nationkey,
-            "n_name": np.array([n for n, _ in _NATIONS], dtype=object),
+            "n_name": pa.array([n for n, _ in _NATIONS], pa.string()),
             "n_regionkey": np.array([r for _, r in _NATIONS], dtype=np.int64),
             "n_comment": _comments(rng, 25),
         }
@@ -127,38 +214,29 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
     supplier = pa.table(
         {
             "s_suppkey": np.arange(1, n_supp + 1, dtype=np.int64),
-            "s_name": np.array(
-                [f"Supplier#{i:09d}" for i in range(1, n_supp + 1)], dtype=object
-            ),
+            "s_name": _numbered("Supplier#", np.arange(1, n_supp + 1)),
             "s_address": _comments(rng, n_supp, 3),
             "s_nationkey": s_nation.astype(np.int64),
             "s_phone": _phones(rng, n_supp, s_nation),
             "s_acctbal": np.round(rng.uniform(-999.99, 9999.99, n_supp), 2),
-            "s_comment": _comments(rng, n_supp),
+            # TPC-H q16/q20 need "Customer Complaints" / special comments;
+            # seed a few
+            "s_comment": _comments(
+                rng, n_supp, every=19,
+                instead="wake Customer slyly Complaints haggle"),
         }
-    )
-    # TPC-H q16/q20 need "Customer Complaints" / special comments; seed a few
-    sup_comments = supplier.column("s_comment").to_pylist()
-    for i in range(0, n_supp, 19):
-        sup_comments[i] = "wake Customer slyly Complaints haggle"
-    supplier = supplier.set_column(
-        6, "s_comment", pa.array(sup_comments, type=pa.string())
     )
 
     c_nation = rng.integers(0, 25, n_cust)
     customer = pa.table(
         {
             "c_custkey": np.arange(1, n_cust + 1, dtype=np.int64),
-            "c_name": np.array(
-                [f"Customer#{i:09d}" for i in range(1, n_cust + 1)], dtype=object
-            ),
+            "c_name": _numbered("Customer#", np.arange(1, n_cust + 1)),
             "c_address": _comments(rng, n_cust, 3),
             "c_nationkey": c_nation.astype(np.int64),
             "c_phone": _phones(rng, n_cust, c_nation),
             "c_acctbal": np.round(rng.uniform(-999.99, 9999.99, n_cust), 2),
-            "c_mktsegment": np.array(_SEGMENTS, dtype=object)[
-                rng.integers(0, 5, n_cust)
-            ],
+            "c_mktsegment": _take_strings(_SEGMENTS, rng.integers(0, 5, n_cust)),
             "c_comment": _comments(rng, n_cust),
         }
     )
@@ -166,13 +244,9 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
     p1 = rng.integers(0, len(_TYPES_P1), n_part)
     p2 = rng.integers(0, len(_TYPES_P2), n_part)
     p3 = rng.integers(0, len(_TYPES_P3), n_part)
-    p_type = np.array(
-        [
-            f"{_TYPES_P1[a]} {_TYPES_P2[b]} {_TYPES_P3[c]}"
-            for a, b, c in zip(p1, p2, p3)
-        ],
-        dtype=object,
-    )
+    p_type = _take_strings(
+        [f"{a} {b} {c}" for a in _TYPES_P1 for b in _TYPES_P2 for c in _TYPES_P3],
+        (p1 * len(_TYPES_P2) + p2) * len(_TYPES_P3) + p3)
     brand_m = rng.integers(1, 6, n_part)
     brand_n = rng.integers(1, 6, n_part)
     c1 = rng.integers(0, len(_CONTAINERS_P1), n_part)
@@ -182,30 +256,19 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
             "p_partkey": np.arange(1, n_part + 1, dtype=np.int64),
             # spec shape: five space-joined color words (q9/q20 filter on
             # these; see _COLOR_WORDS)
-            "p_name": np.array(
-                [
-                    " ".join(row)
-                    for row in np.array(_COLOR_WORDS, dtype=object)[
-                        rng.integers(0, len(_COLOR_WORDS), (n_part, 5))
-                    ]
-                ],
-                dtype=object,
-            ),
-            "p_mfgr": np.array(
-                [f"Manufacturer#{m}" for m in brand_m], dtype=object
-            ),
-            "p_brand": np.array(
-                [f"Brand#{m}{n}" for m, n in zip(brand_m, brand_n)], dtype=object
-            ),
+            "p_name": _join_words(
+                _COLOR_WORDS,
+                rng.integers(0, len(_COLOR_WORDS), (n_part, 5)).astype(np.uint8)),
+            "p_mfgr": _take_strings(
+                [f"Manufacturer#{m}" for m in range(1, 6)], brand_m - 1),
+            "p_brand": _take_strings(
+                [f"Brand#{m}{n}" for m in range(1, 6) for n in range(1, 6)],
+                (brand_m - 1) * 5 + brand_n - 1),
             "p_type": p_type,
             "p_size": rng.integers(1, 51, n_part).astype(np.int32),
-            "p_container": np.array(
-                [
-                    f"{_CONTAINERS_P1[a]} {_CONTAINERS_P2[b]}"
-                    for a, b in zip(c1, c2)
-                ],
-                dtype=object,
-            ),
+            "p_container": _take_strings(
+                [f"{a} {b}" for a in _CONTAINERS_P1 for b in _CONTAINERS_P2],
+                c1 * len(_CONTAINERS_P2) + c2),
             "p_retailprice": np.round(
                 900 + (np.arange(1, n_part + 1) % 1000) / 10
                 + 100 * (np.arange(1, n_part + 1) % 10), 2
@@ -254,11 +317,8 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
     receiptdate = shipdate + rng.integers(1, 31, n_li)
     today = 10452  # 1998-08-14-ish cutoff for status
     returnflag = np.where(
-        receiptdate <= 10225,
-        np.where(rng.random(n_li) < 0.5, "R", "A"),
-        "N",
-    )
-    linestatus = np.where(shipdate > today - 61, "O", "F")
+        receiptdate <= 10225, np.where(rng.random(n_li) < 0.5, 0, 1), 2)
+    still_open = shipdate > today - 61
 
     lineitem = pa.table(
         {
@@ -270,8 +330,8 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
             "l_extendedprice": extprice,
             "l_discount": discount,
             "l_tax": tax,
-            "l_returnflag": pa.array(returnflag.tolist(), type=pa.string()),
-            "l_linestatus": pa.array(linestatus.tolist(), type=pa.string()),
+            "l_returnflag": _take_strings("RAN", returnflag),
+            "l_linestatus": _take_strings("FO", still_open),
             "l_shipdate": pa.array(
                 shipdate.astype("int32"), type=pa.int32()
             ).cast(pa.date32()),
@@ -281,12 +341,10 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
             "l_receiptdate": pa.array(
                 receiptdate.astype("int32"), type=pa.int32()
             ).cast(pa.date32()),
-            "l_shipinstruct": np.array(_INSTRUCTIONS, dtype=object)[
-                rng.integers(0, len(_INSTRUCTIONS), n_li)
-            ],
-            "l_shipmode": np.array(_SHIPMODES, dtype=object)[
-                rng.integers(0, len(_SHIPMODES), n_li)
-            ],
+            "l_shipinstruct": _take_strings(
+                _INSTRUCTIONS, rng.integers(0, len(_INSTRUCTIONS), n_li)),
+            "l_shipmode": _take_strings(
+                _SHIPMODES, rng.integers(0, len(_SHIPMODES), n_li)),
             "l_comment": _comments(rng, n_li, 4),
         }
     )
@@ -298,7 +356,7 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
         {
             "o": li_order,
             "rev": extprice * (1 + tax),
-            "open": linestatus == "O",
+            "open": still_open,
         }
     )
     per_order = li_df.groupby("o").agg(total=("rev", "sum"), any_open=("open", "any"),
@@ -307,31 +365,27 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> dict:
         np.arange(1, n_ord + 1)).fillna(0.0).to_numpy(), 2)
     any_open = per_order["any_open"].reindex(np.arange(1, n_ord + 1)).fillna(False).to_numpy()
     all_open = per_order["all_open"].reindex(np.arange(1, n_ord + 1)).fillna(False).to_numpy()
-    status = np.where(all_open, "O", np.where(any_open, "P", "F"))
+    status = np.where(all_open, 0, np.where(any_open, 1, 2))
 
     orders = pa.table(
         {
             "o_orderkey": np.arange(1, n_ord + 1, dtype=np.int64),
             "o_custkey": o_cust,
-            "o_orderstatus": pa.array(status.tolist(), type=pa.string()),
+            "o_orderstatus": _take_strings("OPF", status),
             "o_totalprice": totalprice,
             "o_orderdate": pa.array(o_date, type=pa.int32()).cast(pa.date32()),
-            "o_orderpriority": np.array(_PRIORITIES, dtype=object)[
-                rng.integers(0, 5, n_ord)
-            ],
-            "o_clerk": np.array(
-                [f"Clerk#{i:09d}" for i in rng.integers(1, max(n_supp // 10, 2), n_ord)],
-                dtype=object,
-            ),
+            "o_orderpriority": _take_strings(
+                _PRIORITIES, rng.integers(0, 5, n_ord)),
+            "o_clerk": _take_strings(
+                _numbered("Clerk#", np.arange(n_clerks)).to_pylist(),
+                rng.integers(1, n_clerks, n_ord)),
             "o_shippriority": np.zeros(n_ord, dtype=np.int32),
-            "o_comment": _comments(rng, n_ord),
+            # q13 needs 'special requests' patterns in o_comment
+            "o_comment": _comments(
+                rng, n_ord, every=17,
+                instead="blithely special foxes requests nag"),
         }
     )
-    # q13 needs 'special requests' patterns in o_comment
-    oc = orders.column("o_comment").to_pylist()
-    for i in range(0, n_ord, 17):
-        oc[i] = "blithely special foxes requests nag"
-    orders = orders.set_column(8, "o_comment", pa.array(oc, type=pa.string()))
 
     return {
         "region": region,

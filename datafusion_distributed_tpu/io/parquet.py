@@ -11,6 +11,7 @@ unified dictionary so device-side codes are comparable across files and tasks.
 from __future__ import annotations
 
 import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence
 
 import numpy as np
@@ -24,6 +25,10 @@ from datafusion_distributed_tpu.ops.table import (
     round_up_pow2,
 )
 from datafusion_distributed_tpu.schema import DataType, Field, Schema
+
+
+# a table's string columns are dictionary-encoded on this many threads
+_ENCODE_THREADS = min(8, os.cpu_count() or 1)
 
 
 def _arrow_type_to_dtype(t) -> DataType:
@@ -63,9 +68,11 @@ def schema_from_arrow(arrow_schema) -> Schema:
     )
 
 
-def _encode_sorted_dictionary(col, null_mask) -> tuple[np.ndarray, Dictionary]:
-    """Arrow string array -> (int32 codes, fresh SORTED Dictionary of its
-    non-null values); null rows get code 0 (their validity masks them).
+def _encode_sorted_dictionary(col, null_mask) -> tuple:
+    """Arrow string array -> (int32 codes, its distinct non-null values as a
+    SORTED Arrow array: a fresh dictionary's values); null rows get code 0
+    (their validity masks them). Arrow and numpy calls only, which release
+    the interpreter's lock: a table's columns are encoded side by side.
 
     Arrow hash-encodes the rows and only the DISTINCT values are sorted —
     bytewise on UTF-8, which is code-point order, the order numpy and
@@ -76,31 +83,43 @@ def _encode_sorted_dictionary(col, null_mask) -> tuple[np.ndarray, Dictionary]:
     enc = pc.dictionary_encode(col)
     distinct = enc.dictionary
     if len(distinct) == 0:
-        return (np.zeros(len(col), dtype=np.int32),
-                Dictionary(np.empty(0, dtype=object)))
+        return np.zeros(len(col), dtype=np.int32), distinct
     order = pc.sort_indices(distinct).to_numpy()
     rank = np.empty(len(order), dtype=np.int32)
     rank[order] = np.arange(len(order), dtype=np.int32)
-    idx = pc.fill_null(enc.indices, 0).to_numpy(zero_copy_only=False)
-    codes = np.where(null_mask, rank[idx], 0).astype(np.int32, copy=False)
-    values = distinct.take(order).to_numpy(zero_copy_only=False)
-    return codes, Dictionary(values)
+    # a column with no nulls pays for no mask pass: at 60M rows every pass
+    # is a quarter of a gigabyte of fresh host memory
+    has_nulls = enc.indices.null_count > 0
+    idx = pc.fill_null(enc.indices, 0) if has_nulls else enc.indices
+    codes = rank[idx.to_numpy(zero_copy_only=False)]
+    if has_nulls:
+        codes = np.where(null_mask, codes, 0).astype(np.int32, copy=False)
+    return codes, distinct.take(order)
+
+
+def _encode_under_span(tracer, parent, name, col, null_mask) -> tuple:
+    with tracer.span("encode", "encode", parent=parent, column=name) as span:
+        codes, values = _encode_sorted_dictionary(col, null_mask)
+        if tracer.active:
+            span.set(rows=len(col), distinct=len(values), bytes=col.nbytes)
+    return codes, values
 
 
 def arrow_to_host_columns(
     arrow_table,
     dictionaries: Optional[dict[str, Dictionary]] = None,
+    tracer=spans.NULL_TRACER,
 ) -> tuple[dict[str, np.ndarray], dict[str, np.ndarray], dict[str, Dictionary], Schema]:
     """Arrow table -> (host data arrays, validity arrays, dictionaries, schema).
 
     String columns become int32 code arrays. If ``dictionaries`` supplies a
     Dictionary for a column, codes are produced against it (values missing
     from the dictionary become -1/null); otherwise a fresh sorted dictionary
-    is built from the column's values.
+    is built from the column's values, under an ``encode`` span of
+    ``tracer`` (`SessionContext.register_arrow` hands its own in). Those
+    encodings run on threads beside the other columns' conversion: at
+    TPC-H SF10 `l_comment` alone hashes 60M strings for 11 s.
     """
-    import pyarrow as pa
-    import pyarrow.compute as pc
-
     schema = schema_from_arrow(arrow_table.schema)
     meta = arrow_table.schema.metadata or {}
     if b"dftpu_logical" in meta:
@@ -118,11 +137,32 @@ def arrow_to_host_columns(
     data: dict[str, np.ndarray] = {}
     validity: dict[str, np.ndarray] = {}
     dicts: dict[str, Dictionary] = {}
+    encoding: dict = {}  # column -> future of (codes, sorted values)
+    with ThreadPoolExecutor(_ENCODE_THREADS) as pool:
+        _convert_columns(arrow_table, schema, dictionaries, tracer, pool,
+                         data, validity, dicts, encoding)
+        # in the schema's order, so that dictionary ids are handed out in
+        # one order whatever the threads did; the sorted values stay in
+        # Arrow: `Dictionary.values` makes the Python strings when a reader
+        # first asks for them, not at registration
+        for name, future in encoding.items():
+            data[name], values = future.result()
+            dicts[name] = Dictionary.from_arrow(values)
+    return data, validity, dicts, schema
+
+
+def _convert_columns(arrow_table, schema, dictionaries, tracer, pool,
+                     data, validity, dicts, encoding) -> None:
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    parent = tracer.current_id()
     for f in schema.fields:
         col = arrow_table.column(f.name)
         if isinstance(col, pa.ChunkedArray):
             col = col.combine_chunks()
-        null_mask = np.asarray(col.is_valid())
+        null_mask = (np.asarray(col.is_valid()) if col.null_count
+                     else np.ones(len(col), dtype=np.bool_))
         if f.dtype == DataType.STRING:
             provided = dictionaries.get(f.name) if dictionaries else None
             if pa.types.is_dictionary(col.type) and provided is None:
@@ -158,9 +198,9 @@ def arrow_to_host_columns(
             if pa.types.is_dictionary(col.type):
                 col = col.cast(pa.string())
             if provided is None:
-                data[f.name], dicts[f.name] = _encode_sorted_dictionary(
-                    col, null_mask
-                )
+                encoding[f.name] = pool.submit(
+                    _encode_under_span, tracer, parent, f.name, col,
+                    null_mask)
                 validity[f.name] = null_mask
                 continue
             values = np.asarray(col.to_numpy(zero_copy_only=False), dtype=object)
@@ -187,10 +227,11 @@ def arrow_to_host_columns(
             data[f.name] = codes
             dicts[f.name] = d
         elif f.dtype == DataType.DATE32:
-            arr = col.cast(pa.date32()).to_numpy(zero_copy_only=False)
-            days = arr.astype("datetime64[D]").astype(np.int64).astype(np.int32)
-            days = np.where(null_mask, days, 0).astype(np.int32)
-            data[f.name] = days
+            # date32 is days since the epoch as int32 already
+            days = col.cast(pa.date32()).cast(pa.int32())
+            if days.null_count:
+                days = pc.fill_null(days, 0)
+            data[f.name] = days.to_numpy(zero_copy_only=False)
         elif f.dtype == DataType.BOOL:
             arr = col.to_numpy(zero_copy_only=False)
             arr = np.asarray(arr, dtype=object)
@@ -218,10 +259,9 @@ def arrow_to_host_columns(
                 data[f.name] = np.asarray(arr)
             else:
                 data[f.name] = np.asarray(arr).astype(
-                    f.dtype.logical_np_dtype
+                    f.dtype.logical_np_dtype, copy=False
                 )
         validity[f.name] = null_mask
-    return data, validity, dicts, schema
 
 
 def read_parquet(
@@ -245,13 +285,19 @@ def arrow_to_table(
     arrow_table,
     capacity: Optional[int] = None,
     dictionaries: Optional[dict[str, Dictionary]] = None,
+    tracer=spans.NULL_TRACER,
 ) -> Table:
-    data, validity, dicts, schema = arrow_to_host_columns(arrow_table, dictionaries)
+    data, validity, dicts, schema = arrow_to_host_columns(
+        arrow_table, dictionaries, tracer)
     n = arrow_table.num_rows
     cap = capacity or round_up_pow2(max(n, 1))
-    return Table.from_numpy(
-        data, schema, capacity=cap, validity=validity, dictionaries=dicts
-    )
+    with tracer.span("h2d", "h2d") as hsp:
+        table = Table.from_numpy(
+            data, schema, capacity=cap, validity=validity, dictionaries=dicts
+        )
+        if tracer.active:
+            hsp.set(bytes=spans.table_nbytes(table), rows=n, capacity=cap)
+    return table
 
 
 def table_to_arrow(table: Table, dictionary_gc: bool = False,
@@ -297,7 +343,7 @@ def _table_to_arrow(table: Table, dictionary_gc: bool,
             codes = vals.astype(np.int64)
             valid = np.ones(n, dtype=bool) if mask is None else ~mask
             live = valid & (codes >= 0) & (
-                codes < len(col.dictionary.values)
+                codes < len(col.dictionary)
             )
             used = np.unique(codes[live])
             subset = col.dictionary.values[used]

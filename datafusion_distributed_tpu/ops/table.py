@@ -113,16 +113,25 @@ class Dictionary:
     dictionaries cost nothing at trace time. Dictionaries are sorted at
     construction so that code order == lexicographic order; this lets ORDER
     BY / MIN / MAX / comparisons run directly on int32 codes on device.
+
+    The values are a 1-D numpy array of ``str`` objects, or (`from_arrow`)
+    a pyarrow string array that is kept as it is: a column of millions of
+    distinct strings (TPC-H's comments) then costs no Python object a value
+    at registration. ``values`` decodes it into the object array on first
+    use, and every reader is served from that as before; the length, the
+    sort check, ``code_of`` and ``decode`` read the Arrow array itself.
     """
 
-    __slots__ = ("dict_id", "values", "_index", "__weakref__")
+    __slots__ = ("dict_id", "_values", "_arrow", "_index", "__weakref__")
 
-    def __init__(self, values: np.ndarray):
-        values = np.asarray(values, dtype=object)
-        if values.ndim != 1:
-            raise ValueError("dictionary must be 1-D")
+    def __init__(self, values: Optional[np.ndarray], arrow=None):
+        if arrow is None:
+            values = np.asarray(values, dtype=object)
+            if values.ndim != 1:
+                raise ValueError("dictionary must be 1-D")
         self.dict_id = next(_DICT_COUNTER)
-        self.values = values
+        self._values: Optional[np.ndarray] = values
+        self._arrow = arrow
         self._index: Optional[dict] = None
         _DICT_REGISTRY[self.dict_id] = self
 
@@ -130,11 +139,26 @@ class Dictionary:
     def from_strings(values: Iterable[str]) -> "Dictionary":
         return Dictionary(np.asarray(list(values), dtype=object))
 
+    @staticmethod
+    def from_arrow(values) -> "Dictionary":
+        """Over a pyarrow string array with no nulls, not copied."""
+        return Dictionary(None, arrow=values)
+
+    @property
+    def values(self) -> np.ndarray:
+        if self._values is None:
+            self._values = self._arrow.to_numpy(zero_copy_only=False)
+        return self._values
+
     def __len__(self) -> int:
-        return len(self.values)
+        return len(self._arrow if self._values is None else self._values)
 
     def code_of(self, value: str) -> int:
         """Host-side lookup: string -> code, or -1 if absent."""
+        if self._values is None:
+            import pyarrow.compute as pc
+
+            return pc.index(self._arrow, value).as_py()
         return self.index().get(value, -1)
 
     def index(self) -> dict:
@@ -145,15 +169,27 @@ class Dictionary:
 
     def decode(self, codes: np.ndarray) -> np.ndarray:
         out = np.empty(len(codes), dtype=object)
-        valid = (codes >= 0) & (codes < len(self.values))
-        out[valid] = self.values[codes[valid]]
+        valid = (codes >= 0) & (codes < len(self))
+        if self._values is None:
+            # only the rows asked for become Python strings
+            import pyarrow as pa
+
+            out[valid] = self._arrow.take(
+                pa.array(codes[valid])).to_numpy(zero_copy_only=False)
+        else:
+            out[valid] = self._values[codes[valid]]
         out[~valid] = None
         return out
 
     def is_sorted(self) -> bool:
-        if len(self.values) < 2:
+        if len(self) < 2:
             return True
-        v = self.values.astype(str)
+        if self._values is None:
+            import pyarrow.compute as pc
+
+            return pc.all(pc.less_equal(self._arrow[:-1],
+                                        self._arrow[1:])).as_py()
+        v = self._values.astype(str)
         return bool(np.all(v[:-1] <= v[1:]))
 
     def __eq__(self, other: object) -> bool:
@@ -163,7 +199,7 @@ class Dictionary:
         return hash(("Dictionary", self.dict_id))
 
     def __repr__(self) -> str:
-        return f"Dictionary(id={self.dict_id}, n={len(self.values)})"
+        return f"Dictionary(id={self.dict_id}, n={len(self)})"
 
 
 def get_dictionary(dict_id: int) -> Dictionary:

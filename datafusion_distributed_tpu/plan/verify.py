@@ -535,10 +535,10 @@ def _dictionary_checks(node, p: _Pass) -> None:
         for t in node.tasks:
             for name, col in zip(t.names, t.columns):
                 if col.dictionary is not None:
-                    dicts[name] = len(col.dictionary.values)
+                    dicts[name] = len(col.dictionary)
     elif kind == "ParquetScanExec" and getattr(node, "dictionaries", None):
         dicts = {
-            name: len(d.values) for name, d in node.dictionaries.items()
+            name: len(d) for name, d in node.dictionaries.items()
         }
     for name, size in dicts.items():
         if size > _INT32_MAX:

@@ -1087,7 +1087,16 @@ class SessionContext:
         self.register_table(name, arrow_to_table(arrow, capacity=capacity))
 
     def register_arrow(self, name: str, arrow_table, capacity=None):
-        self.register_table(name, arrow_to_table(arrow_table, capacity))
+        # where a table's registration goes, for whoever waits for it: a
+        # `register` span, under it an `encode` a string column (host
+        # dictionary encoding) and the `h2d` of the padded columns
+        with tracing.trace_call("register", self.config.distributed_options,
+                                table=name,
+                                rows=arrow_table.num_rows) as call:
+            table = arrow_to_table(arrow_table, capacity,
+                                   tracer=call.tracer)
+            call.span.set(capacity=table.capacity)
+            self.register_table(name, table)
 
     def register_table(self, name: str, table: Table):
         self.catalog.register_table(name, table)

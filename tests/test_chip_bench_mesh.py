@@ -237,9 +237,10 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
     # the reducer's pattern misses the TPU's `%all_to_all.N` (PERF.md,
     # PR 29): the reader's file stands, its entry waits for a repair
     assert "collective_ms" not in metrics
-    # the accepted entries that list `direct-q1` stay as they were
+    # the accepted entries that list `direct-q1` still do; a `benchmark`
+    # PR may append cells to them (PERF.md section 7)
     for name in ("prepare_ms", "fetch_transfers", "masked_filters"):
-        assert metrics[name]["workloads"] == ["direct-q1"]
+        assert "direct-q1" in metrics[name]["workloads"]
     # every metric without a list is reported in the new cell too
     assert {m["name"] for m in run.cell_metrics("mesh4-q1", True)} >= {
         "execute_ms", "fetch_ms", "overflow_retries", "hbm_roofline_share",

@@ -162,6 +162,82 @@ def test_string_ingest_builds_sorted_dictionary(case):
             for c, ok in zip(data["s"], validity["s"])] == vals
 
 
+def _parents_encoding(col, null_mask):
+    """`io/parquet.py _encode_sorted_dictionary` as it stood before PR 34:
+    the sorted distinct values turned into a numpy array of `str`."""
+    import pyarrow.compute as pc
+
+    enc = pc.dictionary_encode(col)
+    order = pc.sort_indices(enc.dictionary).to_numpy()
+    rank = np.empty(len(order), dtype=np.int32)
+    rank[order] = np.arange(len(order), dtype=np.int32)
+    idx = pc.fill_null(enc.indices, 0).to_numpy(zero_copy_only=False)
+    codes = np.where(null_mask, rank[idx], 0).astype(np.int32, copy=False)
+    return codes, enc.dictionary.take(order).to_numpy(zero_copy_only=False)
+
+
+@pytest.mark.parametrize("kind", ["repeats", "nulls", "near_unique"])
+def test_string_ingest_keeps_the_dictionary_in_arrow(kind):
+    """The codes and the sorted order are the parent's; the dictionary's
+    values stay in Arrow through registration and through a fetch (a
+    column of millions of distinct comments costs no Python object a value,
+    only the rows a result decodes), and `Dictionary.values` still serves
+    every reader of the object array."""
+    import pyarrow as pa
+
+    from datafusion_distributed_tpu.io.parquet import (
+        arrow_to_table,
+        table_to_arrow,
+    )
+
+    rng = np.random.default_rng(11)
+    n = 3000
+    letters = np.array(list("abcXYZ é中~"))
+    if kind == "near_unique":
+        vals = ["".join(letters[rng.integers(0, len(letters), 12)])
+                for _ in range(n)]
+        vals[17] = vals[5]
+    else:
+        vals = ["".join(letters[rng.integers(0, len(letters), 2)])
+                for _ in range(n)]
+    if kind == "nulls":
+        for i in rng.integers(0, n, n // 3):
+            vals[i] = None
+    col = pa.array(vals, type=pa.string())
+    table = arrow_to_table(pa.table({"s": col, "i": np.arange(n)}))
+    column = table.columns[0]
+    d = column.dictionary
+    assert d._values is None  # nothing decoded at registration
+    want_codes, want_values = _parents_encoding(col, np.asarray(col.is_valid()))
+    np.testing.assert_array_equal(np.asarray(column.data[:n]), want_codes)
+    assert len(d) == len(want_values) and d.is_sorted()
+    assert repr(d) == f"Dictionary(id={d.dict_id}, n={len(want_values)})"
+    assert d.code_of(want_values[3]) == 3 and d.code_of("no such") == -1
+    # both fetches decode the rows back, and only the rows
+    assert table_to_arrow(table).column("s").to_pylist() == vals
+    frame = table.to_pandas()
+    assert [None if v != v or v is None else v for v in frame["s"]] == vals
+    decoded = d.decode(np.array([0, len(d) - 1, -1, len(d)]))
+    assert list(decoded) == [want_values[0], want_values[-1], None, None]
+    assert d._values is None
+    # the object array, made on first use, is the parent's
+    assert d.values.dtype == object and list(d.values) == list(want_values)
+    assert list(want_values) == sorted(set(v for v in vals if v is not None))
+    assert d.index()[want_values[-1]] == len(d) - 1
+    assert d.code_of(want_values[3]) == 3 and d.is_sorted()
+
+
+def test_dictionary_from_arrow_notices_an_unsorted_array():
+    import pyarrow as pa
+
+    from datafusion_distributed_tpu.ops.table import Dictionary
+
+    assert not Dictionary.from_arrow(pa.array(["b", "a"])).is_sorted()
+    assert Dictionary.from_arrow(pa.array(["a", "a", "b"])).is_sorted()
+    assert Dictionary.from_arrow(pa.array([], pa.string())).is_sorted()
+    assert len(Dictionary.from_arrow(pa.array([], pa.string())).values) == 0
+
+
 def test_gather_with_nonzero_pattern():
     t = make_simple_table(n=6, capacity=8)
 

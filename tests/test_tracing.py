@@ -931,6 +931,74 @@ def test_d2h_and_h2d_bytes_are_table_nbytes_of_what_crossed():
     }
 
 
+def test_register_arrow_spans_say_where_a_registration_went():
+    """Under `SET distributed.tracing` a table's registration is a request
+    of its own in the report: the `register` span, an `encode` a string
+    column (host dictionary encoding) and the `h2d` of the padded columns,
+    each with its counters. With tracing off, and for a wire decode
+    (`arrow_to_table` with no tracer, under an open trace), nothing."""
+    from datafusion_distributed_tpu.sql.context import SessionContext
+
+    rng = np.random.default_rng(9)
+    words = np.array(["ash", "birch", "cedar", None], dtype=object)
+    arrow = pa.table({
+        "k": np.arange(100), "v": rng.normal(size=100),
+        "s": pa.array(words[rng.integers(0, 4, 100)], pa.string()),
+        "u": pa.array([f"row {i}" for i in range(100)], pa.string()),
+    })
+    ctx = SessionContext()
+    DEFAULT_TRACE_STORE.clear()
+    ctx.register_arrow("untraced", arrow)
+    assert DEFAULT_TRACE_STORE.finished_traces() == []
+    assert layer_report() == []
+    ctx.sql("SET distributed.tracing = 'on'")
+    DEFAULT_TRACE_STORE.clear()
+    ctx.register_arrow("traced", arrow)
+    (trace,) = DEFAULT_TRACE_STORE.finished_traces()
+    spans = trace.span_list()
+    by_id = {s.span_id: s for s in spans}
+    root = trace.root_span()
+    assert (root.name, root.kind) == ("register", "register")
+    assert (root.attrs["table"], root.attrs["rows"],
+            root.attrs["capacity"]) == ("traced", 100, 128)
+    encodes = {s.attrs["column"]: s for s in spans if s.kind == "encode"}
+    assert sorted(encodes) == ["s", "u"]
+    assert (encodes["s"].attrs["rows"], encodes["s"].attrs["distinct"]) == (
+        100, 3)
+    assert (encodes["u"].attrs["rows"], encodes["u"].attrs["distinct"]) == (
+        100, 100)
+    assert encodes["u"].attrs["bytes"] == arrow.column("u").nbytes
+    (h2d,) = [s for s in spans if s.kind == "h2d"]
+    table = ctx.catalog.tables["traced"]
+    assert h2d.attrs == {"bytes": table_nbytes(table), "rows": 100,
+                         "capacity": 128}
+    for s in [h2d, *encodes.values()]:
+        assert by_id[s.parent_id] is root
+    _assert_monotonic_tree(trace)
+    (row,) = layer_report()
+    assert row["request"] == root.attrs["request"]
+    assert set(row["self_s"]) == {"register", "encode", "h2d"}
+    assert all(v >= 0 for v in row["self_s"].values())
+    # the columns are encoded side by side, on threads: each lies inside the
+    # registration, and the `h2d` begins when the last of them has ended
+    for s in encodes.values():
+        assert root.t0 <= s.t0 <= s.t1 <= h2d.t0 <= h2d.t1 <= root.t1
+    assert row["counters"]["bytes"] == {
+        "encode": arrow.column("s").nbytes + arrow.column("u").nbytes,
+        "h2d": table_nbytes(table)}
+    # the data the spans watched is the data a query reads
+    got = ctx.sql("select count(*) as n, count(s) as s from traced"
+                  ).to_pandas()
+    assert (int(got["n"][0]), int(got["s"][0])) == (
+        100, 100 - arrow.column("s").null_count)
+    # a decode of the wire under an open trace adds no span of these kinds
+    store = TraceStore()
+    with tracing.trace_call("query", {"tracing": "on"}, "r1",
+                            store=store) as call:
+        arrow_to_table(arrow)
+    assert [s.kind for s in call.tracer.trace.span_list()] == ["query"]
+
+
 def test_exchange_spans_split_d2h_and_regroup(tpch_ctx):
     """Inside an exchange the host's work is named: the pull of the
     producers' outputs (`d2h`) and the regroup, children of the
