@@ -22,6 +22,7 @@ from datafusion_distributed_tpu.ops.table import (
     Dictionary,
     Table,
     fetch_counters,
+    fetch_host_buffers,
     round_up_pow2,
 )
 from datafusion_distributed_tpu.schema import DataType, Field, Schema
@@ -304,6 +305,12 @@ def table_to_arrow(table: Table, dictionary_gc: bool = False,
                    logical_metadata: bool = False):
     """Device Table -> Arrow table (host materialization).
 
+    Both shapes get the table's buffers through `ops/table.py
+    fetch_host_buffers`: copied together in one round trip and cut to the
+    rows on the host, or in two for a large result. A traced fetch's span
+    says so: ``transfers`` the buffers copied, ``round_trips`` the times
+    the device was waited on.
+
     Default shape decodes strings to plain arrays (pandas-friendly). The
     WIRE shape (``dictionary_gc=True``) instead ships string columns as
     dictionary arrays whose dictionaries are garbage-collected to only the
@@ -318,26 +325,27 @@ def table_to_arrow(table: Table, dictionary_gc: bool = False,
     a consumer inferring dtypes from the wire would otherwise disagree
     with a same-worker bypass pull of the identical table."""
     if dictionary_gc:  # the wire shape is the codec's work, not a fetch
-        return _table_to_arrow(table, True, logical_metadata)
+        n, buffers, _round_trips = fetch_host_buffers(table)
+        return _table_to_arrow(table, n, buffers, True, logical_metadata)
     with spans.fetch_call(table) as call:
-        out = _table_to_arrow(table, False, logical_metadata)
+        n, buffers, round_trips = fetch_host_buffers(table)
+        out = _table_to_arrow(table, n, buffers, False, logical_metadata)
         if call.tracer.active:
-            call.span.set(**fetch_counters(table, out.num_rows))
+            call.span.set(**fetch_counters(table, n, round_trips))
         return out
 
 
-def _table_to_arrow(table: Table, dictionary_gc: bool,
-                    logical_metadata: bool):
+def _table_to_arrow(table: Table, n: int, buffers: list,
+                    dictionary_gc: bool, logical_metadata: bool):
+    """``buffers``: `fetch_host_buffers`' (data, validity) a column, cut
+    to the table's ``n`` rows."""
     import pyarrow as pa
 
-    n = int(table.num_rows)
     arrays = []
     names = []
-    for name, col in zip(table.names, table.columns):
-        vals = np.asarray(col.data[:n])
-        mask = None
-        if col.validity is not None:
-            mask = ~np.asarray(col.validity[:n])
+    for name, col, (vals, validity) in zip(table.names, table.columns,
+                                           buffers):
+        mask = None if validity is None else ~validity
         if col.dtype == DataType.STRING and dictionary_gc:
             assert col.dictionary is not None
             codes = vals.astype(np.int64)

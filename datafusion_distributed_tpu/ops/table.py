@@ -501,15 +501,14 @@ class Table:
 
     # -- host materialization (NOT jit-safe) --------------------------------
     def to_numpy(self, decode_strings: bool = True) -> dict[str, np.ndarray]:
-        n = int(self.num_rows)
+        _n, buffers, _round_trips = fetch_host_buffers(self)
         out: dict[str, np.ndarray] = {}
-        for name, col in zip(self.names, self.columns):
-            vals = np.asarray(col.data[:n])
+        for name, col, (vals, mask) in zip(self.names, self.columns,
+                                           buffers):
             if col.dtype == DataType.STRING and decode_strings:
                 assert col.dictionary is not None
                 vals = col.dictionary.decode(vals)
-            if col.validity is not None:
-                mask = np.asarray(col.validity[:n])
+            if mask is not None:
                 if vals.dtype == object:
                     vals = vals.copy()
                     vals[~mask] = None
@@ -525,20 +524,19 @@ class Table:
         import pandas as pd
 
         with spans.fetch_call(self) as call:
-            n = int(self.num_rows)
+            n, buffers, round_trips = fetch_host_buffers(self)
             cols = {}
-            for name, col in zip(self.names, self.columns):
-                vals = np.asarray(col.data[:n])
+            for name, col, (vals, mask) in zip(self.names, self.columns,
+                                               buffers):
                 if col.dtype == DataType.STRING:
                     assert col.dictionary is not None
                     vals = col.dictionary.decode(vals)
                 s = pd.Series(vals)
-                if col.validity is not None:
-                    mask = np.asarray(col.validity[:n])
+                if mask is not None:
                     s = s.where(pd.Series(mask), other=None)
                 cols[name] = s
             if call.tracer.active:
-                call.span.set(**fetch_counters(self, n))
+                call.span.set(**fetch_counters(self, n, round_trips))
             return pd.DataFrame(cols)
 
     def __repr__(self) -> str:
@@ -626,25 +624,115 @@ def to_device(arr) -> jnp.ndarray:
     return jnp.asarray(arr)
 
 
-def fetch_counters(table: "Table", rows: int) -> dict:
-    """What a result fetch (`to_pandas`, `table_to_arrow`) moved: one
-    device-to-host pull for every buffer of ``table`` that lives on a
-    device (the row count, then each column's data and validity, cut to
-    ``rows``), and their bytes."""
-    pulled = [table.num_rows]
-    for col in table.columns:
-        pulled.append(col.data)
-        if col.validity is not None:
-            pulled.append(col.validity)
-    on_device = [b for b in pulled if isinstance(b, jax.Array)]
+def fetch_counters(table: "Table", rows: int, round_trips: int) -> dict:
+    """What a result fetch (`to_pandas`, `table_to_arrow`) moved:
+    ``transfers``, the buffers copied from a device to the host (the row
+    count, then each column's data and validity: one each that lives on a
+    device), their ``bytes`` cut to ``rows``, and ``round_trips``, the
+    times the fetch blocked on the device for them
+    (`fetch_host_buffers`)."""
+    on_device = [b for b in _fetched_buffers(table)
+                 if isinstance(b, jax.Array)]
     return {
         "transfers": len(on_device),
+        "round_trips": round_trips,
         "bytes": sum(
             b.dtype.itemsize * (min(rows, b.shape[0]) if b.ndim else 1)
             for b in on_device
         ),
         "rows": rows,
     }
+
+
+def _fetched_buffers(table: "Table") -> list:
+    """The buffers a fetch brings to the host: the row count, then each
+    column's data and, where it has one, validity."""
+    buffers = [table.num_rows]
+    for col in table.columns:
+        buffers.append(col.data)
+        if col.validity is not None:
+            buffers.append(col.validity)
+    return buffers
+
+
+#: A result whose device buffers together hold at most this many bytes is
+#: copied to the host whole, in one round trip, and cut to its rows there;
+#: a larger one costs a second round trip (the row count first) so that
+#: only its live rows cross. On the v5e (my chip runs, PERF.md, PR 35), ms
+#: a fetch of fresh buffers holding 4 live rows, medians of 15-25: "loop"
+#: is what the three fetches did before (a slice and a pull a buffer),
+#: "whole" and "two-step" the two paths of `fetch_host_buffers`:
+#:   buffers' bytes  4 KiB  64 KiB  1 MiB  4 MiB  8 MiB  16 MiB  32 MiB  64 MiB
+#:   q1's shape: 10 columns, 20 buffers with the count
+#:     loop           24.1    23.7   23.7   22.9   24.3    24.8    25.3    24.6
+#:     whole          1.76    1.71   1.83   1.86   2.10    2.23    3.25    5.74
+#:     two-step       6.80    6.65   6.76   6.57   7.06    6.98    7.20    7.10
+#:   2 columns, 4 buffers
+#:     loop           4.03       -   3.97      -   3.98    4.09    4.19    4.73
+#:     whole          0.54       -   0.62      -   1.42    2.20    3.99    33.9
+#:     two-step       1.67       -   1.68      -   1.69    1.67    1.76    2.15
+#:   40 columns, 80 buffers
+#:     loop          102.3       -  100.1      -  105.6   103.5    97.8    97.5
+#:     whole          6.56       -   6.86      -   7.31    7.62    7.21    7.48
+#:     two-step       26.5       -   26.4      -   27.6    28.0    25.9    26.1
+#: A whole pull costs ~0.085 ms a buffer and its bytes (8 GB/s, until one
+#: buffer passes some 20 MB: then 2 GB/s; another machine read 22.4 for
+#: q1's shape at 64 MiB), a slice ~0.33 ms a buffer whatever its size, a
+#: round trip 0.4. So the two cross where the bytes outweigh the slices:
+#: near 10 MiB with 4 buffers, past 64 MiB with 20, nowhere measured with
+#: 80. 8 MiB is the largest power of two at which no shape loses (at 16
+#: MiB two columns pay 0.5 ms), and both paths beat the loop at every size.
+_FETCH_WHOLE_MAX_BYTES = 8 << 20
+
+
+def _one_replica(buf):
+    """A buffer replicated whole on several devices, as the first
+    addressable device's own single-device array, so that nothing run on
+    it (a slice) runs on every device and nothing gathers; a sharded, a
+    single-device or a host buffer as it is."""
+    if (isinstance(buf, jax.Array) and len(buf.sharding.device_set) > 1
+            and buf.is_fully_replicated):
+        return buf.addressable_data(0)
+    return buf
+
+
+def fetch_host_buffers(table: "Table"):
+    """The buffers of a concrete ``table`` on the host, for every host
+    materialization (`Table.to_numpy`, `Table.to_pandas`,
+    `io/parquet.py table_to_arrow`).
+    -> (rows, [(data, validity or None) a column], round_trips): numpy
+    arrays cut to ``rows`` (views: not to be written), and the times the
+    device was waited on.
+
+    Up to `_FETCH_WHOLE_MAX_BYTES` of device buffers, one `jax.device_get`
+    brings the row count and every whole buffer (every copy is started
+    before the first is waited on) and the cut to ``rows`` is a numpy
+    view: one round trip, and no program is run or compiled, whatever the
+    row count. Over it, the row count comes first, every buffer is sliced
+    to it on its device without a wait in between, and one `device_get`
+    brings the slices: two round trips. Host (numpy) buffers pass through:
+    a host-backed table waits on nothing."""
+    buffers = [_one_replica(b) for b in _fetched_buffers(table)]
+    on_device = [b for b in buffers if isinstance(b, jax.Array)]
+    round_trips = 1 if on_device else 0
+    if sum(b.nbytes for b in on_device) > _FETCH_WHOLE_MAX_BYTES:
+        if isinstance(buffers[0], jax.Array):
+            round_trips += 1
+        rows = int(buffers[0])
+        buffers[1:] = [
+            jax.lax.slice_in_dim(b, 0, rows)
+            if isinstance(b, jax.Array) and rows < b.shape[0] else b
+            for b in buffers[1:]
+        ]
+    num_rows, *host = jax.device_get(buffers)
+    rows = int(num_rows)
+    host = iter(host)
+    columns = [
+        (next(host)[:rows],
+         next(host)[:rows] if col.validity is not None else None)
+        for col in table.columns
+    ]
+    return rows, columns, round_trips
 
 
 def is_host_backed(table: Table) -> bool:

@@ -268,9 +268,11 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
       kinds add up to ``wall_s`` where no two tasks overlap;
     - ``total_s``: whole durations summed by span NAME (`worker_execute`
       is the stage programs, each ended by its flag fetch);
-    - ``counters``: ``bytes`` by span kind, ``transfers`` (device-to-host
-      pulls of the fetch), ``retries`` (overflow retries, stamped on the
-      root that succeeded), ``new_traces`` (programs traced afresh),
+    - ``counters``: ``bytes`` by span kind, ``transfers`` (buffers the
+      fetch copied from a device), ``round_trips`` (times the fetch
+      blocked on the device for them), ``retries`` (overflow retries,
+      stamped on the root that succeeded), ``new_traces`` (programs
+      traced afresh),
       and every name of `spans.PROGRAM_COUNTERS` (what its programs
       counted while they were traced), zero included.
 
@@ -281,7 +283,8 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
 
 def _layer_rows(traces) -> list:
     # the span attributes summed into a request's ``counters``
-    summed = ("transfers", "new_traces") + _spans.PROGRAM_COUNTERS
+    summed = (("transfers", "round_trips", "new_traces")
+              + _spans.PROGRAM_COUNTERS)
     rows: dict = {}
     for trace in traces:
         root = trace.root_span()
@@ -404,7 +407,8 @@ def render_profile(trace: QueryTrace, top_n: int = 10) -> str:
         c = row["counters"]
         lines.append(
             f"counters: bytes {_fmt_bytes(sum(c['bytes'].values()))}"
-            f"  transfers {c['transfers']}  retries {c['retries']}"
+            f"  transfers {c['transfers']}"
+            f"  round_trips {c['round_trips']}  retries {c['retries']}"
             f"  new_traces {c['new_traces']}"
         )
     return "\n".join(lines)

@@ -72,8 +72,10 @@ def test_the_sf10_cell_agrees_with_the_reference(trace, capsys,
         assert wanted == {
             "generate_s", "register_s", "warmup_s", "compiles_in_window",
             "parse_ms", "execute_ms", "fetch_ms", "overflow_retries",
-            "device_busy_ms", "hbm_roofline_share"}
+            "device_busy_ms", "hbm_roofline_share", "fetch_round_trips"}
         assert result["attempted"] == 3  # the mix's traced_queries
+        # the four rows' twenty buffers in one wait (PR 35)
+        assert result["metrics"]["fetch_round_trips"]["value"] == 1
         assert result["metrics"]["overflow_retries"]["value"] == 0
         assert result["metrics"]["generate_s"]["value"] > 0
         assert result["metrics"]["register_s"]["value"] > 0
@@ -166,10 +168,13 @@ def test_benchmark_json_lists_the_sf10_configuration_and_its_cell():
     assert run.read_json("traffic", "q1-closed1.json") == {
         "loop": "closed", "queries": ["q1"], "clients": 1,
         "traced_queries": 3}
-    # no list of an accepted metric names the new cell: a `benchmark` PR
-    # appends it (PERF.md section 7)
+    # no list of a metric accepted before the cell names it: a `benchmark`
+    # PR appends it (PERF.md section 7). `fetch_round_trips` came after it
+    # (PR 35), with every cell in its list from the start
     for kind in ("end_to_end", "per_layer"):
-        assert not any(CELL in m.get("workloads", []) for m in bench[kind])
+        assert [m["name"] for m in bench[kind]
+                if CELL in m.get("workloads", [])] == (
+            ["fetch_round_trips"] if kind == "per_layer" else [])
 
 
 def test_benchmark_json_agrees_with_the_files():

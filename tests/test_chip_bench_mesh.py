@@ -190,6 +190,29 @@ def test_an_operator_counter_reads_the_mesh_and_the_direct_tier(name, cell,
     assert read(name, {"queries": []}) is None
 
 
+def test_the_fetch_of_a_replicated_result_is_one_round_trip(cell, window):
+    """`metrics/fetch_round_trips.py` (PR 35) on the mesh and the direct
+    tier: the replicated result's twenty buffers (`fetch_transfers`' count,
+    as it was) come from one device's copies in one wait, through
+    `table_to_arrow` here and `Table.to_pandas` there. (Reads the window's
+    store: before the tests below, which clear it.)"""
+    for row in window["rows"]:
+        assert row["counters"]["round_trips"] == 1
+        assert row["counters"]["transfers"] == 20
+    assert read("fetch_round_trips", window["record"]) == 1
+    assert read("fetch_transfers", window["record"]) == 20
+    start = time.perf_counter()
+    cell["direct"].ctx.config.distributed_options["tracing"] = "on"
+    try:
+        run_traced(cell["direct"], cell["sql"])
+    finally:
+        cell["direct"].ctx.config.distributed_options.pop("tracing", None)
+    record = {"queries": [{"start": start}]}
+    assert read("fetch_round_trips", record) == 1
+    assert read("fetch_transfers", record) == 20
+    assert read("fetch_round_trips", {"queries": []}) is None
+
+
 @pytest.mark.parametrize("name", MESH_METRICS)
 def test_the_mesh_readers_find_nothing_on_another_tier(name, cell, window,
                                                        monkeypatch):
@@ -241,7 +264,14 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
     # PR may append cells to them (PERF.md section 7)
     for name in ("prepare_ms", "fetch_transfers", "masked_filters"):
         assert "direct-q1" in metrics[name]["workloads"]
+    # PR 35's reader of the fetch's waits lists every cell from the start
+    module = run.load_module("metrics", "fetch_round_trips.py")
+    assert metrics["fetch_round_trips"] == {
+        "name": "fetch_round_trips", "unit": module.UNIT, "better": "lower",
+        "source": module.SOURCE, "layer": metrics["fetch_ms"]["layer"],
+        "moves": "query_p50_s",
+        "workloads": [w["name"] for w in bench["workloads"]]}
     # every metric without a list is reported in the new cell too
     assert {m["name"] for m in run.cell_metrics("mesh4-q1", True)} >= {
         "execute_ms", "fetch_ms", "overflow_retries", "hbm_roofline_share",
-        *MESH_METRICS}
+        "fetch_round_trips", *MESH_METRICS}

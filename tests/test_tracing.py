@@ -692,6 +692,7 @@ def test_profiler_session_switches_tracing_on_and_off(tier, tpch_ctx,
     assert want <= set(spans), sorted(set(spans))
     (row,) = [r for r in layer_report() if r["request"] == request_id]
     assert row["counters"]["transfers"] > 0
+    assert row["counters"]["round_trips"] == 1  # the fetch joined its row
     # the second sink: the same spans in the profiler's own trace
     events = _host_events(tmp_path)
     names = {name for name, _, _ in events}
@@ -757,7 +758,14 @@ def test_direct_q1_span_tree_under_one_request(tpch_ctx):
     assert fetch.attrs["transfers"] == 1 + sum(
         1 + (c.validity is not None) for c in table.columns
     )
+    # and one round trip: the buffers are copied together, whole, and cut
+    # to the four rows on the host
+    assert fetch.attrs["round_trips"] == 1
+    assert row["counters"]["round_trips"] == 1
+    assert row["counters"]["transfers"] == fetch.attrs["transfers"]
     assert "layers (self time by span kind)" in render_profile(traces[1])
+    assert (f"transfers {fetch.attrs['transfers']}  round_trips 1  retries 0"
+            in render_profile(traces[2]))
     # a DataFrame that is never collected pins nothing in the store
     tpch_ctx.config.distributed_options["tracing"] = "on"
     try:
@@ -846,6 +854,7 @@ def test_untraced_cache_hit_after_a_traced_collect_leaves_no_trace(
                   if r["request"] == df.request_id]
         transfers = row["counters"]["transfers"]
         assert transfers > 0
+        assert row["counters"]["round_trips"] == 1
         hits0 = tpch_ctx.result_cache().stats()["hits"]
         DEFAULT_TRACE_STORE.clear()
         df2 = tpch_ctx.sql(sql)
