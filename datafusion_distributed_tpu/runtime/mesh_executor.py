@@ -12,13 +12,15 @@ runtime/coordinator.py, which shells out to this executor per mesh.)
 from __future__ import annotations
 
 import os
+import threading
+from dataclasses import dataclass
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from datafusion_distributed_tpu import precision
 from datafusion_distributed_tpu.ops.table import Table
@@ -74,6 +76,70 @@ def make_mesh(num_tasks: Optional[int] = None, devices=None) -> Mesh:
     return Mesh(np.asarray(devices[:n]), (AXIS,))
 
 
+def place_task_tables(per_task, mesh: Mesh) -> Table:
+    """``per_task[i]`` on the mesh's device i, as ONE Table whose buffers
+    are ``[tasks, ...]`` arrays laid out as a `shard_map` with ``in_specs``
+    of ``P(axis)`` asks: shard i is task i's buffer under a leading axis of
+    one, copied from where it lives straight to device i (a host buffer
+    enters the device there, once). Nothing is stacked on one device, and
+    `jit` has nothing to move before the program starts. The tables share
+    their shapes and their pytree structure."""
+    devices = list(mesh.devices.flat)
+    sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
+
+    def place(*buffers):
+        shards = [
+            jax.device_put(
+                # a row count may be a Python int (`partition_table`)
+                (b if hasattr(b, "ndim") else np.int32(b))[None], device
+            )
+            for b, device in zip(buffers, devices)
+        ]
+        return jax.make_array_from_single_device_arrays(
+            (len(devices),) + shards[0].shape[1:], sharding, shards
+        )
+
+    return jax.tree.map(place, *per_task)
+
+
+@dataclass(frozen=True)
+class _Placement:
+    """A leaf's inputs on a mesh's chips, kept on the leaf: it lives as
+    long as the cached plan does, and no longer."""
+
+    devices: tuple  # the mesh's devices, in task order
+    # what `load` returned, task by task. Held, not `id`s: an object that
+    # is alive cannot hand its identity to another.
+    sources: tuple
+    table: Table  # `place_task_tables(sources, mesh)`
+
+
+_PLACEMENT_ATTR = "_dftpu_mesh_placement"
+
+
+def _placed_on_mesh(leaf, mesh: Mesh):
+    """-> (the leaf's ``[tasks, ...]`` input on ``mesh``, whether an
+    earlier request placed it). A placement is reused while the leaf's
+    `load` hands back the SAME Table objects (tables are immutable; a
+    cached plan's `MemoryScanExec` does, a `ParquetScanExec` or a scan of
+    fresh exchange output does not) for the same devices; anything else
+    is placed anew, and the old placement dropped first."""
+    num_tasks = mesh.shape[AXIS]
+    sources = tuple(
+        leaf.load(DistributedTaskContext(i, num_tasks))
+        for i in range(num_tasks)
+    )
+    devices = tuple(mesh.devices.flat)
+    held = getattr(leaf, _PLACEMENT_ATTR, None)
+    if (held is not None and held.devices == devices
+            and all(a is b for a, b in zip(held.sources, sources))):
+        return held.table, True
+    setattr(leaf, _PLACEMENT_ATTR, None)
+    table = place_task_tables(sources, mesh)
+    setattr(leaf, _PLACEMENT_ATTR, _Placement(devices, sources, table))
+    return table, False
+
+
 def execute_on_mesh(
     plan: ExecutionPlan,
     mesh: Mesh,
@@ -95,45 +161,46 @@ def execute_on_mesh(
     params = prep.param_arrays()
     leaves = exec_target.collect(lambda n: not n.children())
 
-    # host phase: load every task's slice of every leaf, stack to [T, ...].
+    # host phase: every task's slice of every leaf on that task's chip,
+    # placed once and kept on the leaf (`_placed_on_mesh`).
     # POSITIONAL (leaf traversal order), not node-id keyed: node ids are
     # minted per plan object, and a dict keyed on them would change the
     # input pytree structure between fingerprint-equal plan copies.
-    leaf_ids = [leaf.node_id for leaf in leaves if hasattr(leaf, "load")]
-    stacked_inputs: list[Table] = []
+    scans = [leaf for leaf in leaves if hasattr(leaf, "load")]
+    leaf_ids = [leaf.node_id for leaf in scans]
     tr = tracing.current()
     traces_before = trace_count()
     with tr.span("mesh.stack_inputs", "mesh.stack_inputs") as ssp:
-        for leaf in leaves:
-            if not hasattr(leaf, "load"):
-                continue
-            per_task = [
-                leaf.load(DistributedTaskContext(i, num_tasks))
-                for i in range(num_tasks)
-            ]
-            stacked_inputs.append(
-                jax.tree.map(lambda *xs: jnp.stack(xs), *per_task)
-            )
+        placed = [_placed_on_mesh(leaf, mesh) for leaf in scans]
+        stacked_inputs = [table for table, _ in placed]
         if tr.active:
-            # every task's slice of every leaf, stacked on the first
-            # device before `shard_map` re-places it
-            ssp.set(bytes=sum(tracing.table_nbytes(t)
-                              for t in stacked_inputs),
-                    tasks=num_tasks)
+            # what THIS request put on the chips: 0 bytes where every
+            # leaf's placement was reused
+            ssp.set(bytes=sum(tracing.table_nbytes(table)
+                              for table, reused in placed if not reused),
+                    tasks=num_tasks,
+                    reused=sum(reused for _, reused in placed))
 
     trace = ProgramTrace()
 
     def axis_any(flags):
         return jax.lax.pmax(any_flag(flags).astype(jnp.int32), AXIS) > 0
 
+    # the plan a (re)trace reads is the CALLER's, handed over for the
+    # length of its call: an entry of the compile cache outlives the plan
+    # that made it (fingerprint-equal plans share it), and must not pin
+    # that plan's leaves, their task slices and their placement
+    calling = threading.local()
+
     def run(inputs_stacked, param_vecs):
+        target, target_leaf_ids = calling.plan
         # local view: leading task axis of size 1 -> squeeze
         local_inputs = {
             nid: jax.tree.map(lambda x: x[0], t)
-            for nid, t in zip(leaf_ids, inputs_stacked)
+            for nid, t in zip(target_leaf_ids, inputs_stacked)
         }
         out, cap_flags, prec_flags, metric_vals = trace_plan(
-            exec_target, DistributedTaskContext(0, num_tasks), local_inputs,
+            target, DistributedTaskContext(0, num_tasks), local_inputs,
             {"mesh_axis": AXIS, "num_tasks": num_tasks}, param_vecs, trace,
         )
         if metric_vals:
@@ -142,7 +209,10 @@ def execute_on_mesh(
             )[None, :]
         else:
             mvec = jnp.zeros((1, 0), dtype=_METRIC_DTYPE)
-        return out, axis_any(cap_flags), axis_any(prec_flags), mvec
+        # ONE flag vector, as `execute_plan` packs it: each scalar pulled
+        # alone is a round trip of its own
+        flags = jnp.stack([axis_any(cap_flags), axis_any(prec_flags)])
+        return out, flags, mvec
 
     # pytree-PREFIX specs (one spec per leaf Table / param vector, applied
     # to the whole subtree): a full spec tree would bake the creator's
@@ -169,23 +239,26 @@ def execute_on_mesh(
                 run,
                 mesh=mesh,
                 in_specs=(in_specs, param_specs),
-                out_specs=(P(), P(), P(), P(AXIS)),
+                out_specs=(P(), P(), P(AXIS)),
                 check_rep=False,
             )
         )
-        cached = (fn, trace)
+        cached = (fn, trace, calling)
         _MESH_COMPILE_CACHE[cache_key] = cached
-    fn, trace = cached
-    # ends on the fetch of the two flags, the sync this path already makes
+    fn, trace, calling = cached
+    # ends on the fetch of the flags, the sync this path already makes
     with tr.span("mesh.execute", "mesh.execute",
                  cache="hit" if cached_hit else "miss") as xsp:
-        out, any_overflow, any_precision, mvec = fn(stacked_inputs, params)
-        any_overflow = check_overflow and bool(any_overflow)
-        any_precision = bool(any_precision)
+        calling.plan = (exec_target, leaf_ids)
+        try:
+            out, flags, mvec = fn(stacked_inputs, params)
+        finally:
+            del calling.plan
+        flags = np.asarray(flags)  # one fetch for both checks
         if tr.active:
             xsp.set(new_traces=trace_count() - traces_before,
                     **trace.counters)
-    raise_flagged(trace, "mesh", any_overflow, any_precision)
+    raise_flagged(trace, "mesh", check_overflow and flags[0], flags[1])
     if metrics_store is not None:
         nodes = plan.collect(lambda _n: True)
         m = np.asarray(mvec)  # [T, M]

@@ -41,6 +41,9 @@ from datafusion_distributed_tpu.plan.physical import (
     raise_flagged,
     trace_plan,
 )
+from datafusion_distributed_tpu.runtime.mesh_executor import (
+    place_task_tables,
+)
 from datafusion_distributed_tpu.runtime.worker import (
     TaskData,
     TaskKey,
@@ -143,45 +146,19 @@ def execute_stage_span_on_mesh(
     on (plan_obj JSON hash, mesh devices, input shape/dict signature) at
     set_stage_plan and reuse the decoded plan object so jit's own cache
     hits."""
-    leaves = plan.collect(lambda n: not n.children())
-    stacked: dict = {}
-
-    def _stack(*xs):
-        # host-backed leaves (the zero-copy plane's peer pulls arrive as
-        # numpy views) stack ON THE HOST: their buffers then enter the
-        # device exactly once, at the device_put below, instead of paying
-        # a per-slice H2D for the stack plus a D2H for the re-stage
-        if all(isinstance(x, (np.ndarray, np.generic)) for x in xs):
-            return np.stack(xs)
-        return jnp.stack(xs)
-
-    for leaf in leaves:
-        if not hasattr(leaf, "load"):
-            continue
-        per_task = [
-            leaf.load(DistributedTaskContext(i, task_count))
-            for i in range(span_width)
-        ]
-        per_task = _repad_uniform(per_task)
-        stacked[leaf.node_id] = jax.tree.map(_stack, *per_task)
-
-    # Inputs pulled from OTHER meshes arrive committed to foreign devices
-    # (the in-process bypass shares buffers); stage them onto THIS mesh
-    # explicitly, through host — exactly the DCN hop a real multi-host
-    # deployment pays here. Host-resident (numpy) buffers skip the
-    # round-trip and enter via device_put directly (on CPU jax shares the
-    # buffer through the dlpack/Arrow-layout import — see
-    # ops.table.to_device for the column-level dlpack path).
-    from jax.sharding import NamedSharding
-
-    sharding = NamedSharding(mesh, P(AXIS))
+    # each task's slice goes straight to its device of THIS mesh
+    # (`place_task_tables`): an input pulled from another mesh arrives
+    # committed to foreign devices (the in-process bypass shares buffers),
+    # a host-backed one (the zero-copy plane's peer pulls arrive as numpy
+    # views) enters the device exactly once
     stacked = {
-        nid: jax.tree.map(
-            lambda x: jax.device_put(
-                x if isinstance(x, np.ndarray) else np.asarray(x), sharding
-            ), t
-        )
-        for nid, t in stacked.items()
+        leaf.node_id: place_task_tables(
+            _repad_uniform([
+                leaf.load(DistributedTaskContext(i, task_count))
+                for i in range(span_width)
+            ]), mesh)
+        for leaf in plan.collect(lambda n: not n.children())
+        if hasattr(leaf, "load")
     }
 
     trace = ProgramTrace()

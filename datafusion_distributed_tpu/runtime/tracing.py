@@ -312,7 +312,7 @@ def _layer_rows(traces) -> list:
                 row["total_s"].get(span.name, 0.0) + span.duration
             )
             nbytes = span.attrs.get("bytes")
-            if nbytes:
+            if nbytes is not None:  # a declared 0 is reported as 0
                 counters["bytes"][span.kind] = (
                     counters["bytes"].get(span.kind, 0) + int(nbytes)
                 )

@@ -1071,6 +1071,12 @@ class SessionContext:
 
     def _plan_cache_put(self, key, plan) -> None:
         with self._plans_lock:
+            # ``key[1]`` is the catalog generation (`DataFrame.
+            # _plan_cache_put`), which only grows: a plan of an older one
+            # is never asked for again, and goes with what its leaves pin
+            # on the devices (task slices, a mesh placement)
+            for stale in [k for k in self._plans if k[1] < key[1]]:
+                del self._plans[stale]
             while len(self._plans) >= self._PLAN_CACHE_ENTRIES:
                 self._plans.pop(next(iter(self._plans)))
             self._plans[key] = plan

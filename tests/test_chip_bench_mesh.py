@@ -14,6 +14,7 @@ import time
 
 import pytest
 
+from datafusion_distributed_tpu import spans
 from datafusion_distributed_tpu.runtime import tracing
 from datafusion_distributed_tpu.sql.context import SessionContext
 
@@ -134,7 +135,8 @@ def test_traced_mesh_q1_rows_carry_the_tiers_spans_and_counters(window):
     for row in rows:
         assert row["total_s"]["mesh.stack_inputs"] > 0
         assert row["total_s"]["mesh.execute"] > 0
-        assert row["counters"]["bytes"]["mesh.stack_inputs"] > 0
+        # the warm query placed the plan's inputs (PR 36): a declared 0
+        assert row["counters"]["bytes"]["mesh.stack_inputs"] == 0
         assert row["counters"]["masked_filters"] == 1
         assert row["counters"]["new_traces"] == 0
         assert row["counters"]["transfers"] > 0  # the fetch joined its row
@@ -149,7 +151,8 @@ def test_traced_mesh_q1_rows_carry_the_tiers_spans_and_counters(window):
         else:
             assert "masked_filters" not in span.attrs
         if span.kind == "mesh.stack_inputs":
-            assert span.attrs["tasks"] == 4
+            assert (span.attrs["tasks"], span.attrs["bytes"],
+                    span.attrs["reused"]) == (4, 0, 1)
 
 
 @pytest.mark.parametrize("name", MESH_METRICS)
@@ -163,10 +166,50 @@ def test_the_mesh_tiers_metric_files_read_the_report(name, window):
     }[name]
     value = read(name, record)
     assert value == pytest.approx(statistics.median(map(want, rows)))
-    assert value > 0
+    # nothing is placed in a warm window: 0.0, a number and not None
+    assert value == 0.0 if name == "stack_input_mb" else value > 0
     # requests from before the window, or no request at all: nothing
     assert read(name, {"queries": [{"start": time.perf_counter()}]}) is None
     assert read(name, {"queries": []}) is None
+
+
+def test_a_plans_first_request_places_bytes_and_later_ones_declare_zero(cell):
+    """What `mesh.stack_inputs` says since PR 36, by both of its readers: a
+    plan's first request puts its inputs on the chips (`bytes` > 0, nothing
+    reused), every later one reuses them (0 bytes, a small positive time).
+    A fresh `SessionContext` plans anew, so its first request is a first."""
+    ctx = SessionContext()
+    for name in cell["mesh"].ctx.catalog.tables:
+        ctx.register_table(name, cell["mesh"].ctx.catalog.tables[name])
+    tier = run.load_module("tiers", "mesh.py").Tier(
+        ctx, {"num_tasks": 4}, cell["suite"])
+    ctx.config.distributed_options["tracing"] = "on"
+    readings = []
+    for _ in range(2):
+        start = time.perf_counter()
+        frame, _ = run_traced(tier, cell["sql"])
+        cell["suite"].compare(frame, cell["expected"])
+        record = {"queries": [{"start": start, "end": time.perf_counter()}]}
+        readings.append((read("stack_input_mb", record),
+                         read("stack_inputs_ms", record)))
+    (first_mb, first_ms), (later_mb, later_ms) = readings
+    assert first_mb > 0 and later_mb == 0.0
+    assert first_ms > later_ms > 0
+
+
+def test_layer_rows_report_a_declared_zero_of_bytes():
+    """`_layer_rows` keeps a span's `bytes` of 0 (it dropped them before
+    PR 36, and `stack_input_mb` would have read None), and still leaves out
+    a span that declares none."""
+    store = spans.TraceStore()
+    with spans.trace_call("query", {"tracing": "on"}, store=store) as call:
+        with call.tracer.span("mesh.stack_inputs",
+                              "mesh.stack_inputs") as span:
+            span.set(bytes=0, tasks=4, reused=1)
+        with call.tracer.span("mesh.execute", "mesh.execute"):
+            pass
+    (row,) = tracing.layer_report(store)
+    assert row["counters"]["bytes"] == {"mesh.stack_inputs": 0}
 
 
 @pytest.mark.parametrize("name", ["direct_groupings", "dense_aggregates"])
