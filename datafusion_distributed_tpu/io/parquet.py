@@ -72,8 +72,9 @@ def schema_from_arrow(arrow_schema) -> Schema:
 def _encode_sorted_dictionary(col, null_mask) -> tuple:
     """Arrow string array -> (int32 codes, its distinct non-null values as a
     SORTED Arrow array: a fresh dictionary's values); null rows get code 0
-    (their validity masks them). Arrow and numpy calls only, which release
-    the interpreter's lock: a table's columns are encoded side by side.
+    (their validity masks them; ``null_mask`` is None where the column holds
+    no null). Arrow and numpy calls only, which release the interpreter's
+    lock: a table's columns are encoded side by side.
 
     Arrow hash-encodes the rows and only the DISTINCT values are sorted —
     bytewise on UTF-8, which is code-point order, the order numpy and
@@ -90,7 +91,7 @@ def _encode_sorted_dictionary(col, null_mask) -> tuple:
     rank[order] = np.arange(len(order), dtype=np.int32)
     # a column with no nulls pays for no mask pass: at 60M rows every pass
     # is a quarter of a gigabyte of fresh host memory
-    has_nulls = enc.indices.null_count > 0
+    has_nulls = null_mask is not None
     idx = pc.fill_null(enc.indices, 0) if has_nulls else enc.indices
     codes = rank[idx.to_numpy(zero_copy_only=False)]
     if has_nulls:
@@ -110,8 +111,25 @@ def arrow_to_host_columns(
     arrow_table,
     dictionaries: Optional[dict[str, Dictionary]] = None,
     tracer=spans.NULL_TRACER,
+    mask_nullable_fields: bool = False,
 ) -> tuple[dict[str, np.ndarray], dict[str, np.ndarray], dict[str, Dictionary], Schema]:
     """Arrow table -> (host data arrays, validity arrays, dictionaries, schema).
+
+    A column that holds no NULL gets NO validity array: its name is left out
+    of the validity dict, `Table.from_numpy` makes it with ``validity`` None,
+    and no all-true mask is built, uploaded or read by any operator. What
+    decides is the data itself, the Arrow column's ``null_count`` (and, for a
+    string column encoded against a provided dictionary, whether a value
+    missing from it came out NULL); a column with one NULL has its mask, and
+    so has one with no rows (a padding slot's code 0 must not read as a valid
+    index into a dictionary that may be empty). A caller whose output must
+    agree in tree structure with conversions of OTHER data (a task's file
+    group, a shipped slice: each task sees only its own rows) passes
+    ``mask_nullable_fields=True``: then a column has a mask, all-true or
+    not, where its Arrow field is nullable (or it holds a NULL against its
+    field's word), so the structure follows from the schema alone. A caller
+    that sees the whole table (`SessionContext.register_arrow`,
+    `register_parquet`) leaves it False.
 
     String columns become int32 code arrays. If ``dictionaries`` supplies a
     Dictionary for a column, codes are produced against it (values missing
@@ -149,6 +167,16 @@ def arrow_to_host_columns(
         for name, future in encoding.items():
             data[name], values = future.result()
             dicts[name] = Dictionary.from_arrow(values)
+    # `_convert_columns` leaves None for a column without NULLs: an all-true
+    # mask all the same where the schema decides and the field is nullable,
+    # or where the data does and there are no rows; else no entry at all
+    rows = arrow_table.num_rows
+    for f in schema.fields:
+        if validity[f.name] is None:
+            if f.nullable if mask_nullable_fields else rows == 0:
+                validity[f.name] = np.ones(rows, dtype=np.bool_)
+            else:
+                del validity[f.name]
     return data, validity, dicts, schema
 
 
@@ -162,8 +190,10 @@ def _convert_columns(arrow_table, schema, dictionaries, tracer, pool,
         col = arrow_table.column(f.name)
         if isinstance(col, pa.ChunkedArray):
             col = col.combine_chunks()
-        null_mask = (np.asarray(col.is_valid()) if col.null_count
-                     else np.ones(len(col), dtype=np.bool_))
+        # None: the column holds no NULL, and no pass over its rows is
+        # spent on saying so (at 60M rows an all-true mask is 60 MB of
+        # fresh host memory, and as much again on the device)
+        null_mask = np.asarray(col.is_valid()) if col.null_count else None
         if f.dtype == DataType.STRING:
             provided = dictionaries.get(f.name) if dictionaries else None
             if pa.types.is_dictionary(col.type) and provided is None:
@@ -183,15 +213,14 @@ def _convert_columns(arrow_table, schema, dictionaries, tracer, pool,
                 # (duplicate entries would give equal strings distinct
                 # codes, splitting their groups)
                 if len(sv) < 2 or bool(np.all(sv[:-1] < sv[1:])):
-                    import pyarrow.compute as pc
-
                     idx = col.indices
-                    if not null_mask.all():
+                    if null_mask is not None:
                         idx = pc.fill_null(idx, 0)
                     codes = np.asarray(
                         idx.to_numpy(zero_copy_only=False)
                     ).astype(np.int32)
-                    codes = np.where(null_mask, codes, 0).astype(np.int32)
+                    if null_mask is not None:
+                        codes = np.where(null_mask, codes, 0).astype(np.int32)
                     data[f.name] = codes
                     dicts[f.name] = Dictionary(dvals)
                     validity[f.name] = null_mask
@@ -205,7 +234,9 @@ def _convert_columns(arrow_table, schema, dictionaries, tracer, pool,
                 validity[f.name] = null_mask
                 continue
             values = np.asarray(col.to_numpy(zero_copy_only=False), dtype=object)
-            strs = np.where(null_mask, values, "").astype(str)
+            if null_mask is not None:
+                values = np.where(null_mask, values, "")
+            strs = values.astype(str)
             d = provided
             # Vectorized encode: a sorted dictionary admits searchsorted with
             # an equality check for absent values; unsorted (caller-provided)
@@ -223,8 +254,14 @@ def _convert_columns(arrow_table, schema, dictionaries, tracer, pool,
                 codes = np.asarray(
                     [idx.get(v, -1) for v in strs], dtype=np.int32
                 )
-            null_mask = null_mask & (codes >= 0)
-            codes = np.where(codes < 0, 0, codes)
+            # a value the dictionary lacks is a NULL of this column: the
+            # one case where a source without nulls still needs its mask
+            found = codes >= 0
+            if null_mask is not None:
+                null_mask = null_mask & found
+            elif not found.all():
+                null_mask = found
+            codes = np.where(found, codes, 0)
             data[f.name] = codes
             dicts[f.name] = d
         elif f.dtype == DataType.DATE32:
@@ -235,9 +272,10 @@ def _convert_columns(arrow_table, schema, dictionaries, tracer, pool,
             data[f.name] = days.to_numpy(zero_copy_only=False)
         elif f.dtype == DataType.BOOL:
             arr = col.to_numpy(zero_copy_only=False)
-            arr = np.asarray(arr, dtype=object)
-            arr = np.where(null_mask, arr, False)
-            data[f.name] = arr.astype(np.bool_)
+            if null_mask is not None:
+                arr = np.where(null_mask, np.asarray(arr, dtype=object),
+                               False)
+            data[f.name] = np.asarray(arr).astype(np.bool_)
         else:
             # Fill nulls inside Arrow first: pyarrow's to_numpy converts
             # nullable int columns through float64, which silently rounds
@@ -249,7 +287,7 @@ def _convert_columns(arrow_table, schema, dictionaries, tracer, pool,
                 col = col.cast(pa.int64())
             elif pa.types.is_decimal(col.type):
                 col = col.cast(pa.float64())
-            if not null_mask.all():
+            if null_mask is not None:
                 col = pc.fill_null(col, 0)
             arr = col.to_numpy(zero_copy_only=False)
             # Keep the column's native (wide) width here: Column.from_numpy
@@ -271,7 +309,10 @@ def read_parquet(
     capacity: Optional[int] = None,
     dictionaries: Optional[dict[str, Dictionary]] = None,
 ) -> Table:
-    """Read parquet file(s) into a single padded device Table."""
+    """Read parquet file(s) into a single padded device Table. The files
+    are one task's share of a scan, so a nullable field keeps its validity
+    array whether these files hold a NULL or not: every task of the stage
+    makes the same tree (`arrow_to_host_columns`, `Table.empty`)."""
     import pyarrow.parquet as pq
     import pyarrow as pa
 
@@ -279,7 +320,9 @@ def read_parquet(
         paths = [paths]
     tables = [pq.read_table(p, columns=list(columns) if columns else None) for p in paths]
     arrow_table = pa.concat_tables(tables) if len(tables) > 1 else tables[0]
-    return arrow_to_table(arrow_table, capacity=capacity, dictionaries=dictionaries)
+    return arrow_to_table(arrow_table, capacity=capacity,
+                          dictionaries=dictionaries,
+                          mask_nullable_fields=True)
 
 
 def arrow_to_table(
@@ -287,9 +330,13 @@ def arrow_to_table(
     capacity: Optional[int] = None,
     dictionaries: Optional[dict[str, Dictionary]] = None,
     tracer=spans.NULL_TRACER,
+    mask_nullable_fields: bool = False,
 ) -> Table:
+    """Arrow table -> padded device Table; which columns get a validity
+    array is `arrow_to_host_columns`' rule. The ``h2d`` span says what went
+    up: ``bytes``, and ``masks``, the validity arrays among them."""
     data, validity, dicts, schema = arrow_to_host_columns(
-        arrow_table, dictionaries, tracer)
+        arrow_table, dictionaries, tracer, mask_nullable_fields)
     n = arrow_table.num_rows
     cap = capacity or round_up_pow2(max(n, 1))
     with tracer.span("h2d", "h2d") as hsp:
@@ -297,7 +344,9 @@ def arrow_to_table(
             data, schema, capacity=cap, validity=validity, dictionaries=dicts
         )
         if tracer.active:
-            hsp.set(bytes=spans.table_nbytes(table), rows=n, capacity=cap)
+            hsp.set(bytes=spans.table_nbytes(table),
+                    masks=table.validity_masks,
+                    rows=n, capacity=cap)
     return table
 
 
@@ -376,7 +425,17 @@ def _table_to_arrow(table: Table, n: int, buffers: list,
         else:
             arrays.append(pa.array(vals, mask=mask))
         names.append(name)
-    out = pa.table(dict(zip(names, arrays)))
+    if dictionary_gc:
+        # the wire says which columns carry a validity array, as the
+        # field's ``nullable``: the receiver (`codec.decode_table`) makes a
+        # mask for exactly those, so a table crosses the wire, or comes
+        # back from a spill, with the tree structure it had
+        out = pa.Table.from_arrays(arrays, schema=pa.schema([
+            pa.field(name, arr.type, nullable=col.validity is not None)
+            for name, arr, col in zip(names, arrays, table.columns)
+        ]))
+    else:
+        out = pa.table(dict(zip(names, arrays)))
     if logical_metadata:
         import json as _json
 

@@ -28,9 +28,10 @@ Group keys may be any fixed-width device dtype (dict codes included); nulls
 group together (SQL semantics), tracked via a folded-in validity lane.
 
 Where every group key is dictionary-coded and the keys' whole domain fits
-the planned table (`_dictionary_bases`: q1's 3 x 2 codes, 4 x 3 with their
-NULLs, in 2048 slots), no table is built at all: a row's group id is the mixed-radix number of its
-codes (`direct_group_table`), one fused elementwise pass in place of the
+the planned table (`_dictionary_bases`: q1's 3 x 2 codes in 2048 slots; a key
+column that holds a NULL has a validity array and one digit more, 4 x 3 were
+both to), no table is built at all: a row's group id is the mixed-radix number
+of its codes (`direct_group_table`), one fused elementwise pass in place of the
 claim loop. Up to `_DENSE_MAX_DOMAIN` slots every reduction is then
 a dense pass over that domain, padded to the planned ``[num_slots]`` width,
 so the pack and the partial / final state schema keep their shapes; beyond
@@ -238,10 +239,13 @@ def _dictionary_bases(key_columns: Sequence[Column],
     """The one rule for addressing groups directly: every key column is
     dictionary-coded and the keys' domain, the product of each column's
     ``len(dictionary)`` (one more where it has a validity array: NULL is
-    a key of its own), is not empty and fits the ``num_slots`` the planner
-    gave the aggregate. -> each column's base, or None for the claim loop.
-    All of it is static at trace time (``Column.dictionary`` is pytree
-    aux). It rests on a live, valid row's code lying in
+    a key of its own; a column without NULLs is registered with none,
+    `io/parquet.py arrow_to_host_columns`, so TPC-H q1's domain is its
+    dictionaries' own 3 x 2), is not empty and fits the ``num_slots`` the
+    planner gave the aggregate. -> each column's base, or None for the
+    claim loop. All of it is static at trace time (``Column.dictionary``
+    and whether ``validity`` is None are pytree structure). It rests on a
+    live, valid row's code lying in
     ``[0, len(dictionary))``, which every producer of a dictionary column
     keeps (tests/test_aggregate.py pins it)."""
     if any(c.dictionary is None for c in key_columns):

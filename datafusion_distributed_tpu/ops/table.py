@@ -223,7 +223,9 @@ class Column:
     """A single padded device column.
 
     ``data``: [capacity] jnp array (dtype per DataType; strings = int32 codes)
-    ``validity``: [capacity] bool jnp array, or None when non-nullable.
+    ``validity``: [capacity] bool jnp array, or None: no row is NULL. None
+    reads as all true everywhere (`valid_mask`), costs no buffer and no
+    pass, and is what ingestion gives a column without NULLs.
     ``dtype``/``dictionary``: static metadata (pytree aux).
     """
 
@@ -251,6 +253,11 @@ class Column:
         validity: Optional[np.ndarray] = None,
         dictionary: Optional[Dictionary] = None,
     ) -> "Column":
+        """``values`` padded to ``capacity`` on the device. ``validity``
+        None makes a column WITHOUT a validity array ("no NULLs": what
+        `io/parquet.py arrow_to_host_columns` hands over for a column whose
+        source holds none); an array is padded with False and uploaded
+        beside the data, one byte a slot."""
         n = len(values)
         if n > capacity:
             raise ValueError(f"{n} values > capacity {capacity}")
@@ -424,6 +431,12 @@ class Table:
 
     def as_dict(self) -> dict[str, Column]:
         return dict(zip(self.names, self.columns))
+
+    @property
+    def validity_masks(self) -> int:
+        """How many columns carry a validity array (None reads as "no
+        NULLs" and costs no buffer: `Column.from_numpy`)."""
+        return sum(c.validity is not None for c in self.columns)
 
     def schema(self) -> Schema:
         return Schema(

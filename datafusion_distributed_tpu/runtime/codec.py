@@ -1320,14 +1320,18 @@ def decode_table(data, capacity: Optional[int] = None) -> Table:
     """Arrow IPC payload -> Table. Reads through `pa.BufferReader` (no
     BytesIO staging copy); ``capacity`` passes through to the column build,
     where a buffer that already satisfies it skips the zero-fill + pad copy
-    (Column.from_numpy fast path)."""
+    (Column.from_numpy fast path). A column gets a validity array where
+    its field is nullable, which is where the sender's column had one
+    (`table_to_arrow`'s wire shape): what a slice holds does not decide,
+    so every slice of one table decodes to one tree structure."""
     import pyarrow as pa
 
     from datafusion_distributed_tpu.io.parquet import arrow_to_table
 
     with pa.ipc.open_stream(pa.BufferReader(data)) as r:
         arrow = r.read_all()
-    return arrow_to_table(arrow, capacity=capacity)
+    return arrow_to_table(arrow, capacity=capacity,
+                          mask_nullable_fields=True)
 
 
 # ---------------------------------------------------------------------------
@@ -1423,4 +1427,5 @@ def decode_table_adaptive(blobs: dict, num_cols: int,
     arrow = parts[0]
     for t in parts[1:]:
         arrow = arrow.append_column(t.schema.field(0), t.column(0))
-    return arrow_to_table(arrow, capacity=capacity)
+    return arrow_to_table(arrow, capacity=capacity,
+                          mask_nullable_fields=True)

@@ -272,7 +272,8 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
       fetch copied from a device), ``round_trips`` (times the fetch
       blocked on the device for them), ``retries`` (overflow retries,
       stamped on the root that succeeded), ``new_traces`` (programs
-      traced afresh),
+      traced afresh), ``masks`` (validity arrays a registration uploaded:
+      one a column that holds a NULL),
       and every name of `spans.PROGRAM_COUNTERS` (what its programs
       counted while they were traced), zero included.
 
@@ -283,7 +284,7 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
 
 def _layer_rows(traces) -> list:
     # the span attributes summed into a request's ``counters``
-    summed = (("transfers", "round_trips", "new_traces")
+    summed = (("transfers", "round_trips", "new_traces", "masks")
               + _spans.PROGRAM_COUNTERS)
     rows: dict = {}
     for trace in traces:

@@ -1104,6 +1104,12 @@ class SessionContext:
             call.span.set(capacity=table.capacity)
             self.register_table(name, table)
 
+    def table_masks(self, name: str) -> int:
+        """The validity arrays the registered table holds on the device:
+        one a column that holds a NULL, none for a column without (what a
+        traced registration's ``h2d`` span reads as ``masks``)."""
+        return self.catalog.tables[name.lower()].validity_masks
+
     def register_table(self, name: str, table: Table):
         self.catalog.register_table(name, table)
         rc = self._result_cache

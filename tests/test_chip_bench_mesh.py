@@ -218,7 +218,7 @@ def test_an_operator_counter_reads_the_mesh_and_the_direct_tier(name, cell,
     """`metrics/direct_groupings.py` (PR 30) and `metrics/dense_aggregates.py`
     (PR 32) read `execute` and `mesh.execute` alike: q1's partial and final
     aggregates on the mesh, its one aggregate on the direct tier, all
-    grouped by dictionary codes, all reduced densely over a domain of 12,
+    grouped by dictionary codes, all reduced densely over a domain of 6,
     and counted on a program-cache hit. (Reads the window's store: before
     the tests below, which clear it.)"""
     assert [r["counters"][name] for r in window["rows"]] == [2] * REQUESTS
@@ -235,15 +235,17 @@ def test_an_operator_counter_reads_the_mesh_and_the_direct_tier(name, cell,
 
 def test_the_fetch_of_a_replicated_result_is_one_round_trip(cell, window):
     """`metrics/fetch_round_trips.py` (PR 35) on the mesh and the direct
-    tier: the replicated result's twenty buffers (`fetch_transfers`' count,
-    as it was) come from one device's copies in one wait, through
+    tier: the replicated result's eighteen buffers (`fetch_transfers`'
+    count: the row count, ten columns and seven masks; twenty until PR 37,
+    while q1's two group keys carried a mask of their own) come from one
+    device's copies in one wait, through
     `table_to_arrow` here and `Table.to_pandas` there. (Reads the window's
     store: before the tests below, which clear it.)"""
     for row in window["rows"]:
         assert row["counters"]["round_trips"] == 1
-        assert row["counters"]["transfers"] == 20
+        assert row["counters"]["transfers"] == 18
     assert read("fetch_round_trips", window["record"]) == 1
-    assert read("fetch_transfers", window["record"]) == 20
+    assert read("fetch_transfers", window["record"]) == 18
     start = time.perf_counter()
     cell["direct"].ctx.config.distributed_options["tracing"] = "on"
     try:
@@ -252,7 +254,7 @@ def test_the_fetch_of_a_replicated_result_is_one_round_trip(cell, window):
         cell["direct"].ctx.config.distributed_options.pop("tracing", None)
     record = {"queries": [{"start": start}]}
     assert read("fetch_round_trips", record) == 1
-    assert read("fetch_transfers", record) == 20
+    assert read("fetch_transfers", record) == 18
     assert read("fetch_round_trips", {"queries": []}) is None
 
 

@@ -145,7 +145,14 @@ def loop_to_arrow(table: Table, dictionary_gc: bool) -> pa.Table:
             arrays.append(arr.cast(pa.date32()))
         else:
             arrays.append(pa.array(vals, mask=mask))
-    return pa.table(dict(zip(table.names, arrays)))
+    out = pa.table(dict(zip(table.names, arrays)))
+    if dictionary_gc:
+        # since PR 37 the wire shape says which columns carry a validity
+        # array (the field's ``nullable``); the values are the loop's
+        out = pa.Table.from_arrays(out.columns, schema=pa.schema([
+            f.with_nullable(c.validity is not None)
+            for f, c in zip(out.schema, table.columns)]))
+    return out
 
 
 # ---- one comparison a shape ----------------------------------------------

@@ -413,14 +413,29 @@ def test_filter_compacts_for_every_consumer_but_an_aggregate(consumer,
     assert _compacting_filters(_filter_under(consumer)) == compact_ops
 
 
-@pytest.fixture(scope="module")
-def tpch_ctx():
+def _tpch_session():
     from datafusion_distributed_tpu.data.tpchgen import gen_tpch
     from datafusion_distributed_tpu.sql.context import SessionContext
 
     ctx = SessionContext()
     for name, arrow in gen_tpch(sf=0.002, seed=7).items():
         ctx.register_arrow(name, arrow)
+    return ctx
+
+
+@pytest.fixture(scope="module")
+def tpch_ctx():
+    return _tpch_session()
+
+
+@pytest.fixture(scope="module")
+def tpch_ctx_masked():
+    """`tpch_ctx`'s tables with an all-true validity array on every
+    column: the form every registered column had until PR 37."""
+    from mask_forms import force_all_true_masks
+
+    ctx = _tpch_session()
+    force_all_true_masks(ctx)
     return ctx
 
 
@@ -436,9 +451,11 @@ def _tpch_plan(ctx, query):
     ("q1", 1, {}),
     # Projection/Aggregate/Projection/Filter x4/Projection/scan
     ("q6", 4, {}),
-    # every filter feeds a join: the program at the parent commit
-    ("q3", 3, {"FilterExec.7": 24, "FilterExec.10": 24,
-               "FilterExec.13": 24}),
+    # every filter feeds a join: the program at the parent commit, less
+    # the two gathers of the mask each scan's columns no longer carry
+    # (PR 37: 24 with them)
+    ("q3", 3, {"FilterExec.7": 22, "FilterExec.10": 22,
+               "FilterExec.13": 22}),
 ])
 def test_tpch_programs_compact_only_under_joins(tpch_ctx, query, filters,
                                                 compact_ops):
@@ -464,16 +481,47 @@ def _scatter_scopes(text: str) -> list:
 
 
 # sha256 of q3's and q18's lowered text (no debug info) over `tpch_ctx`'s
-# tables at the parent commit (94fb45c): their aggregates keep the claim
-# loop and the scatters, so the program is the parent's, byte for byte
+# tables: their aggregates keep the claim loop and the scatters, so the
+# program is the parent's, byte for byte. "masked": every column with an
+# all-true validity array, as registration made them until PR 37; taken at
+# commit 94fb45c. "registered": the columns as registration makes them
+# since (no NULL, no mask); it is what the PARENT's operators (f86743d)
+# lower to over tables whose all-true masks were taken off by hand.
 _PARENT_LOWERED_SHA256 = {
-    "q3": "0346fb98c2af7a3b4fdebe6531f4a287a935d63f4e69b77e79c762a061a16b6c",
-    "q18": "7a234fcf9a7a97778e2f29bdf36695960c9b04bff52785996fd2be0f6d81b317",
+    ("q3", "masked"):
+        "0346fb98c2af7a3b4fdebe6531f4a287a935d63f4e69b77e79c762a061a16b6c",
+    ("q18", "masked"):
+        "7a234fcf9a7a97778e2f29bdf36695960c9b04bff52785996fd2be0f6d81b317",
+    ("q3", "registered"):
+        "dc689a6711755adae23dd2a5fc1f53870396c380008597d0896180384c78df86",
+    ("q18", "registered"):
+        "fe336009f0bc53779fd9625a5361e1efe697d4c1ccd08584538295136cf6bb1b",
 }
 
 
+def _plain_lowered_sha256(plan) -> str:
+    """sha256 of the plan's lowered text, no debug info."""
+    import hashlib
+
+    from datafusion_distributed_tpu.spans import NULL_TRACER
+
+    prog = phys._prepare_program(
+        plan, DistributedTaskContext(), None, False, None, None, NULL_TRACER)
+    plain = prog.fn.lower(prog.inputs, prog.params).as_text()
+    return hashlib.sha256(plain.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("query", ["q3", "q18"])
+def test_masked_inputs_lower_to_the_parents_text(tpch_ctx_masked, query):
+    """Columns that DO carry a mask (all true here, as every column did
+    until PR 37) reach the operators they reached: q3's and q18's lowered
+    text is the parent's, byte for byte."""
+    assert _plain_lowered_sha256(_tpch_plan(tpch_ctx_masked, query)) == (
+        _PARENT_LOWERED_SHA256[query, "masked"])
+
+
 @pytest.mark.parametrize("query,grouping", [
-    # l_returnflag x l_linestatus: dictionary codes, (3+1) x (2+1) <= 2048
+    # l_returnflag x l_linestatus: dictionary codes, 3 x 2 <= 2048
     ("q1", "agg.direct"),
     # integer and date keys: the claim loop, as at the parent commit
     ("q3", "agg.claim"),
@@ -486,14 +534,10 @@ def test_tpch_programs_claim_only_without_dictionary_keys(tpch_ctx, query,
     """q1's group ids are arithmetic on its keys' dictionary codes: no op
     under ``agg.claim`` and no `while` in its lowered program. Keys
     without a dictionary still build the group table by claim rounds.
-    The reductions follow: over q1's domain of 12 and q6's of one they are
+    The reductions follow: over q1's domain of 6 and q6's of one they are
     dense passes, no `stablehlo.scatter` under ``agg.reduce.*`` or
     ``agg.global``; q3 and q18 scatter into their 2Mi-slot tables as at the
     parent commit, their whole lowered text unchanged."""
-    import hashlib
-
-    from datafusion_distributed_tpu.spans import NULL_TRACER
-
     plan = _tpch_plan(tpch_ctx, query)
     text = _lowered(plan)
     for scope in ("agg.direct", "agg.claim"):
@@ -504,12 +548,8 @@ def test_tpch_programs_claim_only_without_dictionary_keys(tpch_ctx, query,
                 if "/agg.reduce." in name or "/agg.global" in name]
     if grouping == "agg.claim":
         assert reducing
-        prog = phys._prepare_program(
-            plan, DistributedTaskContext(), None, False, None, None,
-            NULL_TRACER)
-        plain = prog.fn.lower(prog.inputs, prog.params).as_text()
-        assert hashlib.sha256(plain.encode()).hexdigest() == (
-            _PARENT_LOWERED_SHA256[query])
+        assert _plain_lowered_sha256(plan) == (
+            _PARENT_LOWERED_SHA256[query, "registered"])
     else:
         assert reducing == []
 
@@ -545,10 +585,14 @@ def test_masked_filter_reports_the_kept_rows():
 
 
 # the fingerprints of `_aggregate_over(*_masked_case_input("agg_proj_filter",
-# some), "single")` at the parent commit (ff446a1): as they are, and as the
-# program cache keys them (literals hoisted)
-_MASKED_CASE_FINGERPRINT = "d97adc7c137ba9b5a77aede26c5f7c7d"
-_MASKED_CASE_HOISTED_FINGERPRINT = "c181cd94e922ee1b19c9e7c723166ae1"
+# some), "single")`: as they are, and as the program cache keys them
+# (literals hoisted). Taken at commit f86743d (the tree before PR 37) with
+# the all-true mask of the scan's key ``k`` taken off by hand: a
+# fingerprint covers the scan's schema, and since PR 37 a column without
+# NULLs has no mask and is not nullable there (d97adc7c137ba9b5a77aede26c5f7c7d
+# and c181cd94e922ee1b19c9e7c723166ae1 with the mask, at ff446a1).
+_MASKED_CASE_FINGERPRINT = "7b83aba7f5c3a0328cefbc808d62a32e"
+_MASKED_CASE_HOISTED_FINGERPRINT = "a418541c6e040de6bdf625fd8aea811b"
 
 
 def test_masked_path_adds_nothing_to_fingerprint_or_codec():

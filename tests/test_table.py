@@ -130,10 +130,12 @@ def test_parquet_roundtrip(tmp_path):
     assert back.column("val").to_pylist()[1] is None
 
 
-@pytest.mark.parametrize("case", ["mixed", "all_null", "empty", "chunked"])
+@pytest.mark.parametrize("case", ["mixed", "all_null", "empty", "chunked",
+                                  "no_nulls"])
 def test_string_ingest_builds_sorted_dictionary(case):
     """Arrow strings -> (codes, sorted dictionary): held to the plain
-    reference (sorted set of the non-null values, code = position)."""
+    reference (sorted set of the non-null values, code = position). A
+    column without a NULL comes with no validity array at all (PR 37)."""
     import pyarrow as pa
 
     from datafusion_distributed_tpu.io.parquet import arrow_to_host_columns
@@ -147,7 +149,7 @@ def test_string_ingest_builds_sorted_dictionary(case):
         vals = []
     else:
         vals = [pool[i] for i in rng.integers(0, len(pool), 400)]
-        for i in rng.integers(0, 400, 40):
+        for i in rng.integers(0, 400, 0 if case == "no_nulls" else 40):
             vals[i] = None
     col = pa.array(vals, type=pa.string())
     if case == "chunked":
@@ -157,9 +159,11 @@ def test_string_ingest_builds_sorted_dictionary(case):
     assert list(dicts["s"].values) == expected
     assert dicts["s"].is_sorted()
     assert data["s"].dtype == np.int32
-    assert list(validity["s"]) == [v is not None for v in vals]
+    assert ("s" in validity) == (case != "no_nulls")
+    valid = validity.get("s", np.ones(len(vals), dtype=bool))
+    assert list(valid) == [v is not None for v in vals]
     assert [expected[c] if ok else None
-            for c, ok in zip(data["s"], validity["s"])] == vals
+            for c, ok in zip(data["s"], valid)] == vals
 
 
 def _parents_encoding(col, null_mask):

@@ -78,13 +78,17 @@ def _shape(sharding, dtype, *dims):
 
 
 def _table(columns: dict, capacity: int, sharding,
-           lead: tuple = ()) -> Table:
-    """A Table of shapes (no arrays: a described device holds none), every
-    column nullable as arrow ingestion makes them. ``lead``: extra leading
-    axes (the mesh tier stacks per-task slices)."""
+           lead: tuple = (), masked: bool = True) -> Table:
+    """A Table of shapes (no arrays: a described device holds none).
+    ``masked``: every column with a validity array, as a column that holds
+    a NULL has (and as arrow ingestion made every column until PR 37);
+    False: none, as registration makes a column without NULLs (TPC-H's
+    all are). ``lead``: extra leading axes (the mesh tier stacks per-task
+    slices)."""
     cols = tuple(
         Column(_shape(sharding, np.dtype(dt.np_dtype), *lead, capacity),
-               _shape(sharding, jnp.bool_, *lead, capacity), dt)
+               _shape(sharding, jnp.bool_, *lead, capacity) if masked
+               else None, dt)
         for dt in columns.values()
     )
     return Table(tuple(columns), cols, _shape(sharding, jnp.int32, *lead))
@@ -136,27 +140,50 @@ def test_claim_loop_group_by_compiles(one_chip, query):
              scopes=("agg.claim", "agg.reduce.sum", "table.gather"))
 
 
-def _q1_with_dictionaries(one_chip, sizes):
-    """q1's table at 8Mi rows, its two keys dictionary-coded with
-    ``sizes`` values (and a validity array each, as arrow ingestion
-    leaves them: a domain of ``(sizes[0] + 1) * (sizes[1] + 1)``)."""
+def _q1_with_dictionaries(one_chip, sizes, rows=8 * MI, masked=False):
+    """q1's table at ``rows`` slots (8Mi: SF1; 64Mi: SF10), its two keys
+    dictionary-coded with ``sizes`` values. As registration makes them
+    since PR 37, no column carries a validity array (TPC-H holds no NULL):
+    a domain of ``sizes[0] * sizes[1]``; ``masked``: one each, as a column
+    with a NULL has, and a domain of ``(sizes[0] + 1) * (sizes[1] + 1)``."""
     from datafusion_distributed_tpu.ops.table import Dictionary
 
     columns = GROUP_BY_CASES["q1"][0]
     dictionaries = {
         f"g{i}": Dictionary.from_strings([f"{v:05d}" for v in range(size)])
         for i, size in enumerate(sizes)}
-    t = _table(columns, 8 * MI, one_chip)
+    t = _table(columns, rows, one_chip, masked=masked)
     return Table(t.names, tuple(
         Column(c.data, c.validity, c.dtype, dictionaries.get(name))
         for name, c in zip(t.names, t.columns)), t.num_rows)
 
 
-def test_direct_group_by_compiles(one_chip):
+def _slot_reductions(text: str) -> list:
+    """The result types of the optimized program's reductions into a
+    small vector (one a dense pass by slot), as ``s32[6]``."""
+    import re
+
+    return re.findall(r"= (\w+\[\d+\])\{[^}]*\} reduce\(", text)
+
+
+@pytest.mark.parametrize("rows,masked,domain,count_passes", [
+    # SF1 and SF10 as they are registered: 3 x 2 slots, and one count
+    # pass for the eight aggregates (every ``valid`` is the live mask)
+    (8 * MI, False, 6, 1),
+    (64 * MI, False, 6, 1),
+    # a mask a column (columns that hold NULLs): a NULL digit a key, and a
+    # count pass an aggregate input beside `count(*)`'s
+    (8 * MI, True, 12, 5),
+], ids=["sf1", "sf10", "sf1_masked"])
+def test_direct_group_by_compiles(one_chip, rows, masked, domain,
+                                  count_passes):
     """q1 as it runs since PR 30: its two keys carry dictionaries of 3 and
-    2 values (and a validity array each), so the group ids are arithmetic
-    on the codes, (3+1) x (2+1) = 12 slots of the 2048 can be used, and
-    the chip's program holds no `while` at all."""
+    2 values, so the group ids are arithmetic on the codes and the chip's
+    program holds no `while` at all. Since PR 37 the registered columns
+    carry no validity array: 3 x 2 = 6 slots of the 2048 can be used (12
+    with a NULL digit a key), and the per-slot counts are ONE reduction.
+    At SF10's 64Mi slots the described v5e accepts the program and it
+    fits the chip's 16 GB beside nothing else (`_compile`)."""
     _, keys, aggs, slots, out_capacity = GROUP_BY_CASES["q1"]
     direct: list = []
 
@@ -164,25 +191,32 @@ def test_direct_group_by_compiles(one_chip):
         return hash_aggregate(t, keys, aggs, slots, "single",
                               out_capacity=out_capacity, direct=direct)
 
-    compiled = _compile(kernel, _q1_with_dictionaries(one_chip, (3, 2)),
-                        scopes=("agg.direct", "agg.reduce.sum",
-                                "table.gather"))
-    assert direct == [12]
-    assert " while(" not in compiled.as_text()
-    assert "/agg.claim/" not in compiled.as_text()
+    compiled = _compile(
+        kernel, _q1_with_dictionaries(one_chip, (3, 2), rows, masked),
+        scopes=("agg.direct", "agg.reduce.sum", "table.gather"))
+    assert direct == [domain]
+    text = compiled.as_text()
+    assert " while(" not in text
+    assert "/agg.claim/" not in text
+    reduced = _slot_reductions(text)
+    assert reduced.count(f"s32[{domain}]") == count_passes, reduced
+    # four sums; the three averages share theirs
+    assert reduced.count(f"f32[{domain}]") == 4
 
 
-@pytest.mark.parametrize("domain", ["q1", "the_cut"])
+@pytest.mark.parametrize("domain", ["q1", "q1_masked", "the_cut"])
 def test_dense_reduction_compiles(one_chip, domain):
     """q1's reductions as they run since PR 32, at 8Mi rows over its domain
-    of 12 and over a domain at the cut (`_DENSE_MAX_DOMAIN`): dense
-    masked passes. The chip's compiler accepts them, no scatter is left under
-    ``agg.reduce.*`` (only the pack's, over the slots), and no
-    ``[domain, rows]`` operand is materialised: the temporaries stay within
-    a quarter of what this compiler read in PR 32, 136.4 MB at 12 slots and
-    172.4 MB at the cut, where one ``f32[12, 8Mi]`` operand alone is 403 MB.
-    (They are NOT under the scatter form's 71 MB, as ISSUE 32 asked: sibling
-    reductions share one pass, so their inputs are live together.)"""
+    of 6 (12 where its columns carry masks) and over a domain at the cut
+    (`_DENSE_MAX_DOMAIN`): dense masked passes. The chip's compiler accepts
+    them, no scatter is left under ``agg.reduce.*`` (only the pack's, over
+    the slots), and no ``[domain, rows]`` operand is materialised: the
+    temporaries stay within a quarter of what this compiler read: with a
+    mask a column 136.4 MB at 12 slots (PR 32; 172.4 MB at the cut),
+    without (PR 37) 67.6 MB at 6 slots and 135.7 MB at the cut, where one
+    ``f32[12, 8Mi]`` operand alone is 403 MB. (Maskless, q1's are under
+    the scatter form's 71 MB, as ISSUE 32 asked; with masks they are not:
+    sibling reductions share one pass, so their inputs are live together.)"""
     import re
 
     from datafusion_distributed_tpu.ops import aggregate
@@ -191,8 +225,8 @@ def test_dense_reduction_compiles(one_chip, domain):
     cut = aggregate._DENSE_MAX_DOMAIN
     assert cut & (cut - 1) == 0 and cut >= 16
     half = cut.bit_length() // 2  # cut = 2^(half) * 2^(rest)
-    sizes = (3, 2) if domain == "q1" else (
-        (1 << half) - 1, (cut >> half) - 1)
+    masked = domain == "q1_masked"
+    sizes = (1 << half, cut >> half) if domain == "the_cut" else (3, 2)
     slots = max(slots, cut)
     direct: list = []
 
@@ -200,13 +234,15 @@ def test_dense_reduction_compiles(one_chip, domain):
         return hash_aggregate(t, keys, aggs, slots, "single",
                               out_capacity=slots, direct=direct)
 
-    compiled = _compile(kernel, _q1_with_dictionaries(one_chip, sizes),
-                        scopes=("agg.direct", "agg.reduce.sum"))
-    assert direct == [12 if domain == "q1" else cut]
+    compiled = _compile(
+        kernel, _q1_with_dictionaries(one_chip, sizes, masked=masked),
+        scopes=("agg.direct", "agg.reduce.sum"))
+    assert direct == [{"q1": 6, "q1_masked": 12, "the_cut": cut}[domain]]
     text = compiled.as_text()
     assert not re.search(r'op_name="[^"]*/agg\.reduce\.[^"]*scatter', text)
     assert text.count(" scatter(") == 1  # `nonzero` of the pack
-    measured = {"q1": 136_443_392, "the_cut": 172_352_512}[domain]
+    measured = {"q1": 67_592_704, "q1_masked": 136_443_392,
+                "the_cut": 135_701_504}[domain]
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries <= 1.25 * measured, temporaries
 

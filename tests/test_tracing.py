@@ -777,7 +777,7 @@ def test_direct_q1_span_tree_under_one_request(tpch_ctx):
 
 @pytest.mark.parametrize("tier,query,masked,direct,dense", [
     # one filter, under the aggregate's projection; two dictionary keys,
-    # a domain of 12: dense reductions
+    # a domain of 6: dense reductions
     ("direct", TPCH_Q1, 1, 1, 1),
     # four stacked filters under the global aggregate: no group-by, one slot
     ("direct", TPCH_Q6, 4, 0, 1),
@@ -979,8 +979,12 @@ def test_register_arrow_spans_say_where_a_registration_went():
     assert encodes["u"].attrs["bytes"] == arrow.column("u").nbytes
     (h2d,) = [s for s in spans if s.kind == "h2d"]
     table = ctx.catalog.tables["traced"]
-    assert h2d.attrs == {"bytes": table_nbytes(table), "rows": 100,
-                         "capacity": 128}
+    # one validity array went up, ``s``'s (the only column with a NULL),
+    # and the session says the same of the table (PR 37)
+    assert h2d.attrs == {"bytes": table_nbytes(table), "masks": 1,
+                         "rows": 100, "capacity": 128}
+    assert ctx.table_masks("traced") == 1
+    assert table_nbytes(table) == 128 * (4 * 4 + 1)
     for s in [h2d, *encodes.values()]:
         assert by_id[s.parent_id] is root
     _assert_monotonic_tree(trace)
@@ -995,6 +999,7 @@ def test_register_arrow_spans_say_where_a_registration_went():
     assert row["counters"]["bytes"] == {
         "encode": arrow.column("s").nbytes + arrow.column("u").nbytes,
         "h2d": table_nbytes(table)}
+    assert row["counters"]["masks"] == 1
     # the data the spans watched is the data a query reads
     got = ctx.sql("select count(*) as n, count(s) as s from traced"
                   ).to_pandas()
