@@ -857,22 +857,32 @@ def execute_plan(
     gate = prog.first_call_gate
     # ends on the fetch of the flag vector: the sync the program already
     # makes, so the span holds the device's work and adds no wait
+    def launch():
+        # the jitted call up to its return: flatten, argument checks,
+        # enqueue (and, a first call, trace and compile)
+        with tr.span("launch", "launch"):
+            return prog.fn(prog.inputs, prog.params)
+
     with tr.span("execute", "execute") as xsp:
         result = None
         if gate is not None and not gate["warmed"]:
+            waited = tr.start_span("gate_wait", "wait")
             with gate["lock"]:
+                tr.end_span(waited)
                 # double-check: threads that queued behind the creator
                 # must NOT execute under the gate (that would serialize
                 # the whole task wave) — only the creator's
                 # trace+compile+first-run is serialized; everyone else
                 # re-checks and runs concurrently
                 if not gate["warmed"]:
-                    result = prog.fn(prog.inputs, prog.params)
+                    result = launch()
                     gate["warmed"] = True
         if result is None:
-            result = prog.fn(prog.inputs, prog.params)
+            result = launch()
         out, flags, metric_vals = result
-        flags = np.asarray(flags)  # one fetch for both sentinel checks
+        # one fetch for both sentinel checks: the wait for the device
+        with tr.span("sync", "sync", what="flags", values=1, syncs=1):
+            flags = np.asarray(flags)
         if tr.active:
             xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before,
                     **prog.trace.counters)
@@ -882,9 +892,13 @@ def execute_plan(
         # original ids, so callers can look metrics up on their own plan)
         nodes = plan.collect(lambda _n: True)
         node_metrics: dict = {}
-        for (pos, name), v in zip(prog.trace.metric_names, metric_vals):
-            if 0 <= pos < len(nodes):
-                node_metrics.setdefault(nodes[pos].node_id, {})[name] = int(v)
+        # each `int(v)` is a blocking read of its own
+        with tr.span("sync", "sync", what="metrics",
+                     values=len(metric_vals), syncs=len(metric_vals)):
+            for (pos, name), v in zip(prog.trace.metric_names, metric_vals):
+                if 0 <= pos < len(nodes):
+                    node_metrics.setdefault(
+                        nodes[pos].node_id, {})[name] = int(v)
         metrics_store.insert(task_label or f"task{task.task_index}", node_metrics)
     return out
 

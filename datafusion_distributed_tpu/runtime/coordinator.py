@@ -2975,7 +2975,7 @@ class Coordinator:
                             )
                         else:
                             try:
-                                with tr.span("execute_rpc", "execute",
+                                with tr.span("execute_rpc", "rpc",
                                              worker=worker.url):
                                     out = self._execute_attempt(
                                         worker, key,
@@ -3260,7 +3260,7 @@ class Coordinator:
 
             def run() -> None:
                 sp = tr.start_span(
-                    "execute_rpc", "execute", parent=asp.span_id,
+                    "execute_rpc", "rpc", parent=asp.span_id,
                     worker=att["worker"].url, hedge=speculative,
                 )
                 payload = None
@@ -3447,7 +3447,7 @@ class Coordinator:
 
             def run() -> None:
                 sp = tr.start_span(
-                    "pull_attempt", "execute", parent=pull_span.span_id,
+                    "pull_attempt", "rpc", parent=pull_span.span_id,
                     worker=att["worker"].url, hedge=speculative,
                 )
                 it = None
@@ -3726,7 +3726,7 @@ class Coordinator:
             # suspensions, ending when the attempt resolves or the
             # consumer closes the stream
             pull_span = tr.start_span(
-                "pull", "execute", parent=pull_parent,
+                "pull", "rpc", parent=pull_parent,
                 stage=stage_id, task=task_number, attempt=state.attempt,
             )
             try:

@@ -133,8 +133,15 @@ def _handlers(worker: Worker):
                     worker.table_store.put_as(
                         tid, decode_table(raw, capacity=caps.get(tid))
                     )
+            config = header.get("config")
+            tctx = (config or {}).get("trace_ctx")
+            if tctx:
+                # the context crossed a wire: this worker's phases ride
+                # the progress payload back (`tracing.worker_phase`), a
+                # localhost server in the coordinator's own process too
+                tctx["wire"] = True
             worker.set_plan(key, header["plan"], header["task_count"],
-                            config=header.get("config"),
+                            config=config,
                             headers=header.get("headers"),
                             ttl=header.get("ttl"))
             return json.dumps({"ok": True}).encode()

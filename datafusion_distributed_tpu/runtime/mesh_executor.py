@@ -251,17 +251,22 @@ def execute_on_mesh(
                  cache="hit" if cached_hit else "miss") as xsp:
         calling.plan = (exec_target, leaf_ids)
         try:
-            out, flags, mvec = fn(stacked_inputs, params)
+            with tr.span("launch", "launch"):
+                out, flags, mvec = fn(stacked_inputs, params)
         finally:
             del calling.plan
-        flags = np.asarray(flags)  # one fetch for both checks
+        # one fetch for both checks: the wait for the chips
+        with tr.span("sync", "sync", what="flags", values=1, syncs=1):
+            flags = np.asarray(flags)
         if tr.active:
             xsp.set(new_traces=trace_count() - traces_before,
                     **trace.counters)
     raise_flagged(trace, "mesh", check_overflow and flags[0], flags[1])
     if metrics_store is not None:
         nodes = plan.collect(lambda _n: True)
-        m = np.asarray(mvec)  # [T, M]
+        with tr.span("sync", "sync", what="metrics",
+                     values=int(mvec.size), syncs=1):
+            m = np.asarray(mvec)  # [T, M]
         for t in range(m.shape[0]):
             node_metrics: dict = {}
             for (pos, name), v in zip(trace.metric_names, m[t]):

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
+from datafusion_distributed_tpu import spans
 from datafusion_distributed_tpu.ops.table import Table, concat_tables
 from datafusion_distributed_tpu.plan.physical import (
     DistributedTaskContext,
@@ -202,10 +203,14 @@ class PeerShuffleScanExec(ExecutionPlan):
             if local_store is not None
             and hasattr(local_store, "under_pressure") else None
         )
-        chunks, stats = stream_stage_chunks(
-            [make_puller(s) for s in specs], self.budget_bytes,
-            pressure=pressure,
-        )
+        # a consumer task blocked on its producer stage: the pullers run
+        # on threads of their own (a producer's first pull executes it)
+        with spans.current().span("input_wait", "wait", on="peers",
+                                  producers=len(specs)):
+            chunks, stats = stream_stage_chunks(
+                [make_puller(s) for s in specs], self.budget_bytes,
+                pressure=pressure,
+            )
         flat = [c for per in chunks for c in per]
         self.last_pull_stats = {
             "bytes_pulled": stats.bytes_streamed,

@@ -33,6 +33,7 @@ import weakref
 from dataclasses import dataclass, field
 from typing import Callable, Iterator, Optional
 
+from datafusion_distributed_tpu import spans
 from datafusion_distributed_tpu.runtime import leakcheck as _leakcheck
 from datafusion_distributed_tpu.ops.table import Table, concat_tables
 from datafusion_distributed_tpu.plan.physical import (
@@ -749,7 +750,10 @@ class StreamScanExec(ExecutionPlan):
         EXACTLY ONCE (the feed's chunks drain on first take); concurrent
         callers — task retries, a hedged re-dispatch of the same
         consumer task — wait for the first build and observe the same
-        table object."""
+        table object. Where the caller is traced, the time it is blocked
+        on the producer stage (the feed closing this partition, or the
+        first builder's install) is its ``input_wait`` spans."""
+        tr = spans.current()
         with self._cv:
             while True:
                 hit = self._slices.get(partition)
@@ -762,11 +766,17 @@ class StreamScanExec(ExecutionPlan):
                 # install (timeout so an external cancel still unwinds)
                 if self._cancelled is not None and self._cancelled():
                     raise _feed_cancel_error()
-                self._cv.wait(
-                    timeout=0.25 if self._cancelled is not None else None
-                )
+                with tr.span("input_wait", "wait", partition=partition,
+                             on="builder"):
+                    self._cv.wait(
+                        timeout=0.25 if self._cancelled is not None
+                        else None
+                    )
         try:
-            chunks = self.feed.wait_partition(partition, self._cancelled)
+            with tr.span("input_wait", "wait", partition=partition,
+                         on="feed"):
+                chunks = self.feed.wait_partition(partition,
+                                                  self._cancelled)
             if chunks:
                 rows = sum(int(t.num_rows) for t in chunks)
                 cap = max(-(-rows // 8) * 8, 8)

@@ -44,11 +44,24 @@ Design constraints (mirrors the MetricsStore contracts):
 
 Cross-wire propagation: the coordinator attaches ``trace_ctx``
 (`{"q": query_id, "parent": span_id}`) to the per-dispatch config dict of
-the task envelope (runtime/coordinator.py `_dispatch_task`); the worker
-records its decode/execute spans as plain JSON-able dicts carrying that
-wire parent (runtime/worker.py), and they ride the existing task-progress
-payload back — over the in-process transport AND the gRPC response — to
-be spliced into the query trace under the propagated parent span.
+the task envelope (runtime/coordinator.py `_dispatch_task`). A worker in
+the coordinator's own process opens its phases (`worker_phase`:
+``worker_decode``, ``worker_execute``, ``worker_output``) as LIVE spans on
+whatever thread they run: the query's running trace is found on the
+thread, or by ``q`` in `spans.running_tracer`'s process-wide registry
+(a stage task runs under a pull's generator or on a deadline thread,
+where no tracer is open), and the tracer goes on the thread's stack, so
+that everything below records into the same trace: `execute_plan`'s
+``prepare`` > ``h2d`` > ``input_wait`` (a consumer blocked on its
+producer stage) and ``execute`` > ``gate_wait``, ``launch`` (the jitted
+call up to its return), ``sync`` (each blocking read of a program's small
+outputs: flags, metric values, a row count; ``syncs`` counts them),
+``program_lookup`` (the stage's shared-program slot), `host_view`'s
+``d2h``, the ``regroup``. A worker behind a wire (the gRPC server marks
+the context ``wire``) records its phases as plain JSON-able dicts
+carrying that wire parent, and they ride the existing task-progress
+payload back to be spliced into the query trace under the propagated
+parent span, childless.
 
 Exports: Chrome trace-event JSON (``to_chrome_trace`` — load the file in
 Perfetto / chrome://tracing), a text profile report (``render_profile``,
@@ -58,6 +71,7 @@ folded into `explain_analyze`), and live aggregate counters
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Optional
 
@@ -94,37 +108,62 @@ def worker_span(name: str, kind: str, t0: float, t1: float,
             "wire_parent": wire_parent, "attrs": attrs}
 
 
+# worker tasks in flight in this process (a ``worker_execute`` phase
+# counts itself in and out), touched only under a live tracer
+_tasks_running = [0]  # guarded-by: _tasks_lock
+_tasks_lock = threading.Lock()
+
+
 class worker_phase:
-    """One worker-side phase (plan decode, task execute). Where the
-    coordinator's trace is open on this very thread (the in-process
-    transport) it is a live child span, profiler annotation included;
-    else (a gRPC worker, a deadline thread) a `worker_span` dict
-    appended to ``sink``, which rides the task-progress payload back to
-    `Tracer.splice`. ``tctx`` None: nothing at all."""
+    """One worker-side phase (plan decode, task execute). In the
+    coordinator's own process it is a live span whatever thread it runs
+    on: a child of the thread's open span where the query's trace is open
+    there, else (a pull's generator, `call_with_deadline`'s thread) the
+    query's running trace is looked up by the ``q`` of ``tctx`` and the
+    span opened under ``tctx["parent"]``. Either way the tracer is on the
+    thread's stack, so `spans.current()` below it (`execute_plan`,
+    `host_view`, a scan's `load`) records into the same trace, profiler
+    annotations included. Where the context crossed a wire (``wire``,
+    set by the gRPC server) or no such trace runs in this process: a
+    `worker_span` dict appended to ``sink``, which rides the
+    task-progress payload back to `Tracer.splice`. ``count_task``: carry
+    ``running``, the worker tasks in flight in this process when this
+    one began. ``tctx`` None: nothing at all, no lookup, no clock read."""
 
     __slots__ = ("_tctx", "_name", "_kind", "_sink", "_attrs", "_ctx",
-                 "_t0", "live")
+                 "_t0", "_count_task")
 
-    def __init__(self, tctx, name: str, kind: str, sink: list, **attrs):
+    def __init__(self, tctx, name: str, kind: str, sink: list,
+                 count_task: bool = False, **attrs):
         self._tctx = tctx
         self._name = name
         self._kind = kind
         self._sink = sink
         self._attrs = attrs
         self._ctx = None
-        self.live = False
+        self._count_task = count_task
 
     def __enter__(self) -> "worker_phase":
         tctx = self._tctx
         if not tctx:
             return self
-        tracer = current()
-        if tracer.active and tracer.trace.query_id == tctx.get("q"):
-            self.live = True
-            self._ctx = tracer.span(self._name, self._kind, **self._attrs)
-            self._ctx.__enter__()
-        else:
+        tracer, parent = NULL_TRACER, None
+        if not tctx.get("wire"):
+            tracer = current()
+            if not (tracer.active
+                    and tracer.trace.query_id == tctx.get("q")):
+                tracer = _spans.running_tracer(tctx.get("q"))
+                parent = tctx.get("parent")
+        if not tracer.active:
             self._t0 = time.monotonic()
+            return self
+        if self._count_task:
+            with _tasks_lock:
+                self._attrs["running"] = _tasks_running[0]
+                _tasks_running[0] += 1
+        self._ctx = tracer.span(self._name, self._kind, parent=parent,
+                                **self._attrs)
+        self._ctx.__enter__()
         return self
 
     def set(self, **attrs) -> None:
@@ -135,6 +174,9 @@ class worker_phase:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if self._ctx is not None:
+            if self._count_task:
+                with _tasks_lock:
+                    _tasks_running[0] -= 1
             return self._ctx.__exit__(exc_type, exc, tb)
         if self._tctx and exc_type is None:
             self._sink.append(worker_span(
@@ -263,17 +305,24 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
     - ``t0_s``: when its first trace began (`time.monotonic`);
     - ``wall_s``: the summed wall of its traces' roots;
     - ``self_s``: `self_times` summed by span KIND (`parse`, `plan`,
-      `attempt`, `prepare`, `execute`, `fetch`, `exchange`, `d2h`, ...):
-      what each layer itself costs, children taken out, so that the
-      kinds add up to ``wall_s`` where no two tasks overlap;
+      `attempt`, `prepare`, `execute`, `launch`, `sync`, `wait`,
+      `worker`, `rpc`, `fetch`, `exchange`, `d2h`, ...): what each layer
+      itself costs, children taken out, so that the kinds add up to
+      ``wall_s`` where no two tasks overlap (the coordinator tier's
+      worker threads do: there a kind is a sum over threads);
     - ``total_s``: whole durations summed by span NAME (`worker_execute`
-      is the stage programs, each ended by its flag fetch);
+      is a task inside its worker, its wait for its inputs included;
+      `launch` the jitted calls up to their return);
     - ``counters``: ``bytes`` by span kind, ``transfers`` (buffers the
       fetch copied from a device), ``round_trips`` (times the fetch
       blocked on the device for them), ``retries`` (overflow retries,
       stamped on the root that succeeded), ``new_traces`` (programs
       traced afresh), ``masks`` (validity arrays a registration uploaded:
-      one a column that holds a NULL),
+      one a column that holds a NULL), ``syncs`` (blocking device-to-host
+      reads of a program's small outputs: the flag vector, the metric
+      values, a row count; summed from the ``sync`` spans), ``tasks``
+      (worker tasks run: one a ``worker_execute`` span, by which a sum
+      over worker threads can be read a task),
       and every name of `spans.PROGRAM_COUNTERS` (what its programs
       counted while they were traced), zero included.
 
@@ -284,7 +333,7 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
 
 def _layer_rows(traces) -> list:
     # the span attributes summed into a request's ``counters``
-    summed = (("transfers", "round_trips", "new_traces", "masks")
+    summed = (("transfers", "round_trips", "new_traces", "masks", "syncs")
               + _spans.PROGRAM_COUNTERS)
     rows: dict = {}
     for trace in traces:
@@ -298,7 +347,7 @@ def _layer_rows(traces) -> list:
                 "request": key, "traces": [], "t0_s": trace.t0,
                 "wall_s": 0.0,
                 "self_s": {}, "total_s": {},
-                "counters": {"bytes": {}, "retries": 0,
+                "counters": {"bytes": {}, "retries": 0, "tasks": 0,
                              **dict.fromkeys(summed, 0)},
             }
         row["traces"].append(trace.query_id)
@@ -319,6 +368,7 @@ def _layer_rows(traces) -> list:
                 )
             for name in summed:
                 counters[name] += int(span.attrs.get(name, 0) or 0)
+            counters["tasks"] += span.name == "worker_execute"
     return list(rows.values())
 
 
@@ -411,6 +461,7 @@ def render_profile(trace: QueryTrace, top_n: int = 10) -> str:
             f"  transfers {c['transfers']}"
             f"  round_trips {c['round_trips']}  retries {c['retries']}"
             f"  new_traces {c['new_traces']}"
+            f"  syncs {c['syncs']}  tasks {c['tasks']}"
         )
     return "\n".join(lines)
 

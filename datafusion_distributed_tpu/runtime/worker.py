@@ -20,7 +20,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
-from datafusion_distributed_tpu.ops.table import Table
+from datafusion_distributed_tpu import spans
+from datafusion_distributed_tpu.ops.table import Table, is_host_backed
 from datafusion_distributed_tpu.plan.physical import (
     DistributedTaskContext,
     ExecContext,
@@ -70,6 +71,17 @@ def call_with_deadline(fn, timeout: Optional[float], worker_url: str, task):
     if "error" in box:
         raise box["error"]
     return box["value"]
+
+
+def _row_count(table: Table) -> int:
+    """``int(table.num_rows)``, under a ``sync`` span where that is a
+    blocking read from the device (a `host_view` holds its count on the
+    host)."""
+    if is_host_backed(table):
+        return int(table.num_rows)
+    with spans.current().span("sync", "sync", what="rows", values=1,
+                              syncs=1):
+        return int(table.num_rows)
 
 
 @dataclass(frozen=True)
@@ -620,9 +632,9 @@ class Worker:
         data.executed_at = time.time()
         tctx = (data.config or {}).get("trace_ctx")
         phase = worker_phase(
-            tctx, "worker_execute", "execute",
+            tctx, "worker_execute", "worker",
             data.metrics.setdefault("spans", []) if tctx else None,
-            worker=self.url,
+            count_task=True, worker=self.url,
         )
         try:
             with phase:
@@ -640,6 +652,18 @@ class Worker:
         self._tm_exec.observe(data.metrics["elapsed_s"])
         return out
 
+    def _output_phase(self, data, plane: str):
+        """The ``worker_output`` phase: a task's output on its way to a
+        consumer over the streaming or the partition plane (the execute
+        it triggers, `host_view`'s ``d2h``, the ``regroup``, the
+        staging), which runs on whatever thread pulls first."""
+        tctx = (data.config or {}).get("trace_ctx") if data else None
+        return worker_phase(
+            tctx, "worker_output", "exchange",
+            data.metrics.setdefault("spans", []) if tctx else None,
+            worker=self.url, plane=plane,
+        )
+
     def _execute_task_plan(self, key: TaskKey, data, phase) -> Table:
         """`_execute_task_body`'s work inside its ``worker_execute``
         phase: run the stage program, note rows and wall."""
@@ -649,7 +673,12 @@ class Worker:
 
         traces_before = _phys.trace_count()
         store = MetricsStore()
-        shared_cache, shared_key = self._stage_compile_cache(key, data)
+        tr = spans.current()
+        with tr.span("program_lookup", "prepare") as lsp:
+            shared_cache, shared_key = self._stage_compile_cache(key, data)
+            # the stage's slot: holding programs, new, or not shareable
+            lsp.set(cache="off" if shared_cache is None
+                    else "hit" if shared_cache else "miss")
         # the wire trace context must NOT reach ExecContext.config or
         # any compile-cache key: span ids differ per task, and keying
         # a program on them would force one XLA trace per task
@@ -673,10 +702,10 @@ class Worker:
             f"task{key.task_number}", {}
         )
         data.finished_at = time.time()
-        data.metrics["rows_out"] = int(out.num_rows)
+        data.metrics["rows_out"] = _row_count(out)
         data.metrics["elapsed_s"] = data.finished_at - data.executed_at
         phase.set(rows=data.metrics["rows_out"])
-        if not phase.live:
+        if not tr.active:
             # compile-cache attribution: new_traces > 0 means this
             # execute paid a fresh XLA trace (a stage-compile cache miss);
             # 0 means it reused a shared program (hit). A live phase
@@ -704,10 +733,11 @@ class Worker:
 
         data = self.registry.get(key)
         zc = zero_copy_enabled(data.config if data is not None else None)
-        out = self.execute_task(key)
-        if zc:
-            out = host_view(out)
-        n = int(out.num_rows)
+        with self._output_phase(data, "stream"):
+            out = self.execute_task(key)
+            if zc:
+                out = host_view(out)
+            n = _row_count(out)
         width = row_width(out.schema())
         if n == 0:
             yield out.slice_rows(0, 0), 0
@@ -761,42 +791,44 @@ class Worker:
                     zero_copy_enabled,
                 )
 
-                zc = zero_copy_enabled(data.config)
-                out = self.execute_task(key)
-                if zc:
-                    # rebind to host buffers ONCE (free on CPU, the one
-                    # unavoidable D2H elsewhere); all partition slices and
-                    # chunk yields below are views of this buffer
-                    out = host_view(out)
-                if not key_names:
-                    # replicate mode (peer broadcast / gather): the FULL
-                    # output serves under every virtual partition id — the
-                    # reference's NetworkBroadcastExec virtual-partition
-                    # scheme (`broadcast.rs:30-69`); entries are references,
-                    # not copies, and the per-partition drop accounting
-                    # self-invalidates after the last consumer pulled
-                    data.partition_slices = [out] * num_partitions
-                else:
-                    # same hash as the in-mesh shuffle kernel, so codes
-                    # co-locate across tiers (function-level import:
-                    # runtime/coordinator.py imports this module at top
-                    # level)
-                    from datafusion_distributed_tpu.runtime.coordinator import (  # noqa: E501
-                        _shuffle_regroup,
-                    )
+                with self._output_phase(data, "partitions"):
+                    zc = zero_copy_enabled(data.config)
+                    out = self.execute_task(key)
+                    if zc:
+                        # rebind to host buffers ONCE (free on CPU, the
+                        # one unavoidable D2H elsewhere); all partition
+                        # slices and chunk yields below are views of it
+                        out = host_view(out)
+                    if not key_names:
+                        # replicate mode (peer broadcast / gather): the
+                        # FULL output serves under every virtual partition
+                        # id — the reference's NetworkBroadcastExec
+                        # virtual-partition scheme (`broadcast.rs:30-69`);
+                        # entries are references, not copies, and the
+                        # per-partition drop accounting self-invalidates
+                        # after the last consumer pulled
+                        data.partition_slices = [out] * num_partitions
+                    else:
+                        # same hash as the in-mesh shuffle kernel, so
+                        # codes co-locate across tiers (function-level
+                        # import: runtime/coordinator.py imports this
+                        # module at top level)
+                        from datafusion_distributed_tpu.runtime.coordinator import (  # noqa: E501
+                            _shuffle_regroup,
+                        )
 
-                    cap = per_dest_capacity or max(int(out.capacity), 8)
-                    data.partition_slices = _shuffle_regroup(
-                        [out], key_names, num_partitions, cap,
-                        zero_copy=zc, exact=zc,
-                    )
-                data.partition_spec = spec
-                data.partitions_served = set()
-                data.partitions_remaining = num_partitions
-                # staged-byte accounting on EITHER plane (the copying
-                # plane's padded slices are real allocations too); on the
-                # view plane these are views/aliases of one buffer
-                self._stage_partition_slices(key, data)
+                        cap = per_dest_capacity or max(int(out.capacity), 8)
+                        data.partition_slices = _shuffle_regroup(
+                            [out], key_names, num_partitions, cap,
+                            zero_copy=zc, exact=zc,
+                        )
+                    data.partition_spec = spec
+                    data.partitions_served = set()
+                    data.partitions_remaining = num_partitions
+                    # staged-byte accounting on EITHER plane (the copying
+                    # plane's padded slices are real allocations too); on
+                    # the view plane these are views/aliases of one buffer
+                    self._stage_partition_slices(key, data)
             # a concurrent stream finishing its range must not yank the
             # slices out from under this one: hold our own reference
             slices = data.partition_slices
@@ -808,7 +840,7 @@ class Worker:
         try:
             for p in range(part_lo, min(part_hi, num_partitions)):
                 piece = slices[p]
-                n = int(piece.num_rows)
+                n = _row_count(piece)
                 width = row_width(piece.schema())
                 view = is_host_backed(piece)
                 if n == 0:

@@ -20,6 +20,7 @@ from __future__ import annotations
 import threading
 import time
 import uuid
+import weakref
 import zlib
 from collections import deque
 from typing import Optional
@@ -124,6 +125,21 @@ class request_scope:
         return False
 
 
+# the traces running in this process, by query id: what lets a worker
+# phase on a thread that holds no tracer (a pull's generator, a deadline
+# thread, another coordinator's store) join its query's trace
+# (`running_tracer`). `TraceStore.begin` fills it and `finish` empties it
+# (one assignment, pop or get each, so no lock of its own); a trace that
+# is begun and never finished goes with its Tracer.
+_RUNNING: "weakref.WeakValueDictionary" = weakref.WeakValueDictionary()
+
+
+def running_tracer(query_id):
+    """The live Tracer of a query whose trace is running in THIS process
+    (whichever store began it), else NULL_TRACER."""
+    return _RUNNING.get(query_id, NULL_TRACER)
+
+
 def _sampled(query_id: str, rate: float) -> bool:
     """Deterministic per-query sampling decision: a hash of the query id
     against ``rate`` — the same query id always decides the same way, so a
@@ -202,6 +218,7 @@ class _NullTracer:
 
     __slots__ = ()
     active = False
+    trace = None
 
     def span(self, name, kind, parent=None, **attrs):
         return _A_NULL_CTX
@@ -329,7 +346,7 @@ class Tracer:
     passes an explicit ``parent`` (usually a reserved stage span id) to
     seed its own stack."""
 
-    __slots__ = ("trace", "_local")
+    __slots__ = ("trace", "_local", "__weakref__")
     active = True
 
     def __init__(self, trace: QueryTrace):
@@ -535,7 +552,8 @@ class TraceStore:
             self._traces[query_id] = trace
             self._started_total += 1
             self._evict_locked()
-        return Tracer(trace)
+        tracer = _RUNNING[query_id] = Tracer(trace)
+        return tracer
 
     def finish(self, query_id: str) -> None:
         with self._lock:
@@ -543,6 +561,8 @@ class TraceStore:
             trace = self._traces.get(query_id)
             self._evict_locked()
         if trace is not None:
+            if running_tracer(query_id).trace is trace:
+                _RUNNING.pop(query_id, None)
             trace.finish()
 
     def _evict_locked(self) -> None:

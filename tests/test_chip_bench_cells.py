@@ -72,7 +72,8 @@ def test_the_sf10_cell_agrees_with_the_reference(trace, capsys,
         assert wanted == {
             "generate_s", "register_s", "warmup_s", "compiles_in_window",
             "parse_ms", "execute_ms", "fetch_ms", "overflow_retries",
-            "device_busy_ms", "hbm_roofline_share", "fetch_round_trips"}
+            "device_busy_ms", "hbm_roofline_share", "fetch_round_trips",
+            "launch_ms", "device_wait_ms", "device_syncs"}
         assert result["attempted"] == 3  # the mix's traced_queries
         # the four rows' twenty buffers in one wait (PR 35)
         assert result["metrics"]["fetch_round_trips"]["value"] == 1
@@ -170,11 +171,13 @@ def test_benchmark_json_lists_the_sf10_configuration_and_its_cell():
         "traced_queries": 3}
     # no list of a metric accepted before the cell names it: a `benchmark`
     # PR appends it (PERF.md section 7). `fetch_round_trips` came after it
-    # (PR 35), with every cell in its list from the start
+    # (PR 35), with every cell in its list from the start, and so did the
+    # three readers of the `launch` and `sync` spans (PR 38)
     for kind in ("end_to_end", "per_layer"):
         assert [m["name"] for m in bench[kind]
                 if CELL in m.get("workloads", [])] == (
-            ["fetch_round_trips"] if kind == "per_layer" else [])
+            ["fetch_round_trips", "launch_ms", "device_wait_ms",
+             "device_syncs"] if kind == "per_layer" else [])
 
 
 def test_benchmark_json_agrees_with_the_files():

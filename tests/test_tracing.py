@@ -183,13 +183,15 @@ def _assert_monotonic_tree(trace):
         parent = by_id.get(s.parent_id)
         assert parent is not None, f"{s.name} has dangling parent"
         # ordering on ONE monotonic clock: a child never starts before
-        # its parent (small epsilon for cross-thread recording). Remote
-        # (worker-side) spans may legitimately END after their ship-time
-        # parent: peer-plane producers execute LAZILY at first consumer
+        # its parent (small epsilon for cross-thread recording). A
+        # worker's phases (live or spliced: `worker_decode`,
+        # `worker_execute`, `worker_output`) hang under the parent the
+        # task envelope carried and may legitimately END after it:
+        # peer-plane producers execute LAZILY at first consumer
         # pull, long after the dispatch that shipped them — the trace
         # records that truthfully instead of faking nesting.
         assert s.t0 >= parent.t0 - 0.05, (s.name, parent.name)
-        if not s.attrs.get("remote"):
+        if not (s.attrs.get("remote") or s.name.startswith("worker_")):
             assert s.t1 <= parent.t1 + 0.05, (s.name, parent.name)
 
 
@@ -249,11 +251,15 @@ def test_q3_span_tree_shape(tpch_ctx):
         parent = by_id[s.parent_id]
         assert parent.kind == "stage"
         assert parent.attrs.get("stage") == s.attrs.get("stage")
-    # worker-side spans joined via the propagated trace context
-    remote = [s for s in spans if s.attrs.get("remote")]
-    assert remote, "no worker-side spans spliced into the trace"
-    for s in remote:
+    # worker-side spans joined via the propagated trace context: live
+    # ones, since an in-process worker finds the running trace (PR 38)
+    worker_side = [s for s in spans
+                   if s.name in ("worker_decode", "worker_execute")]
+    assert {s.name for s in worker_side} == {"worker_decode",
+                                             "worker_execute"}
+    for s in worker_side:
         assert s.parent_id in by_id, "wire parent did not resolve"
+        assert not s.attrs.get("remote"), "an in-process span was spliced"
     # planner cost hints rode onto stage spans
     staged = [s for s in spans
               if s.kind == "stage" and s.attrs.get("stage", -1) >= 0]
@@ -290,8 +296,8 @@ def test_q5_coverage_and_data_rates(tpch_ctx):
     cov, max_gap = trace_coverage(trace)
     assert cov >= 0.95, f"span tree covers only {cov:.1%} of query wall"
     assert max_gap <= 0.05, f"unattributed gap of {max_gap:.1%}"
-    # worker-side spans joined cross-wire
-    assert any(s.attrs.get("remote") for s in trace.span_list())
+    # worker-side spans joined through the propagated context
+    assert any(s.name == "worker_execute" for s in trace.span_list())
     # per-stage exchange bytes/sec measured
     rates = stage_data_rates(trace)
     assert rates, "no per-stage data-plane attribution"
@@ -440,6 +446,19 @@ def test_grpc_cross_wire_spans():
                    if s.kind == "dispatch")
     finally:
         cluster.shutdown()
+    # a context that crossed a wire is never looked up in the registry of
+    # running traces (PR 38), a localhost server in the coordinator's own
+    # process too: its phases are spliced dicts and carry no children
+    tasks = [s for s in spans if s.name == "worker_execute"]
+    assert tasks and {s.span_id for s in tasks} <= {
+        s.span_id for s in remote}
+    for task in tasks:
+        assert task.kind == "worker"
+        assert "new_traces" in task.attrs and "running" not in task.attrs
+        assert not any(s.parent_id == task.span_id for s in spans)
+    (row,) = [r for r in layer_report() if trace.query_id in r["traces"]]
+    assert row["counters"]["tasks"] == len(tasks)
+    assert row["counters"]["syncs"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -741,7 +760,8 @@ def test_direct_q1_span_tree_under_one_request(tpch_ctx):
     assert [tree(t) for t in traces] == [
         ("sql", [("parse", []), ("plan", [])]),
         ("query", [("attempt", [
-            ("prepare", [("h2d", [])]), ("execute", []),
+            ("prepare", [("h2d", [])]),
+            ("execute", [("launch", []), ("sync", [])]),
         ])]),
         ("fetch", []),
     ]
@@ -1186,3 +1206,286 @@ def test_dftpu109_flags_spans_in_traced_code(tmp_path):
         capture_output=True, text=True,
     )
     assert proc2.returncode == 0, proc2.stdout + proc2.stderr
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 38: a worker's phases are live on whatever thread they run on; a
+# span a launch, a device sync and an input wait; `syncs` and `tasks`
+# ---------------------------------------------------------------------------
+
+
+def _below(trace) -> dict:
+    """span id -> its direct children, oldest first."""
+    kids: dict = {}
+    for s in sorted(trace.span_list(), key=lambda s: s.t0):
+        kids.setdefault(s.parent_id, []).append(s)
+    return kids
+
+
+def _assert_worker_executes_hold_their_work(trace) -> list:
+    """Every ``worker_execute`` span of ``trace`` holds its program's
+    lookup, ``prepare`` and ``execute``, and ``execute`` its ``launch``
+    and its wait for the flags. -> the ``worker_execute`` spans."""
+    kids = _below(trace)
+    tasks = [s for s in trace.span_list() if s.name == "worker_execute"]
+    for task in tasks:
+        assert task.kind == "worker" and not task.attrs.get("remote")
+        assert task.attrs["running"] >= 0
+        below = {k.name: k for k in kids.get(task.span_id, ())}
+        assert {"program_lookup", "prepare", "execute"} <= set(below), (
+            task.attrs, sorted(below))
+        assert below["program_lookup"].kind == "prepare"
+        assert below["program_lookup"].attrs["cache"] in ("hit", "miss")
+        inside = kids.get(below["execute"].span_id, ())
+        assert {"launch", "sync"} <= {k.name for k in inside}
+        (flags,) = [k for k in inside if k.name == "sync"]
+        assert (flags.kind, flags.attrs["what"], flags.attrs["syncs"]) == (
+            "sync", "flags", 1)
+        # the task's own blocking reads: the metric values as one span,
+        # the row count
+        what = {k.attrs["what"]: k for k in kids[task.span_id]
+                if k.name == "sync"}
+        assert set(what) == {"metrics", "rows"}
+        assert what["metrics"].attrs["syncs"] == (
+            what["metrics"].attrs["values"])
+    return tasks
+
+
+def test_served_stage_tasks_record_live_spans(tpch_ctx):
+    """q1 through a `ServingSession` at four tasks a stage: every stage
+    task runs on a pull's thread, where no tracer is open, and still
+    holds its children; a final-stage task's ``h2d`` is its wait for the
+    producer stage, and says so."""
+    from datafusion_distributed_tpu.runtime.serving import ServingSession
+
+    tpch_ctx.config.distributed_options["tracing"] = "on"
+    try:
+        with ServingSession(tpch_ctx, num_workers=4, num_tasks=4) as srv:
+            srv.submit(TPCH_Q1).result(timeout=600)  # warm
+            h = srv.submit(TPCH_Q1)
+            h.result(timeout=600)
+    finally:
+        tpch_ctx.config.distributed_options.pop("tracing", None)
+    trace = h.query_trace()
+    _assert_monotonic_tree(trace)
+    tasks = _assert_worker_executes_hold_their_work(trace)
+    assert len(tasks) > 2, "q1 did not fan out"
+    kids = _below(trace)
+    by_id = {s.span_id: s for s in trace.span_list()}
+    self_s = {s.span_id: t for s, t in tracing.self_times(trace)}
+    # the kinds self time can split: the transport's spans are `rpc`
+    for s in trace.span_list():
+        if s.name in ("pull", "execute_rpc", "ship"):
+            assert s.kind == "rpc", s.name
+    assert {s.name for s in trace.span_list()} >= {"pull", "execute_rpc"}
+    # a consumer blocked on its producers: under `h2d`, which keeps the
+    # hand-over as its own time
+    waits = [s for s in trace.span_list() if s.name == "input_wait"]
+    assert waits
+    for wait in waits:
+        assert wait.kind == "wait"
+        h2d = by_id[wait.parent_id]
+        assert h2d.name == "h2d"
+        assert self_s[h2d.span_id] < wait.duration
+    # a producer's output on its way to the consumers is spanned on the
+    # thread that pulled it first
+    outputs = [s for s in trace.span_list() if s.name == "worker_output"]
+    assert outputs and all(s.kind == "exchange" for s in outputs)
+    assert any(k.name == "d2h" for s in outputs
+               for k in kids.get(s.span_id, ()))
+    # the report: the blocking reads and the tasks of the request
+    (row,) = [r for r in layer_report() if r["request"] == h.request_id]
+    syncs = [s for s in trace.span_list() if s.name == "sync"]
+    assert row["counters"]["syncs"] == sum(s.attrs["syncs"] for s in syncs)
+    assert row["counters"]["syncs"] >= 3 * len(tasks)
+    assert row["counters"]["tasks"] == len(tasks)
+    assert {"worker", "launch", "sync", "wait", "rpc"} <= set(row["self_s"])
+    # what is left to `worker_execute` itself is little of it
+    own = sum(self_s[t.span_id] for t in tasks)
+    assert own <= 0.25 * row["total_s"]["worker_execute"]
+
+
+def test_profile_holds_the_workers_spans(tpch_ctx, tmp_path):
+    """One span, two sinks, on the workers' threads too: the profile of a
+    served request holds a `dftpu.worker_execute` event a span of the
+    store, and the launches, syncs and input waits."""
+    import jax
+    from datafusion_distributed_tpu.runtime.serving import ServingSession
+
+    with ServingSession(tpch_ctx, num_workers=4, num_tasks=4) as srv:
+        srv.submit(TPCH_Q1).result(timeout=600)  # warm, untraced
+        DEFAULT_TRACE_STORE.clear()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            h = srv.submit(TPCH_Q1)
+            h.result(timeout=600)
+        finally:
+            jax.profiler.stop_trace()
+    stored: dict = {}
+    for s in h.query_trace().span_list():
+        stored[s.name] = stored.get(s.name, 0) + 1
+    events: dict = {}
+    for name, _, _ in _host_events(tmp_path):
+        events[name] = events.get(name, 0) + 1
+    for name in ("worker_execute", "launch", "sync", "input_wait",
+                 "program_lookup", "worker_output"):
+        assert stored[name] > 0
+        assert events[tracing.PROFILE_PREFIX + name] == stored[name], name
+
+
+def test_a_coordinator_with_its_own_store_joins_its_workers_spans():
+    cluster = InMemoryCluster(2)
+    own = TraceStore()
+    coord = Coordinator(resolver=cluster, channels=cluster, trace_store=own,
+                        config_options=dict(FAST))
+    coord.execute(_plan(num_tasks=2))  # cold: the stage programs compile
+    cold = own.get(coord.last_query_id)
+    _assert_worker_executes_hold_their_work(cold)
+    # the creator of a stage-shared program passes its first-call gate
+    gates = [s for s in cold.span_list() if s.name == "gate_wait"]
+    by_id = {s.span_id: s for s in cold.span_list()}
+    assert gates and all(
+        (s.kind, by_id[s.parent_id].name) == ("wait", "execute")
+        for s in gates)
+    coord.execute(_plan(num_tasks=2))
+    trace = own.get(coord.last_query_id)
+    assert _assert_worker_executes_hold_their_work(trace)
+    assert DEFAULT_TRACE_STORE.get(coord.last_query_id) is None
+    # the registry holds running traces only
+    assert tracing._spans.running_tracer(coord.last_query_id) is NULL_TRACER
+
+
+def test_a_task_on_the_deadline_thread_is_live():
+    """`task_timeout_s` runs a task on `call_with_deadline`'s thread,
+    where no tracer is open: the phase finds the running trace."""
+    cluster = InMemoryCluster(2)
+    coord = _coord(cluster, task_timeout_s=120.0, dispatch_timeout_s=120.0)
+    coord.execute(_plan(num_tasks=2))
+    trace = coord.last_query_trace()
+    assert _assert_worker_executes_hold_their_work(trace)
+    decodes = [s for s in trace.span_list() if s.name == "worker_decode"]
+    assert decodes and not any(s.attrs.get("remote") for s in decodes)
+    _assert_monotonic_tree(trace)
+
+
+def test_tracing_off_looks_nothing_up(monkeypatch):
+    lookups = []
+    found = tracing._spans.running_tracer
+    monkeypatch.setattr(
+        tracing._spans, "running_tracer",
+        lambda query_id: lookups.append(query_id) or found(query_id))
+    cluster = InMemoryCluster(2)
+    off = Coordinator(resolver=cluster, channels=cluster,
+                      config_options={"task_retry_backoff_s": 0.001,
+                                      "task_timeout_s": 120.0})
+    off.execute(_plan(num_tasks=2))
+    assert lookups == []
+    assert DEFAULT_TRACE_STORE.get(off.last_query_id) is None
+    assert off.last_query_id not in tracing._spans._RUNNING
+    assert tracing._tasks_running == [0]
+    on = _coord(cluster, task_timeout_s=120.0)
+    on.execute(_plan(num_tasks=2))
+    assert set(lookups) == {on.last_query_id}
+    # the registry holds running traces only, the counter tasks in flight
+    assert on.last_query_id not in tracing._spans._RUNNING
+    assert tracing._tasks_running == [0]
+
+
+@pytest.mark.parametrize("tier", ["direct", "coordinator", "mesh"])
+def test_every_tier_splits_its_execute_into_launch_and_sync(tier, tpch_ctx):
+    """The jitted call up to its return and the wait for the flag vector
+    are two spans inside `execute` / `mesh.execute` on every tier, and a
+    request's `syncs` and `tasks` say how many blocking reads and worker
+    tasks it made: 1 and 0 for a direct q1."""
+    _run_tier(tpch_ctx, tier, TPCH_Q1)  # warm
+    tpch_ctx.config.distributed_options["tracing"] = "on"
+    try:
+        _frame, request_id, _ = _run_tier(tpch_ctx, tier, TPCH_Q1)
+    finally:
+        tpch_ctx.config.distributed_options.pop("tracing", None)
+    spans = _request_spans(request_id)
+    executes = spans["mesh.execute" if tier == "mesh" else "execute"]
+    by_parent: dict = {}
+    for s in spans["launch"] + spans["sync"]:
+        by_parent.setdefault(s.parent_id, []).append(s)
+    for execute in executes:
+        launch, sync = sorted(by_parent[execute.span_id],
+                              key=lambda s: s.t0)
+        assert (launch.name, launch.kind) == ("launch", "launch")
+        assert (sync.name, sync.kind) == ("sync", "sync")
+        assert (sync.attrs["what"], sync.attrs["syncs"]) == ("flags", 1)
+        assert execute.t0 <= launch.t0 <= launch.t1 <= sync.t0
+        assert sync.t1 <= execute.t1
+    (row,) = [r for r in layer_report() if r["request"] == request_id]
+    assert row["total_s"]["launch"] > 0 and "sync" in row["self_s"]
+    tasks = len(spans.get("worker", ()))
+    assert row["counters"]["tasks"] == tasks
+    assert (tasks > 0) == (tier == "coordinator")
+    if tier == "coordinator":
+        assert row["counters"]["syncs"] >= 3 * tasks
+    else:
+        assert row["counters"]["syncs"] == 1
+
+
+def test_worker_phases_from_many_threads_keep_the_registry_and_the_count():
+    """More threads than cores, a short switch interval: each begins a
+    trace in a store of its own, runs worker phases that find it through
+    the registry from a second thread, and finishes it. No phase joins
+    another query's trace, the in-flight count comes back to zero and the
+    registry holds nothing that finished."""
+    from datafusion_distributed_tpu.runtime.tracing import worker_phase
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    errors: list = []
+    tracers: dict = {}
+
+    def one(i: int) -> None:
+        try:
+            store = TraceStore()
+            for n in range(20):
+                qid = f"stress-{i}-{n}"
+                tracer = store.begin(qid, "on")
+                root = tracer.open_root("query", "query")
+                tctx = {"q": qid, "parent": root.span_id}
+
+                def task():
+                    assert tracing.current() is NULL_TRACER
+                    with worker_phase(tctx, "worker_execute", "worker", [],
+                                      count_task=True) as phase:
+                        assert tracing.current() is tracer
+                        with tracing.current().span("sync", "sync", syncs=1):
+                            pass
+                        phase.set(rows=n)
+
+                worker = threading.Thread(target=task)
+                worker.start()
+                worker.join(timeout=60)
+                assert not worker.is_alive()
+                tracer.end_span(root)
+                store.finish(qid)
+                tracers[qid] = tracer  # alive: only `finish` empties
+                names = sorted(s.name for s in store.get(qid).span_list())
+                assert names == ["query", "sync", "worker_execute"], names
+                (row,) = tracing._layer_rows([store.get(qid)])
+                assert (row["counters"]["tasks"],
+                        row["counters"]["syncs"]) == (1, 1)
+        except BaseException as e:  # re-raised in the test's thread
+            errors.append(e)
+
+    try:
+        threads = [threading.Thread(target=one, args=(i,))
+                   for i in range(4 * (os.cpu_count() or 2))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    if errors:
+        raise errors[0]
+    assert tracing._tasks_running == [0]
+    assert not any(q in tracing._spans._RUNNING for q in tracers)
