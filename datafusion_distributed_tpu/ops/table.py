@@ -668,6 +668,16 @@ def _fetched_buffers(table: "Table") -> list:
     return buffers
 
 
+def _column_buffers(table: "Table", host) -> list:
+    """`_fetched_buffers`' order read back: ``host`` holds the buffers
+    after the row count; -> (data, validity or None) a column."""
+    host = iter(host)
+    return [
+        (next(host), next(host) if col.validity is not None else None)
+        for col in table.columns
+    ]
+
+
 #: A result whose device buffers together hold at most this many bytes is
 #: copied to the host whole, in one round trip, and cut to its rows there;
 #: a larger one costs a second round trip (the row count first) so that
@@ -739,11 +749,9 @@ def fetch_host_buffers(table: "Table"):
         ]
     num_rows, *host = jax.device_get(buffers)
     rows = int(num_rows)
-    host = iter(host)
     columns = [
-        (next(host)[:rows],
-         next(host)[:rows] if col.validity is not None else None)
-        for col in table.columns
+        (data[:rows], validity[:rows] if validity is not None else None)
+        for data, validity in _column_buffers(table, host)
     ]
     return rows, columns, round_trips
 
@@ -763,29 +771,34 @@ def is_host_backed(table: Table) -> bool:
 
 
 def host_view(table: Table) -> Table:
-    """Rebind a table's buffers to host numpy arrays WITHOUT copying where
-    the backend allows (a jax CPU array shares its buffer with numpy —
-    `np.asarray` returns a readonly view; an accelerator pays its one
-    unavoidable D2H here, once, instead of per slice)."""
+    """Rebind a table's buffers to host numpy arrays: whole padded buffers
+    (the table keeps its capacity), ``validity`` None stays None, a host
+    (numpy) buffer passes through. The device buffers and the row count
+    come in ONE `jax.device_get` (every copy is started before the first
+    is waited on, as on `fetch_host_buffers`' whole path), whatever the
+    column count: the ``d2h`` span's ``buffers`` says how many crossed,
+    its ``round_trips`` (1) how often the device was waited on. WITHOUT
+    copying where the backend allows: a jax CPU array shares its buffer
+    with numpy, and the pull returns a readonly view; an accelerator pays
+    its one unavoidable D2H here, once, instead of per slice."""
     if isinstance(table.num_rows, jax.core.Tracer):
         raise ValueError("host_view of a traced table")
     if is_host_backed(table):
         return table
     tr = spans.current()
     with tr.span("d2h", "d2h") as sp:
+        buffers = [_one_replica(b) for b in _fetched_buffers(table)]
+        num_rows, *host = jax.device_get(buffers)
         cols = tuple(
-            Column(
-                np.asarray(c.data),
-                np.asarray(c.validity) if c.validity is not None else None,
-                c.dtype,
-                c.dictionary,
-            )
-            for c in table.columns
+            Column(data, validity, c.dtype, c.dictionary)
+            for c, (data, validity) in zip(
+                table.columns, _column_buffers(table, host))
         )
-        rows = int(table.num_rows)
+        rows = int(num_rows)
         if tr.active:
             sp.set(bytes=spans.table_nbytes(table), rows=rows,
-                   capacity=table.capacity)
+                   capacity=table.capacity, round_trips=1,
+                   buffers=sum(isinstance(b, jax.Array) for b in buffers))
         return Table(table.names, cols, np.int32(rows))
 
 
@@ -814,10 +827,13 @@ def slice_view(table: Table, lo: int, count: int) -> Table:
 
 
 def _base_buffer(arr: np.ndarray):
-    """Walk the numpy view chain to the owning object (an ndarray, or the
-    memoryview a jax CPU buffer exports)."""
+    """Walk the numpy view chain to the owning object: an ndarray (one
+    that owns its bytes, or the one `jax.device_get` hands back for a jax
+    CPU buffer, whose own base is an opaque capsule), or the memoryview
+    such a buffer exports to `np.asarray`."""
     base = arr
-    while isinstance(base, np.ndarray) and base.base is not None:
+    while isinstance(base, np.ndarray) and isinstance(
+            base.base, (np.ndarray, memoryview)):
         base = base.base
     return base
 

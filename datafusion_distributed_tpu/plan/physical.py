@@ -145,6 +145,10 @@ class ExecContext:
 # traced on this thread (`traced_positions`), for `node_scope`
 _POSITIONS = threading.local()
 
+#: `ProgramTrace.metric_names` entry of a program's own row count: the
+#: root (pre-order position 0) records ``output_rows`` as every node does
+_ROOT_ROWS = (0, "output_rows")
+
 
 @contextlib.contextmanager
 def traced_positions(plan: "ExecutionPlan"):
@@ -823,8 +827,11 @@ def execute_plan(
     plan in `TaskData`, extended across queries). Plans containing nodes the
     fingerprint cannot canonicalize fall back to object-identity keying.
     When ``metrics_store`` is given, the traced
-    per-node metrics are returned as program outputs and inserted under
-    ``task_label`` (runtime/metrics.py MetricsStore protocol).
+    per-node metrics are returned as program outputs, brought to the host
+    on the one pull that brings the flag vector, and inserted under
+    ``task_label`` with the output's row count (runtime/metrics.py
+    MetricsStore protocol, ``rows_out``): the call waits on the device
+    once either way, and the output itself stays on the device.
 
     ``shared_cache``/``shared_key`` let a caller share ONE traced program
     across *distinct plan objects of the same stage* (the worker runtime:
@@ -880,9 +887,26 @@ def execute_plan(
         if result is None:
             result = launch()
         out, flags, metric_vals = result
-        # one fetch for both sentinel checks: the wait for the device
-        with tr.span("sync", "sync", what="flags", values=1, syncs=1):
-            flags = np.asarray(flags)
+        # the program's small outputs in ONE pull, the wait for the
+        # device: the flag vector (both sentinel checks) and, for a
+        # caller that keeps metrics, every traced metric value (every
+        # copy is started before the first is waited on). The output's
+        # row count is among them, the root's ``output_rows``; a trace
+        # that recorded no metrics (``collect_metrics`` off) sends
+        # ``out.num_rows`` along instead
+        pulled = [flags]
+        if metrics_store is not None:
+            pulled += metric_vals
+            names = prog.trace.metric_names
+            if _ROOT_ROWS in names:
+                rows_at = 1 + names.index(_ROOT_ROWS)
+            else:
+                rows_at = len(pulled)
+                pulled.append(out.num_rows)
+        with tr.span("sync", "sync", what="flags", values=len(pulled),
+                     syncs=1):
+            pulled = jax.device_get(pulled)
+        flags = pulled[0]
         if tr.active:
             xsp.set(new_traces=_TRACE_STATS["traces"] - traces_before,
                     **prog.trace.counters)
@@ -892,14 +916,12 @@ def execute_plan(
         # original ids, so callers can look metrics up on their own plan)
         nodes = plan.collect(lambda _n: True)
         node_metrics: dict = {}
-        # each `int(v)` is a blocking read of its own
-        with tr.span("sync", "sync", what="metrics",
-                     values=len(metric_vals), syncs=len(metric_vals)):
-            for (pos, name), v in zip(prog.trace.metric_names, metric_vals):
-                if 0 <= pos < len(nodes):
-                    node_metrics.setdefault(
-                        nodes[pos].node_id, {})[name] = int(v)
-        metrics_store.insert(task_label or f"task{task.task_index}", node_metrics)
+        for (pos, name), v in zip(prog.trace.metric_names, pulled[1:]):
+            if 0 <= pos < len(nodes):
+                node_metrics.setdefault(
+                    nodes[pos].node_id, {})[name] = int(v)
+        metrics_store.insert(task_label or f"task{task.task_index}",
+                             node_metrics, rows_out=int(pulled[rows_at]))
     return out
 
 

@@ -43,6 +43,8 @@ class MetricsStore:
     running-query pin set, and LRU eviction all serialize on one lock."""
 
     per_task: dict = field(default_factory=dict)  # guarded-by: _lock
+    #: task_label -> rows of the task's output (`insert`'s ``rows_out``)
+    rows_out: dict = field(default_factory=dict)  # guarded-by: _lock
     #: query_id -> {stage_id: {"submit_s","start_s","end_s","wall_s",
     #:                          "queue_s","plane"}} (LRU-ordered: a touch
     #: moves the query to the end; eviction pops from the front)
@@ -57,12 +59,18 @@ class MetricsStore:
         #: queries currently executing — exempt from LRU eviction
         self._running: set = set()  # guarded-by: _lock
 
-    def insert(self, task_label: str, node_metrics: dict) -> None:
+    def insert(self, task_label: str, node_metrics: dict,
+               rows_out: Optional[int] = None) -> None:
+        """``rows_out``: the task's output row count, where the executor
+        read it with the metric values (`execute_plan` does: its caller
+        then needs no read of the device for it)."""
         # DFTPU201 fix: concurrent task threads insert into one shared
         # store under the serving tier; an unlocked dict write raced the
         # snapshot reads below
         with self._lock:
             self.per_task[task_label] = node_metrics
+            if rows_out is not None:
+                self.rows_out[task_label] = rows_out
 
     # -- query lifetime (eviction pinning) ----------------------------------
     def begin_query(self, query_id: str) -> None:

@@ -54,10 +54,16 @@ where no tracer is open), and the tracer goes on the thread's stack, so
 that everything below records into the same trace: `execute_plan`'s
 ``prepare`` > ``h2d`` > ``input_wait`` (a consumer blocked on its
 producer stage) and ``execute`` > ``gate_wait``, ``launch`` (the jitted
-call up to its return), ``sync`` (each blocking read of a program's small
-outputs: flags, metric values, a row count; ``syncs`` counts them),
+call up to its return), ``sync`` (the blocking read of a program's small
+outputs, ONE a call of `execute_plan`: the flag vector and, where the
+caller keeps metrics, every metric value and with them the output's row
+count ride one `jax.device_get`; ``values`` says how many, ``syncs`` is
+1. A second ``sync``, ``what=rows``, is left only where a stage output
+stays on the device: the copying plane),
 ``program_lookup`` (the stage's shared-program slot), `host_view`'s
-``d2h``, the ``regroup``. A worker behind a wire (the gRPC server marks
+``d2h`` (a stage output's buffers and its row count in one pull:
+``buffers``, ``round_trips`` 1, beside ``bytes``, ``rows``,
+``capacity``), the ``regroup``. A worker behind a wire (the gRPC server marks
 the context ``wire``) records its phases as plain JSON-able dicts
 carrying that wire parent, and they ride the existing task-progress
 payload back to be spliced into the query trace under the propagated
@@ -315,12 +321,16 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
       `launch` the jitted calls up to their return);
     - ``counters``: ``bytes`` by span kind, ``transfers`` (buffers the
       fetch copied from a device), ``round_trips`` (times the fetch
-      blocked on the device for them), ``retries`` (overflow retries,
-      stamped on the root that succeeded), ``new_traces`` (programs
-      traced afresh), ``masks`` (validity arrays a registration uploaded:
-      one a column that holds a NULL), ``syncs`` (blocking device-to-host
-      reads of a program's small outputs: the flag vector, the metric
-      values, a row count; summed from the ``sync`` spans), ``tasks``
+      blocked on the device for them; both from the ``fetch`` span
+      alone: a stage output's ``d2h`` carries ``buffers`` and
+      ``round_trips`` of its own, which no counter sums), ``retries``
+      (overflow retries, stamped on the root that succeeded),
+      ``new_traces`` (programs traced afresh), ``masks`` (validity arrays
+      a registration uploaded: one a column that holds a NULL), ``syncs``
+      (blocking device-to-host
+      reads of a program's small outputs, summed from the ``sync``
+      spans: ONE span a program's call, so one a worker task, whose
+      flags, metric values and row count ride one pull), ``tasks``
       (worker tasks run: one a ``worker_execute`` span, by which a sum
       over worker threads can be read a task),
       and every name of `spans.PROGRAM_COUNTERS` (what its programs
@@ -333,8 +343,10 @@ def layer_report(store: Optional[TraceStore] = None) -> list:
 
 def _layer_rows(traces) -> list:
     # the span attributes summed into a request's ``counters``
-    summed = (("transfers", "round_trips", "new_traces", "masks", "syncs")
-              + _spans.PROGRAM_COUNTERS)
+    summed = ("new_traces", "masks", "syncs") + _spans.PROGRAM_COUNTERS
+    # the result fetch's own: a stage output's ``d2h`` carries
+    # ``round_trips`` too, and is no fetch
+    of_fetch = ("transfers", "round_trips")
     rows: dict = {}
     for trace in traces:
         root = trace.root_span()
@@ -348,7 +360,7 @@ def _layer_rows(traces) -> list:
                 "wall_s": 0.0,
                 "self_s": {}, "total_s": {},
                 "counters": {"bytes": {}, "retries": 0, "tasks": 0,
-                             **dict.fromkeys(summed, 0)},
+                             **dict.fromkeys(of_fetch + summed, 0)},
             }
         row["traces"].append(trace.query_id)
         row["wall_s"] += root.duration
@@ -366,7 +378,8 @@ def _layer_rows(traces) -> list:
                 counters["bytes"][span.kind] = (
                     counters["bytes"].get(span.kind, 0) + int(nbytes)
                 )
-            for name in summed:
+            names = summed + of_fetch if span.name == "fetch" else summed
+            for name in names:
                 counters[name] += int(span.attrs.get(name, 0) or 0)
             counters["tasks"] += span.name == "worker_execute"
     return list(rows.values())

@@ -74,9 +74,12 @@ def call_with_deadline(fn, timeout: Optional[float], worker_url: str, task):
 
 
 def _row_count(table: Table) -> int:
-    """``int(table.num_rows)``, under a ``sync`` span where that is a
-    blocking read from the device (a `host_view` holds its count on the
-    host)."""
+    """``int(table.num_rows)`` of a partition slice on its way to a
+    consumer, under a ``sync`` span where that is a blocking read from
+    the device: a slice the copying plane regrouped there (``SET
+    distributed.zero_copy = off``). A view of a `host_view` holds its
+    count on the host, and a task's own ``rows_out`` comes with
+    `execute_plan`'s one pull, not from here."""
     if is_host_backed(table):
         return int(table.num_rows)
     with spans.current().span("sync", "sync", what="rows", values=1,
@@ -673,6 +676,7 @@ class Worker:
 
         traces_before = _phys.trace_count()
         store = MetricsStore()
+        label = f"task{key.task_number}"
         tr = spans.current()
         with tr.span("program_lookup", "prepare") as lsp:
             shared_cache, shared_key = self._stage_compile_cache(key, data)
@@ -693,16 +697,15 @@ class Worker:
             DistributedTaskContext(key.task_number, data.task_count),
             config=exec_config or None,
             metrics_store=store,
-            task_label=f"task{key.task_number}",
+            task_label=label,
             use_cache=False,  # freshly decoded plans never hit the cache
             shared_cache=shared_cache,
             shared_key=shared_key,
         )
-        data.metrics["nodes"] = store.per_task.get(
-            f"task{key.task_number}", {}
-        )
+        data.metrics["nodes"] = store.per_task.get(label, {})
         data.finished_at = time.time()
-        data.metrics["rows_out"] = _row_count(out)
+        # on the host already: it rode `execute_plan`'s one pull
+        data.metrics["rows_out"] = store.rows_out[label]
         data.metrics["elapsed_s"] = data.finished_at - data.executed_at
         phase.set(rows=data.metrics["rows_out"])
         if not tr.active:
@@ -737,7 +740,8 @@ class Worker:
             out = self.execute_task(key)
             if zc:
                 out = host_view(out)
-            n = _row_count(out)
+            # on the host since the task's one pull, on either plane
+            n = data.metrics["rows_out"]
         width = row_width(out.schema())
         if n == 0:
             yield out.slice_rows(0, 0), 0

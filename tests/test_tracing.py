@@ -1225,7 +1225,9 @@ def _below(trace) -> dict:
 def _assert_worker_executes_hold_their_work(trace) -> list:
     """Every ``worker_execute`` span of ``trace`` holds its program's
     lookup, ``prepare`` and ``execute``, and ``execute`` its ``launch``
-    and its wait for the flags. -> the ``worker_execute`` spans."""
+    and its ONE wait for the device: the flags, the metric values and
+    with them the row count in one pull. -> the ``worker_execute``
+    spans."""
     kids = _below(trace)
     tasks = [s for s in trace.span_list() if s.name == "worker_execute"]
     for task in tasks:
@@ -1238,16 +1240,14 @@ def _assert_worker_executes_hold_their_work(trace) -> list:
         assert below["program_lookup"].attrs["cache"] in ("hit", "miss")
         inside = kids.get(below["execute"].span_id, ())
         assert {"launch", "sync"} <= {k.name for k in inside}
-        (flags,) = [k for k in inside if k.name == "sync"]
-        assert (flags.kind, flags.attrs["what"], flags.attrs["syncs"]) == (
+        (pull,) = [k for k in inside if k.name == "sync"]
+        assert (pull.kind, pull.attrs["what"], pull.attrs["syncs"]) == (
             "sync", "flags", 1)
-        # the task's own blocking reads: the metric values as one span,
-        # the row count
-        what = {k.attrs["what"]: k for k in kids[task.span_id]
-                if k.name == "sync"}
-        assert set(what) == {"metrics", "rows"}
-        assert what["metrics"].attrs["syncs"] == (
-            what["metrics"].attrs["values"])
+        # the flag vector and at least the root's ``output_rows`` rode it
+        assert pull.attrs["values"] >= 2
+        # and the task reads the device for nothing else (ISSUE 39: the
+        # metric values were a read each, the row count one more)
+        assert not [k for k in kids[task.span_id] if k.name == "sync"]
     return tasks
 
 
@@ -1297,7 +1297,12 @@ def test_served_stage_tasks_record_live_spans(tpch_ctx):
     (row,) = [r for r in layer_report() if r["request"] == h.request_id]
     syncs = [s for s in trace.span_list() if s.name == "sync"]
     assert row["counters"]["syncs"] == sum(s.attrs["syncs"] for s in syncs)
-    assert row["counters"]["syncs"] >= 3 * len(tasks)
+    # one a task: a stage output's row count comes with `host_view`'s pull
+    assert row["counters"]["syncs"] == len(tasks) == len(syncs)
+    # 1 + the task's metric values (``output_rows`` a node): q1's four
+    # producers hold five nodes, its four consumers three, the root two
+    assert sorted(s.attrs["values"] for s in syncs) == (
+        [3] + [4] * 4 + [6] * 4)
     assert row["counters"]["tasks"] == len(tasks)
     assert {"worker", "launch", "sync", "wait", "rpc"} <= set(row["self_s"])
     # what is left to `worker_execute` itself is little of it
@@ -1424,9 +1429,12 @@ def test_every_tier_splits_its_execute_into_launch_and_sync(tier, tpch_ctx):
     assert row["counters"]["tasks"] == tasks
     assert (tasks > 0) == (tier == "coordinator")
     if tier == "coordinator":
-        assert row["counters"]["syncs"] >= 3 * tasks
+        assert row["counters"]["syncs"] == tasks
+        # 1 + the task's metric values (one ``output_rows`` a node)
+        assert all(s.attrs["values"] >= 3 for s in spans["sync"])
     else:
         assert row["counters"]["syncs"] == 1
+        assert [s.attrs["values"] for s in spans["sync"]] == [1]
 
 
 def test_worker_phases_from_many_threads_keep_the_registry_and_the_count():
