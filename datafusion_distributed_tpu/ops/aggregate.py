@@ -30,12 +30,14 @@ group together (SQL semantics), tracked via a folded-in validity lane.
 Where every group key is dictionary-coded and the keys' whole domain fits
 the planned table (`_dictionary_bases`: q1's 3 x 2 codes in 2048 slots; a key
 column that holds a NULL has a validity array and one digit more, 4 x 3 were
-both to), no table is built at all: a row's group id is the mixed-radix number
-of its codes (`direct_group_table`), one fused elementwise pass in place of the
-claim loop. Up to `_DENSE_MAX_DOMAIN` slots every reduction is then
-a dense pass over that domain, padded to the planned ``[num_slots]`` width,
-so the pack and the partial / final state schema keep their shapes; beyond
-it, and for every table the claim loop builds, they stay scatters.
+both to; the planner makes such a table at least as wide as the domain,
+`sql/planner.py _agg_slots`), no table is built at all: a row's group id is
+the mixed-radix number of its codes (`direct_group_table`), one fused
+elementwise pass in place of the claim loop. Up to `_DENSE_MAX_DOMAIN` slots
+every reduction is then a dense pass over that domain, padded to the planned
+``[num_slots]`` width, so the pack and the partial / final state schema keep
+their shapes; beyond it (ClickBench q12's 2.1M search phrases), and for every
+table the claim loop builds, they stay scatters.
 `global_aggregate` is the domain of one: plain masked reductions into slot
 0, no scatter at all.
 """
@@ -372,6 +374,7 @@ def hash_aggregate(
     out_capacity: Optional[int] = None,
     live: Optional[jnp.ndarray] = None,
     direct: Optional[list] = None,
+    scatters: Optional[list] = None,
 ) -> tuple[Table, jnp.ndarray]:
     """GROUP BY aggregation. Returns (result table, overflow flag).
 
@@ -388,6 +391,11 @@ def hash_aggregate(
     no group table was built: the executor's ``direct_groupings``. A domain
     of at most `_DENSE_MAX_DOMAIN` also reduced by dense masked passes and
     not by scatters (`_reduce_by_slot`): its ``dense_aggregates``.
+
+    ``scatters``, when given, collects one entry for each reduction by
+    slot that lowered as a scatter, and one for a direct grouping's
+    slot-presence pass over a domain past `_DENSE_MAX_DOMAIN`: the
+    executor's ``scatter_reductions``.
 
     Modes mirror DataFusion's AggregateMode as used by the reference planner:
       partial        -> emits sum/count/min/max accumulator columns per agg
@@ -409,6 +417,8 @@ def hash_aggregate(
         gt = direct_group_table(key_columns, bases, live, num_slots)
         if direct is not None:
             direct.append(math.prod(bases))
+        if scatters is not None and math.prod(bases) > _DENSE_MAX_DOMAIN:
+            scatters.append("presence")
     else:
         gt = build_group_table(
             [c.data for c in key_columns],
@@ -427,7 +437,7 @@ def hash_aggregate(
     for spec in aggs:
         out_cols.update(
             _eval_agg(spec, table, gid, live, num_slots, mode, prec_flags,
-                      dense_domain)
+                      dense_domain, scatters)
         )
 
     # Pack used slots to the front — into a TIGHTER capacity when the
@@ -496,21 +506,26 @@ def _mean_shifted_seg_sum(vals, valid, seg_sum, group_counts):
 
 
 def _eval_agg(spec, table, gid, live, num_slots, mode, prec_flags,
-              dense_domain):
+              dense_domain, scatters=None):
     """Produce the output column(s) for one AggSpec in the given mode,
     under the scope ``agg.reduce.<func>``; every reduction by slot goes
-    through `_reduce_by_slot` with ``dense_domain``."""
+    through `_reduce_by_slot` with ``dense_domain``, and ``scatters``
+    (when given) gets an entry for each that lowers as a scatter."""
+    def by_slot(op, ids, vals):
+        if dense_domain is None and scatters is not None:
+            scatters.append(op)
+        return _reduce_by_slot(op, ids, vals, num_slots, dense_domain)
+
     def seg_sum(vals, dtype=None):
-        return _reduce_by_slot("sum", gid, vals.astype(dtype or vals.dtype),
-                               num_slots, dense_domain)
+        return by_slot("sum", gid, vals.astype(dtype or vals.dtype))
 
     with jax.named_scope(f"agg.reduce.{spec.func}"):
         return _eval_agg_columns(spec, table, gid, live, num_slots, mode,
-                                 seg_sum, prec_flags, dense_domain)
+                                 seg_sum, by_slot, prec_flags)
 
 
 def _eval_agg_columns(spec, table, gid, live, num_slots, mode, seg_sum,
-                      prec_flags, dense_domain):
+                      by_slot, prec_flags):
     name = spec.output_name
     if spec.func == "count_star":
         if mode in ("final", "partial_reduce"):
@@ -535,10 +550,8 @@ def _eval_agg_columns(spec, table, gid, live, num_slots, mode, seg_sum,
             if spec.func == "sum":
                 _check_int32_sum_range(vals, seg_sum, prec_flags)
         else:
-            merged = _reduce_by_slot(
-                spec.func, jnp.where(valid, gid, num_slots), acc.data,
-                num_slots, dense_domain,
-            )
+            merged = by_slot(
+                spec.func, jnp.where(valid, gid, num_slots), acc.data)
         nonempty = seg_sum(jnp.where(valid, 1, 0).astype(_ACC_INT))
         if spec.func == "count":
             return {name: Column(merged, None, DataType.INT64)}
@@ -634,8 +647,7 @@ def _eval_agg_columns(spec, table, gid, live, num_slots, mode, seg_sum,
         return {name: Column(avg, cnt > 0, DataType.FLOAT64)}
 
     if spec.func in ("min", "max"):
-        red = _reduce_by_slot(spec.func, vgid, col.data, num_slots,
-                              dense_domain)
+        red = by_slot(spec.func, vgid, col.data)
         nonempty = seg_sum(jnp.where(valid, 1, 0).astype(_ACC_INT))
         return {
             name: Column(red, nonempty > 0, col.dtype, col.dictionary)

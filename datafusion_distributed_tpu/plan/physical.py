@@ -107,6 +107,10 @@ class ExecContext:
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] += n
 
+    def count_largest(self, name: str, n: int) -> None:
+        """A counter that keeps the largest value seen, not the sum."""
+        self.counters[name] = max(self.counters[name], n)
+
     def child(self) -> "ExecContext":
         """A context to trace a part of the plan apart from this one (a
         `lax.cond` branch, a probe for shapes): this context's task,
@@ -595,22 +599,27 @@ class HashAggregateExec(ExecutionPlan):
         t, live, _ = self.child.execute_masked(ctx)
         prec_flags: list = []
         direct: list = []
+        scatters: list = []
         if not self.group_names:
             from datafusion_distributed_tpu.ops.aggregate import global_aggregate
 
             out = global_aggregate(t, self.aggs, self.mode,
                                    prec_flags=prec_flags, live=live)
             ctx.count("dense_aggregates")  # one slot: no scatter to choose
+            ctx.count_largest("group_slots", 1)
         else:
             out, overflow = hash_aggregate(
                 t, self.group_names, self.aggs, self.num_slots, self.mode,
                 prec_flags=prec_flags, out_capacity=self.out_capacity,
-                live=live, direct=direct,
+                live=live, direct=direct, scatters=scatters,
             )
             ctx.record_overflow(self, overflow)
             ctx.count("direct_groupings", len(direct))
             ctx.count("dense_aggregates",
                       sum(d <= _DENSE_MAX_DOMAIN for d in direct))
+            ctx.count_largest("group_slots",
+                              direct[0] if direct else self.num_slots)
+            ctx.count("scatter_reductions", len(scatters))
         for f in prec_flags:
             ctx.record_precision_error(self, f)
         return out

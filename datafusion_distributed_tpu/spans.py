@@ -51,8 +51,13 @@ PROFILE_PREFIX = "dftpu."
 #: aggregates that addressed their groups by dictionary codes and built
 #: no group table; ``dense_aggregates``: aggregates, grouped or global,
 #: whose reductions ran as dense masked passes over a small known domain
-#: and not as scatters.
-PROGRAM_COUNTERS = ("masked_filters", "direct_groupings", "dense_aggregates")
+#: and not as scatters; ``group_slots``: the slots of the largest group
+#: table the program built (the claim loop's) or addressed (a direct
+#: grouping's domain; 1 for a global aggregate), `ExecContext.count_largest`;
+#: ``scatter_reductions``: the per-slot reductions, and a direct
+#: grouping's slot-presence pass, that lowered as scatters.
+PROGRAM_COUNTERS = ("masked_filters", "direct_groupings", "dense_aggregates",
+                    "group_slots", "scatter_reductions")
 
 _SPAN_CAP = 4096     # ring-buffer bound per query
 _EVENT_CAP = 2048    # trace-level event bound per query

@@ -134,6 +134,19 @@ class Catalog:
                 return ndv
             return self._ndv_cache.setdefault(key, ndv)
 
+    def column_domain(self, table: str, column: str):
+        """The codes a dictionary column's rows can hold: ``len`` of its
+        dictionary, one more where it has a validity array (NULL is a key
+        of its own; `ops/aggregate.py _dictionary_bases`). None for a
+        column without a dictionary."""
+        t = self.tables.get(table.lower())
+        if t is None or column not in t:
+            return None
+        col = t.column(column)
+        if col.dictionary is None:
+            return None
+        return len(col.dictionary) + (col.validity is not None)
+
     def scan_exec(self, name: str, columns: Sequence[str]) -> ExecutionPlan:
         t = self.tables[name.lower()]
         return MemoryScanExec([t.select(columns)], t.schema().select(columns))
@@ -1420,6 +1433,11 @@ class _ViewCatalog:
         if table.lower() in self.views:
             return None
         return self.catalog.column_ndv(table, column)
+
+    def column_domain(self, table: str, column: str):
+        if table.lower() in self.views:
+            return None
+        return self.catalog.column_domain(table, column)
 
     def scan_exec(self, name: str, columns):
         return self.catalog.scan_exec(name, columns)

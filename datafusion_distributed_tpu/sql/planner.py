@@ -49,6 +49,14 @@ class PlannerConfig:
     max_out_capacity: int = 1 << 22
 
 
+#: the widest group table a GROUP BY over dictionary keys is given so that
+#: it can address its groups by their codes (`PhysicalPlanner._agg_slots`):
+#: a table of that width is a few [slots] arrays of 4 bytes (keys, one
+#: state an aggregate, the used slots, the packed output), 256 MiB each
+#: at 2^26, a fraction of one v5e chip's 16 GB beside the tables
+DIRECT_MAX_SLOTS = 1 << 26
+
+
 class PhysicalPlanner:
     def __init__(self, catalog, config: Optional[PlannerConfig] = None,
                  subquery_executor=None):
@@ -207,11 +215,11 @@ class PhysicalPlanner:
 
             by_name = dict(zip([a.name for a in node.aggs], specs))
             plain_specs = [by_name[a.name] for a in regular]
-            groups_ndv = self._exprs_ndv(node.child,
-                                         [e for e, _ in node.groups],
-                                         loose=True)
-            slots = self._agg_slots(proj.output_capacity(), groups_ndv,
-                                    child=proj)
+            group_exprs = [e for e, _ in node.groups]
+            groups_ndv = self._exprs_ndv(node.child, group_exprs, loose=True)
+            slots = self._agg_slots(
+                proj.output_capacity(), groups_ndv, child=proj,
+                domain=self._dictionary_domain(node.child, group_exprs))
             base_slots = 16 if not group_names else slots
             combined = HashAggregateExec(
                 "single", group_names, plain_specs, proj, base_slots
@@ -220,13 +228,15 @@ class PhysicalPlanner:
                 combined.est_rows = float(groups_ndv)
             for i, a in enumerate(distinct_aggs):
                 s = by_name[a.name]
-                dedup_ndv = self._exprs_ndv(
-                    node.child, [e for e, _ in node.groups] + [a.arg],
-                    loose=True,
-                )
+                dedup_exprs = group_exprs + [a.arg]
+                dedup_ndv = self._exprs_ndv(node.child, dedup_exprs,
+                                            loose=True)
                 dedup = HashAggregateExec(
                     "single", group_names + [s.input_name], [], proj,
-                    self._agg_slots(proj.output_capacity(), dedup_ndv, child=proj),
+                    self._agg_slots(
+                        proj.output_capacity(), dedup_ndv, child=proj,
+                        domain=self._dictionary_domain(node.child,
+                                                       dedup_exprs)),
                 )
                 cnt = HashAggregateExec(
                     "single", group_names,
@@ -257,13 +267,12 @@ class PhysicalPlanner:
         if distinct_aggs:
             # COUNT(DISTINCT x): dedup (groups + x), then count per group.
             inner_groups = group_names + [s.input_name for s in specs]
-            inner_ndv = self._exprs_ndv(
-                node.child,
-                [e for e, _ in node.groups] + [a.arg for a in node.aggs],
-                loose=True,
-            )
-            slots = self._agg_slots(proj.output_capacity(), inner_ndv,
-                                    child=proj)
+            inner_exprs = ([e for e, _ in node.groups]
+                           + [a.arg for a in node.aggs])
+            inner_ndv = self._exprs_ndv(node.child, inner_exprs, loose=True)
+            slots = self._agg_slots(
+                proj.output_capacity(), inner_ndv, child=proj,
+                domain=self._dictionary_domain(node.child, inner_exprs))
             dedup = HashAggregateExec("single", inner_groups, [], proj, slots)
             if inner_ndv:
                 # estimate_rows(dedup) would otherwise fall back to
@@ -272,11 +281,11 @@ class PhysicalPlanner:
             outer_specs = [
                 AggSpec("count", s.input_name, s.output_name) for s in specs
             ]
-            groups_ndv = self._exprs_ndv(node.child,
-                                         [e for e, _ in node.groups],
-                                         loose=True)
-            slots2 = self._agg_slots(dedup.output_capacity(), groups_ndv,
-                                     child=dedup)
+            group_exprs = [e for e, _ in node.groups]
+            groups_ndv = self._exprs_ndv(node.child, group_exprs, loose=True)
+            slots2 = self._agg_slots(
+                dedup.output_capacity(), groups_ndv, child=dedup,
+                domain=self._dictionary_domain(node.child, group_exprs))
             out = HashAggregateExec(
                 "single", group_names, outer_specs, dedup, slots2
             )
@@ -284,10 +293,11 @@ class PhysicalPlanner:
                 out.est_rows = float(groups_ndv)
             return out
 
-        groups_ndv = self._exprs_ndv(node.child, [e for e, _ in node.groups],
-                                     loose=True)
-        slots = self._agg_slots(proj.output_capacity(), groups_ndv,
-                                child=proj)
+        group_exprs = [e for e, _ in node.groups]
+        groups_ndv = self._exprs_ndv(node.child, group_exprs, loose=True)
+        slots = self._agg_slots(
+            proj.output_capacity(), groups_ndv, child=proj,
+            domain=self._dictionary_domain(node.child, group_exprs))
         out = HashAggregateExec("single", group_names, specs, proj, slots)
         if groups_ndv:
             # catalog NDV as the group-count estimate (replaces the cost
@@ -328,7 +338,7 @@ class PhysicalPlanner:
         return None
 
     def _agg_slots(self, cap: int, ndv: Optional[int] = None,
-                   child=None) -> int:
+                   child=None, domain: Optional[int] = None) -> int:
         """Hash-table slots for a group-by: capacity-bounded, NDV-driven,
         row-estimate-capped.
 
@@ -349,6 +359,13 @@ class PhysicalPlanner:
         NDV-product saturates while the filtered join feeds ~29k rows).
         Row estimates are coarser than sampled NDV, so this bound gets 4x
         headroom instead of 2x; an underestimate costs one overflow-retry.
+
+        ``domain`` (`_dictionary_domain`: every key a dictionary column)
+        is a floor none of the three bounds lowers: a table at least that
+        wide addresses its groups by their codes and builds nothing
+        (`ops/aggregate.py _dictionary_bases`), where a narrower one runs
+        the claim loop over more groups than slots and overflows. Only
+        what the chip can hold, `DIRECT_MAX_SLOTS`, bounds it.
         """
         by_cap = min(
             round_up_pow2(max(int(cap * self.config.agg_slot_factor), 16)),
@@ -370,11 +387,27 @@ class PhysicalPlanner:
                 max(int(est * self.config.agg_slot_factor * 4), 16)
             )
             best = min(best, by_est)
+        if domain and round_up_pow2(domain) <= DIRECT_MAX_SLOTS:
+            best = max(best, round_up_pow2(domain))
         return best
+
+    def _dictionary_domain(self, child: lg.LogicalPlan,
+                           exprs: Sequence[pe.PhysicalExpr]) -> Optional[int]:
+        """The product of the group keys' dictionary domains
+        (`Catalog.column_domain`) where every key is a base table's
+        dictionary column, followed through projection aliases; else None.
+        A group table at least this wide is addressed by the keys' codes
+        (`ops/aggregate.py _dictionary_bases`), and `_agg_slots` gives it
+        that width whatever ``max_slots`` says."""
+        if not exprs or getattr(self.catalog, "column_domain", None) is None:
+            return None
+        return self._exprs_ndv(child, exprs,
+                               column_stat=self.catalog.column_domain)
 
     def _exprs_ndv(self, child: lg.LogicalPlan,
                    exprs: Sequence[pe.PhysicalExpr],
-                   loose: bool = False) -> Optional[int]:
+                   loose: bool = False,
+                   column_stat=None) -> Optional[int]:
         """Distinct-count estimate for a tuple of expressions, or None.
 
         Two modes:
@@ -397,8 +430,11 @@ class PhysicalPlanner:
         the extrapolation threshold they can undercount true NDV, so the
         catalog pads non-extrapolated sampled counts (see
         `Catalog.column_ndv`) — treat the result as a best-effort sizing
-        hint backed by the overflow-retry loop, not a hard upper bound."""
-        ndv_fn = getattr(self.catalog, "column_ndv", None)
+        hint backed by the overflow-retry loop, not a hard upper bound.
+
+        ``column_stat(table, column)``, when given, stands in for the
+        catalog's NDV (`_dictionary_domain` passes the dictionary's)."""
+        ndv_fn = column_stat or getattr(self.catalog, "column_ndv", None)
         if ndv_fn is None:
             return None
         aliases: dict[str, str] = {}

@@ -1,8 +1,10 @@
-"""`BENCHMARK.json` against the files it names, and the cell PR 34 added,
-`sf10-direct-q1` (`tpch-sf10-direct`: TPC-H SF10 resident on one chip),
-rehearsed on the CPU at SF0.01 through the function a chip run calls,
-`run.run_cell`, traced and not, against the benchmark's plain reference.
-Answers and counts only: none of the numbers is a measurement."""
+"""`BENCHMARK.json` against the files it names, and two cells rehearsed on
+the CPU through the function a chip run calls, `run.run_cell`, traced and
+not, against the benchmark's plain reference: `sf10-direct-q1`
+(`tpch-sf10-direct`: TPC-H SF10 resident on one chip) at SF0.01 and
+`cb-direct-q12` (`clickbench-half-direct`: half of ClickBench's hits on one
+chip) at 20,000 rows. Answers and counts only: none of the numbers is a
+measurement."""
 
 import importlib.util
 import json
@@ -13,6 +15,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED = 3000000019  # more than 32 signed bits hold, as the driver's are
 CELL, CONFIG = "sf10-direct-q1", "tpch-sf10-direct"
+CB_CELL, CB_CONFIG = "cb-direct-q12", "clickbench-half-direct"
 
 
 def _load(name: str, *parts: str):
@@ -139,19 +142,25 @@ def test_the_uncached_suite_refuses_a_program_that_cannot_set_up_in_a_run(
     assert "tpch-uncached" in str(refused.value.code)
 
 
+#: the first configurations and cells of BENCHMARK.json, in their order:
+#: the accepted entries, then this PR's; a later PR appends after them
+CONFIGS = ["tpch-sf1-direct", "tpch-sf1-mesh4", "tpch-sf1-coord4", CONFIG,
+           CB_CONFIG]
+CELLS = ["direct-q1", "mesh4-q1", "direct-q6", "coord4-q1", CELL, CB_CELL]
+
+
 def test_benchmark_json_lists_the_sf10_configuration_and_its_cell():
     bench = benchmark_json()
     workload = run.read_json("workloads", f"{CELL}.json")
     config = run.read_json("configs", f"{CONFIG}.json")
-    # appended: the accepted entries before them, in their order
-    assert [c["name"] for c in bench["configs"]] == [
-        "tpch-sf1-direct", "tpch-sf1-mesh4", "tpch-sf1-coord4", CONFIG]
-    assert [w["name"] for w in bench["workloads"]] == [
-        "direct-q1", "mesh4-q1", "direct-q6", "coord4-q1", CELL]
-    assert bench["workloads"][-1] == {
+    # appended: the accepted entries before them, in their order, and the
+    # later ones after them
+    assert [c["name"] for c in bench["configs"]][:len(CONFIGS)] == CONFIGS
+    assert [w["name"] for w in bench["workloads"]][:len(CELLS)] == CELLS
+    assert bench["workloads"][CELLS.index(CELL)] == {
         "name": CELL, "config": CONFIG, "traffic": "q1-closed1", "chips": 1,
         "why": workload["why"]}
-    assert bench["configs"][-1] == {
+    assert bench["configs"][CONFIGS.index(CONFIG)] == {
         "name": CONFIG, "source": config["source"],
         "file": f"benchmarks/chip/configs/{CONFIG}.json", "reduced": [],
         "why": config["deployment"]}
@@ -207,7 +216,7 @@ def test_benchmark_json_agrees_with_the_files():
     # two deployments from one public benchmark: sources that differ
     assert len({c["source"] for c in configs.values()}) == len(configs)
     cells = [c["name"] for c in bench["workloads"]]
-    assert len(cells) == len(set(cells)) == 5
+    assert len(cells) == len(set(cells)) and cells[:len(CELLS)] == CELLS
     assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1 <= max(
         1, len(cells) // 2)
     end_to_end = {m["name"] for m in bench["end_to_end"]}
@@ -228,3 +237,116 @@ def test_benchmark_json_agrees_with_the_files():
         assert {"setup_s", "query_p50_s"} <= {
             m["name"] for m in run.cell_metrics(cell, False)}
         assert run.cell_metrics(cell, True)
+
+
+# -- cb-direct-q12: ClickBench's hits, this chip's half ----------------------
+
+#: 20,000 rows of hits: ceil(99,997,497 x scale)
+CB_SCALE = 0.0002
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["plain", "traced"])
+def test_the_clickbench_cell_agrees_with_the_reference(trace, capsys,
+                                                       as_a_chip_run_is_set_up):
+    result = run.run_cell(CB_CELL, SEED, 0.5, trace, scale=CB_SCALE)
+    assert result["correct"] is True
+    assert result["attempted"] >= 1 and result["failed"] == 0
+    assert list(result)[-1] == "compared"
+    assert {name: n["value"] for name, n in result["compared"].items()} == {
+        "rows_off": 0, "columns_off": 0, "cells_differing": 0,
+        "rows_out_of_order": 0, "results_missing": 0}
+    audit = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    assert (audit[0]["cell"], audit[0]["seed"]) == (CB_CELL, SEED)
+    window = next(line for line in audit if "samples" in line)
+    assert window["compiles_in_window"] == 0
+    assert window["rows"] == {"hits": 20_000}
+    reported = set(result["metrics"])
+    wanted = {m["name"] for m in run.cell_metrics(CB_CELL, trace)}
+    assert wanted - {"peak_device_gb", "device_busy_ms",
+                     "hbm_roofline_share"} <= reported <= wanted
+    if trace:
+        # every per-layer metric that lists no cells, and the two of its own
+        assert wanted == {
+            "generate_s", "register_s", "warmup_s", "compiles_in_window",
+            "parse_ms", "execute_ms", "fetch_ms", "overflow_retries",
+            "device_busy_ms", "hbm_roofline_share", "group_slots",
+            "scatter_reductions"}
+        assert result["attempted"] == 1  # the mix's traced_queries
+        metrics = {name: m["value"] for name, m in result["metrics"].items()}
+        assert metrics["overflow_retries"] == 0
+        # 20,000 rows draw a few thousand phrases: a direct grouping over
+        # them, reduced densely (past 4,096 the count and the slot-presence
+        # pass are scatters: tests/test_dictionary_domain.py)
+        assert 2_000 < metrics["group_slots"] <= 4_096
+        assert metrics["scatter_reductions"] == 0
+    else:
+        assert wanted == {"setup_s", "query_p50_s", "queries_per_s",
+                          "peak_device_gb"}
+
+
+def test_the_clickbench_cells_least_bytes_are_one_column_of_every_row():
+    """`hbm_roofline_share` through q12's sidecar: SearchPhrase, 4 B a row."""
+    suite = run.load_module("suites", "clickbench", "suite.py")
+
+    class Rows:
+        num_rows = 49_998_749
+
+    assert suite.least_bytes("q12", {"hits": Rows}) == 4 * 49_998_749
+    assert suite.HITS_ROWS == 99_997_497
+
+
+@pytest.mark.parametrize("lacking", ["SEARCH_PHRASES", "DIRECT_MAX_SLOTS"])
+def test_the_clickbench_suite_refuses_a_program_that_cannot_run_it(
+        lacking, monkeypatch, tmp_path):
+    """A program without the generator's phrase law or the planner's
+    domain-wide group tables (the parent of the PR that brought the cell)
+    fails at once and cleanly, before any data is made, and does not hang
+    in the claim loop."""
+    from datafusion_distributed_tpu.data import clickbenchgen
+    from datafusion_distributed_tpu.sql import planner
+
+    suite = run.load_module("suites", "clickbench", "suite.py")
+    monkeypatch.delattr(
+        clickbenchgen if lacking == "SEARCH_PHRASES" else planner, lacking)
+    monkeypatch.setattr(clickbenchgen, "gen_clickbench",
+                        lambda *a: pytest.fail("made"))
+    with pytest.raises(SystemExit) as refused:
+        suite.load(0.5, SEED, str(tmp_path))
+    assert refused.value.code not in (None, 0)
+    assert "suites/clickbench" in str(refused.value.code)
+
+
+def test_benchmark_json_lists_the_clickbench_configuration_and_its_cell():
+    bench = benchmark_json()
+    workload = run.read_json("workloads", f"{CB_CELL}.json")
+    config = run.read_json("configs", f"{CB_CONFIG}.json")
+    assert bench["workloads"][CELLS.index(CB_CELL)] == {
+        "name": CB_CELL, "config": CB_CONFIG, "traffic": "cb-q12-closed1",
+        "chips": 1, "why": workload["why"]}
+    assert bench["configs"][CONFIGS.index(CB_CONFIG)] == {
+        "name": CB_CONFIG, "source": config["source"],
+        "file": f"benchmarks/chip/configs/{CB_CONFIG}.json",
+        "reduced": ["rows", "columns"], "why": config["deployment"]}
+    assert len(config["source"]) <= 200 and "ClickBench" in config["source"]
+    assert len(workload["why"]) <= 200 and len(config["deployment"]) <= 200
+    assert set(config["cuts"]) == set(config["reduced"])
+    assert config["cuts"]["rows"].startswith("99,997,497 -> 49,998,749")
+    assert config["cuts"]["columns"].startswith("105 -> 25")
+    assert (config["suite"], config["scale"], config["tier"],
+            config["tier_args"], config["chips"]) == (
+        "clickbench", 0.5, "direct", {}, 1)
+    assert run.read_json("traffic", "cb-q12-closed1.json") == {
+        "loop": "closed", "queries": ["q12"], "clients": 1,
+        "traced_queries": 1}
+    # the cell's own metrics are the two new ones, each listing it alone;
+    # no accepted metric's list names it
+    for kind in ("end_to_end", "per_layer"):
+        assert [m["name"] for m in bench[kind]
+                if CB_CELL in m.get("workloads", [])] == (
+            ["group_slots", "scatter_reductions"] if kind == "per_layer"
+            else [])
+    for name in ("group_slots", "scatter_reductions"):
+        (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+        assert (entry["unit"], entry["layer"], entry["source"],
+                entry["moves"], entry["workloads"]) == (
+            "count", "operators", "program_counter", "query_p50_s", [CB_CELL])

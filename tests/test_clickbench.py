@@ -39,6 +39,7 @@ EXACT = ["q0", "q1", "q2", "q3", "q5"]
 TOPK = {  # qname -> (merge keys, float cols)
     "q8": (["RegionID"], []),
     "q9": (["RegionID"], ["a"]),
+    "q12": (["SearchPhrase"], []),
     "q13": (["SearchPhrase"], []),
     "q18": (["UserID", "m", "SearchPhrase"], []),
     "q21": (["SearchPhrase"], []),
@@ -95,6 +96,9 @@ def _oracle(qname: str, h: pd.DataFrame) -> pd.DataFrame:
             s=("AdvEngineID", "sum"), c=("RegionID", "size"),
             a=("ResolutionWidth", "mean"), u=("UserID", "nunique"),
         ).reset_index()
+    if qname == "q12":
+        m = h[h.SearchPhrase != ""]
+        return m.groupby("SearchPhrase").size().rename("c").reset_index()
     if qname == "q13":
         m = h[h.SearchPhrase != ""]
         return (m.groupby("SearchPhrase")["UserID"].nunique().rename("c")
