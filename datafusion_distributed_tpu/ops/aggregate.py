@@ -37,7 +37,9 @@ elementwise pass in place of the claim loop. Up to `_DENSE_MAX_DOMAIN` slots
 every reduction is then a dense pass over that domain, padded to the planned
 ``[num_slots]`` width, so the pack and the partial / final state schema keep
 their shapes; beyond it (ClickBench q12's 2.1M search phrases), and for every
-table the claim loop builds, they stay scatters.
+table the claim loop builds, they stay scatters, and a direct grouping
+whose aggregates count each slot's live rows (COUNT(*)) reads its used
+slots off that one count rather than scatter them a second time.
 `global_aggregate` is the domain of one: plain masked reductions into slot
 0, no scatter at all.
 """
@@ -260,7 +262,8 @@ def _dictionary_bases(key_columns: Sequence[Column],
 #: Up to this domain a direct grouping touches no scatter: `_reduce_by_slot`
 #: reduces by dense masked passes and `direct_group_table` finds the used
 #: slots by comparing every row's id with each slot (N x D compares, fused
-#: into one pass); beyond it each is a scatter, N serialized updates. On the
+#: into one pass); beyond it each is a scatter, N serialized updates (the
+#: presence none where a COUNT(*) scatters the same ids). On the
 #: v5e (my chip run, PERF.md, PR 32), ms for 8Mi rows (2Mi in brackets), one
 #: f32 sum and ten that share a pass, an int32 count and an f32 min reading
 #: as the one sum does to 0.1 ms:
@@ -280,33 +283,52 @@ def _dictionary_bases(key_columns: Sequence[Column],
 _DENSE_MAX_DOMAIN = 4096
 
 
+def _radices(bases: Sequence[int]) -> list[int]:
+    return [math.prod(bases[i + 1:]) for i in range(len(bases))]
+
+
 @scoped("agg.direct")
-def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
-                       live: jnp.ndarray, num_slots: int) -> GroupTable:
-    """`build_group_table`'s result with no table built: the group id of a
-    row is ``sum(digit_i * radix_i)`` over its key columns, ``digit_i`` the
-    dictionary code of a valid key and ``len(dictionary_i)`` of a NULL one
-    (``bases`` from `_dictionary_bases`). Slot ``s`` of the domain holds the
-    keys ``(s // radix_i) % base_i``; a slot is used if some live row has
-    its id. The arrays keep the planned ``[num_slots]`` width, so what
-    packs by slot sees the claim loop's shapes (the reductions read the
-    domain off the same rule: `hash_aggregate`, `_reduce_by_slot`); groups
-    come out in radix order."""
-    domain = math.prod(bases)
-    radices = [math.prod(bases[i + 1:]) for i in range(len(bases))]
+def direct_group_ids(key_columns: Sequence[Column], bases: Sequence[int],
+                     live: jnp.ndarray, num_slots: int) -> jnp.ndarray:
+    """The group id of each row with no table built: ``sum(digit_i *
+    radix_i)`` over its key columns, ``digit_i`` the dictionary code of a
+    valid key and ``len(dictionary_i)`` of a NULL one (``bases`` from
+    `_dictionary_bases`); a dead row's id is ``num_slots``, which names no
+    slot."""
     gid = jnp.zeros(live.shape, dtype=jnp.int32)
-    for col, radix in zip(key_columns, radices):
+    for col, radix in zip(key_columns, _radices(bases)):
         digit = col.data.astype(jnp.int32)
         if col.validity is not None:
             digit = jnp.where(col.validity, digit, len(col.dictionary))
         gid = gid + digit * np.int32(radix)
-    gid = jnp.where(live, gid, num_slots)  # dead rows use no slot
+    return jnp.where(live, gid, num_slots)  # dead rows use no slot
+
+
+@scoped("agg.direct")
+def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
+                       gid: jnp.ndarray, num_slots: int,
+                       slot_counts: Optional[jnp.ndarray] = None
+                       ) -> GroupTable:
+    """`build_group_table`'s result over the ids `direct_group_ids` gave.
+    Slot ``s`` of the domain holds the keys ``(s // radix_i) % base_i``; a
+    slot is used if some live row has its id: up to `_DENSE_MAX_DOMAIN`
+    found by comparing every id with each slot; past it read off
+    ``slot_counts`` (the live rows of each slot, which an aggregate that
+    counts them has reduced already: `hash_aggregate`) where given, else a
+    scatter of its own. The arrays keep the planned ``[num_slots]`` width,
+    so what packs by slot sees the claim loop's shapes (the reductions read
+    the domain off the same rule: `hash_aggregate`, `_reduce_by_slot`);
+    groups come out in radix order."""
+    domain = math.prod(bases)
+    radices = _radices(bases)
     if domain <= _DENSE_MAX_DOMAIN:
         present = jnp.any(
             jnp.arange(domain, dtype=jnp.int32)[:, None] == gid[None, :],
             axis=1,
         )
         slot_used = jnp.pad(present, (0, num_slots - domain))
+    elif slot_counts is not None:
+        slot_used = slot_counts > 0
     else:
         slot_used = jnp.zeros(num_slots, dtype=jnp.bool_).at[gid].set(
             True, mode="drop"
@@ -364,6 +386,13 @@ def _reduce_by_slot(op: str, ids, vals, num_slots: int,
                    constant_values=identity)
 
 
+def _counts_live_rows(spec: AggSpec, mode: str) -> bool:
+    """Whether ``spec``'s value is the number of live rows of each slot:
+    COUNT(*) over raw rows. In ``final`` and ``partial_reduce`` it sums
+    partial counts instead."""
+    return spec.func == "count_star" and mode in ("single", "partial")
+
+
 def hash_aggregate(
     table: Table,
     group_names: Sequence[str],
@@ -375,6 +404,7 @@ def hash_aggregate(
     live: Optional[jnp.ndarray] = None,
     direct: Optional[list] = None,
     scatters: Optional[list] = None,
+    presence_from_count: Optional[list] = None,
 ) -> tuple[Table, jnp.ndarray]:
     """GROUP BY aggregation. Returns (result table, overflow flag).
 
@@ -394,8 +424,15 @@ def hash_aggregate(
 
     ``scatters``, when given, collects one entry for each reduction by
     slot that lowered as a scatter, and one for a direct grouping's
-    slot-presence pass over a domain past `_DENSE_MAX_DOMAIN`: the
-    executor's ``scatter_reductions``.
+    slot-presence pass over a domain past `_DENSE_MAX_DOMAIN` that
+    scatters: the executor's ``scatter_reductions``.
+
+    Past `_DENSE_MAX_DOMAIN` a direct grouping whose aggregates count the
+    live rows of each slot (`_counts_live_rows`) reduces that count once,
+    hands it to every such aggregate, and reads its used slots off it
+    (``count > 0``) in place of a presence scatter; ``presence_from_count``,
+    when given, collects the domain of each grouping that did: the
+    executor's ``presence_from_count``.
 
     Modes mirror DataFusion's AggregateMode as used by the reference planner:
       partial        -> emits sum/count/min/max accumulator columns per agg
@@ -413,12 +450,24 @@ def hash_aggregate(
         live = table.row_mask()
     key_columns = [table.column(g) for g in group_names]
     bases = _dictionary_bases(key_columns, num_slots)
+    row_counts = None  # [num_slots]: the live rows of each slot, reduced once
     if bases is not None:
-        gt = direct_group_table(key_columns, bases, live, num_slots)
+        domain = math.prod(bases)
+        ids = direct_group_ids(key_columns, bases, live, num_slots)
+        counter = next((s for s in aggs if _counts_live_rows(s, mode)), None)
+        if domain > _DENSE_MAX_DOMAIN and counter is not None:
+            row_counts = _eval_agg(counter, table, ids, live, num_slots, mode,
+                                   prec_flags, None, scatters
+                                   )[counter.output_name].data
+        gt = direct_group_table(key_columns, bases, ids, num_slots,
+                                row_counts)
         if direct is not None:
-            direct.append(math.prod(bases))
-        if scatters is not None and math.prod(bases) > _DENSE_MAX_DOMAIN:
+            direct.append(domain)
+        if (scatters is not None and domain > _DENSE_MAX_DOMAIN
+                and row_counts is None):
             scatters.append("presence")
+        if presence_from_count is not None and row_counts is not None:
+            presence_from_count.append(domain)
     else:
         gt = build_group_table(
             [c.data for c in key_columns],
@@ -435,6 +484,10 @@ def hash_aggregate(
     if bases is not None and math.prod(bases) <= _DENSE_MAX_DOMAIN:
         dense_domain = math.prod(bases)
     for spec in aggs:
+        if row_counts is not None and _counts_live_rows(spec, mode):
+            out_cols[spec.output_name] = Column(row_counts, None,
+                                                DataType.INT64)
+            continue
         out_cols.update(
             _eval_agg(spec, table, gid, live, num_slots, mode, prec_flags,
                       dense_domain, scatters)

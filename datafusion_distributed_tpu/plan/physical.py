@@ -600,6 +600,7 @@ class HashAggregateExec(ExecutionPlan):
         prec_flags: list = []
         direct: list = []
         scatters: list = []
+        presence_from_count: list = []
         if not self.group_names:
             from datafusion_distributed_tpu.ops.aggregate import global_aggregate
 
@@ -612,6 +613,7 @@ class HashAggregateExec(ExecutionPlan):
                 t, self.group_names, self.aggs, self.num_slots, self.mode,
                 prec_flags=prec_flags, out_capacity=self.out_capacity,
                 live=live, direct=direct, scatters=scatters,
+                presence_from_count=presence_from_count,
             )
             ctx.record_overflow(self, overflow)
             ctx.count("direct_groupings", len(direct))
@@ -620,6 +622,7 @@ class HashAggregateExec(ExecutionPlan):
             ctx.count_largest("group_slots",
                               direct[0] if direct else self.num_slots)
             ctx.count("scatter_reductions", len(scatters))
+            ctx.count("presence_from_count", len(presence_from_count))
         for f in prec_flags:
             ctx.record_precision_error(self, f)
         return out

@@ -55,9 +55,11 @@ PROFILE_PREFIX = "dftpu."
 #: table the program built (the claim loop's) or addressed (a direct
 #: grouping's domain; 1 for a global aggregate), `ExecContext.count_largest`;
 #: ``scatter_reductions``: the per-slot reductions, and a direct
-#: grouping's slot-presence pass, that lowered as scatters.
+#: grouping's slot-presence pass, that lowered as scatters;
+#: ``presence_from_count``: direct groupings past the dense cut whose used
+#: slots were read off the COUNT(*) they reduce, with no presence scatter.
 PROGRAM_COUNTERS = ("masked_filters", "direct_groupings", "dense_aggregates",
-                    "group_slots", "scatter_reductions")
+                    "group_slots", "scatter_reductions", "presence_from_count")
 
 _SPAN_CAP = 4096     # ring-buffer bound per query
 _EVENT_CAP = 2048    # trace-level event bound per query

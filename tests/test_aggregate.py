@@ -374,6 +374,106 @@ def test_direct_grouping_equals_the_claim_loop(case, mode, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# past the dense cut: the used slots are read off a COUNT(*) already reduced
+# ---------------------------------------------------------------------------
+
+_WIDE_LIVE = {
+    "all_rows": lambda t: t.row_mask(),
+    "no_live_row": lambda t: jnp.zeros(t.capacity, dtype=jnp.bool_),
+    "live_with_holes": lambda t: t.row_mask() & jnp.asarray(
+        np.random.default_rng(5).random(t.capacity) < 0.5),
+}
+_WIDE_AGGS = {
+    "count_star": [AggSpec("count_star", None, "n")],
+    "count_star_and_sum": [AggSpec("count_star", None, "n"),
+                           AggSpec("sum", "v", "sv")],
+    "sum_only": [AggSpec("sum", "v", "sv")],
+}
+
+
+def _run_wide(table, groups, aggs, slots, mode, live):
+    """-> (result, direct, scatters, presence_from_count) of one jitted
+    `hash_aggregate`."""
+    direct, scatters, from_count = [], [], []
+    out, overflow = jax.jit(
+        lambda t: hash_aggregate(t, groups, aggs, slots, mode, live=live(t),
+                                 direct=direct, scatters=scatters,
+                                 presence_from_count=from_count))(table)
+    assert not bool(overflow)
+    return out, direct, scatters, from_count
+
+
+def _assert_claim_loop_groups(got, table, groups, aggs, slots, mode, live):
+    """The same groups, values and group count as the claim loop's."""
+    want, *_ = _run_wide(_without_dictionaries(table, groups), groups, aggs,
+                         slots, mode, live)
+    assert got.names == want.names and got.capacity == want.capacity
+    assert int(got.num_rows) == int(want.num_rows)
+    got_rows, want_rows = _group_rows(got, groups), _group_rows(want, groups)
+    assert got_rows.keys() == want_rows.keys()
+    for key, want_vals in want_rows.items():
+        _assert_same_values(got.names[len(groups):], got_rows[key],
+                            want_vals, key)
+
+
+@pytest.mark.parametrize("mode", ["single", "partial"])
+@pytest.mark.parametrize("aggs", sorted(_WIDE_AGGS))
+@pytest.mark.parametrize("live", sorted(_WIDE_LIVE))
+def test_a_wide_direct_grouping_reads_its_presence_off_the_count(
+        live, aggs, mode, monkeypatch):
+    """A direct grouping past `_DENSE_MAX_DOMAIN` (10,000 codes) whose
+    aggregates hold COUNT(*) over raw rows scatters that count once and
+    reads its used slots off it: the result is the claim loop's, group for
+    group, and bit for bit the presence scatter's (the same call with no
+    aggregate taken for a row count), groups in the same packed order. A
+    SUM alone keeps the presence scatter."""
+    from datafusion_distributed_tpu.ops import aggregate
+
+    table, groups, slots, _, domain = _direct_case("wide_domain")
+    specs = _WIDE_AGGS[aggs]
+    engaged = aggs != "sum_only"
+    got, direct, scatters, from_count = _run_wide(
+        table, groups, specs, slots, mode, _WIDE_LIVE[live])
+    assert direct == [domain]
+    assert from_count == ([domain] if engaged else [])
+    assert ("presence" in scatters) is not engaged
+    _assert_claim_loop_groups(got, table, groups, specs, slots, mode,
+                              _WIDE_LIVE[live])
+
+    monkeypatch.setattr(aggregate, "_counts_live_rows", lambda *a: False)
+    by_scatter, _, scatters_before, none = _run_wide(
+        table, groups, specs, slots, mode, _WIDE_LIVE[live])
+    assert "presence" in scatters_before and none == []
+    assert len(scatters_before) == len(scatters) + engaged
+    assert _same_bits(got, by_scatter)
+    if live == "no_live_row":
+        assert int(got.num_rows) == 0
+
+
+@pytest.mark.parametrize("mode", ["final", "partial_reduce"])
+def test_a_wide_merge_of_partial_counts_keeps_its_presence_scatter(
+        mode, monkeypatch):
+    """Merging partial states, COUNT(*) sums partial counts, not rows: a
+    wide direct grouping there keeps its presence scatter, and its result
+    is the claim loop's."""
+    from datafusion_distributed_tpu.ops.table import concat_tables
+
+    table, groups, slots, _, domain = _direct_case("wide_domain")
+    halves = [table.row_mask() & (jnp.arange(table.capacity) % 2 == h)
+              for h in (0, 1)]
+    states = [hash_aggregate(table, groups, _DIRECT_AGGS, slots, "partial",
+                             live=half)[0] for half in halves]
+    merged = concat_tables(states, capacity=2 * states[0].capacity)
+    live = _WIDE_LIVE["all_rows"]
+    got, direct, scatters, from_count = _run_wide(
+        merged, groups, _DIRECT_AGGS, slots, mode, live)
+    assert direct == [domain] and from_count == []
+    assert "presence" in scatters
+    _assert_claim_loop_groups(got, merged, groups, _DIRECT_AGGS, slots, mode,
+                              live)
+
+
+# ---------------------------------------------------------------------------
 # dense reductions: a small known domain reduces by masked passes, not scatters
 # ---------------------------------------------------------------------------
 

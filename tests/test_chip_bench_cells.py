@@ -275,8 +275,8 @@ def test_the_clickbench_cell_agrees_with_the_reference(trace, capsys,
         metrics = {name: m["value"] for name, m in result["metrics"].items()}
         assert metrics["overflow_retries"] == 0
         # 20,000 rows draw a few thousand phrases: a direct grouping over
-        # them, reduced densely (past 4,096 the count and the slot-presence
-        # pass are scatters: tests/test_dictionary_domain.py)
+        # them, reduced densely (past 4,096 the count is a scatter and the
+        # slot presence is read off it: tests/test_dictionary_domain.py)
         assert 2_000 < metrics["group_slots"] <= 4_096
         assert metrics["scatter_reductions"] == 0
     else:

@@ -124,25 +124,75 @@ def test_tpch_aggregates_reduce_without_a_scatter(tpch_ctx, query,
     assert counters["group_slots"] == group_slots
 
 
-def test_clickbench_q12_past_the_dense_cut_counts_its_scatters():
-    """60,000 rows of hits draw more phrases than `_DENSE_MAX_DOMAIN`: the
-    direct grouping's count and its slot-presence pass are scatters."""
-    from datafusion_distributed_tpu.ops.aggregate import _DENSE_MAX_DOMAIN
-
+@pytest.fixture(scope="module")
+def q12_hits():
+    """60,000 rows of hits, registered: they draw more phrases than
+    `_DENSE_MAX_DOMAIN`."""
     arrow = clickbenchgen.gen_clickbench(60_000, SEED)
     ctx = SessionContext()
     ctx.register_arrow("hits", arrow)
+    return arrow, ctx
+
+
+def test_clickbench_q12_past_the_dense_cut_counts_its_scatters(q12_hits):
+    """Past `_DENSE_MAX_DOMAIN` the direct grouping's count is a scatter,
+    and its slot-presence pass is read off that count: one scatter, not
+    two."""
+    from datafusion_distributed_tpu.ops.aggregate import _DENSE_MAX_DOMAIN
+
+    arrow, ctx = q12_hits
     got, counters, retries, slots = _counters(ctx, _clickbench_sql("q12"))
     domain = len(set(arrow.column("SearchPhrase").to_pylist()))
     assert domain > _DENSE_MAX_DOMAIN and retries == 0
     assert counters["direct_groupings"] == 1
     assert counters["group_slots"] == domain
     assert slots[0] >= domain
-    assert counters["scatter_reductions"] >= 2
+    assert counters["scatter_reductions"] == 1
+    assert counters["presence_from_count"] == 1
     phrases = arrow.column("SearchPhrase").to_pandas()
     counts = phrases[phrases != ""].value_counts()
     assert got.c.tolist() == counts.head(10).tolist()
     assert all(counts[p] == c for p, c in zip(got.SearchPhrase, got.c))
+
+
+def _aggregate_scatters(text: str, rows: int) -> list[str]:
+    """The scopes of the ``stablehlo.scatter`` ops of a lowered program
+    (``as_text(debug_info=True)``) that scatter ``[rows]`` updates under
+    an ``agg.*`` scope: the reductions by slot and the presence pass of an
+    aggregate, not the pack's ``nonzero``."""
+    import re
+
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    scopes = []
+    for m in re.finditer(r'"stablehlo\.scatter"', text):
+        sig = re.compile(
+            r'\}\) : \(([^)]*)\) -> tensor<[^>]*> loc\((#loc\d+)\)'
+        ).search(text, m.end())
+        updates = sig.group(1).split(", ")[-1]
+        scope = names.get(sig.group(2), "")
+        if updates.startswith(f"tensor<{rows}x") and "/agg." in scope:
+            scopes.append(scope.split("/agg.")[1].split("/")[0])
+    return scopes
+
+
+def test_clickbench_q12_lowers_one_scatter_over_its_rows(q12_hits):
+    """q12's lowered program scatters its rows once, for COUNT(*), where
+    the presence pass scattered them a second time: the shared count must
+    not come back as a scatter of its own."""
+    from datafusion_distributed_tpu.plan import physical as phys
+    from datafusion_distributed_tpu.plan.physical import (
+        DistributedTaskContext,
+    )
+    from datafusion_distributed_tpu.spans import NULL_TRACER
+
+    _, ctx = q12_hits
+    plan = ctx.sql(_clickbench_sql("q12")).physical_plan()
+    prog = phys._prepare_program(
+        plan, DistributedTaskContext(), None, False, None, None, NULL_TRACER)
+    text = prog.fn.lower(prog.inputs, prog.params).as_text(debug_info=True)
+    (hits,) = prog.inputs
+    rows = hits.capacity
+    assert _aggregate_scatters(text, rows) == ["reduce.count_star"]
 
 
 # the parent's TPC-H plans at SF0.002, seed 7: every aggregate's slots in
