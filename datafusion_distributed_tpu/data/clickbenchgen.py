@@ -138,10 +138,32 @@ def _from_vocabulary(vocabulary, codes: np.ndarray):
 
 def gen_clickbench(rows: int = 100_000, seed: int = 0):
     """Generate the `hits` table as a pyarrow Table."""
+    return _hits(rows, np.random.default_rng(seed), seed)
+
+
+def gen_clickbench_partitions(rows: int, seed: int, partitions: int) -> list:
+    """`hits` of ``rows`` rows as ``partitions`` row partitions, pyarrow
+    Tables of ``ceil(rows / partitions)`` rows (the last one the rest), as
+    a table split over the chips of a host is registered
+    (`io/parquet.py Partitions`). Partition i is drawn from ``(seed, i)``
+    on a thread of its own; its phrases are written by `phrase_texts` from
+    the table's ``seed``, so a popularity rank is one text in every
+    partition."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    per = -(-rows // partitions)
+    sizes = [min(per, rows - i * per) for i in range(partitions)]
+    with ThreadPoolExecutor(partitions) as pool:
+        return list(pool.map(
+            lambda i: _hits(sizes[i], np.random.default_rng([seed, i]), seed),
+            range(partitions)))
+
+
+def _hits(n: int, rng, seed: int):
+    """``n`` rows of `hits` drawn from ``rng``; ``seed`` writes the
+    phrases' texts."""
     import pyarrow as pa
 
-    rng = np.random.default_rng(seed)
-    n = rows
 
     event_day = rng.integers(0, _DAYS, n)
     event_date = (_EPOCH_2013_07_01 + event_day).astype(np.int32)

@@ -20,6 +20,7 @@ from datafusion_distributed_tpu import spans
 from datafusion_distributed_tpu.ops.table import (
     Column,
     Dictionary,
+    PartitionedTable,
     Table,
     fetch_counters,
     fetch_host_buffers,
@@ -348,6 +349,140 @@ def arrow_to_table(
                     masks=table.validity_masks,
                     rows=n, capacity=cap)
     return table
+
+
+class Partitions:
+    """A table given as row partitions, pyarrow Tables of one schema:
+    `SessionContext.register_arrow` registers partition i resident on
+    ``jax.devices()[i]`` (DataFusion's `MemTable` holds its partitions the
+    same way). ``num_rows`` and ``schema`` are the whole table's."""
+
+    def __init__(self, tables):
+        self.tables = tuple(tables)
+        if not self.tables:
+            raise ValueError("Partitions needs at least one table")
+        schema = self.tables[0].schema
+        if any(not t.schema.equals(schema) for t in self.tables):
+            raise ValueError("the partitions of a table differ in schema")
+
+    @classmethod
+    def split(cls, arrow_table, parts: int) -> "Partitions":
+        """``arrow_table`` cut into ``parts`` row ranges of
+        ``ceil(rows / parts)`` rows (the last one the rest), zero-copy."""
+        per = -(-arrow_table.num_rows // parts)
+        return cls(arrow_table.slice(i * per, per) for i in range(parts))
+
+    @property
+    def num_rows(self) -> int:
+        return sum(t.num_rows for t in self.tables)
+
+    @property
+    def schema(self):
+        return self.tables[0].schema
+
+
+def _encode_partitions_under_span(tracer, parent, name, cols) -> tuple:
+    """A string column given a chunk a partition -> (int32 codes a
+    partition, the distinct non-null values of all of them as ONE sorted
+    Arrow array: the dictionary every partition's codes index), under one
+    ``encode`` span. Each partition is hash-encoded alone and its own
+    dictionary's values are looked up in the sorted union: one string
+    array of every partition's rows can pass the 2 GiB its 32-bit offsets
+    address (ClickBench's URL column of 100M rows does)."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    with tracer.span("encode", "encode", parent=parent, column=name) as span:
+        encoded = [pc.dictionary_encode(col) for col in cols]
+        values = pc.unique(pa.chunked_array(
+            [e.dictionary for e in encoded], type=cols[0].type))
+        values = values.take(pc.sort_indices(values))
+        codes = []
+        for col, enc in zip(cols, encoded):
+            if len(enc.dictionary) == 0:  # every row NULL, or none
+                codes.append(np.zeros(len(col), dtype=np.int32))
+                continue
+            rank = pc.index_in(enc.dictionary, value_set=values).to_numpy(
+                zero_copy_only=False).astype(np.int32)
+            idx = pc.fill_null(enc.indices, 0) if col.null_count else (
+                enc.indices)
+            part = rank[idx.to_numpy(zero_copy_only=False)]
+            if col.null_count:
+                part = np.where(np.asarray(col.is_valid()), part,
+                                0).astype(np.int32, copy=False)
+            codes.append(part)
+        if tracer.active:
+            span.set(rows=sum(len(c) for c in cols), distinct=len(values),
+                     bytes=sum(c.nbytes for c in cols))
+    return codes, values
+
+
+def arrow_to_partitions(
+    partitions: Partitions,
+    devices: Sequence,
+    capacity: Optional[int] = None,
+    tracer=spans.NULL_TRACER,
+) -> PartitionedTable:
+    """`Partitions` -> a `PartitionedTable` whose partition i is resident on
+    ``devices[i]``. A string column is encoded once over every partition,
+    under one ``encode`` span, into ONE Dictionary that every partition
+    shares; a column that holds a NULL in any partition has a validity
+    array in all of them, so that the partitions agree in tree structure.
+    Then each partition's rows, converted on the host alone
+    (`arrow_to_host_columns`) and padded to one capacity, go to their
+    device, a thread a partition, under an ``h2d`` span a partition
+    (``device``, ``bytes``, ``rows``, ``capacity``, ``masks``)."""
+    tables = partitions.tables
+    if len(tables) > len(devices):
+        raise ValueError(
+            f"a table of {len(tables)} partitions needs a device "
+            f"a partition; {len(devices)} device(s) are available")
+    schema = schema_from_arrow(partitions.schema)
+    strings = [f.name for f in schema.fields if f.dtype == DataType.STRING]
+    parent = tracer.current_id()
+    with ThreadPoolExecutor(_ENCODE_THREADS) as pool:
+        encoding = {
+            name: pool.submit(
+                _encode_partitions_under_span, tracer, parent, name,
+                [t.column(name).combine_chunks() for t in tables])
+            for name in strings}
+        encoded = {name: future.result() for name, future in encoding.items()}
+    dicts = {name: Dictionary.from_arrow(values)
+             for name, (_, values) in encoded.items()}
+    # the columns with a validity array: `arrow_to_host_columns`' rule for
+    # the whole table, in every partition alike
+    masked = {f.name for f in schema.fields
+              if partitions.num_rows == 0
+              or any(t.column(f.name).null_count for t in tables)}
+    rows = tuple(t.num_rows for t in tables)
+    cap = capacity or round_up_pow2(max(max(rows), 1))
+
+    def place(i: int) -> Table:
+        table, device = tables[i], devices[i]
+        data, found, _, _ = arrow_to_host_columns(
+            table.drop_columns(strings))
+        for name in strings:
+            data[name] = encoded[name][0][i]
+            column = table.column(name)
+            if column.null_count:
+                found[name] = np.asarray(column.combine_chunks().is_valid())
+        validity = {name: found[name] if name in found
+                    else np.ones(table.num_rows, dtype=np.bool_)
+                    for name in masked}
+        with tracer.span("h2d", "h2d", parent=parent,
+                         device=str(device)) as hsp:
+            part = Table.from_numpy(data, schema, capacity=cap,
+                                    validity=validity, dictionaries=dicts,
+                                    device=device)
+            if tracer.active:
+                hsp.set(bytes=spans.table_nbytes(part),
+                        masks=part.validity_masks, rows=table.num_rows,
+                        capacity=cap)
+        return part
+
+    with ThreadPoolExecutor(len(tables)) as pool:
+        parts = tuple(pool.map(place, range(len(tables))))
+    return PartitionedTable(parts, rows)
 
 
 def table_to_arrow(table: Table, dictionary_gc: bool = False,

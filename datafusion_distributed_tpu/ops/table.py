@@ -252,12 +252,18 @@ class Column:
         capacity: int,
         validity: Optional[np.ndarray] = None,
         dictionary: Optional[Dictionary] = None,
+        device=None,
     ) -> "Column":
-        """``values`` padded to ``capacity`` on the device. ``validity``
+        """``values`` padded to ``capacity`` on the device: the default
+        one, uncommitted, or ``device``, committed to it. ``validity``
         None makes a column WITHOUT a validity array ("no NULLs": what
         `io/parquet.py arrow_to_host_columns` hands over for a column whose
         source holds none); an array is padded with False and uploaded
         beside the data, one byte a slot."""
+        def upload(buf):
+            return to_device(buf) if device is None else jax.device_put(
+                buf, device)
+
         n = len(values)
         if n > capacity:
             raise ValueError(f"{n} values > capacity {capacity}")
@@ -285,16 +291,16 @@ class Column:
             # no zero-fill + pad copy; `to_device` hands it over via dlpack
             # where the backend allows (ownership transfers: the caller
             # must not mutate it afterwards)
-            data = to_device(np.ascontiguousarray(vals))
+            data = upload(np.ascontiguousarray(vals))
         else:
             buf = np.zeros(capacity, dtype=np_dtype)
             buf[:n] = vals
-            data = to_device(buf)
+            data = upload(buf)
         col_validity = None
         if validity is not None:
             v = np.zeros(capacity, dtype=np.bool_)
             v[:n] = validity
-            col_validity = to_device(v)
+            col_validity = upload(v)
         return Column(data, col_validity, dtype, dictionary)
 
     @property
@@ -379,9 +385,11 @@ class Table:
         capacity: Optional[int] = None,
         validity: Optional[dict[str, np.ndarray]] = None,
         dictionaries: Optional[dict[str, Dictionary]] = None,
+        device=None,
     ) -> "Table":
         """Build a device Table from host arrays (string columns must already
-        be int32 codes with a matching entry in ``dictionaries``)."""
+        be int32 codes with a matching entry in ``dictionaries``), on the
+        default device or, committed, on ``device`` (`Column.from_numpy`)."""
         if not data:
             raise ValueError("from_numpy needs at least one column")
         n = len(next(iter(data.values())))
@@ -395,8 +403,11 @@ class Table:
             d = dictionaries.get(f.name) if dictionaries else None
             if f.dtype == DataType.STRING and d is None:
                 raise ValueError(f"string column {f.name} needs a dictionary")
-            cols[f.name] = Column.from_numpy(vals, f.dtype, cap, v, d)
-        return Table.make(cols, n)
+            cols[f.name] = Column.from_numpy(vals, f.dtype, cap, v, d, device)
+        if device is None:
+            return Table.make(cols, n)
+        return Table(tuple(cols), tuple(cols.values()),
+                     jax.device_put(np.int32(n), device))
 
     @staticmethod
     def empty(schema: Schema, capacity: int, dictionaries=None) -> "Table":
@@ -564,6 +575,46 @@ jax.tree_util.register_pytree_node(
     lambda t: t.tree_flatten(),
     Table.tree_unflatten,
 )
+
+
+@dataclass(frozen=True)
+class PartitionedTable:
+    """A table registered as row partitions (`io/parquet.py
+    arrow_to_partitions`): ``parts[i]`` is a Table resident on device i,
+    every part of one capacity and one tree structure, a string column's
+    ONE Dictionary shared by all of them, so that equal strings are equal
+    codes on every device. ``rows``: each part's row count, on the host.
+    What a catalog asks of a table it answers for the whole (`column` is
+    part 0's: its dictionary and its validity array, or none, are every
+    part's)."""
+
+    parts: tuple
+    rows: tuple
+
+    @property
+    def num_rows(self) -> int:
+        return sum(self.rows)
+
+    @property
+    def names(self) -> tuple:
+        return self.parts[0].names
+
+    @property
+    def capacity(self) -> int:
+        return self.parts[0].capacity
+
+    @property
+    def validity_masks(self) -> int:
+        return self.parts[0].validity_masks
+
+    def schema(self) -> Schema:
+        return self.parts[0].schema()
+
+    def column(self, name: str) -> Column:
+        return self.parts[0].column(name)
+
+    def __contains__(self, name: str) -> bool:
+        return name in self.parts[0]
 
 
 def _round_up(n: int, multiple: int = 8) -> int:

@@ -25,6 +25,9 @@ planner re-plans with a bigger bound — the pending->ready analogue.
 
 from __future__ import annotations
 
+import contextlib
+import math
+import threading
 from typing import Optional, Sequence
 
 import jax
@@ -33,6 +36,37 @@ import numpy as np
 
 from datafusion_distributed_tpu.ops.hash import hash_columns
 from datafusion_distributed_tpu.ops.table import Column, Table, scoped
+
+# trace time: the bytes the collectives traced on this thread carry, while
+# a `collective_tally` is open (None: none is)
+_TALLY = threading.local()
+
+
+@contextlib.contextmanager
+def collective_tally():
+    """Open while a program is traced: -> a one-element list that holds,
+    once the block ends, the bytes its collectives carry, each one's
+    operand on every chip of its axis (`_collective`). Shapes only: a
+    count of the planned buffers, kept with the program it was traced
+    for (`plan/physical.py trace_plan`)."""
+    outer = getattr(_TALLY, "bytes", None)
+    _TALLY.bytes = 0
+    carried = [0]
+    try:
+        yield carried
+    finally:
+        carried[0] = _TALLY.bytes
+        _TALLY.bytes = outer
+
+
+def _collective(op, x, axis, *args, **kwargs):
+    """``op(x, axis, ...)``, a collective, its operand's bytes on every
+    chip of ``axis`` booked to the open `collective_tally`."""
+    if getattr(_TALLY, "bytes", None) is not None:
+        # the operand's static shape, not its values
+        _TALLY.bytes += (math.prod(jnp.shape(x)) * jnp.result_type(x).itemsize
+                         * jax.lax.axis_size(axis))
+    return op(x, axis, *args, **kwargs)
 
 
 @scoped("exchange.shuffle")
@@ -119,7 +153,8 @@ def _route_by_dest(
             (num_tasks * per_dest_capacity, nl), dtype=stack.dtype
         ).at[flat_idx].set(stack, mode="drop")
         send = send.reshape(num_tasks, per_dest_capacity, nl)
-        recv = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
+        recv = _collective(jax.lax.all_to_all, send, axis, 0, 0,
+                           tiled=False)
         recv = recv.reshape(num_tasks * per_dest_capacity, nl)
         for li, i in enumerate(idxs):
             recv_by_lane[i] = recv[:, li]
@@ -138,14 +173,15 @@ def _route_by_dest(
         new_cols.append(Column(data, validity, col.dtype, col.dictionary))
 
     # received per-source counts -> liveness mask + compaction
-    my_counts = jax.lax.all_to_all(
-        bucket_counts.reshape(num_tasks, 1), axis, 0, 0
+    my_counts = _collective(
+        jax.lax.all_to_all, bucket_counts.reshape(num_tasks, 1), axis, 0, 0
     ).reshape(num_tasks)  # rows from each source task
     local = jnp.arange(per_dest_capacity, dtype=jnp.int32)
     live_mask = (local[None, :] < my_counts[:, None]).reshape(-1)
     out = Table(table.names, tuple(new_cols), jnp.sum(my_counts))
     out = _compact_with_mask(out, live_mask)
-    overflow = jax.lax.pmax(overflow.astype(jnp.int32), axis) > 0
+    overflow = _collective(jax.lax.pmax, overflow.astype(jnp.int32),
+                           axis) > 0
     return out, overflow
 
 
@@ -241,8 +277,8 @@ def range_shuffle_exchange(
     samp = [e[pos] for e in enc]
 
     # gather all tasks' samples: [T*s] per key lane, dead samples sort last
-    g_live = jax.lax.all_gather(samp_live, axis).reshape(-1)
-    g = [jax.lax.all_gather(e, axis).reshape(-1) for e in samp]
+    g_live = _collective(jax.lax.all_gather, samp_live, axis).reshape(-1)
+    g = [_collective(jax.lax.all_gather, e, axis).reshape(-1) for e in samp]
     order = jnp.argsort(~g_live, stable=True).astype(jnp.int32)
     for lane in reversed(g):
         # stable composition, least-significant first; dead-last applied
@@ -280,14 +316,15 @@ def broadcast_exchange(table: Table, axis: str, num_tasks: int) -> Table:
     joins — the reference's BroadcastExec + NetworkBroadcastExec pair)."""
     new_cols = []
     for col in table.columns:
-        g = jax.lax.all_gather(col.data, axis)  # [T, C]
+        g = _collective(jax.lax.all_gather, col.data, axis)  # [T, C]
         data = g.reshape(-1)
         if col.validity is not None:
-            validity = jax.lax.all_gather(col.validity, axis).reshape(-1)
+            validity = _collective(jax.lax.all_gather, col.validity,
+                                   axis).reshape(-1)
         else:
             validity = None
         new_cols.append(Column(data, validity, col.dtype, col.dictionary))
-    counts = jax.lax.all_gather(table.num_rows, axis)  # [T]
+    counts = _collective(jax.lax.all_gather, table.num_rows, axis)  # [T]
     cap = table.capacity
     local = jnp.arange(cap, dtype=jnp.int32)
     live_mask = (local[None, :] < counts[:, None]).reshape(-1)
@@ -336,13 +373,13 @@ def group_coalesce_exchange(
         # ppermute requires nothing of unlisted tasks; their recv is zeros
         part_cols = []
         for col in table.columns:
-            data = jax.lax.ppermute(col.data, axis, perm)
+            data = _collective(jax.lax.ppermute, col.data, axis, perm)
             validity = (
-                jax.lax.ppermute(col.validity, axis, perm)
+                _collective(jax.lax.ppermute, col.validity, axis, perm)
                 if col.validity is not None else None
             )
             part_cols.append(Column(data, validity, col.dtype, col.dictionary))
-        nrows = jax.lax.ppermute(table.num_rows, axis, perm)
+        nrows = _collective(jax.lax.ppermute, table.num_rows, axis, perm)
         # tasks that received nothing this round hold zeroed buffers with
         # nrows == 0 (ppermute zero-fills unaddressed receivers)
         recv_parts.append(Table(table.names, tuple(part_cols), nrows))

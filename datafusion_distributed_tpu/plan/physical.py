@@ -332,6 +332,12 @@ class ExecutionPlan:
 # ---------------------------------------------------------------------------
 
 
+class PartitionedTableError(ValueError):
+    """A tier that runs a plan on one device, or on the host's workers, was
+    given a scan of a table registered as partitions, a partition a device
+    (`Catalog.scan_exec`): it would answer over one partition."""
+
+
 class MemoryScanExec(ExecutionPlan):
     """Scan over pre-loaded per-task device Tables.
 
@@ -341,7 +347,8 @@ class MemoryScanExec(ExecutionPlan):
     """
 
     def __init__(self, tasks: Sequence[Table], schema: Schema,
-                 pinned: bool = False, replicated: bool = False):
+                 pinned: bool = False, replicated: bool = False,
+                 partitioned: bool = False):
         super().__init__()
         self.tasks = list(tasks)
         self._schema = schema
@@ -353,6 +360,12 @@ class MemoryScanExec(ExecutionPlan):
         # and the coordinator may run a stage reading only replicated scans
         # as a single task (its output is the complete result)
         self.replicated = replicated
+        # partitioned: ``tasks`` are a registered table's row partitions,
+        # task i's resident on device i (`Catalog.scan_exec` of a
+        # `PartitionedTable`); the mesh tier uses them where they lie
+        # (`runtime/mesh_executor.py place_partitions`), and a tier that
+        # would run fewer tasks than partitions is refused
+        self.partitioned = partitioned
 
     def children(self):
         return []
@@ -372,6 +385,9 @@ class MemoryScanExec(ExecutionPlan):
         return max((t.capacity for t in self.tasks), default=8)
 
     def load(self, task: DistributedTaskContext) -> Table:
+        if self.partitioned and task.task_count < len(self.tasks):
+            raise partitioned_table_error(
+                len(self.tasks), "a collect of fewer tasks than partitions")
         if self.pinned or self.replicated:
             return self.tasks[0]
         if task.task_index >= len(self.tasks):
@@ -385,7 +401,26 @@ class MemoryScanExec(ExecutionPlan):
         return ctx.inputs[self.node_id]
 
     def display(self):
-        return f"MemoryScan tasks={len(self.tasks)} cap={self.output_capacity()}"
+        mark = " partitioned" if self.partitioned else ""
+        return (f"MemoryScan tasks={len(self.tasks)}{mark} "
+                f"cap={self.output_capacity()}")
+
+
+def partitioned_table_error(partitions: int, tier: str) -> PartitionedTableError:
+    return PartitionedTableError(
+        f"a table registered as {partitions} partitions, a partition a "
+        f"device, is read by the mesh tier alone: "
+        f"collect_distributed(mesh=make_mesh({partitions})); {tier} would "
+        "answer over one partition")
+
+
+def refuse_partitioned_scans(plan: "ExecutionPlan", tier: str) -> None:
+    """Raise `PartitionedTableError` where ``plan`` scans a partitioned
+    table: ``tier`` (the coordinator's, the serving tier's) runs its
+    tasks on the host's workers, not where the partitions lie."""
+    scans = plan.collect(lambda n: getattr(n, "partitioned", False))
+    if scans:
+        raise partitioned_table_error(len(scans[0].tasks), tier)
 
 
 class ParquetScanExec(ExecutionPlan):
@@ -963,6 +998,7 @@ def trace_plan(exec_target: ExecutionPlan, task: DistributedTaskContext,
     ``into``; -> (output table, capacity flags, precision flags, metric
     values), the lists traced scalars in ``into``'s order. How the flags
     are reduced and fetched is the calling executor's."""
+    from datafusion_distributed_tpu.parallel.exchange import collective_tally
     from datafusion_distributed_tpu.plan.fingerprint import bound_params
 
     _TRACE_STATS["traces"] += 1
@@ -975,8 +1011,9 @@ def trace_plan(exec_target: ExecutionPlan, task: DistributedTaskContext,
     with traced_positions(exec_target) as pos_of, (
         contextlib.nullcontext() if param_vecs is None
         else bound_params(param_vecs)
-    ):
+    ), collective_tally() as carried:
         out = exec_target.execute(ctx)
+    ctx.count("mesh_exchange_bytes", carried[0])
     into.capacity_nodes[:] = [label for label, _ in ctx.capacity_flags]
     into.precision_nodes[:] = [label for label, _ in ctx.precision_flags]
     into.metric_names[:] = [

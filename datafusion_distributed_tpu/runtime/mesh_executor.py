@@ -102,6 +102,43 @@ def place_task_tables(per_task, mesh: Mesh) -> Table:
     return jax.tree.map(place, *per_task)
 
 
+def _lies_on(buffer, device) -> bool:
+    """Whether ``buffer`` is a device array held by ``device`` alone."""
+    return getattr(buffer, "devices", lambda: None)() == {device}
+
+
+def place_partitions(per_task, mesh: Mesh) -> tuple[Table, int]:
+    """A partitioned table's partitions (`MemoryScanExec.partitioned`) as
+    the shards of ONE Table on ``mesh``, where they lie: each column a flat
+    ``[tasks x capacity]`` array laid out as ``P(axis)`` asks, whose shard i
+    IS task i's buffer, and the row counts a ``[tasks]`` array. A buffer
+    already on its task's device is neither copied nor stacked under a
+    leading axis (`place_task_tables` makes a ``[1, capacity]`` copy); one
+    elsewhere (a task past the partitions reads an empty table) is copied
+    there. -> (the Table, the bytes copied)."""
+    devices = list(mesh.devices.flat)
+    sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
+    copied = [0]
+
+    def shard(buffer, device):
+        if _lies_on(buffer, device):
+            return buffer
+        copied[0] += int(buffer.nbytes)
+        return jax.device_put(buffer, device)
+
+    def place(*buffers):
+        shards = [shard(b, d) for b, d in zip(buffers, devices)]
+        return jax.make_array_from_single_device_arrays(
+            (len(devices) * shards[0].shape[0],), sharding, shards)
+
+    columns = jax.tree.map(place, *[t.columns for t in per_task])
+    rows = jax.make_array_from_single_device_arrays(
+        (len(devices),), sharding,
+        [jax.device_put(np.asarray(t.num_rows, np.int32).reshape(1), d)
+         for t, d in zip(per_task, devices)])
+    return Table(per_task[0].names, columns, rows), copied[0]
+
+
 @dataclass(frozen=True)
 class _Placement:
     """A leaf's inputs on a mesh's chips, kept on the leaf: it lives as
@@ -111,19 +148,28 @@ class _Placement:
     # what `load` returned, task by task. Held, not `id`s: an object that
     # is alive cannot hand its identity to another.
     sources: tuple
-    table: Table  # `place_task_tables(sources, mesh)`
+    # `place_partitions(sources, mesh)` where ``resident``, else
+    # `place_task_tables(sources, mesh)`
+    table: Table
+    # the leaf's partitions are the shards, where they lie: flat
+    # ``[tasks x capacity]`` buffers, not ``[tasks, capacity]``
+    resident: bool
+    copied: int  # the bytes the placement put on the chips
 
 
 _PLACEMENT_ATTR = "_dftpu_mesh_placement"
 
 
-def _placed_on_mesh(leaf, mesh: Mesh):
-    """-> (the leaf's ``[tasks, ...]`` input on ``mesh``, whether an
-    earlier request placed it). A placement is reused while the leaf's
-    `load` hands back the SAME Table objects (tables are immutable; a
-    cached plan's `MemoryScanExec` does, a `ParquetScanExec` or a scan of
-    fresh exchange output does not) for the same devices; anything else
-    is placed anew, and the old placement dropped first."""
+def _placed_on_mesh(leaf, mesh: Mesh) -> tuple[_Placement, bool]:
+    """-> (the leaf's input on ``mesh``, whether an earlier request placed
+    it). A placement is reused while the leaf's `load` hands back the SAME
+    Table objects (tables are immutable; a cached plan's `MemoryScanExec`
+    does, a `ParquetScanExec` or a scan of fresh exchange output does not)
+    for the same devices; anything else is placed anew, and the old
+    placement dropped first. A partitioned scan whose every partition
+    lies on its task's device is used where it lies (`place_partitions`);
+    any other leaf's tasks are copied to their chips
+    (`place_task_tables`)."""
     num_tasks = mesh.shape[AXIS]
     sources = tuple(
         leaf.load(DistributedTaskContext(i, num_tasks))
@@ -133,11 +179,20 @@ def _placed_on_mesh(leaf, mesh: Mesh):
     held = getattr(leaf, _PLACEMENT_ATTR, None)
     if (held is not None and held.devices == devices
             and all(a is b for a, b in zip(held.sources, sources))):
-        return held.table, True
+        return held, True
     setattr(leaf, _PLACEMENT_ATTR, None)
-    table = place_task_tables(sources, mesh)
-    setattr(leaf, _PLACEMENT_ATTR, _Placement(devices, sources, table))
-    return table, False
+    resident = getattr(leaf, "partitioned", False) and all(
+        _lies_on(column.data, device)
+        for source, device in zip(sources[:len(leaf.tasks)], devices)
+        for column in source.columns)
+    if resident:
+        table, copied = place_partitions(sources, mesh)
+    else:
+        table = place_task_tables(sources, mesh)
+        copied = tracing.table_nbytes(table)
+    held = _Placement(devices, sources, table, resident, copied)
+    setattr(leaf, _PLACEMENT_ATTR, held)
+    return held, False
 
 
 def execute_on_mesh(
@@ -172,12 +227,13 @@ def execute_on_mesh(
     traces_before = trace_count()
     with tr.span("mesh.stack_inputs", "mesh.stack_inputs") as ssp:
         placed = [_placed_on_mesh(leaf, mesh) for leaf in scans]
-        stacked_inputs = [table for table, _ in placed]
+        stacked_inputs = [held.table for held, _ in placed]
+        resident = tuple(held.resident for held, _ in placed)
         if tr.active:
             # what THIS request put on the chips: 0 bytes where every
-            # leaf's placement was reused
-            ssp.set(bytes=sum(tracing.table_nbytes(table)
-                              for table, reused in placed if not reused),
+            # leaf's placement was reused, or lay where it is used
+            ssp.set(bytes=sum(held.copied for held, reused in placed
+                              if not reused),
                     tasks=num_tasks,
                     reused=sum(reused for _, reused in placed))
 
@@ -193,16 +249,23 @@ def execute_on_mesh(
     calling = threading.local()
 
     def run(inputs_stacked, param_vecs):
-        target, target_leaf_ids = calling.plan
-        # local view: leading task axis of size 1 -> squeeze
+        target, target_leaf_ids, leaf_resident = calling.plan
+        # local view: a stacked leaf's leading task axis of size 1 ->
+        # squeeze; a resident one's buffers are the partition's own, and
+        # its row count a [1]
         local_inputs = {
-            nid: jax.tree.map(lambda x: x[0], t)
-            for nid, t in zip(target_leaf_ids, inputs_stacked)
+            nid: (Table(t.names, t.columns, t.num_rows[0]) if flat
+                  else jax.tree.map(lambda x: x[0], t))
+            for nid, t, flat in zip(target_leaf_ids, inputs_stacked,
+                                    leaf_resident)
         }
         out, cap_flags, prec_flags, metric_vals = trace_plan(
             target, DistributedTaskContext(0, num_tasks), local_inputs,
             {"mesh_axis": AXIS, "num_tasks": num_tasks}, param_vecs, trace,
         )
+        # known here, where their inputs arrive as the partitions, and
+        # kept with the program as every counter is
+        trace.counters["partitioned_scans"] = sum(leaf_resident)
         if metric_vals:
             mvec = jnp.stack(
                 [v.astype(_METRIC_DTYPE) for v in metric_vals]
@@ -224,7 +287,7 @@ def execute_on_mesh(
     # fingerprint -> shared across fresh submissions / hoisted variants;
     # unfingerprintable plans fall back to object identity as before
     cache_key = (prep.fingerprint or ("id", plan.node_id),
-                 tuple(d.id for d in mesh.devices.flat))
+                 tuple(d.id for d in mesh.devices.flat), resident)
     cached = _MESH_COMPILE_CACHE.get(cache_key)
     cached_hit = cached is not None
     if cached is not None:
@@ -249,7 +312,7 @@ def execute_on_mesh(
     # ends on the fetch of the flags, the sync this path already makes
     with tr.span("mesh.execute", "mesh.execute",
                  cache="hit" if cached_hit else "miss") as xsp:
-        calling.plan = (exec_target, leaf_ids)
+        calling.plan = (exec_target, leaf_ids, resident)
         try:
             with tr.span("launch", "launch"):
                 out, flags, mvec = fn(stacked_inputs, params)

@@ -859,6 +859,9 @@ class ServingSession:
                 _resume: Optional[str]) -> QueryHandle:
         """`submit` inside its ``submit`` span: planning and costing on
         the client's thread, then the queue."""
+        from datafusion_distributed_tpu.plan.physical import (
+            refuse_partitioned_scans,
+        )
         from datafusion_distributed_tpu.planner.statistics import (
             plan_device_bytes,
         )
@@ -887,9 +890,14 @@ class ServingSession:
         # here is cached by the session plan cache, so a repeated
         # template estimates for free.
         try:
-            est = int(plan_device_bytes(df.physical_plan()))
+            plan = df.physical_plan()
+            est = int(plan_device_bytes(plan))
         except Exception:
-            est = 0  # unplannable estimate -> admit on count alone
+            plan, est = None, 0  # unplannable estimate -> admit on count alone
+        if plan is not None:
+            # the workers run a task where they are, not where a
+            # partitioned table's partitions lie: refused at submit
+            refuse_partitioned_scans(plan, "the serving tier")
         handle = QueryHandle(self, sql, df, priority, est)
         handle._ckpt_record = _resume
         with self._lock:

@@ -57,9 +57,17 @@ PROFILE_PREFIX = "dftpu."
 #: ``scatter_reductions``: the per-slot reductions, and a direct
 #: grouping's slot-presence pass, that lowered as scatters;
 #: ``presence_from_count``: direct groupings past the dense cut whose used
-#: slots were read off the COUNT(*) they reduce, with no presence scatter.
+#: slots were read off the COUNT(*) they reduce, with no presence scatter;
+#: ``partitioned_scans``: scans whose task inputs are a partitioned table's
+#: partitions used where they lie, as the mesh's shards
+#: (`runtime/mesh_executor.py place_partitions`); ``mesh_exchange_bytes``:
+#: the bytes the exchanges' collectives carry (``all_to_all``,
+#: ``all_gather``, ``pmax``, ``ppermute``), each one's operand summed over
+#: the chips of its axis, from the planned buffers' shapes
+#: (`parallel/exchange.py collective_tally`).
 PROGRAM_COUNTERS = ("masked_filters", "direct_groupings", "dense_aggregates",
-                    "group_slots", "scatter_reductions", "presence_from_count")
+                    "group_slots", "scatter_reductions", "presence_from_count",
+                    "partitioned_scans", "mesh_exchange_bytes")
 
 _SPAN_CAP = 4096     # ring-buffer bound per query
 _EVENT_CAP = 2048    # trace-level event bound per query

@@ -20,15 +20,18 @@ from dataclasses import dataclass, replace
 from typing import Optional, Sequence
 
 from datafusion_distributed_tpu.io.parquet import (
+    Partitions,
+    arrow_to_partitions,
     arrow_to_table,
     schema_from_arrow,
     table_to_arrow,
 )
-from datafusion_distributed_tpu.ops.table import Table
+from datafusion_distributed_tpu.ops.table import PartitionedTable, Table
 from datafusion_distributed_tpu.plan.physical import (
     ExecutionPlan,
     MemoryScanExec,
     execute_plan,
+    refuse_partitioned_scans,
 )
 from datafusion_distributed_tpu.runtime import tracing
 from datafusion_distributed_tpu.schema import Schema
@@ -52,12 +55,14 @@ _NDV_MISS = object()
 class Catalog:
     """Named tables (device-resident) + views. NDV computation and
     registration serialize on a lock: the serving tier plans concurrent
-    submissions from N client threads against one catalog."""
+    submissions from N client threads against one catalog. A table is a
+    `Table` on the default device or a `PartitionedTable`, a partition a
+    device; every question below is answered for the whole table."""
 
     def __init__(self) -> None:
         import threading
 
-        self.tables: dict[str, Table] = {}
+        self.tables: dict[str, Table | PartitionedTable] = {}
         self.views: dict[str, LogicalPlan] = {}
         self._ndv_cache: dict = {}
         self._ndv_lock = threading.Lock()
@@ -66,7 +71,8 @@ class Catalog:
         # plan cache keys on this to drop plans built over replaced data
         self.generation = 0
 
-    def register_table(self, name: str, table: Table) -> None:
+    def register_table(self, name: str,
+                       table: Table | PartitionedTable) -> None:
         with self._ndv_lock:
             self.tables[name.lower()] = table
             self.generation += 1
@@ -106,13 +112,21 @@ class Catalog:
             # generated keys are clustered (l_orderkey repeats ~4x in a
             # run), so a prefix under-counts distincts and freezes the
             # estimate below the extrapolation threshold.
-            total = int(t.num_rows)
+            # a partitioned table is sampled at one stride over all of
+            # its partitions, in their order
+            parts, rows = ((t.parts, t.rows) if isinstance(
+                t, PartitionedTable) else ((t,), (int(t.num_rows),)))
+            total = sum(rows)
             n = min(total, 1 << 20)
             stride = max(1, total // max(n, 1))
-            col = t.column(column)
-            vals = np.asarray(col.data[:total:stride][:n])
-            if col.validity is not None:
-                vals = vals[np.asarray(col.validity[:total:stride][:n])]
+            cols = [part.column(column) for part in parts]
+            vals = np.concatenate([
+                np.asarray(col.data[:r:stride])
+                for col, r in zip(cols, rows)])[:n]
+            if cols[0].validity is not None:
+                vals = vals[np.concatenate([
+                    np.asarray(col.validity[:r:stride])
+                    for col, r in zip(cols, rows)])[:n]]
             sampled = max(len(vals), 1)
             ndv = int(len(np.unique(vals)))
             # distinct-on-sample extrapolates only when near-unique
@@ -149,6 +163,12 @@ class Catalog:
 
     def scan_exec(self, name: str, columns: Sequence[str]) -> ExecutionPlan:
         t = self.tables[name.lower()]
+        if isinstance(t, PartitionedTable):
+            # a task a partition, each read where it lies: the mesh tier
+            # alone runs such a scan (`MemoryScanExec.partitioned`)
+            return MemoryScanExec([p.select(columns) for p in t.parts],
+                                  t.schema().select(columns),
+                                  partitioned=True)
         return MemoryScanExec([t.select(columns)], t.schema().select(columns))
 
 
@@ -927,6 +947,7 @@ class DataFrame:
                     plan = self.distributed_plan(
                         num_tasks, dcfg, pcfg, coordinator=coordinator
                     )
+                    refuse_partitioned_scans(plan, "the coordinator tier")
                     guard(plan)
                     out = coordinator.execute(plan)
             finally:
@@ -1106,14 +1127,27 @@ class SessionContext:
         self.register_table(name, arrow_to_table(arrow, capacity=capacity))
 
     def register_arrow(self, name: str, arrow_table, capacity=None):
+        """Register a pyarrow Table on the default device, or a table given
+        as `Partitions` with partition i resident on ``jax.devices()[i]``
+        (`io/parquet.py arrow_to_partitions`), which only the mesh tier
+        reads: `DataFrame.collect_distributed` over a mesh of the devices
+        that hold it."""
         # where a table's registration goes, for whoever waits for it: a
         # `register` span, under it an `encode` a string column (host
-        # dictionary encoding) and the `h2d` of the padded columns
+        # dictionary encoding) and the `h2d` of the padded columns, one a
+        # partition
         with tracing.trace_call("register", self.config.distributed_options,
                                 table=name,
                                 rows=arrow_table.num_rows) as call:
-            table = arrow_to_table(arrow_table, capacity,
-                                   tracer=call.tracer)
+            if isinstance(arrow_table, Partitions):
+                import jax
+
+                table = arrow_to_partitions(arrow_table, jax.devices(),
+                                            capacity, tracer=call.tracer)
+                call.span.set(partitions=len(table.parts))
+            else:
+                table = arrow_to_table(arrow_table, capacity,
+                                       tracer=call.tracer)
             call.span.set(capacity=table.capacity)
             self.register_table(name, table)
 
@@ -1123,7 +1157,7 @@ class SessionContext:
         traced registration's ``h2d`` span reads as ``masks``)."""
         return self.catalog.tables[name.lower()].validity_masks
 
-    def register_table(self, name: str, table: Table):
+    def register_table(self, name: str, table: Table | PartitionedTable):
         self.catalog.register_table(name, table)
         rc = self._result_cache
         if rc is not None:

@@ -1,9 +1,11 @@
-"""`BENCHMARK.json` against the files it names, and two cells rehearsed on
+"""`BENCHMARK.json` against the files it names, and three cells rehearsed on
 the CPU through the function a chip run calls, `run.run_cell`, traced and
 not, against the benchmark's plain reference: `sf10-direct-q1`
-(`tpch-sf10-direct`: TPC-H SF10 resident on one chip) at SF0.01 and
+(`tpch-sf10-direct`: TPC-H SF10 resident on one chip) at SF0.01,
 `cb-direct-q12` (`clickbench-half-direct`: half of ClickBench's hits on one
-chip) at 20,000 rows. Answers and counts only: none of the numbers is a
+chip) at 20,000 rows, and `cb-mesh4-q12` (`clickbench-mesh4`: the whole
+hits in four partitions, a partition a chip) at 20,000 rows on four of the
+virtual devices. Answers and counts only: none of the numbers is a
 measurement."""
 
 import importlib.util
@@ -16,6 +18,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED = 3000000019  # more than 32 signed bits hold, as the driver's are
 CELL, CONFIG = "sf10-direct-q1", "tpch-sf10-direct"
 CB_CELL, CB_CONFIG = "cb-direct-q12", "clickbench-half-direct"
+MESH_CELL, MESH_CONFIG = "cb-mesh4-q12", "clickbench-mesh4"
 
 
 def _load(name: str, *parts: str):
@@ -145,8 +148,9 @@ def test_the_uncached_suite_refuses_a_program_that_cannot_set_up_in_a_run(
 #: the first configurations and cells of BENCHMARK.json, in their order:
 #: the accepted entries, then this PR's; a later PR appends after them
 CONFIGS = ["tpch-sf1-direct", "tpch-sf1-mesh4", "tpch-sf1-coord4", CONFIG,
-           CB_CONFIG]
-CELLS = ["direct-q1", "mesh4-q1", "direct-q6", "coord4-q1", CELL, CB_CELL]
+           CB_CONFIG, MESH_CONFIG]
+CELLS = ["direct-q1", "mesh4-q1", "direct-q6", "coord4-q1", CELL, CB_CELL,
+         MESH_CELL]
 
 
 def test_benchmark_json_lists_the_sf10_configuration_and_its_cell():
@@ -217,7 +221,8 @@ def test_benchmark_json_agrees_with_the_files():
     assert len({c["source"] for c in configs.values()}) == len(configs)
     cells = [c["name"] for c in bench["workloads"]]
     assert len(cells) == len(set(cells)) and cells[:len(CELLS)] == CELLS
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1 <= max(
+    # `mesh4-q1` and `cb-mesh4-q12`: at most half the cells, rounded down
+    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 2 <= max(
         1, len(cells) // 2)
     end_to_end = {m["name"] for m in bench["end_to_end"]}
     for kind in ("end_to_end", "per_layer"):
@@ -350,3 +355,130 @@ def test_benchmark_json_lists_the_clickbench_configuration_and_its_cell():
         assert (entry["unit"], entry["layer"], entry["source"],
                 entry["moves"], entry["workloads"]) == (
             "count", "operators", "program_counter", "query_p50_s", [CB_CELL])
+
+
+# -- cb-mesh4-q12: ClickBench's whole hits, a partition a chip ----------------
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["plain", "traced"])
+def test_the_partitioned_clickbench_cell_agrees_with_the_reference(
+        trace, capsys, as_a_chip_run_is_set_up):
+    """Its suite registers `hits` as four partitions on four of the virtual
+    devices, and the mesh tier answers q12 over them: the same comparison
+    as `cb-direct-q12`'s, the partitions used where they lie."""
+    result = run.run_cell(MESH_CELL, SEED, 0.5, trace, scale=CB_SCALE)
+    assert result["correct"] is True
+    assert result["attempted"] >= 1 and result["failed"] == 0
+    assert {name: n["value"] for name, n in result["compared"].items()} == {
+        "rows_off": 0, "columns_off": 0, "cells_differing": 0,
+        "rows_out_of_order": 0, "results_missing": 0}
+    audit = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    assert (audit[0]["cell"], audit[0]["chips"]) == (MESH_CELL, 4)
+    window = next(line for line in audit if "samples" in line)
+    assert window["compiles_in_window"] == 0
+    assert window["rows"] == {"hits": 20_000}
+    reported = set(result["metrics"])
+    wanted = {m["name"] for m in run.cell_metrics(MESH_CELL, trace)}
+    assert wanted - {"peak_device_gb", "device_busy_ms",
+                     "hbm_roofline_share"} <= reported <= wanted
+    if trace:
+        assert wanted == {
+            "generate_s", "register_s", "warmup_s", "compiles_in_window",
+            "parse_ms", "execute_ms", "fetch_ms", "overflow_retries",
+            "device_busy_ms", "hbm_roofline_share", "partitioned_scans",
+            "mesh_exchange_mb"}
+        assert result["attempted"] == 1  # the mix's traced_queries
+        metrics = {name: m["value"] for name, m in result["metrics"].items()}
+        assert metrics["overflow_retries"] == 0
+        assert metrics["partitioned_scans"] == 1
+        assert metrics["mesh_exchange_mb"] > 0
+    else:
+        assert wanted == {"setup_s", "query_p50_s", "queries_per_s",
+                          "peak_device_gb"}
+
+
+def test_the_partitioned_suite_is_the_clickbench_suite_but_for_load(tmp_path):
+    """`suites/clickbench-partitioned`: `suites/clickbench`'s texts, oracle,
+    comparison and least bytes, over four partitions of the whole row
+    count; its reference over the partitions' rows is the reference over
+    the same rows as one table."""
+    whole = run.load_module("suites", "clickbench", "suite.py")
+    parted = run.load_module("suites", "clickbench-partitioned", "suite.py")
+    assert parted.sql("q12") == whole.sql("q12")
+    assert parted.LIMITS == whole.LIMITS and parted.PARTITIONS == 4
+    tables = parted.load(CB_SCALE, SEED, str(tmp_path))
+    assert not os.listdir(tmp_path)  # nothing cached
+    (hits,) = tables.values()
+    assert [t.num_rows for t in hits.tables] == [5_000] * 4
+    assert parted.least_bytes("q12", tables) == 4 * 20_000
+    import pyarrow as pa
+
+    got = parted.expected("q12", tables)
+    want = whole.expected("q12", {"hits": pa.concat_tables(hits.tables)})
+    assert got[1] == want[1] == 10 and got[0].equals(want[0])
+
+
+@pytest.mark.parametrize("lacking", ["Partitions",
+                                     "gen_clickbench_partitions"])
+def test_the_partitioned_suite_refuses_a_program_that_cannot_register_it(
+        lacking, monkeypatch, tmp_path):
+    """A program that cannot register a table as partitions (one from
+    before partitioned registration) fails at once and cleanly, before any
+    data is made: the whole `hits` on chip 0 would be 13.4 of its 16 GB
+    before the mesh tier sliced it there."""
+    from datafusion_distributed_tpu.data import clickbenchgen
+    from datafusion_distributed_tpu.io import parquet
+
+    suite = run.load_module("suites", "clickbench-partitioned", "suite.py")
+    monkeypatch.delattr(parquet if lacking == "Partitions" else clickbenchgen,
+                        lacking)
+    monkeypatch.setattr(clickbenchgen, "gen_clickbench",
+                        lambda *a: pytest.fail("made"))
+    with pytest.raises(SystemExit) as refused:
+        suite.load(1.0, SEED, str(tmp_path))
+    assert refused.value.code not in (None, 0)
+    assert "suites/clickbench-partitioned" in str(refused.value.code)
+
+
+def test_benchmark_json_lists_the_partitioned_configuration_and_its_cell():
+    bench = benchmark_json()
+    workload = run.read_json("workloads", f"{MESH_CELL}.json")
+    config = run.read_json("configs", f"{MESH_CONFIG}.json")
+    assert bench["workloads"][CELLS.index(MESH_CELL)] == {
+        "name": MESH_CELL, "config": MESH_CONFIG, "traffic": "cb-q12-closed1",
+        "chips": 4, "why": workload["why"]}
+    assert bench["configs"][CONFIGS.index(MESH_CONFIG)] == {
+        "name": MESH_CONFIG, "source": config["source"],
+        "file": f"benchmarks/chip/configs/{MESH_CONFIG}.json",
+        "reduced": ["columns"], "why": config["deployment"]}
+    for text in (config["source"], workload["why"], config["deployment"]):
+        assert 0 < len(text) <= 200 and "\n" not in text
+    assert "ClickBench" in config["source"]
+    assert set(config["cuts"]) == set(config["reduced"]) == {"columns"}
+    assert config["cuts"]["columns"].startswith("105 -> 25")
+    assert (config["suite"], config["scale"], config["tier"],
+            config["tier_args"], config["chips"]) == (
+        "clickbench-partitioned", 1.0, "mesh", {"num_tasks": 4}, 4)
+    # the half's guarantees word for word, and its assumptions and more
+    half = run.read_json("configs", f"{CB_CONFIG}.json")
+    assert config["guarantees"] == half["guarantees"]
+    assert config["assumed"][:len(half["assumed"])] == half["assumed"]
+    # the cell's own metrics are the two new ones; `mesh_exchange_mb` also
+    # reads `mesh4-q1`, the other cell of the mesh tier
+    for kind in ("end_to_end", "per_layer"):
+        assert [m["name"] for m in bench[kind]
+                if MESH_CELL in m.get("workloads", [])] == (
+            ["partitioned_scans", "mesh_exchange_mb"]
+            if kind == "per_layer" else [])
+    metrics = {m["name"]: m for m in bench["per_layer"]}
+    assert metrics["partitioned_scans"] == {
+        "name": "partitioned_scans", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "mesh input placement",
+        "moves": "query_p50_s", "workloads": [MESH_CELL]}
+    assert metrics["mesh_exchange_mb"] == {
+        "name": "mesh_exchange_mb", "unit": "MB", "better": "lower",
+        "source": "program_counter", "layer": "exchange, mesh tier",
+        "moves": "query_p50_s", "workloads": [MESH_CELL, "mesh4-q1"]}
+    # appended last, after every accepted metric
+    assert [m["name"] for m in bench["per_layer"]][-2:] == [
+        "partitioned_scans", "mesh_exchange_mb"]
