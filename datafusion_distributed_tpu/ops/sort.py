@@ -14,10 +14,11 @@ lexicographic). Nulls order via a separate flag pass (no in-band sentinel).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 import jax.numpy as jnp
 
-from datafusion_distributed_tpu.ops.table import Table, scoped
+from datafusion_distributed_tpu.ops.table import Table, _round_up, scoped
 
 
 @dataclass(frozen=True)
@@ -65,8 +66,28 @@ def sort_permutation(table: Table, keys: list[SortKey]) -> jnp.ndarray:
     return out[-1]
 
 
-def sort_table(table: Table, keys: list[SortKey]) -> Table:
-    return table.gather(sort_permutation(table, keys), table.num_rows)
+def fetch_capacity(fetch: Optional[int], capacity: int) -> int:
+    """The capacity of a sort's output under a static ``fetch``: the fetch
+    rounded up to the sublane (8 at least), or ``capacity`` where that is
+    not smaller or there is no fetch."""
+    if fetch is None:
+        return capacity
+    return min(capacity, max(_round_up(fetch), 8))
+
+
+def sort_table(table: Table, keys: list[SortKey],
+               fetch: Optional[int] = None) -> Table:
+    """The table in key order, dead rows last; under a static ``fetch``
+    the first ``fetch`` rows of it. The permutation is always the whole
+    input's; where `fetch_capacity` is below the input's capacity only its
+    first entries are gathered, so a top-k over a wide table moves k rows
+    and not the table."""
+    perm = sort_permutation(table, keys)
+    k = fetch_capacity(fetch, table.capacity)
+    if k < table.capacity:
+        perm = perm[:k]
+    out = table.gather(perm, table.num_rows)
+    return out if fetch is None else out.head(fetch)
 
 
 def limit_table(table: Table, fetch, skip=0) -> Table:

@@ -39,7 +39,12 @@ from datafusion_distributed_tpu.ops.aggregate import (
     AggSpec,
     hash_aggregate,
 )
-from datafusion_distributed_tpu.ops.sort import SortKey, limit_table, sort_table
+from datafusion_distributed_tpu.ops.sort import (
+    SortKey,
+    fetch_capacity,
+    limit_table,
+    sort_table,
+)
 from datafusion_distributed_tpu.ops.table import (
     Column,
     Table,
@@ -774,13 +779,14 @@ class SortExec(ExecutionPlan):
         return self.child.schema()
 
     def output_capacity(self):
-        return self.child.output_capacity()
+        return fetch_capacity(self.fetch, self.child.output_capacity())
 
     def _execute(self, ctx: ExecContext) -> Table:
-        t = sort_table(self.child.execute(ctx), self.keys)
-        if self.fetch is not None:
-            t = t.head(self.fetch)
-        return t
+        t = self.child.execute(ctx)
+        out = sort_table(t, self.keys, self.fetch)
+        if out.capacity < t.capacity:
+            ctx.count("fetch_bounded_sorts")
+        return out
 
     def display(self):
         ks = ", ".join(

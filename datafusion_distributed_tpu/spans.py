@@ -58,6 +58,9 @@ PROFILE_PREFIX = "dftpu."
 #: grouping's slot-presence pass, that lowered as scatters;
 #: ``presence_from_count``: direct groupings past the dense cut whose used
 #: slots were read off the COUNT(*) they reduce, with no presence scatter;
+#: ``fetch_bounded_sorts``: sorts under a static fetch whose output is the
+#: fetch rounded up and not their input's capacity (`ops/sort.py
+#: fetch_capacity`);
 #: ``partitioned_scans``: scans whose task inputs are a partitioned table's
 #: partitions used where they lie, as the mesh's shards
 #: (`runtime/mesh_executor.py place_partitions`); ``mesh_exchange_bytes``:
@@ -67,7 +70,8 @@ PROFILE_PREFIX = "dftpu."
 #: (`parallel/exchange.py collective_tally`).
 PROGRAM_COUNTERS = ("masked_filters", "direct_groupings", "dense_aggregates",
                     "group_slots", "scatter_reductions", "presence_from_count",
-                    "partitioned_scans", "mesh_exchange_bytes")
+                    "partitioned_scans", "mesh_exchange_bytes",
+                    "fetch_bounded_sorts")
 
 _SPAN_CAP = 4096     # ring-buffer bound per query
 _EVENT_CAP = 2048    # trace-level event bound per query

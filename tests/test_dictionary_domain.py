@@ -118,10 +118,12 @@ def _clickbench_sql(query: str) -> str:
 @pytest.mark.parametrize("query,group_slots", [("q1", 6), ("q6", 1)])
 def test_tpch_aggregates_reduce_without_a_scatter(tpch_ctx, query,
                                                   group_slots):
-    """q1's domain of 6 and q6's global aggregate: dense passes only."""
+    """q1's domain of 6 and q6's global aggregate: dense passes only.
+    Neither sorts under a fetch (q1's ORDER BY has no LIMIT)."""
     _, counters, retries, _ = _counters(tpch_ctx, _tpch_sql(query))
     assert counters["scatter_reductions"] == 0 and retries == 0
     assert counters["group_slots"] == group_slots
+    assert counters["fetch_bounded_sorts"] == 0
 
 
 @pytest.fixture(scope="module")
@@ -149,6 +151,10 @@ def test_clickbench_q12_past_the_dense_cut_counts_its_scatters(q12_hits):
     assert slots[0] >= domain
     assert counters["scatter_reductions"] == 1
     assert counters["presence_from_count"] == 1
+    # the top-10 gathers 16 of the group table's slots: a result of 16
+    # rows comes back in one round trip
+    assert counters["fetch_bounded_sorts"] == 1
+    assert counters["round_trips"] == 1
     phrases = arrow.column("SearchPhrase").to_pandas()
     counts = phrases[phrases != ""].value_counts()
     assert got.c.tolist() == counts.head(10).tolist()

@@ -25,7 +25,14 @@ from datafusion_distributed_tpu.data.clickbenchgen import (
 )
 from datafusion_distributed_tpu.io.parquet import Partitions
 from datafusion_distributed_tpu.ops.table import PartitionedTable
-from datafusion_distributed_tpu.plan.physical import PartitionedTableError
+from datafusion_distributed_tpu.plan.exchanges import (
+    CoalesceExchangeExec,
+    ShuffleExchangeExec,
+)
+from datafusion_distributed_tpu.plan.physical import (
+    PartitionedTableError,
+    SortExec,
+)
 from datafusion_distributed_tpu.runtime import mesh_executor, tracing
 from datafusion_distributed_tpu.runtime.mesh_executor import make_mesh
 from datafusion_distributed_tpu.runtime.serving import ServingSession
@@ -243,6 +250,40 @@ def test_q12_reads_one_partitioned_scan_and_the_exchange_bytes(hits):
     (row,) = [r for r in tracing.layer_report() if "execute" in r["self_s"]]
     assert row["counters"]["mesh_exchange_bytes"] == 0
     assert row["counters"]["partitioned_scans"] == 0
+
+
+def test_q12s_top_ten_crosses_the_mesh_at_its_fetch(hits):
+    """Each chip's top-10 hands the `all_gather` coalesce 16 rows, not its
+    group table: `mesh_exchange_bytes` is the shuffle's share and four
+    16-row operands, and both sorts (local and final) count as cut."""
+    ctx = registered(hits, traced=True)
+    mesh = make_mesh(4)
+    tracing.DEFAULT_TRACE_STORE.clear()
+    got = ctx.sql(Q12).collect_distributed(mesh=mesh).to_pandas()
+    assert oracle.measure_results(got, q12_reference(hits)) == {
+        "rows_off": 0, "columns_off": 0, "cells_differing": 0,
+        "rows_out_of_order": 0}
+    (row,) = [r["counters"] for r in tracing.layer_report()
+              if "mesh.execute" in r["self_s"]]
+    assert row["fetch_bounded_sorts"] == 2
+    df = ctx.sql(Q12)
+    plan = df.distributed_plan(4, dataclasses.replace(
+        df._seeded_distributed_config(4), uniform_stage_tasks=True),
+        ctx.config.planner, mesh=mesh)
+    (shuffle,) = plan.collect(lambda n: isinstance(n, ShuffleExchangeExec))
+    (coalesce,) = plan.collect(lambda n: isinstance(n, CoalesceExchangeExec))
+    local = coalesce.child
+    assert isinstance(local, SortExec) and local.fetch == 10
+    groups = local.child.output_capacity()
+    assert local.output_capacity() == 16 < groups
+    tasks, per_dest = shuffle.num_tasks, shuffle.per_dest_capacity
+    row_bytes = 2 * 4  # the phrase's code and the count, no validity
+    # the send buffer's all_to_all, the row counts', the overflow's pmax
+    shuffled = (tasks * (tasks * per_dest * row_bytes) + tasks * tasks * 4
+                + tasks * 4)
+    # every chip's 16 rows and row count gathered to every chip
+    coalesced = tasks * 16 * row_bytes + tasks * 4
+    assert row["mesh_exchange_bytes"] == shuffled + coalesced
 
 
 def _digest(table) -> str:
