@@ -320,7 +320,6 @@ def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
     the domain off the same rule: `hash_aggregate`, `_reduce_by_slot`);
     groups come out in radix order."""
     domain = math.prod(bases)
-    radices = _radices(bases)
     if domain <= _DENSE_MAX_DOMAIN:
         present = jnp.any(
             jnp.arange(domain, dtype=jnp.int32)[:, None] == gid[None, :],
@@ -333,18 +332,8 @@ def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
         slot_used = jnp.zeros(num_slots, dtype=jnp.bool_).at[gid].set(
             True, mode="drop"
         )
-    slot = jnp.arange(num_slots, dtype=jnp.int32)
-    slot_keys = []
-    slot_key_valid = []
-    for col, base, radix in zip(key_columns, bases, radices):
-        digit = (slot // np.int32(radix)) % np.int32(base)
-        if col.validity is not None:
-            key_valid = digit < len(col.dictionary)
-            digit = jnp.where(key_valid, digit, 0)
-            slot_key_valid.append(key_valid)
-        else:
-            slot_key_valid.append(None)
-        slot_keys.append(digit.astype(col.data.dtype))
+    slot_keys, slot_key_valid = _slot_keys(
+        key_columns, bases, jnp.arange(num_slots, dtype=jnp.int32))
     return GroupTable(
         group_ids=gid,
         slot_used=slot_used,
@@ -353,6 +342,25 @@ def direct_group_table(key_columns: Sequence[Column], bases: Sequence[int],
         num_groups=jnp.sum(slot_used, dtype=jnp.int32),
         overflow=jnp.asarray(False),
     )
+
+
+def _slot_keys(key_columns: Sequence[Column], bases: Sequence[int],
+               slot: jnp.ndarray) -> tuple[list, list]:
+    """The keys of each slot index in ``slot``, a direct grouping's: key
+    ``i`` is the digit ``(slot // radix_i) % base_i``, its code, and the
+    digit ``len(dictionary_i)`` a NULL -> (per key column the codes in its
+    dtype, per key column its validity or None)."""
+    keys, valids = [], []
+    for col, base, radix in zip(key_columns, bases, _radices(bases)):
+        digit = (slot // np.int32(radix)) % np.int32(base)
+        if col.validity is not None:
+            key_valid = digit < len(col.dictionary)
+            digit = jnp.where(key_valid, digit, 0)
+            valids.append(key_valid)
+        else:
+            valids.append(None)
+        keys.append(digit.astype(col.data.dtype))
+    return keys, valids
 
 
 # op -> (the dense reduction, the scatter's method)
@@ -373,8 +381,7 @@ def _reduce_by_slot(op: str, ids, vals, num_slots: int,
     no ``[domain, N]`` operand exists. A NaN stays in its own slot (it is
     masked, not multiplied), integers stay integers."""
     reduce, scatter = _SLOT_REDUCTIONS[op]
-    identity = 0 if op == "sum" else (
-        _dtype_max if op == "min" else _dtype_min)(vals.dtype)
+    identity = _identity(op, vals.dtype)
     if dense_domain is None:
         init = jnp.full(num_slots, identity, vals.dtype)
         return getattr(init.at[ids], scatter)(vals, mode="drop")
@@ -386,11 +393,84 @@ def _reduce_by_slot(op: str, ids, vals, num_slots: int,
                    constant_values=identity)
 
 
+def _identity(op: str, dtype):
+    """What ``op`` ("sum" | "min" | "max") leaves a slot no row names."""
+    return 0 if op == "sum" else (
+        _dtype_max if op == "min" else _dtype_min)(dtype)
+
+
 def _counts_live_rows(spec: AggSpec, mode: str) -> bool:
     """Whether ``spec``'s value is the number of live rows of each slot:
     COUNT(*) over raw rows. In ``final`` and ``partial_reduce`` it sums
     partial counts instead."""
     return spec.func == "count_star" and mode in ("single", "partial")
+
+
+def aggregate_slots(
+    table: Table,
+    group_names: Sequence[str],
+    aggs: Sequence[AggSpec],
+    num_slots: int,
+    mode: str,
+    prec_flags: Optional[list] = None,
+    live: Optional[jnp.ndarray] = None,
+    direct: Optional[list] = None,
+    scatters: Optional[list] = None,
+    presence_from_count: Optional[list] = None,
+) -> tuple[GroupTable, dict[str, Column], Optional[list[int]]]:
+    """`hash_aggregate` up to its pack (the arguments are its): -> (the
+    group table, every output column at the ``[num_slots]`` width of the
+    table, slot ``s`` holding the keys and the values of group ``s``, an
+    unused slot each reduction's identity, and the keys' bases where the
+    groups were addressed by their codes (`_dictionary_bases`), else
+    None)."""
+    if live is None:
+        live = table.row_mask()
+    key_columns = [table.column(g) for g in group_names]
+    bases = _dictionary_bases(key_columns, num_slots)
+    row_counts = None  # [num_slots]: the live rows of each slot, reduced once
+    if bases is not None:
+        domain = math.prod(bases)
+        ids = direct_group_ids(key_columns, bases, live, num_slots)
+        counter = next((s for s in aggs if _counts_live_rows(s, mode)), None)
+        if domain > _DENSE_MAX_DOMAIN and counter is not None:
+            row_counts = _eval_agg(counter, table, ids, live, num_slots, mode,
+                                   prec_flags, None, scatters
+                                   )[counter.output_name].data
+        gt = direct_group_table(key_columns, bases, ids, num_slots,
+                                row_counts)
+        if direct is not None:
+            direct.append(domain)
+        if (scatters is not None and domain > _DENSE_MAX_DOMAIN
+                and row_counts is None):
+            scatters.append("presence")
+        if presence_from_count is not None and row_counts is not None:
+            presence_from_count.append(domain)
+    else:
+        gt = build_group_table(
+            [c.data for c in key_columns],
+            [c.validity for c in key_columns], live, num_slots,
+        )
+    gid = jnp.where(live, gt.group_ids, num_slots)  # dead rows drop out
+
+    out_cols: dict[str, Column] = {}
+    for g, keys, kv in zip(group_names, gt.slot_keys, gt.slot_key_valid):
+        src = table.column(g)
+        out_cols[g] = Column(keys, kv, src.dtype, src.dictionary)
+
+    dense_domain = None
+    if bases is not None and math.prod(bases) <= _DENSE_MAX_DOMAIN:
+        dense_domain = math.prod(bases)
+    for spec in aggs:
+        if row_counts is not None and _counts_live_rows(spec, mode):
+            out_cols[spec.output_name] = Column(row_counts, None,
+                                                DataType.INT64)
+            continue
+        out_cols.update(
+            _eval_agg(spec, table, gid, live, num_slots, mode, prec_flags,
+                      dense_domain, scatters)
+        )
+    return gt, out_cols, bases
 
 
 def hash_aggregate(
@@ -446,52 +526,9 @@ def hash_aggregate(
     The result table has capacity == min(out_capacity or num_slots,
     num_slots), groups packed to the front.
     """
-    if live is None:
-        live = table.row_mask()
-    key_columns = [table.column(g) for g in group_names]
-    bases = _dictionary_bases(key_columns, num_slots)
-    row_counts = None  # [num_slots]: the live rows of each slot, reduced once
-    if bases is not None:
-        domain = math.prod(bases)
-        ids = direct_group_ids(key_columns, bases, live, num_slots)
-        counter = next((s for s in aggs if _counts_live_rows(s, mode)), None)
-        if domain > _DENSE_MAX_DOMAIN and counter is not None:
-            row_counts = _eval_agg(counter, table, ids, live, num_slots, mode,
-                                   prec_flags, None, scatters
-                                   )[counter.output_name].data
-        gt = direct_group_table(key_columns, bases, ids, num_slots,
-                                row_counts)
-        if direct is not None:
-            direct.append(domain)
-        if (scatters is not None and domain > _DENSE_MAX_DOMAIN
-                and row_counts is None):
-            scatters.append("presence")
-        if presence_from_count is not None and row_counts is not None:
-            presence_from_count.append(domain)
-    else:
-        gt = build_group_table(
-            [c.data for c in key_columns],
-            [c.validity for c in key_columns], live, num_slots,
-        )
-    gid = jnp.where(live, gt.group_ids, num_slots)  # dead rows drop out
-
-    out_cols: dict[str, Column] = {}
-    for g, keys, kv in zip(group_names, gt.slot_keys, gt.slot_key_valid):
-        src = table.column(g)
-        out_cols[g] = Column(keys, kv, src.dtype, src.dictionary)
-
-    dense_domain = None
-    if bases is not None and math.prod(bases) <= _DENSE_MAX_DOMAIN:
-        dense_domain = math.prod(bases)
-    for spec in aggs:
-        if row_counts is not None and _counts_live_rows(spec, mode):
-            out_cols[spec.output_name] = Column(row_counts, None,
-                                                DataType.INT64)
-            continue
-        out_cols.update(
-            _eval_agg(spec, table, gid, live, num_slots, mode, prec_flags,
-                      dense_domain, scatters)
-        )
+    gt, out_cols, _ = aggregate_slots(
+        table, group_names, aggs, num_slots, mode, prec_flags, live, direct,
+        scatters, presence_from_count)
 
     # Pack used slots to the front — into a TIGHTER capacity when the
     # caller supplies one. The hash table stays wide for short probe
@@ -715,6 +752,109 @@ def _eval_agg_columns(spec, table, gid, live, num_slots, mode, seg_sum,
 #: benchmark domains (quantities/prices), exact-enough in x64 mode.
 _VARIANCE_FUNCS = {"stddev", "stddev_samp", "stddev_pop", "var_samp",
                    "var_pop"}
+
+
+#: Aggregate functions whose partial states merge slot by slot, each
+#: state elementwise (`merge_slot_states`): a sum for every sum and count
+#: (AVG's and the variance family's parts too), a min or a max for MIN and
+#: MAX.
+SLOT_MERGE_FUNCS = PUSHDOWN_DECOMPOSABLE_FUNCS | _VARIANCE_FUNCS
+
+
+def merge_slot_states(columns: dict[str, Column], slot_used: jnp.ndarray,
+                      group_names: Sequence[str], aggs: Sequence[AggSpec],
+                      bases: Sequence[int], axis: str, num_tasks: int,
+                      range_slots: int,
+                      prec_flags: Optional[list] = None) -> Table:
+    """A ``partial`` direct grouping's states (`aggregate_slots`: every
+    column ``[num_slots]`` wide, slot ``s`` group ``s``) merged across the
+    mesh axis by slot range. One program holds one dictionary a key
+    column, so slot ``s`` is one group on every task: task ``i`` gets the
+    slots ``[i * range_slots, (i + 1) * range_slots)`` of the domain, each
+    state reduced over the tasks (`parallel/exchange.py
+    slot_merge_exchange`), a sum for every sum and count, a min or a max
+    for MIN and MAX. A SUM's, MIN's or MAX's validity is a lane of its own,
+    summed; a slot is used where the merged COUNT(*) is above 0 or, with
+    no COUNT(*), where a lane of the partials' used slots summed is; an
+    int32 SUM is summed in float32 beside it, as a ``final`` merge checks
+    it (`_check_int32_sum_range`). -> the range's used slots packed to the
+    front, in the partial's schema, their keys the digits of the slot
+    index (`_slot_keys`): no key crosses the axis."""
+    from datafusion_distributed_tpu.parallel.exchange import (
+        slot_merge_exchange,
+    )
+
+    domain = math.prod(bases)
+    padded = num_tasks * range_slots
+    lanes: list[tuple[str, jnp.ndarray]] = []
+
+    def lane(op, x):
+        lanes.append((op, jnp.pad(x[:domain], (0, padded - domain),
+                                  constant_values=_identity(op, x.dtype))))
+        return len(lanes) - 1
+
+    funcs = {spec.output_name: spec.func for spec in aggs}
+    states = [name for name in columns if name not in group_names]
+    at = {}  # state -> its lanes: (data, validity or None, estimate or None)
+    for name in states:
+        col = columns[name]
+        func = funcs.get(name)  # None: a part of AVG or of the variance family
+        data = lane(func if func in ("min", "max") else "sum", col.data)
+        valid = None
+        if func in ("sum", "min", "max") and col.validity is not None:
+            valid = lane("sum", col.validity.astype(jnp.int32))
+        estimate = None
+        if (func == "sum" and prec_flags is not None
+                and jnp.issubdtype(col.data.dtype, jnp.integer)
+                and np.dtype(col.data.dtype).itemsize == 4):
+            estimate = lane("sum", col.data.astype(jnp.float32))
+        at[name] = (data, valid, estimate)
+    counter = next((s.output_name for s in aggs if s.func == "count_star"),
+                   None)
+    presence = (at[counter][0] if counter is not None
+                else lane("sum", slot_used.astype(jnp.int32)))
+    merged = slot_merge_exchange(lanes, axis, num_tasks)
+
+    slot = (jax.lax.axis_index(axis) * np.int32(range_slots)
+            + jnp.arange(range_slots, dtype=jnp.int32))
+    used = merged[presence] > 0
+    (idx,) = jnp.nonzero(used, size=range_slots, fill_value=0)
+    key_columns = [columns[g] for g in group_names]
+    keys, key_valids = _slot_keys(key_columns, bases, slot[idx])
+    out = {g: Column(k, v, c.dtype, c.dictionary)
+           for g, c, k, v in zip(group_names, key_columns, keys, key_valids)}
+    for name in states:
+        data, valid, estimate = at[name]
+        col = columns[name]
+        out[name] = Column(
+            merged[data][idx],
+            None if valid is None else merged[valid][idx] > 0,
+            col.dtype, col.dictionary)
+        if estimate is not None:
+            prec_flags.append(jnp.any(
+                jnp.abs(merged[estimate]) > np.float32(2.0**31 * 0.995)))
+    return Table.make(out, jnp.sum(used, dtype=jnp.int32))
+
+
+def finalize_slot_states(table: Table, group_names: Sequence[str],
+                         aggs: Sequence[AggSpec]) -> Table:
+    """``final`` mode over `merge_slot_states`' rows, one a group with its
+    states merged already: each aggregate's final recipe
+    (`_eval_agg_columns`) with nothing left to reduce, row by row, the
+    rows where they are."""
+    live = table.row_mask()
+
+    def as_is(vals, dtype=None):
+        return vals.astype(dtype or vals.dtype)
+
+    out = {g: table.column(g) for g in group_names}
+    for spec in aggs:
+        with jax.named_scope(f"agg.reduce.{spec.func}"):
+            out.update(_eval_agg_columns(
+                spec, table, jnp.arange(table.capacity, dtype=jnp.int32),
+                live, table.capacity, "final", as_is,
+                lambda op, ids, vals: vals, None))
+    return Table.make(out, table.num_rows)
 
 
 def _variance_result(func: str, s, sq, cnt):

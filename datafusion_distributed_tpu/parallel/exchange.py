@@ -9,6 +9,15 @@ primitive set is XLA collectives inside one `shard_map`ped program:
     hash shuffle (N:M re-shard)  -> `lax.all_to_all`   (NetworkShuffleExec)
     broadcast (replicate build)  -> `lax.all_gather`   (NetworkBroadcastExec)
     coalesce (N -> 1 concat)     -> `lax.all_gather`   (NetworkCoalesceExec)
+    slot-range merge             -> `lax.psum_scatter` (sums, counts);
+      (a direct grouping's          `lax.all_to_all` + a reduce over the
+       partial states)              sources (MIN, MAX)
+
+The slot-range merge replaces the hash shuffle between a partial and a
+final aggregate whose groups are addressed by their dictionary codes: every
+task's partial state is then an array indexed by the same slots, so task i
+takes the sum (min, max) over the tasks of slot range i, and no row, key or
+hash crosses the axis (`slot_merge_exchange`).
 
 Everything here runs *inside* shard_map: `table` holds this task's local
 shard (padded capacity C, traced num_rows), and `axis` is the mesh axis name.
@@ -183,6 +192,36 @@ def _route_by_dest(
     overflow = _collective(jax.lax.pmax, overflow.astype(jnp.int32),
                            axis) > 0
     return out, overflow
+
+
+@scoped("exchange.slot_merge")
+def slot_merge_exchange(lanes: Sequence[tuple[str, jnp.ndarray]], axis: str,
+                        num_tasks: int) -> list[jnp.ndarray]:
+    """Reduce per-slot arrays across the mesh axis by slot range. Each lane
+    is an ``(op, [num_tasks * R])`` pair, ``op`` one of "sum", "min",
+    "max", over slots that name the same thing on every task; -> each
+    lane's ``[R]``: on task i, the op over every task of the slots
+    ``[i * R, (i + 1) * R)``. Lanes of one op and dtype are stacked into
+    ONE collective: a `psum_scatter` for the sums, for a min or a max one
+    `all_to_all` of ``[num_tasks, R]`` blocks and a reduce over the
+    sources."""
+    out: list = [None] * len(lanes)
+    groups: dict = {}
+    for i, (op, x) in enumerate(lanes):
+        groups.setdefault((op, jnp.dtype(x.dtype)), []).append(i)
+    for (op, _), idxs in groups.items():
+        stack = jnp.stack([lanes[i][1] for i in idxs], axis=1)  # [T*R, L]
+        if op == "sum":
+            merged = _collective(jax.lax.psum_scatter, stack, axis,
+                                 scatter_dimension=0, tiled=True)
+        else:
+            blocks = stack.reshape(num_tasks, -1, len(idxs))
+            recv = _collective(jax.lax.all_to_all, blocks, axis, 0, 0,
+                               tiled=False)  # [T, R, L]: one a source
+            merged = (jnp.min if op == "min" else jnp.max)(recv, axis=0)
+        for li, i in enumerate(idxs):
+            out[i] = merged[:, li]
+    return out
 
 
 def _bitcast_unsigned(a: jnp.ndarray) -> jnp.ndarray:

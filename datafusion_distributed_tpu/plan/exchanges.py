@@ -18,11 +18,18 @@ consumers — that path is the DCN/multi-host fallback.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
 
-from datafusion_distributed_tpu.ops.table import Column, Table, round_up_pow2
+from datafusion_distributed_tpu.ops.aggregate import merge_slot_states
+from datafusion_distributed_tpu.ops.table import (
+    Column,
+    Table,
+    _round_up,
+    round_up_pow2,
+)
 from datafusion_distributed_tpu.parallel.exchange import (
     broadcast_exchange,
     coalesce_exchange,
@@ -132,6 +139,64 @@ class ShuffleExchangeExec(ExchangeExec):
         return (
             f"ShuffleExchange keys=[{', '.join(self.key_names)}] "
             f"tasks={self.num_tasks} per_dest_cap={self.per_dest_capacity}"
+        )
+
+
+class SlotMergeExchangeExec(ExchangeExec):
+    """The shuffle between a ``partial`` and a ``final`` aggregate on the
+    mesh tier where the partial addresses its groups by their dictionary
+    codes (``bases``, `ops/aggregate.py _dictionary_bases`; the planner
+    reads them off the scan: `planner/distributed.py merge_direct_groupings`):
+    each task's partial states are arrays over the same slots, so task i
+    takes slot range i of their sum, min or max over the tasks
+    (`merge_slot_states`) in place of the rows hashed to it. Its output is
+    the range's used slots, one row a group with its state merged, which
+    the final aggregate only finalizes (``merges_slot_states``)."""
+
+    merges_slot_states = True
+
+    def __init__(self, child: ExecutionPlan, num_tasks: int,
+                 bases: Sequence[int]):
+        super().__init__(child, num_tasks)
+        self.bases = tuple(bases)
+        self.domain = math.prod(self.bases)
+        # a task's slots: the domain split T ways, to the sublane
+        self.range_slots = _round_up(-(-self.domain // num_tasks))
+
+    def with_new_children(self, children):
+        n = SlotMergeExchangeExec(children[0], self.num_tasks, self.bases)
+        n.stage_id = self.stage_id
+        n.producer_tasks = self.producer_tasks
+        n.consumer_fetch = self.consumer_fetch
+        n.predicted_exchange_bytes = self.predicted_exchange_bytes
+        return n
+
+    def output_capacity(self):
+        return self.range_slots
+
+    def _execute(self, ctx: ExecContext) -> Table:
+        partial = self.child
+        gt, columns, bases = partial.execute_slot_states(ctx)
+        if tuple(bases or ()) != self.bases:
+            raise RuntimeError(
+                f"the partial aggregate's keys have bases {bases} where "
+                f"the plan read {list(self.bases)} off its scan"
+            )
+        prec_flags: list = []
+        out = merge_slot_states(
+            columns, gt.slot_used, partial.group_names, partial.aggs,
+            self.bases, self._require_axis(ctx), self.num_tasks,
+            self.range_slots, prec_flags,
+        )
+        for f in prec_flags:
+            ctx.record_precision_error(self, f)
+        ctx.count("slot_merges")
+        return out
+
+    def display(self):
+        return (
+            f"SlotMergeExchange tasks={self.num_tasks} domain={self.domain} "
+            f"range={self.range_slots}"
         )
 
 

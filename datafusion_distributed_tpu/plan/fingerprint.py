@@ -382,6 +382,9 @@ _PLAN_ATTRS: dict = {
         tuple(n.key_names), int(n.num_tasks), int(n.per_dest_capacity),
         n.producer_tasks, n.consumer_fetch,
     ),
+    "SlotMergeExchangeExec": lambda n: (
+        int(n.num_tasks), tuple(n.bases), n.producer_tasks, n.consumer_fetch,
+    ),
     "RangeShuffleExchangeExec": lambda n: (
         _canon_value(n.sort_keys), int(n.num_tasks),
         int(n.per_dest_capacity), n.producer_tasks, n.consumer_fetch,

@@ -37,6 +37,8 @@ from datafusion_distributed_tpu import spans
 from datafusion_distributed_tpu.ops.aggregate import (
     _DENSE_MAX_DOMAIN,
     AggSpec,
+    aggregate_slots,
+    finalize_slot_states,
     hash_aggregate,
 )
 from datafusion_distributed_tpu.ops.sort import (
@@ -610,10 +612,18 @@ class HashAggregateExec(ExecutionPlan):
         # never needs more than pow2(input capacity) — downstream operators
         # (the final sort especially) pay capacity-proportional work, and
         # slots = 2x input would hand them double-width padding for free.
-        self.out_capacity = min(
-            self.num_slots,
-            round_up_pow2(max(child.output_capacity(), 16)),
+        # Over a slot merge a row IS a group: its input's capacity.
+        self.out_capacity = (
+            child.output_capacity() if self._merged_input() else min(
+                self.num_slots,
+                round_up_pow2(max(child.output_capacity(), 16)),
+            )
         )
+
+    def _merged_input(self) -> bool:
+        """Whether the child hands up groups with their states merged
+        already (`plan/exchanges.py SlotMergeExchangeExec`)."""
+        return getattr(self.child, "merges_slot_states", False)
 
     def children(self):
         return [self.child]
@@ -634,6 +644,13 @@ class HashAggregateExec(ExecutionPlan):
         return self.out_capacity if self.group_names else self.num_slots
 
     def _execute(self, ctx: ExecContext) -> Table:
+        if self._merged_input():
+            # one row a group, merged by slot range: no grouping is left,
+            # and the groups were addressed by their codes
+            out = finalize_slot_states(self.child.execute(ctx),
+                                       self.group_names, self.aggs)
+            self._count(ctx, [self.child.domain], [], [])
+            return out
         # every reduction below masks dead rows out, so the input need not
         # be packed: filters and projections underneath hand their mask up
         t, live, _ = self.child.execute_masked(ctx)
@@ -656,16 +673,43 @@ class HashAggregateExec(ExecutionPlan):
                 presence_from_count=presence_from_count,
             )
             ctx.record_overflow(self, overflow)
-            ctx.count("direct_groupings", len(direct))
-            ctx.count("dense_aggregates",
-                      sum(d <= _DENSE_MAX_DOMAIN for d in direct))
-            ctx.count_largest("group_slots",
-                              direct[0] if direct else self.num_slots)
-            ctx.count("scatter_reductions", len(scatters))
-            ctx.count("presence_from_count", len(presence_from_count))
+            self._count(ctx, direct, scatters, presence_from_count)
         for f in prec_flags:
             ctx.record_precision_error(self, f)
         return out
+
+    def execute_slot_states(self, ctx: ExecContext):
+        """`execute` of a ``partial`` grouping up to its pack, for the slot
+        merge above it (`plan/exchanges.py SlotMergeExchangeExec`): -> the
+        group table, every output column ``[num_slots]`` wide and the
+        keys' bases (`ops/aggregate.py aggregate_slots`); under this
+        node's scope, with its ``output_rows`` (the groups) and its
+        counters."""
+        prec_flags: list = []
+        direct: list = []
+        scatters: list = []
+        presence_from_count: list = []
+        with jax.named_scope(node_scope(self)):
+            t, live, _ = self.child.execute_masked(ctx)
+            gt, columns, bases = aggregate_slots(
+                t, self.group_names, self.aggs, self.num_slots, self.mode,
+                prec_flags, live, direct, scatters, presence_from_count,
+            )
+        ctx.record_metric(self, "output_rows", gt.num_groups)
+        self._count(ctx, direct, scatters, presence_from_count)
+        for f in prec_flags:
+            ctx.record_precision_error(self, f)
+        return gt, columns, bases
+
+    def _count(self, ctx: ExecContext, direct: list, scatters: list,
+               presence_from_count: list) -> None:
+        ctx.count("direct_groupings", len(direct))
+        ctx.count("dense_aggregates",
+                  sum(d <= _DENSE_MAX_DOMAIN for d in direct))
+        ctx.count_largest("group_slots",
+                          direct[0] if direct else self.num_slots)
+        ctx.count("scatter_reductions", len(scatters))
+        ctx.count("presence_from_count", len(presence_from_count))
 
     def display(self):
         aggs = ", ".join(f"{a.func}({a.input_name or '*'})" for a in self.aggs)

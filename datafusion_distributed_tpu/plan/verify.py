@@ -574,7 +574,7 @@ def _consumer_width(ex) -> Optional[int]:
     is partitioned (None = replicated output; any consumer width is fine)."""
     kind = type(ex).__name__
     if kind in ("ShuffleExchangeExec", "RangeShuffleExchangeExec",
-                "PartitionReplicatedExec"):
+                "SlotMergeExchangeExec", "PartitionReplicatedExec"):
         return int(ex.num_tasks)
     if kind == "CoalesceExchangeExec":
         m = int(getattr(ex, "num_consumers", 1))

@@ -36,7 +36,11 @@ import enum
 from dataclasses import dataclass
 from typing import Optional
 
-from datafusion_distributed_tpu.ops.aggregate import AggSpec
+from datafusion_distributed_tpu.ops.aggregate import (
+    SLOT_MERGE_FUNCS,
+    AggSpec,
+    _dictionary_bases,
+)
 from datafusion_distributed_tpu.ops.table import round_up_pow2
 from datafusion_distributed_tpu.parallel.exchange import partition_table
 from datafusion_distributed_tpu.plan.exchanges import (
@@ -44,7 +48,9 @@ from datafusion_distributed_tpu.plan.exchanges import (
     RangeShuffleExchangeExec,
     CoalesceExchangeExec,
     ShuffleExchangeExec,
+    SlotMergeExchangeExec,
 )
+from datafusion_distributed_tpu.plan.expressions import Col
 from datafusion_distributed_tpu.plan.joins import (
     CrossJoinExec,
     HashJoinExec,
@@ -264,6 +270,81 @@ def distribute_plan(
     out = _multiway_fusion_pass(out, config)
     out = _prepare(out)
     return out
+
+
+def merge_direct_groupings(plan: ExecutionPlan,
+                           num_tasks: int) -> ExecutionPlan:
+    """The mesh tier's plan (`SessionContext.distributed_plan(...,
+    mesh=...)`): each ``final(ShuffleExchange(partial))`` whose partial
+    addresses its groups by their dictionary codes gets a
+    `SlotMergeExchangeExec` in place of the hash shuffle. What decides is
+    static: the shuffle's keys are the group keys and it is ``num_tasks``
+    wide on both sides, every aggregate's state merges elementwise
+    (`SLOT_MERGE_FUNCS`), and the keys, read off the scan
+    (`_scanned_column`), have bases whose domain fits the partial's table
+    (`ops/aggregate.py _dictionary_bases`, the rule the partial's trace
+    applies). Every other node is left as it is."""
+    def walk(node: ExecutionPlan) -> ExecutionPlan:
+        children = node.children()
+        kids = [walk(c) for c in children]
+        if any(k is not c for k, c in zip(kids, children)):
+            node = node.with_new_children(kids)
+        merge = _slot_merge(node, num_tasks)
+        return node if merge is None else node.with_new_children([merge])
+
+    return walk(plan)
+
+
+def _slot_merge(final: ExecutionPlan,
+                num_tasks: int) -> Optional[SlotMergeExchangeExec]:
+    """The slot merge that can stand in for ``final``'s shuffle
+    (`merge_direct_groupings`), or None."""
+    if not (isinstance(final, HashAggregateExec) and final.mode == "final"
+            and final.group_names):
+        return None
+    shuffle = final.child
+    if not (isinstance(shuffle, ShuffleExchangeExec)
+            and shuffle.key_names == final.group_names
+            and shuffle.num_tasks == num_tasks
+            and shuffle.producer_tasks in (None, num_tasks)):
+        return None
+    partial = shuffle.child
+    if not (isinstance(partial, HashAggregateExec)
+            and partial.mode == "partial"
+            and partial.group_names == final.group_names
+            and partial.aggs == final.aggs
+            and all(a.func in SLOT_MERGE_FUNCS for a in partial.aggs)):
+        return None
+    keys = [_scanned_column(partial.child, g) for g in partial.group_names]
+    if any(k is None for k in keys):
+        return None
+    bases = _dictionary_bases(keys, partial.num_slots)
+    if bases is None:
+        return None
+    merge = SlotMergeExchangeExec(partial, num_tasks, bases)
+    merge.stage_id = shuffle.stage_id
+    merge.consumer_fetch = shuffle.consumer_fetch
+    return merge
+
+
+def _scanned_column(node: ExecutionPlan, name: str):
+    """Column ``name`` of ``node``'s output as the scan under it holds it
+    (its dictionary, whether it has a validity array), where the way down
+    is column references through projections and filters alone; else
+    None."""
+    while True:
+        if isinstance(node, ProjectionExec):
+            expr = next((e for e, out in node.exprs if out == name), None)
+            if not isinstance(expr, Col):
+                return None
+            name, node = expr.name, node.child
+        elif isinstance(node, (FilterExec, CoalescePartitionsExec)):
+            node = node.child
+        elif isinstance(node, MemoryScanExec) and node.tasks:
+            return (node.tasks[0].column(name)
+                    if name in node.tasks[0].names else None)
+        else:
+            return None
 
 
 def _root_distribution(plan: ExecutionPlan) -> Distribution:

@@ -65,13 +65,15 @@ PROFILE_PREFIX = "dftpu."
 #: partitions used where they lie, as the mesh's shards
 #: (`runtime/mesh_executor.py place_partitions`); ``mesh_exchange_bytes``:
 #: the bytes the exchanges' collectives carry (``all_to_all``,
-#: ``all_gather``, ``pmax``, ``ppermute``), each one's operand summed over
-#: the chips of its axis, from the planned buffers' shapes
-#: (`parallel/exchange.py collective_tally`).
+#: ``all_gather``, ``pmax``, ``ppermute``, ``psum_scatter``), each one's
+#: operand summed over the chips of its axis, from the planned buffers'
+#: shapes (`parallel/exchange.py collective_tally`); ``slot_merges``:
+#: exchanges that merged a direct grouping's partial states by slot range
+#: in place of a hash shuffle (`plan/exchanges.py SlotMergeExchangeExec`).
 PROGRAM_COUNTERS = ("masked_filters", "direct_groupings", "dense_aggregates",
                     "group_slots", "scatter_reductions", "presence_from_count",
                     "partitioned_scans", "mesh_exchange_bytes",
-                    "fetch_bounded_sorts")
+                    "fetch_bounded_sorts", "slot_merges")
 
 _SPAN_CAP = 4096     # ring-buffer bound per query
 _EVENT_CAP = 2048    # trace-level event bound per query

@@ -711,6 +711,7 @@ class DataFrame:
         from datafusion_distributed_tpu.planner.distributed import (
             DistributedConfig,
             distribute_plan,
+            merge_direct_groupings,
         )
 
         if config is None:
@@ -774,6 +775,8 @@ class DataFrame:
 
         planner = PhysicalPlanner(self.ctx.catalog, pcfg, subquery_executor)
         plan = distribute_plan(planner.plan(self.logical), cfg)
+        if mesh is not None:
+            plan = merge_direct_groupings(plan, mesh.shape["tasks"])
         self._plan_cache_put(key, plan)
         enforce_verification(plan, **verify_kw)
         return plan

@@ -27,7 +27,7 @@ from datafusion_distributed_tpu.io.parquet import Partitions
 from datafusion_distributed_tpu.ops.table import PartitionedTable
 from datafusion_distributed_tpu.plan.exchanges import (
     CoalesceExchangeExec,
-    ShuffleExchangeExec,
+    SlotMergeExchangeExec,
 )
 from datafusion_distributed_tpu.plan.physical import (
     PartitionedTableError,
@@ -254,8 +254,9 @@ def test_q12_reads_one_partitioned_scan_and_the_exchange_bytes(hits):
 
 def test_q12s_top_ten_crosses_the_mesh_at_its_fetch(hits):
     """Each chip's top-10 hands the `all_gather` coalesce 16 rows, not its
-    group table: `mesh_exchange_bytes` is the shuffle's share and four
-    16-row operands, and both sorts (local and final) count as cut."""
+    group table: `mesh_exchange_bytes` is the slot merge's share (one
+    count a slot of the domain, no key, no row) and four 16-row operands,
+    and both sorts (local and final) count as cut."""
     ctx = registered(hits, traced=True)
     mesh = make_mesh(4)
     tracing.DEFAULT_TRACE_STORE.clear()
@@ -270,20 +271,20 @@ def test_q12s_top_ten_crosses_the_mesh_at_its_fetch(hits):
     plan = df.distributed_plan(4, dataclasses.replace(
         df._seeded_distributed_config(4), uniform_stage_tasks=True),
         ctx.config.planner, mesh=mesh)
-    (shuffle,) = plan.collect(lambda n: isinstance(n, ShuffleExchangeExec))
+    (merge,) = plan.collect(lambda n: isinstance(n, SlotMergeExchangeExec))
     (coalesce,) = plan.collect(lambda n: isinstance(n, CoalesceExchangeExec))
     local = coalesce.child
     assert isinstance(local, SortExec) and local.fetch == 10
     groups = local.child.output_capacity()
-    assert local.output_capacity() == 16 < groups
-    tasks, per_dest = shuffle.num_tasks, shuffle.per_dest_capacity
+    assert local.output_capacity() == 16 < groups == merge.range_slots
+    tasks, slots = merge.num_tasks, merge.range_slots
+    # the partial counts' psum_scatter: one int32 a slot of the domain,
+    # padded to the tasks' ranges, on every chip
+    merged = tasks * (tasks * slots * 4)
     row_bytes = 2 * 4  # the phrase's code and the count, no validity
-    # the send buffer's all_to_all, the row counts', the overflow's pmax
-    shuffled = (tasks * (tasks * per_dest * row_bytes) + tasks * tasks * 4
-                + tasks * 4)
     # every chip's 16 rows and row count gathered to every chip
     coalesced = tasks * 16 * row_bytes + tasks * 4
-    assert row["mesh_exchange_bytes"] == shuffled + coalesced
+    assert row["mesh_exchange_bytes"] == merged + coalesced
 
 
 def _digest(table) -> str:

@@ -36,7 +36,10 @@ from datafusion_distributed_tpu.ops.aggregate import AggSpec, hash_aggregate
 from datafusion_distributed_tpu.ops.join import build_join_table, hash_join
 from datafusion_distributed_tpu.ops.sort import SortKey, sort_table
 from datafusion_distributed_tpu.ops.table import Column, Table
-from datafusion_distributed_tpu.parallel.exchange import shuffle_exchange
+from datafusion_distributed_tpu.parallel.exchange import (
+    shuffle_exchange,
+    slot_merge_exchange,
+)
 from datafusion_distributed_tpu.schema import DataType
 
 MI = 1 << 20
@@ -308,3 +311,29 @@ def test_hash_shuffle_compiles_on_four_chips(mesh4):
                         out_specs=P(AXIS), check_rep=False)
     compiled = _compile(program, stacked, scopes=("exchange.shuffle",))
     assert "all-to-all" in compiled.as_text()
+
+
+def test_slot_merge_compiles_on_four_chips(mesh4):
+    """ClickBench q12's partial counts merged by slot range across four
+    chips as the mesh tier runs it: one int32 count a slot of a
+    3,124,785-code domain, padded to four ranges of 781,200 slots, beside
+    a float sum, an int32 MIN and a float MAX lane. The sums lower to a
+    reduce-scatter (or the all-reduce the compiler may put in its place),
+    the MIN and the MAX to an `all_to_all` each."""
+    per_task = 4 * 781_200
+    sliced = NamedSharding(mesh4, P(AXIS))
+    lanes = [_shape(sliced, dt, 4 * per_task)
+             for dt in (jnp.int32, jnp.float32, jnp.int32, jnp.float32)]
+
+    def merge(count, total, low, high):
+        merged = slot_merge_exchange(
+            [("sum", count), ("sum", total), ("min", low), ("max", high)],
+            AXIS, 4)
+        return tuple(merged)
+
+    program = shard_map(merge, mesh=mesh4, in_specs=(P(AXIS),) * 4,
+                        out_specs=(P(AXIS),) * 4, check_rep=False)
+    text = _compile(program, *lanes,
+                    scopes=("exchange.slot_merge",)).as_text()
+    assert "reduce-scatter" in text or "all-reduce" in text
+    assert text.count("all-to-all") >= 2
