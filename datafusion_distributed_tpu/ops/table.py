@@ -261,7 +261,9 @@ class Column:
         source holds none); an array is padded with False and uploaded
         beside the data, one byte a slot."""
         def upload(buf):
-            return to_device(buf) if device is None else jax.device_put(
+            # uncommitted on the default device (shard_map re-places scan
+            # inputs freely), in one H2D copy
+            return jnp.asarray(buf) if device is None else jax.device_put(
                 buf, device)
 
         n = len(values)
@@ -288,8 +290,7 @@ class Column:
         if n == capacity and vals.ndim == 1 and vals.dtype == np_dtype:
             # a buffer that already satisfies the capacity (the wire decode
             # path when table_caps == live rows) enters the device as-is —
-            # no zero-fill + pad copy; `to_device` hands it over via dlpack
-            # where the backend allows (ownership transfers: the caller
+            # no zero-fill + pad copy (ownership transfers: the caller
             # must not mutate it afterwards)
             data = upload(np.ascontiguousarray(vals))
         else:
@@ -662,30 +663,6 @@ def zero_copy_enabled(config: Optional[dict] = None) -> bool:
     if env is not None:
         return parse_bool_knob(env)
     return parse_bool_knob((config or {}).get("zero_copy", True))
-
-
-def to_device(arr) -> jnp.ndarray:
-    """Host buffer -> device array. The dlpack import
-    (`jax.dlpack.from_dlpack`) is the zero-copy entry on backends whose
-    runtime can adopt an Arrow-layout host buffer — but it is OPT-IN
-    (``DFTPU_DLPACK=1``): on this jax/CPU build the import both copies AND
-    commits the result to one device, and a committed leaf breaks the
-    in-mesh tier's contract that scan inputs are uncommitted (shard_map
-    re-places them freely). The default `jnp.asarray` stays uncommitted and
-    costs the same single H2D copy. Callers hand over OWNERSHIP of the
-    buffer either way: it must not be mutated afterwards."""
-    if (
-        isinstance(arr, np.ndarray)
-        and arr.flags.c_contiguous
-        and os.environ.get("DFTPU_DLPACK") == "1"
-    ):
-        try:
-            import jax.dlpack as _jdl
-
-            return _jdl.from_dlpack(arr)
-        except Exception:
-            pass
-    return jnp.asarray(arr)
 
 
 def fetch_counters(table: "Table", rows: int, round_trips: int) -> dict:

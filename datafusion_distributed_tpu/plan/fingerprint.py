@@ -364,14 +364,6 @@ _PLAN_ATTRS: dict = {
         _canon_value(n.residual), int(n.out_capacity), int(n.num_slots),
         n.mark_name, bool(n.null_aware),
     ),
-    "MultiwayHashJoinExec": lambda n: (
-        tuple(
-            (s.join_type, tuple(s.probe_keys), tuple(s.build_keys),
-             _canon_value(s.residual), int(s.out_capacity),
-             int(s.num_slots), s.mark_name, bool(s.null_aware))
-            for s in n.steps
-        ),
-    ),
     "CrossJoinExec": lambda n: (int(n.out_capacity),),
     "UnionExec": lambda n: (),
     "WindowExec": lambda n: (

@@ -10,7 +10,6 @@ modes, and capacity policy.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
@@ -240,115 +239,6 @@ def _unify_key_dictionaries(probe: Table, build: Table, probe_keys, build_keys):
         probe = remap(pc, probe, pk)
         build = remap(bc, build, bk)
     return probe, build
-
-
-@dataclass(frozen=True)
-class MultiwayJoinStep:
-    """Parameters of one probe step of a fused multiway join — exactly the
-    knobs of the binary HashJoinExec the step replaced, so fusion is
-    reversible (``to_binary_chain``) without re-deriving capacities and the
-    fused plan sizes its tables byte-identically to the chain it fused."""
-
-    probe_keys: tuple
-    build_keys: tuple
-    join_type: str
-    out_capacity: int
-    num_slots: int
-    residual: Optional[PhysicalExpr] = None
-    mark_name: str = "__mark"
-    expansion_factor: float = 1.0
-    null_aware: bool = False
-
-    @classmethod
-    def from_join(cls, j: "HashJoinExec") -> "MultiwayJoinStep":
-        return cls(
-            probe_keys=tuple(j.probe_keys),
-            build_keys=tuple(j.build_keys),
-            join_type=j.join_type,
-            out_capacity=int(j.out_capacity),
-            num_slots=int(j.num_slots),
-            residual=j.residual,
-            mark_name=j.mark_name,
-            expansion_factor=float(j.expansion_factor),
-            null_aware=bool(j.null_aware),
-        )
-
-
-class MultiwayHashJoinExec(ExecutionPlan):
-    """A fused chain of >= 2 hash joins executed as ONE stage. Children are
-    ``[probe, build_1 .. build_K]``; ``steps[k]`` joins the running probe
-    stream against ``build_k``. The planner's fusion pass
-    (planner/distributed._multiway_fusion_pass) only builds this node when
-    every step's probe keys come from the BASE probe stream, which is what
-    lets the intermediate shuffles be deleted (re-hashing the same keys to
-    the same task count is an identity re-partition).
-
-    Execution is exact by construction: it IS the original binary chain
-    (``to_binary_chain``), rebuilt with the captured per-step capacities.
-    """
-
-    def __init__(self, probe: ExecutionPlan, builds: Sequence[ExecutionPlan],
-                 steps: Sequence[MultiwayJoinStep]):
-        super().__init__()
-        if len(builds) != len(steps) or len(steps) < 2:
-            raise ValueError(
-                f"multiway join needs >= 2 steps with one build each; got "
-                f"{len(steps)} steps / {len(builds)} builds"
-            )
-        self.probe = probe
-        self.builds = list(builds)
-        self.steps = list(steps)
-        self._chain_cache: Optional[HashJoinExec] = None
-
-    def children(self):
-        return [self.probe] + list(self.builds)
-
-    def with_new_children(self, children):
-        return MultiwayHashJoinExec(children[0], list(children[1:]),
-                                    self.steps)
-
-    def to_binary_chain(self, rederive: bool = False) -> HashJoinExec:
-        """The equivalent binary HashJoinExec chain. ``rederive=True`` drops
-        the captured capacities so the chain re-sizes from its (measured)
-        children — the bailout path when build estimates lied."""
-        cur = self.probe
-        for build, s in zip(self.builds, self.steps):
-            cur = HashJoinExec(
-                cur, build, list(s.probe_keys), list(s.build_keys),
-                s.join_type, residual=s.residual,
-                out_capacity=None if rederive else s.out_capacity,
-                num_slots=None if rederive else s.num_slots,
-                mark_name=s.mark_name,
-                expansion_factor=s.expansion_factor,
-                null_aware=s.null_aware,
-            )
-        return cur
-
-    def _chain(self) -> HashJoinExec:
-        if self._chain_cache is None:
-            self._chain_cache = self.to_binary_chain()
-        return self._chain_cache
-
-    def schema(self):
-        return self._chain().schema()
-
-    def output_capacity(self):
-        return self._chain().output_capacity()
-
-    def _execute(self, ctx: ExecContext) -> Table:
-        return self._chain()._execute(ctx)
-
-    def display(self):
-        parts = []
-        for s in self.steps:
-            ks = ", ".join(
-                f"{p}={b}" for p, b in zip(s.probe_keys, s.build_keys)
-            )
-            parts.append(f"{s.join_type}[{ks}]")
-        return (
-            f"MultiwayHashJoin {' -> '.join(parts)} "
-            f"out_cap={self.output_capacity()}"
-        )
 
 
 class CrossJoinExec(ExecutionPlan):

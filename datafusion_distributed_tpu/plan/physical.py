@@ -212,25 +212,10 @@ class ExecutionPlan:
     #: the coordinator performs while resolving nested exchange scans.
     bailout_candidate: "bool | None" = None
     predicted_partial_rows: "int | None" = None
-    #: multiway-join fusion annotations (planner/distributed
-    #: _multiway_fusion_pass): a fused MultiwayHashJoinExec the coordinator
-    #: may bail back to its binary chain when measured build sizes diverge.
-    multiway_bailout_candidate: "bool | None" = None
-    #: shuffles the fusion pass deleted building this node (identity
-    #: re-partitions); surfaced in EXPLAIN and asserted by tests
-    multiway_deleted_exchanges: "int | None" = None
-    #: global-hash-agg annotation (_inject_aggregate): marks a single-mode
-    #: aggregate the planner chose over partial+final because predicted NDV
-    #: was too high for partial states to shrink the exchange; guards the
-    #: push-down pass from re-rewriting it.
-    global_agg_selected: "bool | None" = None
-
     #: annotations the __init_subclass__ hook carries across rebuilds
     _PRESERVED_ANNOTATIONS = (
         "est_rows", "est_selectivity",
         "bailout_candidate", "predicted_partial_rows",
-        "multiway_bailout_candidate",
-        "multiway_deleted_exchanges", "global_agg_selected",
     )
 
     def __init__(self) -> None:

@@ -66,7 +66,7 @@ import warnings as _warnings
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
-from datafusion_distributed_tpu.schema import DataType, Field, Schema
+from datafusion_distributed_tpu.schema import DataType, Schema
 
 _INT32_MAX = (1 << 31) - 1
 
@@ -370,56 +370,6 @@ def _node_schema_checks(node, children, p: _Pass) -> None:
                        f"join residual references unknown column: {e}")
             except Exception:
                 pass
-    elif kind == "MultiwayHashJoinExec":
-        # fold the probe-stream schema step by step, mirroring the binary
-        # chain the node lowers to, so every step's keys are checked
-        # against the columns actually visible at that step
-        running = node.probe.schema()
-        for idx, (s, b) in enumerate(zip(node.steps, node.builds)):
-            build_schema = b.schema()
-            ok = _check_names(node, list(s.probe_keys), running,
-                              f"multiway step {idx} probe key", p)
-            ok = _check_names(node, list(s.build_keys), build_schema,
-                              f"multiway step {idx} build key", p) and ok
-            if ok:
-                for pk, bk in zip(s.probe_keys, s.build_keys):
-                    pc = _dtype_class(running.field(pk).dtype)
-                    bc = _dtype_class(build_schema.field(bk).dtype)
-                    if "null" in (pc, bc) or pc == bc:
-                        continue
-                    p.emit(
-                        "DFTPU012", "error", node,
-                        f"multiway step {idx} key {pk}={bk} compares "
-                        f"{pc} to {bc}: hashed bit patterns differ per "
-                        "class, rows would silently never match",
-                    )
-            if s.residual is not None:
-                try:
-                    s.residual.output_field(running.join(build_schema))
-                except KeyError as e:
-                    p.emit(
-                        "DFTPU011", "error", node,
-                        f"multiway step {idx} residual references "
-                        f"unknown column: {e}",
-                    )
-                except Exception:
-                    pass
-            if not ok:
-                break
-            if s.join_type in ("semi", "anti"):
-                continue
-            if s.join_type == "mark":
-                running = Schema(
-                    list(running.fields)
-                    + [Field(s.mark_name, DataType.BOOL, False)]
-                )
-                continue
-            running = Schema(
-                list(running.fields)
-                + [Field(f.name, f.dtype,
-                         True if s.join_type == "left" else f.nullable)
-                   for f in build_schema.fields]
-            )
     elif kind == "UnionExec":
         first = children[0].schema()
         for i, c in enumerate(children[1:], start=1):
@@ -495,22 +445,6 @@ def _capacity_pass(nodes: list, p: _Pass) -> None:
                     f"estimated {int(est)} distinct groups: the claim "
                     "loop will overflow and force a re-plan retry",
                 )
-        elif kind == "MultiwayHashJoinExec":
-            for idx, (s, b) in enumerate(zip(node.steps, node.builds)):
-                try:
-                    build_bound = int(b.output_capacity())
-                except Exception:
-                    build_bound = 0
-                est = getattr(b, "est_rows", None)
-                bound = int(est) if est is not None else build_bound
-                if s.num_slots < bound:
-                    p.emit(
-                        "DFTPU023", "warning", node,
-                        f"multiway step {idx} hash table has "
-                        f"{s.num_slots} slots for a build side bounded "
-                        f"by {bound} rows (load factor > 1): guaranteed "
-                        "overflow retry at full occupancy",
-                    )
         elif kind == "HashJoinExec":
             try:
                 build_bound = int(node.build.output_capacity())
@@ -722,18 +656,6 @@ def _exchange_pass(nodes: list, p: _Pass,
                     f"({sides[0].num_tasks} vs {sides[1].num_tasks}): "
                     "hash%t co-partitioning breaks and matching rows land "
                     "on different tasks",
-                )
-        elif kind == "MultiwayHashJoinExec":
-            sides = [c for c in node.children()
-                     if type(c).__name__ == "ShuffleExchangeExec"]
-            widths = sorted({s.num_tasks for s in sides})
-            if len(widths) > 1:
-                p.emit(
-                    "DFTPU034", "error", node,
-                    f"co-shuffled multiway join sides disagree on task "
-                    f"count ({widths}): every deleted intermediate "
-                    "exchange assumed one hash%t co-partitioning, so "
-                    "matching rows land on different tasks",
                 )
 
 

@@ -1,6 +1,6 @@
 """Adaptive (dynamic) planning: size stages from runtime statistics.
 
-The reference's `dynamic_task_count` mode re-runs boundary injection during
+The reference's dynamic task-count mode re-runs boundary injection during
 execution: each stage ships immediately, `SamplerExec` streams LoadInfo
 (rows/bytes ready + velocity, NDV%, null%) back to the coordinator, and the
 next stage's task count comes from the cost model over those sampled stats

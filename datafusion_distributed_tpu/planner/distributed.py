@@ -54,8 +54,6 @@ from datafusion_distributed_tpu.plan.expressions import Col
 from datafusion_distributed_tpu.plan.joins import (
     CrossJoinExec,
     HashJoinExec,
-    MultiwayHashJoinExec,
-    MultiwayJoinStep,
     UnionExec,
 )
 from datafusion_distributed_tpu.plan.physical import (
@@ -133,9 +131,8 @@ class DistributedConfig:
     worker_connection_buffer_budget_bytes: int = 64 << 20
     shuffle_chunk_bytes: int = 1 << 20
     # task-count estimation (reference: file_scan_config_bytes_per_partition
-    # 16MiB + dynamic_task_count): leaves sized by bytes, not mesh size
+    # 16MiB): leaves sized by bytes, not mesh size
     bytes_per_task: int = 16 << 20
-    dynamic_task_count: bool = False
     # scale factor applied per cardinality-affecting node when sizing a
     # boundary's consumer task count (CardinalityBasedNetworkBoundaryBuilder,
     # `inject_network_boundaries.rs:595-623`): shrinking nodes divide,
@@ -148,9 +145,6 @@ class DistributedConfig:
     size_tasks_to_data: bool = False
     # user TaskEstimator consulted before the built-in leaf estimation
     task_estimator: Optional[TaskEstimator] = None
-    # insert partial_reduce aggregates below hash shuffles (the reference's
-    # `partial_reduce` knob, default off; see _partial_reduce_pass)
-    partial_reduce: bool = False
     # statistics-driven partial-aggregate push-down (`SET
     # distributed.partial_agg_pushdown`): push decomposable aggregates
     # (sum/count/min/max, avg via sum+count) BELOW hash shuffles when the
@@ -169,25 +163,6 @@ class DistributedConfig:
     # high-NDV regime where distribution-aware placement says "aggregate
     # after the exchange")
     partial_agg_pushdown_min_reduction: float = 0.2
-    # multiway join-chain fusion (`SET distributed.multiway_join`): rewrite
-    # chains of >= 2 key-compatible binary hash joins into ONE
-    # MultiwayHashJoinExec stage, deleting the intermediate probe-side
-    # shuffles where re-hashing the same keys to the same task count is an
-    # identity re-partition (see _multiway_fusion_pass; grounding:
-    # *Efficient Multiway Hash Join on Reconfigurable Hardware*,
-    # PAPERS.md). Default off until parity is pinned per deployment.
-    multiway_join: bool = False
-    # combined resident build-side byte budget for one fused stage: every
-    # build table of the chain is live in the same program at once, so the
-    # statistics gate (planner/statistics.multiway_fusion_allowed) bounds
-    # their padded sum
-    multiway_build_bytes_max: int = 1 << 26
-    # global-hash-table aggregation (`SET distributed.global_hash_agg`):
-    # when sampled NDV predicts partial states will NOT shrink the
-    # exchange (the high-NDV regime of *Global Hash Tables Strike
-    # Back!*), plan shuffle-raw-rows + one single-mode aggregate per task
-    # — one shared table, no per-partition tables + merge. Default off.
-    global_hash_agg: bool = False
     # unlimited ORDER BY over data larger than this (global row capacity)
     # plans as a distributed sample sort (range shuffle + local sorts);
     # smaller sorts keep the cheaper coalesce-then-sort shape (two fewer
@@ -259,17 +234,13 @@ def distribute_plan(
         if _root_distribution(plan) == Distribution.PARTITIONED:
             plan = CoalesceExchangeExec(plan, config.num_tasks)
         plan = _partial_agg_pushdown_pass(plan, config)
-        plan = _multiway_fusion_pass(plan, config)
         return _prepare(plan)
     out, dist, ann = _inject(plan, config)
     if dist == Distribution.PARTITIONED:
         out, t_root = _seal_stage(out, ann, config)
         out = CoalesceExchangeExec(out, t_root)
-    out = _partial_reduce_pass(out, config)
     out = _partial_agg_pushdown_pass(out, config)
-    out = _multiway_fusion_pass(out, config)
-    out = _prepare(out)
-    return out
+    return _prepare(out)
 
 
 def merge_direct_groupings(plan: ExecutionPlan,
@@ -731,11 +702,6 @@ def _inject_aggregate(plan: HashAggregateExec, cfg: DistributedConfig):
         )
         return final, Distribution.REPLICATED, TaskCountAnnotation(1)
 
-    if cfg.global_hash_agg:
-        rewritten = _inject_global_agg(plan, child, ann, cfg)
-        if rewritten is not None:
-            return rewritten
-
     partial = HashAggregateExec(
         "partial", plan.group_names, plan.aggs, child, plan.num_slots
     )
@@ -758,51 +724,6 @@ def _inject_aggregate(plan: HashAggregateExec, cfg: DistributedConfig):
     )
     final.est_rows = plan.est_rows
     return final, Distribution.PARTITIONED, TaskCountAnnotation(t_c)
-
-
-def _inject_global_agg(plan: HashAggregateExec, child, ann,
-                       cfg: DistributedConfig):
-    """Global-hash-table aggregation shape (`SET distributed.global_hash_agg`
-    — *Global Hash Tables Strike Back!*): when sampled NDV predicts the
-    partial-state rows will NOT meaningfully undercut the raw rows (the
-    high-NDV regime where per-partition tables + merge is pure overhead),
-    shuffle the RAW rows on the group keys and run ONE single-mode
-    aggregate per task over its disjoint key range — one shared table, no
-    merge step; the single-mode aggregate is the same `hash_aggregate` every
-    other aggregate runs. Off by default. Returns the (plan, dist,
-    annotation) triple or None to keep the partial+final shape."""
-    from datafusion_distributed_tpu.planner.statistics import (
-        estimate_rows,
-        predict_partial_agg_reduction,
-    )
-
-    sealed, t_p = _seal_stage(child, ann, cfg)
-    t_c = _consumer_count(sealed, t_p, cfg)
-    if t_c <= 1:
-        return None  # one consumer: the gather shape is already merge-free
-    rows_in = estimate_rows(child)
-    ndv = (max(float(plan.est_rows), 1.0) if plan.est_rows is not None
-           else max(rows_in ** 0.5, 1.0))
-    pred = predict_partial_agg_reduction(rows_in, ndv, t_p)
-    if pred.reduction >= cfg.partial_agg_pushdown_min_reduction:
-        return None  # low NDV: partial states collapse; keep partial+final
-    shuffle = _mk_shuffle(sealed, plan.group_names, cfg, t_c, t_p)
-    # the shared table is NDV-sized upstream (plan.num_slots comes from the
-    # catalog's sampled NDV), capped by what the exchange can deliver to
-    # one task — capacity-safe: groups <= delivered rows
-    single = HashAggregateExec(
-        "single", plan.group_names, plan.aggs, shuffle,
-        min(plan.num_slots,
-            round_up_pow2(max(shuffle.output_capacity(), 16))),
-    )
-    single.est_rows = plan.est_rows
-    single.global_agg_selected = True
-    from datafusion_distributed_tpu.runtime.adaptivity import (
-        note_global_agg_selected,
-    )
-
-    note_global_agg_selected()
-    return single, Distribution.PARTITIONED, TaskCountAnnotation(t_c)
 
 
 def _mk_shuffle(child, keys, cfg: DistributedConfig,
@@ -832,27 +753,28 @@ def _repack_slots(partial: HashAggregateExec) -> int:
 
 def _repack_partial_shuffle(
     node: ShuffleExchangeExec, cfg: DistributedConfig,
-    cap_per_dest: bool = False,
 ) -> ShuffleExchangeExec:
     """Insert a `partial_reduce` re-group between ``node``'s partial
     aggregate and the shuffle, re-sizing the per-destination capacity
-    from the tighter slot count. ONE rewrite shared by
-    `_partial_reduce_pass` (unconditional, knob-gated) and the
-    stats-gated shape of `_partial_agg_pushdown_pass` — the capacity
-    arithmetic must not drift between them. ``cap_per_dest`` bounds the
-    new per-destination capacity by the original shuffle's (the
-    push-down pass never widens an exchange)."""
+    from the tighter slot count, never above the original shuffle's (the
+    stats-gated shape 2 of `_partial_agg_pushdown_pass`, which never
+    widens an exchange).
+
+    TPU rationale: exchange payloads are PADDED capacity buffers, and a
+    partial aggregate is sized for the GLOBAL group cardinality while one
+    task's slice can only hold `slice_capacity` distinct keys; the
+    re-group re-packs partial states into `_repack_slots` slots, shrinking
+    the all_to_all payload (the reference's
+    `partial_reduce_below_network_shuffles.rs`)."""
     partial = node.child
     slots = _repack_slots(partial)
     reduce_node = HashAggregateExec(
         "partial_reduce", partial.group_names, partial.aggs, partial,
         slots,
     )
-    per_dest = round_up_pow2(max(
+    per_dest = min(node.per_dest_capacity, round_up_pow2(max(
         cfg.shuffle_skew_factor * slots // max(node.num_tasks, 1), 8
-    ))
-    if cap_per_dest:
-        per_dest = min(node.per_dest_capacity, per_dest)
+    )))
     ex = ShuffleExchangeExec(
         reduce_node, node.key_names, node.num_tasks, per_dest
     )
@@ -863,44 +785,10 @@ def _repack_partial_shuffle(
     return ex
 
 
-def _partial_reduce_pass(plan: ExecutionPlan,
-                         cfg: DistributedConfig) -> ExecutionPlan:
-    """Insert `mode=partial_reduce` between a producer stage's partial
-    aggregate and its hash shuffle (the reference's
-    `partial_reduce_below_network_shuffles.rs`, gated off by default by
-    `DistributedConfig.partial_reduce` exactly like the reference knob).
-
-    TPU rationale: exchange payloads are PADDED capacity buffers, and a
-    partial aggregate is sized for the GLOBAL group cardinality while one
-    task's slice can only hold `slice_capacity` distinct keys. The inserted
-    re-group re-packs partial states into `min(global_slots,
-    2*slice_capacity)` slots, shrinking the all_to_all payload for
-    high-cardinality GROUP BYs (the merge itself is the same accumulator
-    merge the reference performs post-repartition)."""
-    if not cfg.partial_reduce:
-        return plan
-
-    def walk(node: ExecutionPlan) -> ExecutionPlan:
-        children = [walk(c) for c in node.children()]
-        if children:
-            node = node.with_new_children(children)
-        if not (
-            isinstance(node, ShuffleExchangeExec)
-            and isinstance(node.child, HashAggregateExec)
-            and node.child.mode == "partial"
-            and node.child.group_names
-            and list(node.key_names) == list(node.child.group_names)
-        ):
-            return node
-        return _repack_partial_shuffle(node, cfg)
-
-    return walk(plan)
-
-
 def _partial_agg_pushdown_pass(plan: ExecutionPlan,
                                cfg: DistributedConfig) -> ExecutionPlan:
     """Statistics-driven partial-aggregate push-down below hash shuffles
-    (`DistributedConfig.partial_agg_pushdown`, default off).
+    (`DistributedConfig.partial_agg_pushdown`, default on).
 
     Two shapes, both decided from the SAMPLED key-distribution
     statistics the planner already carries (catalog NDV samples stamped
@@ -924,9 +812,9 @@ def _partial_agg_pushdown_pass(plan: ExecutionPlan,
        the exchange already carries partial states; stamp the predicted
        exchange bytes (so the coordinator can record
        predicted-vs-measured through the telemetry registry) and insert
-       a `partial_reduce` re-pack — the `_partial_reduce_pass` rewrite —
-       only where the statistics predict it pays (per-task groups well
-       under the padded slice capacity), instead of unconditionally.
+       a `partial_reduce` re-pack (`_repack_partial_shuffle`) only where
+       the statistics predict it pays (per-task groups well under the
+       padded slice capacity).
 
     The decision is the distribution-aware placement of *Chasing
     Similarity*: low-NDV keys collapse under pre-exchange aggregation
@@ -965,9 +853,6 @@ def _partial_agg_pushdown_pass(plan: ExecutionPlan,
             and set(node.child.key_names) <= set(node.group_names)
             and all(a.func in PUSHDOWN_DECOMPOSABLE_FUNCS
                     for a in node.aggs)
-            # the global-hash-agg shape IS single-over-raw-shuffle by
-            # design — never rewrite it back to partial+final
-            and not getattr(node, "global_agg_selected", False)
         ):
             ex = node.child
             t_prod = (ex.producer_tasks if ex.producer_tasks is not None
@@ -1040,148 +925,17 @@ def _partial_agg_pushdown_pass(plan: ExecutionPlan,
             )
             partial.bailout_candidate = True
             partial.predicted_partial_rows = int(pred.rows_out)
-            # stats-gated partial_reduce re-pack (the SAME rewrite the
-            # partial_reduce knob applies unconditionally —
-            # _repack_partial_shuffle): only when a task's slice
-            # capacity bounds its groups far tighter than the global
-            # table AND the key distribution actually collapses
+            # stats-gated partial_reduce re-pack: only when a task's
+            # slice capacity bounds its groups far tighter than the
+            # global table AND the key distribution actually collapses
             if (_repack_slots(partial) < partial.num_slots
                     and pred.reduction >= threshold
                     and not isinstance(partial.child,
                                        HashAggregateExec)):
-                return _repack_partial_shuffle(node, cfg,
-                                               cap_per_dest=True)
+                return _repack_partial_shuffle(node, cfg)
         return node
 
     return walk(plan)
-
-
-def _multiway_fusion_pass(
-    plan: ExecutionPlan, cfg: DistributedConfig
-) -> ExecutionPlan:
-    """Fuse chains of >= 2 key-compatible binary hash joins into one
-    MultiwayHashJoinExec stage (`SET distributed.multiway_join`).
-
-    Two link shapes extend a chain downward through a join's probe side:
-
-    - **same-stage link** (broadcast build): the probe child IS another
-      hash join — no exchange separates them, fusing just packs both probes
-      into one node (one compiled program instead of two kernel subtrees).
-    - **shuffle link**: the probe child is a shuffle S over a join whose
-      OWN probe arrived through a shuffle S2 with the SAME key names and
-      the SAME task count. Probe-side key columns pass through a join
-      unchanged, so re-hashing them sends every row back to the task it is
-      already on — S is an identity re-partition and is DELETED. Name
-      safety: each key must resolve on the probe stream and be unshadowed
-      by any build-side column, otherwise the "same columns" premise
-      breaks.
-
-    Gates: the statistics module bounds the fused stage's combined
-    resident build bytes (every build table is live in one program), and
-    kept build-side shuffles must match the base layout's task count. The
-    fused node is marked `multiway_bailout_candidate` so the coordinator
-    can swap it back to the binary chain when measured build sizes diverge
-    (runtime/coordinator._bailout_multiway).
-
-    Runs AFTER the push-down pass (so aggregate rewrites see the original
-    exchanges) and BEFORE _prepare (stage ids are stamped on whatever
-    exchanges survive).
-    """
-    if not cfg.multiway_join:
-        return plan
-
-    from datafusion_distributed_tpu.planner.statistics import (
-        multiway_fusion_allowed,
-    )
-
-    def build_schemas(j):
-        if isinstance(j, MultiwayHashJoinExec):
-            return [b.schema() for b in j.builds]
-        return [j.build.schema()]
-
-    def fusible_inner(p):
-        """(inner join-or-fused-stage feeding ``p``, shuffle this link
-        deletes or None) — or (None, None) when the chain stops here."""
-        if isinstance(p, (HashJoinExec, MultiwayHashJoinExec)):
-            return p, None  # same-stage link
-        if (type(p) is ShuffleExchangeExec
-                and isinstance(p.child,
-                               (HashJoinExec, MultiwayHashJoinExec))):
-            inner = p.child
-            s2 = inner.probe
-            if (type(s2) is ShuffleExchangeExec
-                    and list(p.key_names) == list(s2.key_names)
-                    and p.num_tasks == s2.num_tasks):
-                probe_names = set(inner.probe.schema().names)
-                build_names = set()
-                for bs in build_schemas(inner):
-                    build_names |= set(bs.names)
-                if (set(p.key_names) <= probe_names
-                        and not (set(p.key_names) & build_names)):
-                    return inner, p
-        return None, None
-
-    def try_fuse(outer: ExecutionPlan) -> ExecutionPlan:
-        if not isinstance(outer, HashJoinExec):
-            return outer
-        steps = [MultiwayJoinStep.from_join(outer)]
-        builds = [outer.build]
-        probe = outer.probe
-        deleted = 0
-        while True:
-            inner, ex = fusible_inner(probe)
-            if inner is None:
-                break
-            if isinstance(inner, MultiwayHashJoinExec):
-                steps = list(inner.steps) + steps
-                builds = list(inner.builds) + builds
-                deleted += inner.multiway_deleted_exchanges or 0
-            else:
-                steps = [MultiwayJoinStep.from_join(inner)] + steps
-                builds = [inner.build] + builds
-            if ex is not None:
-                deleted += 1
-            probe = inner.probe
-        if len(steps) < 2:
-            return outer
-        if deleted:
-            # the fused stage runs on the base shuffle's layout; every kept
-            # co-shuffled build must agree with it
-            t = (probe.num_tasks if type(probe) is ShuffleExchangeExec
-                 else None)
-            if t is None:
-                return outer
-            for b in builds:
-                if type(b) is ShuffleExchangeExec and b.num_tasks != t:
-                    return outer
-        if not multiway_fusion_allowed(builds, cfg.multiway_build_bytes_max):
-            return outer
-        mw = MultiwayHashJoinExec(probe, builds, steps)
-        mw.multiway_bailout_candidate = True
-        mw.est_rows = outer.est_rows
-        mw.multiway_deleted_exchanges = deleted
-        return mw
-
-    def walk(node: ExecutionPlan) -> ExecutionPlan:
-        children = [walk(c) for c in node.children()]
-        if children:
-            node = node.with_new_children(children)
-        return try_fuse(node)
-
-    out = walk(plan)
-    fused = 0
-    removed = 0
-    for n in out.collect(lambda x: isinstance(x, MultiwayHashJoinExec)):
-        if getattr(n, "multiway_deleted_exchanges", None) is not None:
-            fused += len(n.steps)
-            removed += n.multiway_deleted_exchanges
-    if fused:
-        from datafusion_distributed_tpu.runtime.adaptivity import (
-            note_multiway_fusion,
-        )
-
-        note_multiway_fusion(fused, removed)
-    return out
 
 
 def _inject_join(plan: HashJoinExec, cfg: DistributedConfig):

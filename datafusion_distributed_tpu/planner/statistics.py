@@ -28,7 +28,6 @@ from datafusion_distributed_tpu.plan.exchanges import (
 from datafusion_distributed_tpu.plan.joins import (
     CrossJoinExec,
     HashJoinExec,
-    MultiwayHashJoinExec,
     UnionExec,
 )
 from datafusion_distributed_tpu.plan.physical import (
@@ -147,14 +146,6 @@ def estimate_rows(plan: ExecutionPlan, stats: Optional[PlanStatistics] = None) -
         # that fanout — ignoring it here would systematically undercut
         # row-estimate-capped hash sizing above such joins
         return p * max(float(getattr(plan, "expansion_factor", 1.0)), 1.0)
-    if isinstance(plan, MultiwayHashJoinExec):
-        p = estimate_rows(plan.probe, stats)
-        for s in plan.steps:
-            if s.join_type in ("semi", "anti"):
-                p = p / 2.0
-            else:
-                p = p * max(float(s.expansion_factor), 1.0)
-        return p
     if isinstance(plan, CrossJoinExec):
         return estimate_rows(plan.left, stats) * estimate_rows(plan.right, stats)
     if isinstance(plan, UnionExec):
@@ -208,10 +199,6 @@ def operator_compute_rows(
         b = estimate_rows(plan.build, stats)
         p = estimate_rows(plan.probe, stats)
         return b + p
-    if isinstance(plan, MultiwayHashJoinExec):
-        # one row-stream pass resolves every table: probe once + K builds
-        p = estimate_rows(plan.probe, stats)
-        return p + sum(estimate_rows(b, stats) for b in plan.builds)
     if isinstance(plan, CrossJoinExec):
         return (estimate_rows(plan.left, stats)
                 * estimate_rows(plan.right, stats))
@@ -367,35 +354,6 @@ def predict_partial_agg_reduction(
         rows_in=rows_in, rows_out=rows_out, rows_per_task=per_task,
         reduction=max(reduction, 0.0),
     )
-
-
-def multiway_build_bytes(builds) -> int:
-    """Padded byte footprint of a fused join chain's build sides — they are
-    ALL resident in one stage's program at once (the cost the binary chain
-    amortizes across stages), so the fusion pass gates on their sum against
-    DistributedConfig.multiway_build_bytes_max."""
-    total = 0
-    for b in builds:
-        try:
-            w = row_width(b.schema())
-        except Exception:
-            w = 8
-        try:
-            cap = int(b.output_capacity())
-        except Exception:
-            cap = 0
-        total += cap * max(w, 1)
-    return total
-
-
-def multiway_fusion_allowed(builds, max_bytes: int) -> bool:
-    """Statistics gate for the multiway fusion pass: every build side must
-    carry a usable size AND their combined resident footprint must fit the
-    configured budget. (Per-step NDV bounds ride on each step's captured
-    num_slots, checked by the verifier's DFTPU023 pass.)"""
-    if not builds:
-        return False
-    return multiway_build_bytes(builds) <= max_bytes
 
 
 def plan_device_bytes(plan) -> int:

@@ -49,9 +49,6 @@ __all__ = [
     "note_skew_split",
     "note_partial_agg_bailout",
     "note_replan",
-    "note_multiway_fusion",
-    "note_multiway_bailout",
-    "note_global_agg_selected",
 ]
 
 
@@ -178,14 +175,6 @@ _count("dftpu_partial_agg_bailouts",
        "pushed-down partial aggregations bailed out to passthrough", 0)
 _count("dftpu_replans",
        "mid-query re-cost/re-order passes over undispatched stages", 0)
-_count("dftpu_joins_fused",
-       "binary hash joins fused into multiway join stages", 0)
-_count("dftpu_exchanges_deleted",
-       "shuffle exchanges deleted by multiway join fusion", 0)
-_count("dftpu_global_agg_selected",
-       "aggregations planned as one global hash table (high NDV)", 0)
-_count("dftpu_multiway_bailouts",
-       "fused multiway joins bailed back to their binary chains", 0)
 
 
 def note_skew_split(
@@ -224,56 +213,6 @@ def note_partial_agg_bailout(
             ratio=round(float(ratio), 4),
             predicted_rows=int(predicted_rows),
         )
-    except Exception:
-        pass
-
-
-def note_multiway_fusion(joins_fused: int, exchanges_deleted: int) -> None:
-    """Planner-side (no query id yet): a fusion pass collapsed
-    ``joins_fused`` binary joins into multiway stages and deleted
-    ``exchanges_deleted`` intermediate shuffles."""
-    _count("dftpu_joins_fused",
-           "binary hash joins fused into multiway join stages",
-           int(joins_fused))
-    _count("dftpu_exchanges_deleted",
-           "shuffle exchanges deleted by multiway join fusion",
-           int(exchanges_deleted))
-    try:
-        log_event(
-            "multiway_fusion",
-            joins_fused=int(joins_fused),
-            exchanges_deleted=int(exchanges_deleted),
-        )
-    except Exception:
-        pass
-
-
-def note_multiway_bailout(
-    query_id, steps: int, measured_rows: int, num_slots: int,
-) -> None:
-    """A fused multiway join was swapped back to its binary chain because
-    a measured build side outgrew the captured table sizing."""
-    _count("dftpu_multiway_bailouts",
-           "fused multiway joins bailed back to their binary chains")
-    try:
-        log_event(
-            "multiway_bailout",
-            query_id=query_id,
-            steps=int(steps),
-            measured_rows=int(measured_rows),
-            num_slots=int(num_slots),
-        )
-    except Exception:
-        pass
-
-
-def note_global_agg_selected() -> None:
-    """Planner-side: sampled NDV was high enough that the aggregate was
-    planned as one shared global hash table instead of partial+merge."""
-    _count("dftpu_global_agg_selected",
-           "aggregations planned as one global hash table (high NDV)")
-    try:
-        log_event("global_agg_selected")
     except Exception:
         pass
 

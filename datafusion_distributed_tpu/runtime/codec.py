@@ -33,8 +33,6 @@ from datafusion_distributed_tpu.plan.exchanges import (
 from datafusion_distributed_tpu.plan.joins import (
     CrossJoinExec,
     HashJoinExec,
-    MultiwayHashJoinExec,
-    MultiwayJoinStep,
     UnionExec,
 )
 from datafusion_distributed_tpu.plan.physical import (
@@ -1020,26 +1018,6 @@ def _encode_plan_node(p: ExecutionPlan, store: TableStore) -> dict:
             "probe": _encode_plan_node(p.probe, store),
             "build": _encode_plan_node(p.build, store),
         }
-    if isinstance(p, MultiwayHashJoinExec):
-        return {
-            "t": "mwjoin",
-            "steps": [
-                {
-                    "jt": s.join_type,
-                    "pk": list(s.probe_keys),
-                    "bk": list(s.build_keys),
-                    "residual": (encode_expr(s.residual)
-                                 if s.residual else None),
-                    "out_cap": s.out_capacity,
-                    "slots": s.num_slots,
-                    "mark": s.mark_name,
-                    "null_aware": s.null_aware,
-                }
-                for s in p.steps
-            ],
-            "probe": _encode_plan_node(p.probe, store),
-            "builds": [_encode_plan_node(b, store) for b in p.builds],
-        }
     if isinstance(p, CrossJoinExec):
         return {"t": "crossjoin", "out_cap": p.out_capacity,
                 "l": _encode_plan_node(p.left, store),
@@ -1193,22 +1171,6 @@ def decode_plan(o: dict, store: TableStore) -> ExecutionPlan:
             residual=decode_expr(o["residual"]) if o["residual"] else None,
             out_capacity=o["out_cap"], num_slots=o["slots"],
             mark_name=o["mark"], null_aware=o["null_aware"],
-        )
-    if t == "mwjoin":
-        return MultiwayHashJoinExec(
-            decode_plan(o["probe"], store),
-            [decode_plan(b, store) for b in o["builds"]],
-            [
-                MultiwayJoinStep(
-                    probe_keys=tuple(s["pk"]), build_keys=tuple(s["bk"]),
-                    join_type=s["jt"], out_capacity=s["out_cap"],
-                    num_slots=s["slots"],
-                    residual=(decode_expr(s["residual"])
-                              if s["residual"] else None),
-                    mark_name=s["mark"], null_aware=s["null_aware"],
-                )
-                for s in o["steps"]
-            ],
         )
     if t == "crossjoin":
         return CrossJoinExec(decode_plan(o["l"], store),

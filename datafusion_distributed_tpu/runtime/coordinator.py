@@ -1015,11 +1015,8 @@ class Coordinator:
             for c in plan.children()
         ]
         if children:
-            plan = self._bailout_multiway(
-                self._widen_bailed_out_merge(
-                    plan.with_new_children(children)
-                ),
-                query_id,
+            plan = self._widen_bailed_out_merge(
+                plan.with_new_children(children)
             )
         if not getattr(plan, "is_exchange", False):
             return plan
@@ -1070,11 +1067,8 @@ class Coordinator:
             children = [resolve(c) for c in node.children()]
             if not children:
                 return node
-            return self._bailout_multiway(
-                self._widen_bailed_out_merge(
-                    node.with_new_children(children)
-                ),
-                query_id,
+            return self._widen_bailed_out_merge(
+                node.with_new_children(children)
             )
 
         waiting = {sid: set(n.deps) for sid, n in nodes.items()}
@@ -2594,56 +2588,6 @@ class Coordinator:
             setattr(rebuilt, attr, getattr(node, attr, None))
         return rebuilt
 
-    def _bailout_multiway(self, node, query_id: str):
-        """Multiway half of the bail-out: once a fused stage's build
-        boundaries resolve to materialized MemoryScans, their row counts
-        are MEASURED, not estimated. If any measured build outgrew the
-        hash table the planner captured for its step (per-task load
-        factor would exceed 0.5 — the bound the binary constructor sizes
-        to), the fused stage is swapped back to its binary chain with
-        ``rederive=True`` so every join re-sizes from the resolved
-        children. Output bytes are unchanged either way — the chain is
-        the fused stage's reference semantics — only the sizing and
-        kernel choice differ. Capacity paddings never trigger this:
-        only actual materialized rows count, so the peer/stream planes
-        (whose rows never cross the coordinator) simply never bail —
-        the same measurability rule _maybe_replan follows.
-        Deterministic: the same measured rows always bail the same
-        stages."""
-        from datafusion_distributed_tpu.plan.joins import (
-            MultiwayHashJoinExec,
-        )
-
-        if not isinstance(node, MultiwayHashJoinExec):
-            return node
-        if not getattr(node, "multiway_bailout_candidate", False):
-            return node
-
-        def measured_rows(build):
-            # the per-task build table: replicated scans load the full
-            # table on every task, partitioned scans one shard each
-            if not isinstance(build, MemoryScanExec) or not build.tasks:
-                return None
-            if getattr(build, "replicated", False):
-                return int(build.tasks[0].num_rows)
-            return max(int(t.num_rows) for t in build.tasks)
-
-        worst = 0
-        slots = 0
-        for build, step in zip(node.builds, node.steps):
-            rows = measured_rows(build)
-            if rows is not None and 2 * rows > int(step.num_slots):
-                worst = max(worst, rows)
-                slots = int(step.num_slots)
-        if not worst:
-            return node
-        from datafusion_distributed_tpu.runtime.adaptivity import (
-            note_multiway_bailout,
-        )
-
-        note_multiway_bailout(query_id, len(node.steps), worst, slots)
-        return node.to_binary_chain(rederive=True)
-
     def _maybe_replan(self, query_id: str, stage_id: int, nodes, scan,
                       submitted) -> bool:
         """Mid-query re-cost: when stage `stage_id`'s measured output
@@ -4109,7 +4053,7 @@ class Coordinator:
 
 @dataclass
 class AdaptiveCoordinator(Coordinator):
-    """Dynamic-planning coordinator (the reference's `dynamic_task_count`
+    """Dynamic-planning coordinator (the reference's dynamic task-count
     mode): consumer stages are re-sized from runtime LoadInfo — planning
     and execution interleave (`prepare_dynamic_plan.rs`). Both CAPACITIES
     (resize_for_inputs) and TASK COUNTS (compute_based_task_count analogue:
@@ -4298,7 +4242,7 @@ class AdaptiveCoordinator(Coordinator):
 
     def _consumer_task_count(self, exchange, outputs) -> int:
         """Recompute the consumer task count from producer-output bytes
-        (dynamic_task_count semantics); the planned count is only an upper
+        (dynamic task-count semantics); the planned count is only an upper
         bound. Uses the mid-execution prediction when one was frozen,
         exact bytes otherwise.
 

@@ -317,8 +317,7 @@ class SessionConfig:
                     )
             elif key in ("fair_share", "zero_copy", "hedging",
                          "checkpointing", "pipelined_shuffle",
-                         "partial_agg_pushdown", "multiway_join",
-                         "global_hash_agg", "result_cache"):
+                         "partial_agg_pushdown", "result_cache"):
                 # boolean knobs: fair_share (serving scheduler policy),
                 # zero_copy (view-based data plane — `off` restores the
                 # copying plane everywhere), hedging (straggler
@@ -326,11 +325,7 @@ class SessionConfig:
                 # checkpoint/resume), pipelined_shuffle (streaming
                 # first-slice shuffle boundaries — `off` restores the
                 # materialized plane), partial_agg_pushdown (statistics-
-                # driven pre-exchange partial aggregation), multiway_join
-                # (fuse key-compatible join chains into one stage,
-                # deleting intermediate shuffles), global_hash_agg
-                # (high-NDV aggregation as one shared hash table instead
-                # of per-partition tables + merge), result_cache
+                # driven pre-exchange partial aggregation), result_cache
                 # (fingerprint-keyed whole-result + sub-plan reuse —
                 # runtime/result_cache.py). One shared parser so
                 # SET-time coercion and runtime reads can't drift.
@@ -878,7 +873,7 @@ class DataFrame:
         ``coordinator`` an in-memory cluster of ``num_workers`` is spun up —
         the reference's InMemoryChannelResolver rung its whole TPC suite
         runs on (`tpch_correctness_test.rs:23-80`). ``adaptive=True`` uses
-        the AdaptiveCoordinator (dynamic_task_count analogue).
+        the AdaptiveCoordinator (the reference's dynamic task count).
 
         With `SET distributed.result_cache` on, the whole-result cache
         is consulted FIRST (runtime/result_cache.py): a hit returns the

@@ -240,23 +240,6 @@ if ! env DFTPU_LOCK_CHECK=1 python -m pytest tests/test_shm_plane.py \
         -p no:cacheprovider "${MARKER_ARGS[@]}" "$@"; then
     FAILED+=("tests/test_shm_plane.py[gate+lockcheck]")
 fi
-# Multiway-join + global-hash-agg gate (tests/test_multiway_join.py):
-# the fusion pass's two link forms (broadcast same-stage chains and
-# identity re-shuffle deletion with dftpu_exchanges_deleted >= 2 on
-# co-shuffled q21), cascaded-probe and global-hash-agg kernel parity vs
-# the claim-loop oracles in interpret mode, MultiwayHashJoinExec
-# byte-identity vs the binary chain it fused on both execution paths,
-# TPC-H q5/q9/q21 fused-vs-unfused byte identity through the
-# coordinator under seeded chaos + membership churn, exact
-# global-agg-vs-merge aggregation, the measured-rows-only coordinator
-# bailout, zero new XLA traces on resubmission, and the
-# DFTPU011/012/023/025/034 verifier arms.
-echo "=== tests/test_multiway_join.py (multiway-join + global-hash-agg gate)"
-if ! python -m pytest tests/test_multiway_join.py \
-        -q --no-header \
-        -p no:cacheprovider "${MARKER_ARGS[@]}" "$@"; then
-    FAILED+=("tests/test_multiway_join.py[gate]")
-fi
 # Result-cache gate (tests/test_result_cache.py): the fingerprint-keyed
 # whole-result + sub-plan cache (runtime/result_cache.py) — hit/miss/
 # LRU/spill-refault unit arms, literal-variant correctness, PlannerConfig
@@ -276,7 +259,6 @@ if ! env DFTPU_LOCK_CHECK=1 DFTPU_LEAK_CHECK=strict python -m pytest tests/test_
 fi
 for f in tests/test_*.py; do
     [ "$f" = "tests/test_memory_pressure.py" ] && continue  # ran above
-    [ "$f" = "tests/test_multiway_join.py" ] && continue  # ran above (gate)
     [ "$f" = "tests/test_recompile_budget.py" ] && continue  # ran above
     [ "$f" = "tests/test_pipelined_shuffle.py" ] && continue  # ran above
     [ "$f" = "tests/test_plan_verify.py" ] && continue  # ran above (gate)

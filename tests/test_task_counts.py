@@ -224,50 +224,55 @@ def test_per_stage_byte_sizing_differs_between_stages():
     )
 
 
-def test_partial_reduce_pass_fires_on_q1_shape():
-    """The automatic partial-reduce pass (reference
-    `partial_reduce_below_network_shuffles.rs`): gated off by default, and
-    when enabled inserts mode=partial_reduce between the producer's partial
-    aggregate and the hash shuffle on a TPC-H q1-shaped plan; mesh results
-    still match single-node."""
+def test_pushdown_repack_fires_on_low_ndv_key():
+    """The push-down pass's stats-gated re-pack (reference
+    `partial_reduce_below_network_shuffles.rs`): on a key whose sampled NDV
+    (300 over 4000 rows) sizes the partial's table past what one task's
+    slice can hold, and whose statistics predict the partial states
+    collapse, mode=partial_reduce sits between the producer's partial
+    aggregate and the hash shuffle; the push-down switched off inserts
+    none; mesh results still match single-node."""
     import jax
 
+    from datafusion_distributed_tpu.plan.exchanges import ShuffleExchangeExec
     from datafusion_distributed_tpu.plan.physical import HashAggregateExec
     from datafusion_distributed_tpu.runtime.mesh_executor import (
         execute_on_mesh,
         make_mesh,
     )
 
-    ctx = _ctx(4000)
+    rng = np.random.default_rng(0)
+    ctx = SessionContext()
+    ctx.register_arrow("t", pa.table({"k": rng.integers(0, 300, 4000),
+                                      "v": rng.normal(size=4000)}))
     df = ctx.sql(
         "select k, sum(v) as sv, count(*) as c, avg(v) as av from t "
         "group by k"
     )
     plan = df.physical_plan()
 
-    off = distribute_plan(plan, DistributedConfig(num_tasks=8))
-    assert not off.collect(
-        lambda n: isinstance(n, HashAggregateExec)
-        and n.mode == "partial_reduce"
-    )
+    def reduces(staged):
+        return staged.collect(
+            lambda n: isinstance(n, HashAggregateExec)
+            and n.mode == "partial_reduce"
+        )
 
-    cfg = DistributedConfig(num_tasks=8, partial_reduce=True)
-    staged = distribute_plan(plan, cfg)
-    reduces = staged.collect(
-        lambda n: isinstance(n, HashAggregateExec)
-        and n.mode == "partial_reduce"
-    )
-    assert reduces, display_staged_plan(staged)
-    # the inserted node sits below a shuffle, above the partial aggregate
-    from datafusion_distributed_tpu.plan.exchanges import ShuffleExchangeExec
+    off = distribute_plan(
+        plan, DistributedConfig(num_tasks=8, partial_agg_pushdown=False))
+    assert not reduces(off), display_staged_plan(off)
 
+    staged = distribute_plan(plan, DistributedConfig(num_tasks=8))
+    assert reduces(staged), display_staged_plan(staged)
+    # the inserted node sits below a shuffle, above the partial aggregate,
+    # in fewer slots than the partial's global table
     shuffles = staged.collect(lambda n: isinstance(n, ShuffleExchangeExec))
     assert any(
         isinstance(s.child, HashAggregateExec)
         and s.child.mode == "partial_reduce"
         and s.child.child.mode == "partial"
+        and s.child.num_slots < s.child.child.num_slots
         for s in shuffles
-    )
+    ), display_staged_plan(staged)
 
     mesh = make_mesh(min(8, len(jax.devices())))
     got = df._strip_quals(execute_on_mesh(staged, mesh)).to_pandas()

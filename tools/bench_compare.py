@@ -105,15 +105,6 @@ _MICRO_DIRECTIONS = {
     "task_p99_ms": False,
     "speedup_vs_static": True,
     "overhead_vs_off": False,
-    # multiway-join fusion / global hash aggregation axes: wall ("ms"
-    # above) plus the measured exchange-byte reduction and the fused-vs-
-    # baseline ratios. exchange_mb lower = fewer bytes crossed a stage
-    # boundary (deleted identity re-shuffles); the *_saved and speedup
-    # axes higher = better.
-    "exchange_mb": False,
-    "exchange_mb_saved": True,
-    "speedup_vs_chain": True,
-    "speedup_vs_merge": True,
 }
 
 
